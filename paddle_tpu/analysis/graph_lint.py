@@ -393,7 +393,7 @@ def _collective_pass(eqn, eqns, i: int, ctx: "_Ctx",
     cc = _cm._collective_cost(eqn, eqns, i, axis_sizes, 1)
     if cc is None:
         return
-    spec = _cm._DEFAULT_SPEC
+    spec = _cm.TARGET_SPEC
     fmt_axes = ",".join(cc.axes)
 
     if "GL011" in cfg.passes and cc.axis_size <= 1:

@@ -45,8 +45,8 @@ def _device(device=None):
 
 
 def memory_stats(device=None) -> Dict[str, int]:
-    """Raw per-device allocator stats (empty dict when the PJRT plugin
-    doesn't report them — e.g. tunneled backends)."""
+    """Raw per-device allocator stats (empty dict when the backend
+    doesn't report them — e.g. the CPU backend)."""
     try:
         stats = _device(device).memory_stats()
     except Exception:

@@ -37,7 +37,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ... import mesh as _mesh
-from ....core import compat as _compat
 
 __all__ = ["scan_blocks", "pipeline_blocks", "stacked_param_sharding"]
 
@@ -205,7 +204,7 @@ def pipeline_blocks(block_fn: Callable, stacked: Sequence, x_micro, *,
 
         # zeros are pp-invariant; the scan carry becomes pp-varying (each
         # stage computes different activations), so pcast the initial carry
-        varying = lambda z: _compat.pcast(z, (pp_axis,), to="varying")  # noqa: E731
+        varying = lambda z: jax.lax.pcast(z, (pp_axis,), to="varying")  # noqa: E731
         # zeros from shape, not zeros_like(x_local[0]): indexing would
         # trace a dead slice+squeeze of the input (GL005)
         state = varying(jnp.zeros(x_local.shape[1:], x_local.dtype))
@@ -268,7 +267,7 @@ def pipeline_blocks(block_fn: Callable, stacked: Sequence, x_micro, *,
         tuple(PartitionSpec(pp_axis, *nd(s)) for s in stacked),
         PartitionSpec(),  # microbatches replicated over pp (dp/sp stay auto)
     )
-    fn = _compat.shard_map(
+    fn = jax.shard_map(
         spmd,
         mesh=mesh,
         in_specs=in_specs,

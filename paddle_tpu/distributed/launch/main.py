@@ -7,6 +7,7 @@ log files per rank, tail-on-failure job/container.py behavior).
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import signal
 import subprocess
@@ -39,6 +40,49 @@ def _parse():
     p.add_argument("script")
     p.add_argument("script_args", nargs=argparse.REMAINDER)
     return p.parse_args()
+
+
+# PCI ids of Google's TPU chips (v2/v3, v4, v5p, v5e, v6e, 7x) — the facts
+# JAX itself reads before it starts a backend
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+_TPU_PCI_DEVICES = {"0x0027", "0x0056", "0x005e", "0x0062", "0x0063",
+                    "0x006f", "0x0076"}
+
+
+def _local_tpu_chips() -> int:
+    """TPU chips on this host's PCI bus.  Read from sysfs, not from JAX:
+    a launcher that started a backend would hold the chips its workers
+    need."""
+    n = 0
+    for vendor in glob.glob("/sys/bus/pci/devices/*/vendor"):
+        try:
+            with open(vendor) as f:
+                if f.read().strip() != _GOOGLE_PCI_VENDOR:
+                    continue
+            with open(os.path.join(os.path.dirname(vendor), "device")) as f:
+                n += f.read().strip() in _TPU_PCI_DEVICES
+        except OSError:
+            continue
+    return n
+
+
+def _check_one_worker_per_tpu_host(nproc: int):
+    """A chip belongs to one process at a time and every worker sees every
+    chip of its host, so on a TPU host a second local worker fails or hangs
+    reaching for chips the first one holds: an error here, not a race
+    there.  Workers pinned off the TPU (``JAX_PLATFORMS`` without ``tpu``)
+    are exempt."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if nproc < 2 or (platforms and "tpu" not in platforms.split(",")):
+        return
+    chips = _local_tpu_chips()
+    if chips:
+        sys.exit(
+            f"launch: --nproc_per_node={nproc} on a host with {chips} TPU "
+            "chip(s): a chip belongs to one process, and each worker would "
+            "reach for all of them.  Run one worker per host (it drives "
+            "every local chip), or pin the workers off the TPU with "
+            "JAX_PLATFORMS=cpu.")
 
 
 def _rendezvous_node_rank(master: str, nnodes: int) -> int:
@@ -74,6 +118,7 @@ _RDZV_STORE = None
 def launch_main(argv=None):
     args = _parse()
     nproc = args.nproc_per_node
+    _check_one_worker_per_tpu_host(nproc)
     world = args.nnodes * nproc
     if str(args.rank) == "auto":
         args.rank = _rendezvous_node_rank(args.master, args.nnodes)

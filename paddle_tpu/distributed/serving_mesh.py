@@ -240,15 +240,18 @@ def shard_paged_cache(cache, mesh):
 # ---------------------------------------------------------------------------
 
 def clone_model(model, model_factory=None):
-    """A fresh model instance with ``model``'s exact weights — each dp
-    replica owns a full copy on its own submesh.  ``model_factory``
-    overrides construction for model classes whose ``__init__`` takes more
-    than the config."""
+    """A fresh model instance with ``model``'s exact weights, dtypes
+    included (the replica of an O2-decorated bf16 model is bf16, not the
+    constructor's fp32) — each dp replica owns a full copy on its own
+    submesh.  ``model_factory`` overrides construction for model classes
+    whose ``__init__`` takes more than the config."""
     if model_factory is not None:
         fresh = model_factory()
     else:
         fresh = type(model)(model.config)
-    fresh.set_state_dict(model.state_dict())
+    src = model.state_dict()
+    for name, t in fresh.state_dict().items():
+        t._set_value(src[name]._value)
     if getattr(model, "training", False):
         fresh.train()
     else:

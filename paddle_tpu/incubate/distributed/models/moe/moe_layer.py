@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .....core import compat as _compat
 from .....distributed import mesh as _mesh
 from .....nn.layer import Layer
 from .....ops import dispatch as _dispatch
@@ -211,7 +210,7 @@ class MoELayer(Layer):
                 overflow = jax.lax.psum(overflow, "ep")
                 return yt.reshape(xr_l.shape), aux, overflow
 
-            return _compat.shard_map(
+            return jax.shard_map(
                 per_shard, mesh=mesh,
                 in_specs=(P("ep"), P("ep"), P("ep"), P("ep"), P("ep"),
                           P("ep")),

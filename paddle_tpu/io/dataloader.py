@@ -17,6 +17,7 @@ import time
 import traceback
 from typing import Any, Callable, Optional
 
+import jax
 import numpy as np
 
 from ..tensor import Tensor, to_tensor
@@ -65,6 +66,26 @@ class _WorkerError:
         self.msg = "".join(traceback.format_exception(exc))
 
 
+def _host_only(obj):
+    """Raise if a worker's sample holds a device array.  The workers are
+    forked from a process whose JAX runtime is live; neither the runtime
+    nor, on a TPU host, its hold on the chip survives a fork, so a worker
+    stays on numpy and the parent alone makes Tensors."""
+    if isinstance(obj, (Tensor, jax.Array)):
+        raise TypeError(
+            "a DataLoader worker produced a device array "
+            f"({type(obj).__name__}); with num_workers > 0 the dataset and "
+            "collate_fn must return numpy — Tensor conversion happens in "
+            "the parent process")
+    if isinstance(obj, (list, tuple)):
+        for o in obj:
+            _host_only(o)
+    elif isinstance(obj, dict):
+        for o in obj.values():
+            _host_only(o)
+    return obj
+
+
 def _worker_loop(dataset, index_queue, data_queue, collate_fn, init_fn, wid):
     """Worker process body (reference: io/dataloader/worker.py _worker_loop).
     Receives (batch_idx, indices); sends (batch_idx, numpy_batch)."""
@@ -80,8 +101,8 @@ def _worker_loop(dataset, index_queue, data_queue, collate_fn, init_fn, wid):
             break
         bidx, indices = item
         try:
-            batch = collate_fn([dataset[i] for i in indices])
-            data_queue.put((bidx, batch))
+            batch = collate_fn([_host_only(dataset[i]) for i in indices])
+            data_queue.put((bidx, _host_only(batch)))
         except BaseException as e:  # noqa: BLE001
             data_queue.put((bidx, _WorkerError(e)))
 

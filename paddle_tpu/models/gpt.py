@@ -189,6 +189,37 @@ def _resolve_use_flash(cfg: GPTConfig) -> bool:
     return bool(_flags.flag("FLAGS_use_pallas_flash_attention"))
 
 
+def _flash_over_mesh(q, k, v, scale):
+    """Causal Pallas flash attention over [B, N, S, D] for the training
+    block.  The compiler cannot partition a Mosaic kernel ("wrap the call
+    in a shard_map"), so under a global mesh the kernel runs per shard —
+    batch over 'dp', heads over 'mp', the layout the column-parallel qkv
+    projection already produces — the way the serving path shards its
+    paged attention (``_raw_attend_paged``).  An axis the mesh lacks, or
+    that does not divide the dim, leaves that dim whole on every chip;
+    with neither axis in play (no mesh, or a pipeline-only one) the call
+    is direct."""
+    from jax.sharding import PartitionSpec as _P
+
+    from ..ops.pallas_kernels.flash_attention import flash_attention_bnsd
+
+    def flash(q_, k_, v_):
+        return flash_attention_bnsd(q_, k_, v_, causal=True, sm_scale=scale)
+
+    mesh = _mesh.get_mesh() if _mesh.has_mesh() else None
+
+    def axis(name, dim):
+        size = dict(mesh.shape).get(name, 1) if mesh is not None else 1
+        return name if size > 1 and dim % size == 0 else None
+
+    dp, mp = axis("dp", q.shape[0]), axis("mp", q.shape[1])
+    if dp is None and mp is None:
+        return flash(q, k, v)
+    spec = _P(dp, mp, None, None)
+    return jax.shard_map(flash, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
 def _ln_f32(x, g, b, eps):
     """fp32 LayerNorm body shared by the train (_block_fn) and decode
     (_cached_block_fn) stacked blocks — one numerics definition.  (Their
@@ -315,8 +346,6 @@ def _raw_attend_paged(qh, kh, vh, pkr, pvr, tables, posr, *, head_dim,
     if mesh is not None and _srv_mesh.mp_size(mesh) > 1:
         from jax.sharding import PartitionSpec as _P
 
-        from ..core.compat import shard_map as _shard_map
-
         n_plan = len(ragged_plan) if ragged_plan is not None else 0
 
         def body(qh_, kh_, vh_, pkr_, pvr_, tbl_, posr_, *rest):
@@ -335,8 +364,8 @@ def _raw_attend_paged(qh, kh, vh, pkr, pvr, tables, posr, *, head_dim,
         hs = _P(None, "mp", None, None)     # head axis of q/k/v and pools
         ss = _P(None, "mp")                 # head axis of the scale bufs
         rep = _P()
-        sm = _shard_map(
-            body, mesh,
+        sm = jax.shard_map(
+            body, mesh=mesh,
             in_specs=(hs, hs, hs, hs, hs, rep, rep)
             + ((ss, ss) if quantized else ()) + (rep,) * n_plan,
             out_specs=(hs, hs, hs) + ((ss, ss) if quantized else ()),
@@ -819,8 +848,7 @@ class GPTStackedDecoder(Layer):
         # derive init keys from the global generator so pt.seed() controls
         # stacked-decoder init like every other layer.  Init runs ON DEVICE
         # (jax.random.normal) — at 1B+ scale, host-side numpy init would
-        # mean multi-GB host->device transfers, which are both slow and, on
-        # tunneled PJRT backends, a reliability hazard.
+        # mean multi-GB host->device transfers.
         from ..ops.random import default_generator
 
         def mk(shape, init="normal"):
@@ -943,13 +971,12 @@ class GPTStackedDecoder(Layer):
             # path inside the kernel); else the XLA expression with fp32
             # softmax.  Both see amp-dtype q/k/v.
             from ..ops.pallas_kernels.flash_attention import (
-                _on_tpu, flash_attention_bnsd, shape_supported,
+                _on_tpu, shape_supported,
             )
 
             if (use_flash and _on_tpu() and not (with_dropout and attn_p > 0.0)
                     and shape_supported(s, hd)):
-                return flash_attention_bnsd(q, k, v, causal=True,
-                                            sm_scale=float(1.0 / np.sqrt(hd)))
+                return _flash_over_mesh(q, k, v, float(1.0 / np.sqrt(hd)))
             scores = jnp.einsum("bnqd,bnkd->bnqk", q, k,
                                 preferred_element_type=jnp.float32)
             scores = scores * float(1.0 / np.sqrt(hd))

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
-from ...core import compat as _compat
 from ...distributed import mesh as _mesh
 
 __all__ = ["ring_attention_raw", "ring_attention"]
@@ -43,7 +42,7 @@ def _block_attend(q, k, v, scale, mask):
 def ring_attention_raw(q, k, v, *, causal=True, axis_name="sp"):
     """Manual-'sp' attention body (call inside shard_map): q/k/v are the
     LOCAL sequence shards [B, s_loc, N, D]."""
-    sp = _compat.axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     b, s_loc, n, d = q.shape
     scale = float(1.0 / (d ** 0.5))
@@ -72,7 +71,7 @@ def ring_attention_raw(q, k, v, *, causal=True, axis_name="sp"):
         # mark pp-invariant zeros as sp-varying for the scan carry; values
         # already derived from sharded inputs are varying and pass through
         try:
-            return _compat.pcast(t, (axis_name,), to="varying")
+            return jax.lax.pcast(t, (axis_name,), to="varying")
         except ValueError:
             return t
 
@@ -104,7 +103,7 @@ def ring_attention(q, k, v, *, causal=True, axis_name="sp"):
         return dispatch.apply(plain, q, k, v, op_name="ring_attention")
 
     spec = PartitionSpec(None, axis_name, None, None)
-    fn = _compat.shard_map(
+    fn = jax.shard_map(
         partial(ring_attention_raw, causal=causal, axis_name=axis_name),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         axis_names=frozenset({axis_name}),

@@ -35,10 +35,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 NEG_INF = np.float32(-1e30)
 
 from .flash_attention import _on_tpu  # noqa: E402  (shared platform gate)
@@ -185,18 +181,26 @@ def _decode_pallas(q, k, v, length, scale, interpret=False, block_kv=None,
     len_arr = jnp.reshape(length, (1,)).astype(jnp.int32)
 
     def kv_index(b, ki, len_ref):
-        last = jnp.maximum((len_ref[0] - 1) // block_kv, 0)
-        return (b, jnp.minimum(ki, last), 0)
+        # lax.div, not `//`: jnp.floor_divide in an index map sends the
+        # Mosaic lowering under x64 into unbounded recursion (truncation
+        # differs from floor only at length 0, which the max() covers)
+        last = jnp.maximum(
+            jax.lax.div(len_ref[0] - 1, np.int32(block_kv)), 0)
+        return (b, jnp.minimum(ki, last), np.int32(0))
+
+    # index maps return int32: Mosaic under x64 rejects i64 (a bare 0)
+    def q_index(b, ki, len_ref):
+        return (b, np.int32(0), np.int32(0))
 
     in_specs = [
-        pl.BlockSpec((1, qr, d), lambda b, ki, len_ref: (b, 0, 0)),
+        pl.BlockSpec((1, qr, d), q_index),
         pl.BlockSpec((1, block_kv, d), kv_index),
         pl.BlockSpec((1, block_kv, d), kv_index),
     ]
     operands = [q, k, v]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1),
-                                  lambda b, ki, len_ref: (b, 0))] * 2
+        in_specs += [pl.BlockSpec(
+            (1, 1), lambda b, ki, len_ref: (b, np.int32(0)))] * 2
         operands += [k_scale.reshape(bh, 1).astype(jnp.float32),
                      v_scale.reshape(bh, 1).astype(jnp.float32)]
 
@@ -204,7 +208,7 @@ def _decode_pallas(q, k, v, length, scale, interpret=False, block_kv=None,
         num_scalar_prefetch=1,
         grid=(bh, n_kv),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, qr, d), lambda b, ki, len_ref: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, qr, d), q_index),
         scratch_shapes=[
             pltpu.VMEM((qr, d), jnp.float32),
             pltpu.VMEM((qr, 128), jnp.float32),
@@ -215,7 +219,7 @@ def _decode_pallas(q, k, v, length, scale, interpret=False, block_kv=None,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, qr, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -36,10 +36,7 @@ import numpy as np
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 def shape_unsupported_reason(seq_len: int, head_dim: int):
@@ -80,7 +77,7 @@ def _dot(a, b, dims):
     "Bad lhs type".  The accumulator is fp32 via preferred_element_type, so
     DEFAULT loses nothing there.  For fp32 operands, DEFAULT would let the
     MXU round inputs through bf16 passes — select HIGHEST so an fp32 call
-    keeps full fp32 contraction (ADVICE round 5)."""
+    keeps full fp32 contraction."""
     fp32 = (jnp.dtype(a.dtype) == jnp.float32
             and jnp.dtype(b.dtype) == jnp.float32)
     return jax.lax.dot_general(

@@ -43,7 +43,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .decode_attention import NEG_INF, _CompilerParams, _dot
+from .decode_attention import NEG_INF, _dot
 from .flash_attention import _on_tpu
 
 __all__ = [
@@ -162,7 +162,7 @@ def _paged_pallas(q, k_pool, v_pool, page_tables, lengths, scale,
         slot = sh // h
         last = jnp.maximum((len_ref[slot] - 1) // page_size, 0)
         page = pt_ref[slot * max_pages + jnp.minimum(pi, last)]
-        return (page, sh % h, 0, 0)
+        return (page, sh % h, np.int32(0), np.int32(0))
 
     def scale_index(sh, pi, pt_ref, len_ref):
         slot = sh // h
@@ -170,8 +170,12 @@ def _paged_pallas(q, k_pool, v_pool, page_tables, lengths, scale,
         page = pt_ref[slot * max_pages + jnp.minimum(pi, last)]
         return (page, sh % h)
 
+    # index maps return int32: Mosaic under x64 rejects i64 (a bare 0)
+    def q_index(sh, pi, pt_ref, len_ref):
+        return (sh, np.int32(0), np.int32(0))
+
     in_specs = [
-        pl.BlockSpec((1, qr, d), lambda sh, pi, pt_ref, len_ref: (sh, 0, 0)),
+        pl.BlockSpec((1, qr, d), q_index),
         pl.BlockSpec((1, 1, page_size, d), kv_index),
         pl.BlockSpec((1, 1, page_size, d), kv_index),
     ]
@@ -184,8 +188,7 @@ def _paged_pallas(q, k_pool, v_pool, page_tables, lengths, scale,
         num_scalar_prefetch=2,
         grid=(s * h, max_pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, qr, d),
-                               lambda sh, pi, pt_ref, len_ref: (sh, 0, 0)),
+        out_specs=pl.BlockSpec((1, qr, d), q_index),
         scratch_shapes=[
             pltpu.VMEM((qr, d), jnp.float32),
             pltpu.VMEM((qr, 128), jnp.float32),
@@ -196,7 +199,7 @@ def _paged_pallas(q, k_pool, v_pool, page_tables, lengths, scale,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s * h, qr, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

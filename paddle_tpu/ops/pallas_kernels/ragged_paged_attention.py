@@ -51,7 +51,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .decode_attention import NEG_INF, _CompilerParams, _dot
+from .decode_attention import NEG_INF, _dot
 from .flash_attention import _on_tpu
 
 __all__ = [
@@ -331,11 +331,11 @@ def _ragged_pallas(q_blocks, k_pool, v_pool, wl_blk, wl_page, wl_ps,
 
     def q_index(hh, w, blk_ref, page_ref, ps_ref, ni_ref, base_ref,
                 rows_ref):
-        return (blk_ref[w], hh, 0, 0)
+        return (blk_ref[w], hh, np.int32(0), np.int32(0))
 
     def kv_index(hh, w, blk_ref, page_ref, ps_ref, ni_ref, base_ref,
                  rows_ref):
-        return (page_ref[w], hh, 0, 0)
+        return (page_ref[w], hh, np.int32(0), np.int32(0))
 
     def scale_index(hh, w, blk_ref, page_ref, ps_ref, ni_ref, base_ref,
                     rows_ref):
@@ -366,7 +366,7 @@ def _ragged_pallas(q_blocks, k_pool, v_pool, wl_blk, wl_page, wl_ps,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nb, h, qb, d), q_blocks.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -196,6 +196,9 @@ class FusedTrainStep:
         """Compiled-program executions to date."""
         return self._step_fn.dispatch_count
 
+    def lowered_texts(self):
+        return self._step_fn.lowered_texts()
+
     def lint_reports(self):
         return self._step_fn.lint_reports()
 

@@ -227,25 +227,31 @@ class AdamW(Adam):
                           else getattr(weight_decay, "_coeff", 0.01))
         self._apply_decay_fun = apply_decay_param_fun
         self._lr_ratio = lr_ratio
+        if use_fused_kernel:
+            from ..ops.pallas_kernels.flash_attention import _on_tpu
+
+            if not _on_tpu():
+                raise RuntimeError(
+                    "AdamW(use_fused_kernel=True) runs a Pallas TPU kernel "
+                    "and JAX's default backend is not a TPU; the "
+                    "XLA-composed update (use_fused_kernel=False) runs "
+                    "anywhere")
         self._use_fused_kernel = use_fused_kernel
         super().__init__(learning_rate, beta1, beta2, epsilon, parameters,
                          None, grad_clip, lazy_mode, multi_precision, name)
 
     def _apply_fused(self, p, g, lr, decay):
-        import jax as _jax
-
         from ..ops.pallas_kernels.fused_adamw import fused_adamw_update
 
         m1 = self._get_accumulator("moment1", p)
         m2 = self._get_accumulator("moment2", p)
         dispatch.note_read(m1)
         dispatch.note_read(m2)
-        interp = _jax.devices()[0].platform != "tpu"
         new_p, new_m1, new_m2 = fused_adamw_update(
             p._value, g._value, m1._value, m2._value,
             lr, self._aux_state[0]._value, self._aux_state[1]._value,
             beta1=self._beta1, beta2=self._beta2, eps=self._epsilon,
-            wd=(self._wd_coeff if decay else 0.0), interpret=interp)
+            wd=(self._wd_coeff if decay else 0.0))
         m1._set_value(new_m1)
         m2._set_value(new_m2)
         self._write_param(p, new_p)

@@ -90,6 +90,7 @@ from .engine import (  # noqa: F401
     SamplingParams,
     ServingEngine,
     ServingError,
+    StepBuildError,
     StepStalledError,
     serve_trace_counts,
     reset_serve_trace_counts,
@@ -134,7 +135,7 @@ __all__ = [
     "random_adapter",
     "serve_trace_counts", "reset_serve_trace_counts",
     "ServingError", "Overloaded", "DeadlineExceeded", "RequestCancelled",
-    "StepStalledError", "NaNLogitsError",
+    "StepStalledError", "StepBuildError", "NaNLogitsError",
     "FaultInjector", "FaultPlan", "InjectedFault", "random_schedule",
     "random_transfer_schedule",
     "DisaggServingEngine", "DisaggElasticController", "RolePlacement",

@@ -134,6 +134,13 @@ class StepStalledError(ServingError):
     """A supervised step exceeded the watchdog's stall budget."""
 
 
+class StepBuildError(ServingError):
+    """The fused step failed the first time its variant was ever
+    dispatched — a kernel the compiler refuses, a pool that does not fit.
+    A build failure, not a request failure: it escapes the containment
+    boundary instead of leaving a server that answers nothing."""
+
+
 class NaNLogitsError(ServingError):
     """The finiteness sentry caught non-finite logits for this slot."""
 
@@ -1024,6 +1031,8 @@ class ServingEngine:
         except StepStalledError as e:
             self._recover(e, rebuild=True, stalled=True)
             out = None
+        except StepBuildError:
+            raise
         except Exception as e:  # noqa: BLE001 — containment boundary
             self._recover(e, rebuild=not _state_intact(e))
             out = None
@@ -1081,12 +1090,17 @@ class ServingEngine:
         fused = (self._fused_sample if self._do_sample.any()
                  else self._fused_greedy)
         budget = self._budget_for([fused])
+        never_ran = not fused.code_cache
         thunk = lambda cancelled: self._fused_thunk(fused, inputs, cancelled)  # noqa: E731,E501
         try:
             toks, fin, built = self._supervised(thunk, budget)
         except StepStalledError:
             raise
-        except Exception:  # noqa: BLE001 — transient device errors retry once
+        except Exception as e:  # noqa: BLE001 — transient device errors retry once
+            if never_ran:
+                raise StepBuildError(
+                    f"{fused.__name__} failed on its first dispatch: "
+                    f"{type(e).__name__}: {e}") from e
             self._totals["step_retries"] += 1
             toks, fin, built = self._supervised(thunk, budget)
         if built is not None:
@@ -1891,6 +1905,11 @@ class ServingEngine:
     @property
     def compiled_programs(self) -> int:
         return sum(len(f.code_cache) for f in self._static_fns)
+
+    def lowered_texts(self):
+        """StableHLO text of the compiled fused-step programs (Mosaic
+        custom calls included)."""
+        return [t for f in self._static_fns for t in f.lowered_texts()]
 
     def lint_reports(self):
         """Graph-lint reports of the compiled fused-step programs

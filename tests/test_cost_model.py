@@ -181,7 +181,11 @@ def test_chip_spec_resolution():
     assert cm.chip_spec("TPU v5p").name == "v5p"
     assert cm.chip_spec("", "TPU v4").name == "v4"
     assert cm.chip_spec("v6e").peak_flops == 918e12
-    assert cm.chip_spec("mystery-chip").name == "v5e"  # default
+    with pytest.raises(ValueError, match="mystery-chip"):
+        cm.chip_spec("mystery-chip")   # an unknown device is an error
+    with pytest.raises(ValueError):
+        cm.chip_spec("", "cpu")
+    assert cm.TARGET_SPEC.name == "v5e"  # the named static-analysis target
     spec = cm.chip_spec("v4")
     assert spec.ridge == pytest.approx(275e12 / 1228e9)
     # attainable clamps at the compute roof past the ridge
@@ -677,10 +681,8 @@ def _axis_mesh(n, name="dp"):
 
 
 def _comm_rep(body, mesh, in_specs, out_specs, *args):
-    from paddle_tpu.core import compat as compat_mod
-
-    fn = compat_mod.shard_map(body, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return analysis.cost(fn, *args)
 
 

@@ -62,10 +62,29 @@ def test_fused_adamw_matches_composed(shape, dtype):
     assert got_p.shape == in_shape and got_p.dtype == in_dtype
 
 
+def test_optimizer_fused_needs_tpu():
+    """Off-TPU the fused route raises at construction instead of running
+    the Mosaic kernel through the interpreter without saying so."""
+    import paddle_tpu as pt
+
+    w = pt.to_tensor(np.zeros((4, 4), np.float32))
+    with pytest.raises(RuntimeError, match="not a TPU"):
+        pt.optimizer.AdamW(parameters=[w], use_fused_kernel=True)
+
+
 def test_optimizer_routes_fused(monkeypatch):
     """AdamW(use_fused_kernel=True) without master weights must produce
-    the same update as the composed path."""
+    the same update as the composed path (the platform gate opened and
+    the kernel interpreted, so the routing itself runs on the CPU)."""
+    import functools
+
     import paddle_tpu as pt
+    from paddle_tpu.ops.pallas_kernels import flash_attention, fused_adamw
+
+    monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(
+        fused_adamw, "fused_adamw_update",
+        functools.partial(fused_adamw.fused_adamw_update, interpret=True))
 
     rng = np.random.RandomState(1)
     w0 = rng.randn(16, 32).astype(np.float32)

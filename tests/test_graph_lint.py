@@ -458,13 +458,11 @@ def _axis_mesh(n, name="dp"):
 
 
 def _shmap(body, mesh, in_specs, out_specs):
-    from paddle_tpu.core import compat as _compat
-
     # check_vma off: the toy bodies reduce dp-varying values locally on
     # purpose (the lint passes care about the collectives, not the rep
-    # typing), and the plain-psum binding keeps the test jax-version-stable
-    return _compat.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
+    # typing)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def test_gl008_unoverlapped_collective_flagged():

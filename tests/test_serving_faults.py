@@ -249,9 +249,36 @@ def test_non_intact_crash_rebuilds_pool_and_keeps_serving(served):
     assert eng.allocator.used_pages == 0
 
 
+def _warm(eng, prompts):
+    """Run one request to completion so the fused step has compiled and
+    run: a fault on a step that has NEVER run is a build failure and
+    propagates (test_first_dispatch_failure_propagates)."""
+    w = eng.submit(prompts[0], 2)
+    eng.run_until_idle()
+    assert w.finished
+
+
+def test_first_dispatch_failure_propagates(served):
+    """A step variant that fails the first time it is ever dispatched has
+    not been built — a kernel the compiler refuses would look like this.
+    The error escapes containment instead of leaving a server that exits
+    clean having answered nothing but FAILED requests."""
+    m, cfg, prompts, refs = served
+    eng = _engine(m)
+    FaultInjector().inject("before_decode", at=0,
+                           kind="step_exception").install(eng)
+    reqs = [eng.submit(p, N_NEW) for p in prompts[:2]]
+    with pytest.raises(serving.StepBuildError, match="first dispatch"):
+        eng.step()
+    assert not any(r.state == RequestState.FAILED for r in reqs)
+    mt = eng.metrics()
+    assert mt["recoveries"] == 0 and mt["step_retries"] == 0
+
+
 def test_recovery_arms_readmission_backoff(served):
     m, cfg, prompts, refs = served
     eng = _engine(m, readmission_backoff_s=0.2)
+    _warm(eng, prompts)
     FaultInjector().inject("before_decode", at=0, times=2,
                            kind="step_exception").install(eng)
     reqs = [eng.submit(p, N_NEW) for p in prompts[:4]]
@@ -499,6 +526,7 @@ def test_randomized_fault_schedule_with_prefix_cache(served, seed):
 def test_generate_batch_raises_on_failed_requests(served):
     m, cfg, prompts, refs = served
     eng = _engine(m)
+    _warm(eng, prompts)
     FaultInjector().inject("before_decode", at=0, times=2,
                            kind="step_exception").install(eng)
     with pytest.raises(serving.ServingError, match="did not complete"):
@@ -506,6 +534,7 @@ def test_generate_batch_raises_on_failed_requests(served):
     assert eng.allocator.used_pages == 0
     # opt-out returns whatever each request produced, states inspectable
     eng2 = _engine(m)
+    _warm(eng2, prompts)
     FaultInjector().inject("before_decode", at=0, times=2,
                            kind="step_exception").install(eng2)
     outs = eng2.generate_batch(prompts[:2], N_NEW, raise_on_failure=False)

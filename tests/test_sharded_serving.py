@@ -420,7 +420,6 @@ def test_cost_model_scales_shard_map_by_shard_count():
     from jax.sharding import Mesh, PartitionSpec as P
 
     from paddle_tpu.analysis.cost_model import cost, cost_jaxpr
-    from paddle_tpu.core.compat import shard_map
 
     devs = jax.devices()
     mesh = Mesh(np.array(devs[:2]), ("mp",))
@@ -428,8 +427,9 @@ def test_cost_model_scales_shard_map_by_shard_count():
     def body(x, w):
         return x @ w
 
-    f = shard_map(body, mesh, in_specs=(P("mp", None), P(None, None)),
-                  out_specs=P("mp", None), check_vma=False)
+    f = jax.shard_map(body, mesh=mesh,
+                      in_specs=(P("mp", None), P(None, None)),
+                      out_specs=P("mp", None), check_vma=False)
     x = jnp.ones((8, 16), jnp.float32)
     w = jnp.ones((16, 4), jnp.float32)
     closed = jax.make_jaxpr(f)(x, w)
@@ -447,15 +447,15 @@ def test_graph_lint_walks_shard_map_without_crashing():
 
     import jax
     from paddle_tpu import analysis
-    from paddle_tpu.core.compat import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:2]), ("mp",))
 
     def body(x, w):
         return x @ w.astype(jnp.float32)    # GL001 bait INSIDE the body
 
-    f = shard_map(body, mesh, in_specs=(P("mp", None), P(None, None)),
-                  out_specs=P("mp", None), check_vma=False)
+    f = jax.shard_map(body, mesh=mesh,
+                      in_specs=(P("mp", None), P(None, None)),
+                      out_specs=P("mp", None), check_vma=False)
     rep = analysis.lint(lambda x, w: f(x, w),
                         jnp.ones((8, 16), jnp.float32),
                         jnp.ones((16, 4), jnp.bfloat16))

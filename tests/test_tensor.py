@@ -153,6 +153,29 @@ class TestDataLoaderWorkers:
         with pytest.raises(RuntimeError, match="worker failed"):
             list(dl)
 
+    def test_worker_device_array_rejected(self):
+        """Workers are forked from a process whose JAX runtime is live:
+        they stay on numpy, and a sample that holds a device array is an
+        error, not a fork-unsafe touch of the device."""
+        import numpy as np
+        import pytest
+        import paddle_tpu as pt
+        from paddle_tpu.io import DataLoader, Dataset
+
+        class OnDevice(Dataset):
+            def __init__(self):
+                self.sample = pt.to_tensor(np.zeros(2, np.float32))
+
+            def __len__(self):
+                return 4
+
+            def __getitem__(self, i):
+                return self.sample      # made in the parent, before the fork
+
+        dl = DataLoader(OnDevice(), batch_size=2, num_workers=2)
+        with pytest.raises(RuntimeError, match="device array"):
+            list(dl)
+
     def test_worker_init_fn_called(self):
         import numpy as np
         from paddle_tpu.io import DataLoader, Dataset

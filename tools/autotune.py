@@ -345,6 +345,10 @@ def run(argv=None) -> int:
     import jax
 
     on_cpu = jax.devices()[0].platform == "cpu"
+    if not on_cpu:
+        from paddle_tpu.sysconfig import enable_compile_cache
+
+        enable_compile_cache()
     if args.train_sweep:
         device = ("cpu" if on_cpu
                   else getattr(jax.devices()[0], "device_kind", "tpu"))

@@ -1425,6 +1425,14 @@ def main() -> int:
                          "gain dp/mp/aggregate tokens/s, per-replica "
                          "occupancy and per-chip pool bytes")
     args = ap.parse_args()
+    import jax
+
+    if jax.devices()[0].platform == "tpu":
+        # on the chip only: the CPU gates must not fill the directory the
+        # chip tool copies with XLA:CPU executables
+        from paddle_tpu.sysconfig import enable_compile_cache
+
+        enable_compile_cache()
     if args.gate:
         return gate()
     if args.chaos:

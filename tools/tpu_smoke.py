@@ -18,9 +18,14 @@ Exit codes (tri-state — CI wrappers must NOT treat 2 as a failure):
 """
 from __future__ import annotations
 
+import os
 import sys
 
 import numpy as np
+
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if _REPO_ROOT not in sys.path:
+    sys.path.insert(0, _REPO_ROOT)
 
 
 def main() -> int:
@@ -30,6 +35,10 @@ def main() -> int:
     if jax.devices()[0].platform == "cpu":
         print("tpu_smoke: no TPU backend; nothing to smoke-test")
         return 2
+
+    from paddle_tpu.sysconfig import enable_compile_cache
+
+    enable_compile_cache()
 
     failures = []
 
@@ -288,7 +297,6 @@ def main() -> int:
         from jax.sharding import Mesh, PartitionSpec as P
 
         from paddle_tpu import analysis
-        from paddle_tpu.core import compat as _compat
 
         devs = jax.devices()
         if len(devs) < 2:
@@ -301,7 +309,7 @@ def main() -> int:
             m2 = 0.9 * m + g
             return (w - 1e-3 * m2).astype(w.dtype), m2
 
-        fn = _compat.shard_map(
+        fn = jax.shard_map(
             step, mesh=mesh, in_specs=(P("dp", None), P(), P()),
             out_specs=(P(), P()))
         x = jnp.zeros((256, 1024), jnp.bfloat16)
@@ -682,12 +690,6 @@ def main() -> int:
     # sockets, heartbeats, generation rendezvous); each pins its own
     # backend to CPU so three processes don't contend for the chip ------
     def dist_fault():
-        import os
-        import sys as _sys
-
-        _repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        if _repo not in _sys.path:
-            _sys.path.insert(0, _repo)
         from tools import dist_fault_gate
 
         assert dist_fault_gate.scenario_kill_rank(verbose=False), \
