@@ -1,0 +1,1 @@
+"""Part of the benchmark; see ``benchmark/__init__.py``."""
