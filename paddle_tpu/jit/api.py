@@ -125,6 +125,7 @@ class _CompiledEntry:
         "lint_report",
         "cost_report",
         "span_args",
+        "__weakref__",      # the tracer remembers dispatched entries weakly
     )
 
     def __init__(self):
@@ -205,6 +206,50 @@ def _span_args(entry) -> dict:
                     pass
         entry.span_args = a
     return a
+
+
+def _lowered(entry: _CompiledEntry):
+    return entry.jitted.lower(
+        entry.arg_structs,
+        [_lowering_struct(t) for t in entry.mut_caps],
+        [_lowering_struct(t) for t in entry.ro_caps])
+
+
+def _compiled_text(lowered) -> str:
+    """The optimized HLO text of ``lowered`` with THIS build's ``op_name``s.
+    The executable that runs may carry another build's: jax leaves metadata
+    out of the persistent compile cache's key, so a cache filled before a
+    scope was added or renamed hands the old text back.  So compile anew:
+    a compiler option (this one at XLA's default) makes jax pass over the
+    executable it holds, and for this one compile the cache's key takes
+    the metadata in.  Metadata changes no instruction: the names are those
+    of the program that runs.  A miss is a whole compile, a second ask of
+    the same build a read of the cache."""
+    name = "jax_compilation_cache_include_metadata_in_key"
+    was = getattr(jax.config, name)
+    jax.config.update(name, True)
+    try:
+        return lowered.compile(compiler_options={"xla_dump_to": ""}).as_text()
+    finally:
+        jax.config.update(name, was)
+
+
+def _entry_op_scopes(entry: _CompiledEntry) -> Dict[str, Any]:
+    from .. import sysconfig
+    from ..telemetry import scopes as _scopes
+
+    lowered = _lowered(entry)
+    found = _scopes.scopes_of_hlo_text(_compiled_text(lowered))
+    if (not any(s.rule == "own" for s in found.values())
+            and _scopes.has_scopes(lowered.as_text(debug_info=True))):
+        # what _compiled_text guards against, should a cache hand it back
+        # all the same
+        raise RuntimeError(
+            "the program has named scopes and its compiled text has none: "
+            "the executable came from a compile cache filled by a build "
+            "without them (the cache's key leaves metadata out); clear "
+            f"{sysconfig.compile_cache_dir()!r} and run again")
+    return found
 
 
 class StaticFunction:
@@ -297,12 +342,18 @@ class StaticFunction:
             entry = self._scout_and_compile(key, args, kwargs, arg_tensors)
             # scout call already produced results eagerly
             return entry._scout_result
-        if _ttrace._tracer is not None:
+        tracer = _ttrace._tracer
+        if tracer is not None:
             # telemetry span per compiled dispatch, carrying the program's
             # static CostReport digest (when FLAGS_graph_cost was on at
             # compile) so the exported trace shows measured-vs-roofline
-            # per fused step.  Disabled path: ONE module-global read.
-            with _ttrace.span(self._span_name(), **_span_args(entry)):
+            # per fused step.  The tracer also keeps the entry, so that
+            # Tracer.program_scopes() can map the device trace's operations
+            # to scopes AFTER the window.  Disabled path: ONE module-global
+            # read.
+            name = self._span_name()
+            tracer.saw_program(name, entry, _entry_op_scopes)
+            with _ttrace.span(name, **_span_args(entry)):
                 return self._run_compiled(entry, arg_tensors)
         return self._run_compiled(entry, arg_tensors)
 
@@ -712,15 +763,25 @@ class StaticFunction:
                     f"[paddle_tpu.graph_cost] cost of '{name}' failed: "
                     f"{type(e).__name__}: {e}\n")
 
+    def _entries(self) -> List[_CompiledEntry]:
+        return [e for e in self._cache.values() if e.jitted is not None]
+
     def lowered_texts(self) -> List[str]:
         """StableHLO text of every compiled entry — the program XLA was
-        handed, Mosaic custom calls included.  Lowers from jit's cached
-        trace: no compile, nothing runs."""
-        return [e.jitted.lower(e.arg_structs,
-                               [_lowering_struct(t) for t in e.mut_caps],
-                               [_lowering_struct(t) for t in e.ro_caps]
-                               ).as_text()
-                for e in self._cache.values() if e.jitted is not None]
+        handed, Mosaic custom calls included, each operation with the
+        ``named_scope`` path that made it (``loc("jit(..)/train.forward/
+        ..")``).  Lowers from jit's cached trace: no compile, nothing
+        runs."""
+        return [_lowered(e).as_text(debug_info=True) for e in self._entries()]
+
+    def op_scopes(self) -> List[Dict[str, Any]]:
+        """For every compiled entry, the OPTIMIZED program's instructions by
+        name (what a device trace prints after ``%``: ``copy.117``,
+        ``fusion.375``, ``all-reduce.41``), each with the
+        ``telemetry.scopes.OpScope`` it falls under.  Compiles each entry's
+        text (a read of the compile cache where one is on): seconds for a
+        large step, so never inside one."""
+        return [_entry_op_scopes(e) for e in self._entries()]
 
     def lint_reports(self):
         """LintReports of every compiled entry (FLAGS_graph_lint runs)."""

@@ -216,8 +216,9 @@ def _flash_over_mesh(q, k, v, scale):
     if dp is None and mp is None:
         return flash(q, k, v)
     spec = _P(dp, mp, None, None)
-    return jax.shard_map(flash, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_vma=False)(q, k, v)
+    with jax.named_scope("shard.flash"):
+        return jax.shard_map(flash, mesh=mesh, in_specs=(spec, spec, spec),
+                             out_specs=spec, check_vma=False)(q, k, v)
 
 
 def _ln_f32(x, g, b, eps):
@@ -427,25 +428,27 @@ def _attend_paged_shard(qh, kh, vh, pkr, pvr, tables, posr, *, head_dim,
     tbl = tables.astype(jnp.int32)
     abs_pos = pos[:, None] + jax.lax.broadcasted_iota(
         jnp.int32, (s_, c), 1)                               # [S, C]
-    # the clip is defensive: the engine reserves every page a request can
-    # touch up front, so real token positions never run past the table
-    page_slot = jnp.clip(abs_pos // page_size, 0, max_pages - 1)
-    page_ids = jnp.take_along_axis(tbl, page_slot, axis=1)   # [S, C]
-    offs = abs_pos % page_size
-    kq = jnp.transpose(kh, (0, 2, 1, 3))                     # [S, C, N, D]
-    vq = jnp.transpose(vh, (0, 2, 1, 3))
-    if quantized:
-        # int8 pools: quantize the fresh rows in-graph and update the
-        # per-(page, head) scale buffers before the scatter
-        from ..quantization.kv import quantize_kv_write
+    with jax.named_scope("attn.pool_write"):
+        # the clip is defensive: the engine reserves every page a request
+        # can touch up front, so real token positions never run past the
+        # table
+        page_slot = jnp.clip(abs_pos // page_size, 0, max_pages - 1)
+        page_ids = jnp.take_along_axis(tbl, page_slot, axis=1)  # [S, C]
+        offs = abs_pos % page_size
+        kq = jnp.transpose(kh, (0, 2, 1, 3))                 # [S, C, N, D]
+        vq = jnp.transpose(vh, (0, 2, 1, 3))
+        if quantized:
+            # int8 pools: quantize the fresh rows in-graph and update the
+            # per-(page, head) scale buffers before the scatter
+            from ..quantization.kv import quantize_kv_write
 
-        kq, ks2 = quantize_kv_write(kq, page_ids, offs, ksr)
-        vq, vs2 = quantize_kv_write(vq, page_ids, offs, vsr)
-    else:
-        ks2 = vs2 = None
-    # advanced indices split by the head slice: result dims [S, C, N, D]
-    pk2 = pkr.at[page_ids, :, offs, :].set(kq.astype(pkr.dtype))
-    pv2 = pvr.at[page_ids, :, offs, :].set(vq.astype(pvr.dtype))
+            kq, ks2 = quantize_kv_write(kq, page_ids, offs, ksr)
+            vq, vs2 = quantize_kv_write(vq, page_ids, offs, vsr)
+        else:
+            ks2 = vs2 = None
+        # advanced indices split by the head slice: result dims [S, C, N, D]
+        pk2 = pkr.at[page_ids, :, offs, :].set(kq.astype(pkr.dtype))
+        pv2 = pvr.at[page_ids, :, offs, :].set(vq.astype(pvr.dtype))
     if c == 1 and ragged_plan is not None:
         out = ragged_paged_attention(qh[:, :, 0, :], pk2, pv2, tbl,
                                      pos + 1, ragged_plan, sm_scale=scale,
@@ -531,13 +534,14 @@ class GPTEmbeddings(Layer):
         self._cfg = cfg
 
     def forward(self, input_ids: Tensor, position_ids: Optional[Tensor] = None) -> Tensor:
-        if position_ids is None:
-            seq_len = input_ids.shape[-1]
-            position_ids = ops.arange(0, seq_len, dtype="int64")
-            position_ids = ops.expand(ops.unsqueeze(position_ids, 0), list(input_ids.shape))
-        h = self.word_embeddings(input_ids) + self.position_embeddings(position_ids)
-        h = self.dropout(h)
-        return _seq_shard(h, self._cfg)
+        with jax.named_scope("embed"):
+            if position_ids is None:
+                seq_len = input_ids.shape[-1]
+                position_ids = ops.arange(0, seq_len, dtype="int64")
+                position_ids = ops.expand(ops.unsqueeze(position_ids, 0), list(input_ids.shape))
+            h = self.word_embeddings(input_ids) + self.position_embeddings(position_ids)
+            h = self.dropout(h)
+            return _seq_shard(h, self._cfg)
 
 
 class GPTAttention(Layer):
@@ -566,17 +570,36 @@ class GPTAttention(Layer):
         cfg = self._cfg
         b, s = x.shape[0], x.shape[1]
         nh, hd = cfg.num_heads, cfg.head_dim
-        qkv = self.qkv_proj(x)                              # [B, S, 3H]
-        if lora is not None:
-            # per-token gathered low-rank delta on the SAME input as the
-            # base projection (serving/lora.py; slabs[0:2] = qkv A/B)
-            slabs, ids, lscale = lora
-            qkv = qkv + ops.gathered_lora_matmul(x, slabs[0], slabs[1],
-                                                 ids, lscale)
-        qkv = ops.reshape(qkv, [b, s, 3, nh, hd])
-        q = ops.squeeze(ops.slice(qkv, [2], [0], [1]), 2)   # [B, S, nh, hd]
-        k = ops.squeeze(ops.slice(qkv, [2], [1], [2]), 2)
-        v = ops.squeeze(ops.slice(qkv, [2], [2], [3]), 2)
+        with jax.named_scope("attn.qkv"):
+            qkv = self.qkv_proj(x)                          # [B, S, 3H]
+            if lora is not None:
+                # per-token gathered low-rank delta on the SAME input as
+                # the base projection (serving/lora.py; slabs[0:2] = qkv
+                # A/B)
+                slabs, ids, lscale = lora
+                qkv = qkv + ops.gathered_lora_matmul(x, slabs[0], slabs[1],
+                                                     ids, lscale)
+            qkv = ops.reshape(qkv, [b, s, 3, nh, hd])
+            q = ops.squeeze(ops.slice(qkv, [2], [0], [1]), 2)  # [B, S, nh, hd]
+            k = ops.squeeze(ops.slice(qkv, [2], [1], [2]), 2)
+            v = ops.squeeze(ops.slice(qkv, [2], [2], [3]), 2)
+        with jax.named_scope("attn.core"):
+            out = self._attend(q, k, v, attn_mask, layer_kv, cache_index,
+                               page_tables, ragged_plan, lora)
+        with jax.named_scope("attn.out"):
+            out = ops.reshape(out, [b, s, nh * hd])
+            proj = self.out_proj(out)
+            if lora is not None:
+                slabs, ids, lscale = lora
+                proj = proj + ops.gathered_lora_matmul(out, slabs[2],
+                                                       slabs[3], ids, lscale)
+            return self.dropout(proj)
+
+    def _attend(self, q, k, v, attn_mask, layer_kv, cache_index,
+                page_tables, ragged_plan, lora) -> Tensor:
+        """q/k/v [B, S, nh, hd] -> [B, S, nh, hd]: the cache write and the
+        attention itself, whichever path the arguments select."""
+        cfg = self._cfg
         if layer_kv is not None:
             # serving path: write K/V into the preallocated cache at
             # cache_index, attend over it (q-len-1 flash-decode kernel for
@@ -627,13 +650,7 @@ class GPTAttention(Layer):
                 training=self.training,
                 use_flash=cfg.use_flash_attention,
             )                                               # [B, S, nh, hd]
-        out = ops.reshape(out, [b, s, nh * hd])
-        proj = self.out_proj(out)
-        if lora is not None:
-            slabs, ids, lscale = lora
-            proj = proj + ops.gathered_lora_matmul(out, slabs[2], slabs[3],
-                                                   ids, lscale)
-        return self.dropout(proj)
+        return out
 
 
 class GPTMLP(Layer):
@@ -677,14 +694,20 @@ class GPTDecoderLayer(Layer):
                 layer_kv=None, cache_index=None,
                 page_tables: Optional[Tensor] = None,
                 ragged_plan=None, lora=None) -> Tensor:
-        x = x + self.attn(self.ln1(x), attn_mask, layer_kv=layer_kv,
-                          cache_index=cache_index, page_tables=page_tables,
-                          ragged_plan=ragged_plan, lora=lora)
-        # pass lora only when active: subclasses swap self.mlp for layers
-        # with plain forward(x) signatures (ernie_moe's MoELayer)
-        h = self.ln2(x)
-        x = x + (self.mlp(h, lora=lora) if lora is not None else self.mlp(h))
-        return _seq_shard(x, self._cfg)
+        with jax.named_scope("attn.qkv"):
+            h = self.ln1(x)
+        a = self.attn(h, attn_mask, layer_kv=layer_kv,
+                      cache_index=cache_index, page_tables=page_tables,
+                      ragged_plan=ragged_plan, lora=lora)
+        with jax.named_scope("attn.out"):
+            x = x + a
+        with jax.named_scope("mlp"):
+            # pass lora only when active: subclasses swap self.mlp for
+            # layers with plain forward(x) signatures (ernie_moe's MoELayer)
+            h = self.ln2(x)
+            x = x + (self.mlp(h, lora=lora) if lora is not None
+                     else self.mlp(h))
+            return _seq_shard(x, self._cfg)
 
 
 class GPTModel(Layer):
@@ -709,16 +732,17 @@ class GPTModel(Layer):
             raise ValueError("a paged KV cache needs page_tables "
                              "([B, max_pages] int32 pool page ids)")
         pos = _as_pos(cache_index) if kv_cache is not None else None
-        if kv_cache is not None and position_ids is None:
-            position_ids = _cache_position_ids(input_ids, pos)
-            if paged:
-                # prefill padding may carry positions past the table; the
-                # write already sinks them into the null page — keep the
-                # embedding lookup in range too
-                position_ids = ops.clip(
-                    position_ids, min=0,
-                    max=self.config.max_position_embeddings - 1)
-        h = self.embeddings(input_ids, position_ids)
+        with jax.named_scope("embed"):
+            if kv_cache is not None and position_ids is None:
+                position_ids = _cache_position_ids(input_ids, pos)
+                if paged:
+                    # prefill padding may carry positions past the table;
+                    # the write already sinks them into the null page —
+                    # keep the embedding lookup in range too
+                    position_ids = ops.clip(
+                        position_ids, min=0,
+                        max=self.config.max_position_embeddings - 1)
+            h = self.embeddings(input_ids, position_ids)
         k = self.config.recompute_interval
         for i, layer in enumerate(self.layers):
             lr = None
@@ -741,7 +765,8 @@ class GPTModel(Layer):
                 h = recompute(layer, h, attn_mask)
             else:
                 h = layer(h, attn_mask, lora=lr)
-        return self.final_ln(h)
+        with jax.named_scope("lm_head"):
+            return self.final_ln(h)
 
 
 class GPTForPretraining(Layer, GenerationMixin):
@@ -767,6 +792,10 @@ class GPTForPretraining(Layer, GenerationMixin):
                      kv_cache=kv_cache, cache_index=cache_index,
                      page_tables=page_tables, ragged_plan=ragged_plan,
                      lora=lora)
+        with jax.named_scope("lm_head"):
+            return self._lm_head(h, out_rows)
+
+    def _lm_head(self, h: Tensor, out_rows: Optional[Tensor]) -> Tensor:
         if out_rows is not None:
             # serving fused step: gather each slot's output row BEFORE the
             # vocab projection, so the LM head projects [S] rows instead of
@@ -1003,15 +1032,19 @@ class GPTStackedDecoder(Layer):
             # for a pure-bf16 model outside auto_cast) — otherwise jax
             # silently promotes the bf16 weights and the matmuls leave the
             # bf16 MXU path (graph_lint GL001)
-            x = ln(h, l1g, l1b).astype(qkvw.dtype)
-            qkv = (x @ qkvw + qkvb).reshape(b, s, 3, nh, hd)
-            q, k, v = (jnp.swapaxes(qkv[:, :, i], 1, 2) for i in range(3))  # [B,N,S,D]
-            out = sdpa(q, k, v, k1, s)                      # [B,N,S,D]
-            out = jnp.swapaxes(out, 1, 2).reshape(b, s, hidden)
-            h = h + drop(out.astype(pw.dtype) @ pw + pb, hid_p, k2).astype(h.dtype)
-            y = ln(h, l2g, l2b).astype(f1w.dtype)
-            y = jax.nn.gelu(y @ f1w + f1b, approximate=True) @ f2w + f2b
-            return h + drop(y, hid_p, k3).astype(h.dtype)
+            with jax.named_scope("attn.qkv"):
+                x = ln(h, l1g, l1b).astype(qkvw.dtype)
+                qkv = (x @ qkvw + qkvb).reshape(b, s, 3, nh, hd)
+                q, k, v = (jnp.swapaxes(qkv[:, :, i], 1, 2) for i in range(3))  # [B,N,S,D]
+            with jax.named_scope("attn.core"):
+                out = sdpa(q, k, v, k1, s)                  # [B,N,S,D]
+            with jax.named_scope("attn.out"):
+                out = jnp.swapaxes(out, 1, 2).reshape(b, s, hidden)
+                h = h + drop(out.astype(pw.dtype) @ pw + pb, hid_p, k2).astype(h.dtype)
+            with jax.named_scope("mlp"):
+                y = ln(h, l2g, l2b).astype(f1w.dtype)
+                y = jax.nn.gelu(y @ f1w + f1b, approximate=True) @ f2w + f2b
+                return h + drop(y, hid_p, k3).astype(h.dtype)
 
         return block, with_dropout
 
@@ -1049,17 +1082,21 @@ class GPTStackedDecoder(Layer):
             # projections — generate() runs OUTSIDE auto_cast, so without
             # this a pure-bf16 model decodes with every matmul silently
             # promoted to fp32 (graph_lint GL001; serving hot path)
-            x = ln(h, l1g, l1b).astype(qkvw.dtype)
-            qkv = (x @ qkvw + qkvb).reshape(b, s, 3, nh, hd)
-            q, k, v = (jnp.swapaxes(qkv[:, :, i], 1, 2) for i in range(3))
-            out, kc, vc = _raw_attend_with_cache(
-                q, k, v, kc, vc, pos, head_dim=hd, use_flash=use_flash,
-                pos_is_zero=pos_is_zero)
-            out = jnp.swapaxes(out, 1, 2).reshape(b, s, hidden)
-            h = h + (out.astype(pw.dtype) @ pw + pb).astype(h.dtype)
-            y = ln(h, l2g, l2b).astype(f1w.dtype)
-            y = jax.nn.gelu(y @ f1w + f1b, approximate=True) @ f2w + f2b
-            return h + y.astype(h.dtype), kc, vc
+            with jax.named_scope("attn.qkv"):
+                x = ln(h, l1g, l1b).astype(qkvw.dtype)
+                qkv = (x @ qkvw + qkvb).reshape(b, s, 3, nh, hd)
+                q, k, v = (jnp.swapaxes(qkv[:, :, i], 1, 2) for i in range(3))
+            with jax.named_scope("attn.core"):
+                out, kc, vc = _raw_attend_with_cache(
+                    q, k, v, kc, vc, pos, head_dim=hd, use_flash=use_flash,
+                    pos_is_zero=pos_is_zero)
+            with jax.named_scope("attn.out"):
+                out = jnp.swapaxes(out, 1, 2).reshape(b, s, hidden)
+                h = h + (out.astype(pw.dtype) @ pw + pb).astype(h.dtype)
+            with jax.named_scope("mlp"):
+                y = ln(h, l2g, l2b).astype(f1w.dtype)
+                y = jax.nn.gelu(y @ f1w + f1b, approximate=True) @ f2w + f2b
+                return h + y.astype(h.dtype), kc, vc
 
         return block
 
@@ -1124,29 +1161,33 @@ class GPTStackedDecoder(Layer):
                 ldelta = lambda x_, a_, b_: jnp.zeros((), x_.dtype)  # noqa: E731,E501
                 qa = qb = pa = pb2 = f1a = f1b2 = f2a = f2b2 = None
             b, s, hidden = h.shape
-            x = ln(h, l1g, l1b).astype(pdt)
-            qkv = (proj(x, qkvw, qkvs, qkvb) + ldelta(x, qa, qb)).reshape(
-                b, s, 3, nh, hd)
-            q, k, v = (jnp.swapaxes(qkv[:, :, i], 1, 2) for i in range(3))
-            if kv_scales is not None:
-                kss, vss = kv_scales
-                out, kc, vc, kss, vss = _raw_attend_paged(
-                    q, k, v, kc, vc, tbl, pos, head_dim=hd,
-                    page_size=page_size, ragged_plan=ragged_plan,
-                    ksr=kss, vsr=vss)
-            else:
-                out, kc, vc = _raw_attend_paged(
-                    q, k, v, kc, vc, tbl, pos, head_dim=hd,
-                    page_size=page_size, ragged_plan=ragged_plan)
-            out = jnp.swapaxes(out, 1, 2).reshape(b, s, hidden)
-            oin = out.astype(pdt)
-            h = h + (proj(oin, pw, pws, pb)
-                     + ldelta(oin, pa, pb2)).astype(h.dtype)
-            y = ln(h, l2g, l2b).astype(pdt)
-            g = jax.nn.gelu(proj(y, f1w, f1s, f1b) + ldelta(y, f1a, f1b2),
-                            approximate=True)
-            y = proj(g, f2w, f2s, f2b) + ldelta(g, f2a, f2b2)
-            h = h + y.astype(h.dtype)
+            with jax.named_scope("attn.qkv"):
+                x = ln(h, l1g, l1b).astype(pdt)
+                qkv = (proj(x, qkvw, qkvs, qkvb)
+                       + ldelta(x, qa, qb)).reshape(b, s, 3, nh, hd)
+                q, k, v = (jnp.swapaxes(qkv[:, :, i], 1, 2) for i in range(3))
+            with jax.named_scope("attn.core"):
+                if kv_scales is not None:
+                    kss, vss = kv_scales
+                    out, kc, vc, kss, vss = _raw_attend_paged(
+                        q, k, v, kc, vc, tbl, pos, head_dim=hd,
+                        page_size=page_size, ragged_plan=ragged_plan,
+                        ksr=kss, vsr=vss)
+                else:
+                    out, kc, vc = _raw_attend_paged(
+                        q, k, v, kc, vc, tbl, pos, head_dim=hd,
+                        page_size=page_size, ragged_plan=ragged_plan)
+            with jax.named_scope("attn.out"):
+                out = jnp.swapaxes(out, 1, 2).reshape(b, s, hidden)
+                oin = out.astype(pdt)
+                h = h + (proj(oin, pw, pws, pb)
+                         + ldelta(oin, pa, pb2)).astype(h.dtype)
+            with jax.named_scope("mlp"):
+                y = ln(h, l2g, l2b).astype(pdt)
+                g = jax.nn.gelu(proj(y, f1w, f1s, f1b)
+                                + ldelta(y, f1a, f1b2), approximate=True)
+                y = proj(g, f2w, f2s, f2b) + ldelta(g, f2a, f2b2)
+                h = h + y.astype(h.dtype)
             if kv_scales is not None:
                 return h, kc, vc, kss, vss
             return h, kc, vc
@@ -1207,7 +1248,8 @@ class GPTStackedDecoder(Layer):
                 return res[0], tuple(res[1:])
 
             xs = tuple(stacked) + (tuple(slabr) if n_lora else ()) + pools
-            h2, new_pools = jax.lax.scan(step, h, xs)
+            with jax.named_scope("layers"):
+                h2, new_pools = jax.lax.scan(step, h, xs)
             return (h2,) + tuple(new_pools)
 
         pool_in = (paged_cache.k, paged_cache.v)
@@ -1243,7 +1285,9 @@ class GPTStackedDecoder(Layer):
                                      posr.astype(jnp.int32))
                 return h2, (kc2, vc2)
 
-            h2, (ck2, cv2) = jax.lax.scan(step, h, tuple(stacked) + (ck, cv))
+            with jax.named_scope("layers"):
+                h2, (ck2, cv2) = jax.lax.scan(step, h,
+                                              tuple(stacked) + (ck, cv))
             return h2, ck2, cv2
 
         out, ck_new, cv_new = dispatch.apply(
@@ -1310,11 +1354,12 @@ class GPTStackedDecoder(Layer):
                 b = h.shape[0]
                 mb = b // n_micro
                 xm = h.reshape(n_micro, mb, *h.shape[1:])
-                out = pp_spmd.pipeline_blocks(
-                    block_mb or block, stacked, xm, layers_per_stage=lps,
-                    remat=remat, remat_policy=remat_policy,
-                    block_takes_index=block_mb is not None,
-                    n_virtual=cfg.virtual_pp_degree)
+                with jax.named_scope("layers"):
+                    out = pp_spmd.pipeline_blocks(
+                        block_mb or block, stacked, xm, layers_per_stage=lps,
+                        remat=remat, remat_policy=remat_policy,
+                        block_takes_index=block_mb is not None,
+                        n_virtual=cfg.virtual_pp_degree)
                 return out.reshape(b, *h.shape[1:])
         else:
             # recompute_interval > 1 groups the remat boundary on the
@@ -1328,9 +1373,10 @@ class GPTStackedDecoder(Layer):
                     f"num_layers={cfg.num_layers} on the stacked scan")
 
             def raw(h, *stacked):
-                return pp_spmd.scan_blocks(block, stacked, h, remat=remat,
-                                           remat_policy=remat_policy,
-                                           remat_interval=k_remat)
+                with jax.named_scope("layers"):
+                    return pp_spmd.scan_blocks(block, stacked, h, remat=remat,
+                                               remat_policy=remat_policy,
+                                               remat_interval=k_remat)
 
         return dispatch.apply(raw, hidden, *stacked_in,
                               op_name="gpt_stacked_decoder")
@@ -1360,16 +1406,23 @@ class GPTStackedForPretraining(Layer, GenerationMixin):
         returns the scalar LM loss through the fused linear+cross-entropy
         head (chunked over tokens, logits never fully materialized — the
         HBM-friendly path; see F.fused_linear_cross_entropy)."""
-        if kv_cache is not None and position_ids is None:
-            position_ids = _cache_position_ids(input_ids, _as_pos(cache_index))
-            if getattr(kv_cache, "paged", False):
-                position_ids = ops.clip(
-                    position_ids, min=0,
-                    max=self.config.max_position_embeddings - 1)
-        h = self.embeddings(input_ids, position_ids)
+        with jax.named_scope("embed"):
+            if kv_cache is not None and position_ids is None:
+                position_ids = _cache_position_ids(input_ids,
+                                                   _as_pos(cache_index))
+                if getattr(kv_cache, "paged", False):
+                    position_ids = ops.clip(
+                        position_ids, min=0,
+                        max=self.config.max_position_embeddings - 1)
+            h = self.embeddings(input_ids, position_ids)
         h = self.decoder(h, n_micro=self.n_micro, kv_cache=kv_cache,
                          cache_index=cache_index, page_tables=page_tables,
                          ragged_plan=ragged_plan, lora=lora)
+        with jax.named_scope("lm_head"):
+            return self._lm_head(h, labels, out_rows)
+
+    def _lm_head(self, h: Tensor, labels: Optional[Tensor],
+                 out_rows: Optional[Tensor]) -> Tensor:
         h = self.final_ln(h)
         if out_rows is not None:
             # serving fused step: gather each slot's output row BEFORE the

@@ -469,6 +469,9 @@ def fused_linear_cross_entropy(hidden, weight, labels, *, chunk_tokens=2048,
         ensure_tensor(hidden), ensure_tensor(weight), ensure_tensor(labels),
     )
 
+    # the scope is entered INSIDE what jax.vjp differentiates, so the backward
+    # pass keeps it (transpose(jvp(lm_head))): docs/observability.md
+    @jax.named_scope("lm_head")
     def fn(h, w, lab):
         hs = h.shape[-1]
         h2 = h.reshape(-1, hs)

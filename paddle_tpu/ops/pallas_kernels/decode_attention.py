@@ -231,6 +231,7 @@ def _decode_pallas(q, k, v, length, scale, interpret=False, block_kv=None,
 # public API
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("kernel.decode")
 def decode_attention(q, k_cache, v_cache, length, *, sm_scale=None,
                      k_scale=None, v_scale=None):
     """Single-query attention over a preallocated KV cache.

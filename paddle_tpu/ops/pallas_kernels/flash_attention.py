@@ -149,6 +149,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc,
         lse_ref[0] = jnp.broadcast_to(lse, lse_ref[0].shape).astype(jnp.float32)
 
 
+@jax.named_scope("kernel.flash_fwd")
 def _flash_fwd(q, k, v, scale, causal, block_q, block_kv):
     bn, s, d = q.shape
     n_q = s // block_q
@@ -277,6 +278,18 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd(q, k, v, out, lse, do, scale, causal, block_q, block_kv):
+    with jax.named_scope("kernel.flash_bwd_dkv"):
+        # delta and the two broadcasts feed both kernels; they sit with
+        # the first
+        dkv, lse, delta = _flash_bwd_dkv(q, k, v, out, lse, do, scale,
+                                         causal, block_q, block_kv)
+    with jax.named_scope("kernel.flash_bwd_dq"):
+        dq = _flash_bwd_dq(q, k, v, do, lse, delta, scale, causal,
+                           block_q, block_kv)
+    return dq, dkv[0], dkv[1]
+
+
+def _flash_bwd_dkv(q, k, v, out, lse, do, scale, causal, block_q, block_kv):
     bn, s, d = q.shape
     n_q = s // block_q
     n_kv = s // block_kv
@@ -314,8 +327,14 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, block_q, block_kv):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(q, k, v, do, lse, delta)
+    return dkv, lse, delta
 
-    dq = pl.pallas_call(
+
+def _flash_bwd_dq(q, k, v, do, lse, delta, scale, causal, block_q, block_kv):
+    bn, s, d = q.shape
+    n_q = s // block_q
+    n_kv = s // block_kv
+    return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_kv=block_kv, n_kv=n_kv),
         grid=(bn, n_q, n_kv),
@@ -334,8 +353,6 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, block_q, block_kv):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(q, k, v, do, lse, delta)
-
-    return dq, dkv[0], dkv[1]
 
 
 # ---------------------------------------------------------------------------

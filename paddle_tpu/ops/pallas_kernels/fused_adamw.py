@@ -60,6 +60,7 @@ def _kernel(lr_ref, b1p_ref, b2p_ref, p_ref, g_ref, m1_ref, m2_ref,
 @functools.partial(jax.jit, static_argnames=("beta1", "beta2", "eps", "wd",
                                              "interpret"),
                    donate_argnums=(0, 2, 3))
+@jax.named_scope("kernel.fused_adamw")
 def fused_adamw_update(p, g, m1, m2, lr, b1p, b2p, *,
                        beta1=0.9, beta2=0.999, eps=1e-8, wd=0.01,
                        interpret=False):

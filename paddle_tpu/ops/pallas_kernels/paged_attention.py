@@ -250,6 +250,7 @@ def gather_pages(pool, page_tables, scale=None):
     return jnp.transpose(g, (0, 2, 1, 3, 4)).reshape(s, h, mp * ps, d)
 
 
+@jax.named_scope("kernel.paged_decode")
 def paged_attention(q, k_pool, v_pool, page_tables, lengths, *,
                     sm_scale=None, k_scale=None, v_scale=None):
     """Single-query attention over a paged KV block pool.

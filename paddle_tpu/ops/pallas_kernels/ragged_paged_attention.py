@@ -381,6 +381,7 @@ def _ragged_pallas(q_blocks, k_pool, v_pool, wl_blk, wl_page, wl_ps,
 # public API
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("kernel.ragged")
 def ragged_paged_attention(q, k_pool, v_pool, token_tables, lengths, plan,
                            *, sm_scale=None, interpret=False,
                            k_scale=None, v_scale=None):

@@ -112,6 +112,7 @@ def _build(norm_math, n_params, name):
         )(x2, r2, *(p.reshape(1, hdim) for p in params))
         return out.reshape(shape), h.reshape(shape)
 
+    @jax.named_scope("kernel.rms_norm")
     def fused_fwd(x, r, params, eps, interpret):
         from .flash_attention import _on_tpu
 

@@ -29,6 +29,7 @@ from __future__ import annotations
 import functools
 from typing import Callable, Optional
 
+import jax
 import jax.numpy as jnp
 
 from ..ops import dispatch
@@ -80,7 +81,8 @@ class FusedTrainStep:
         from ..jit.api import to_static
 
         def fused_train_step(*batch):
-            loss = self._forward(*batch)
+            with jax.named_scope("train.forward"):
+                loss = self._forward(*batch)
             self._backward_and_update(loss)
             return loss
 
@@ -102,12 +104,20 @@ class FusedTrainStep:
     def _backward_and_update(self, loss):
         opt = self._optimizer
         if not self._scaling():
-            loss.backward()
-            opt.step()
+            with jax.named_scope("train.backward"):
+                loss.backward()
+            with jax.named_scope("train.optimizer"):
+                opt.step()
             opt.clear_grad()
             return
         scaler = self._scaler
-        scaler.scale(loss).backward()
+        with jax.named_scope("train.backward"):
+            scaler.scale(loss).backward()
+        with jax.named_scope("train.optimizer"):
+            self._unscale_and_update(opt, scaler)
+        opt.clear_grad()
+
+    def _unscale_and_update(self, opt, scaler):
         # in-graph unscale + fused finiteness (the traced analog of
         # GradScaler.unscale_'s one-host-sync fused kernel)
         dispatch.note_read(scaler._scale)
@@ -135,7 +145,6 @@ class FusedTrainStep:
         self._traced_scaler_update(finite)
         dispatch.note_read(self._finite_t)
         self._finite_t._set_value(finite)
-        opt.clear_grad()
 
     @staticmethod
     def _opt_mutables(opt):
@@ -198,6 +207,9 @@ class FusedTrainStep:
 
     def lowered_texts(self):
         return self._step_fn.lowered_texts()
+
+    def op_scopes(self):
+        return self._step_fn.op_scopes()
 
     def lint_reports(self):
         return self._step_fn.lint_reports()

@@ -825,6 +825,7 @@ class ServingEngine:
             "prompt tokens served from the prefix cache per admission",
         ).labels(**self._engine_label)
         self._step_emitted = 0           # tokens emitted in the current step
+        self._step_prefill = 0           # prompt tokens dispatched in it
         self._last_metrics: dict = {}
         self._last_occupancy = (0.0, 0.0)   # (grid, q-row) of the last step
 
@@ -894,8 +895,9 @@ class ServingEngine:
         def _mk_fused(with_sampling):
             def fused_step(ids, packed, temp, top_p, top_k, do_sample):
                 _count_fused_trace()
-                (token_tables, positions, out_rows, *rest) = \
-                    dispatch.apply_nondiff(_unpack, packed)
+                with jax.named_scope("serve.unpack"):
+                    (token_tables, positions, out_rows, *rest) = \
+                        dispatch.apply_nondiff(_unpack, packed)
                 plan = tuple(rest[:n_plan])
                 lora_in = None
                 if lora_pool is not None:
@@ -912,14 +914,15 @@ class ServingEngine:
                                                     ragged_plan=plan,
                                                     out_rows=out_rows,
                                                     lora=lora_in)
-                    rows = _drop_seq_axis(logits).astype("float32")
-                    fin = _slotwise_finite(rows)
-                    if with_sampling:
-                        tok = _sample_per_slot(rows, temp, top_p, top_k,
-                                               do_sample,
-                                               generator=generator)
-                    else:
-                        tok = ops.argmax(rows, axis=-1)
+                    with jax.named_scope("serve.sample"):
+                        rows = _drop_seq_axis(logits).astype("float32")
+                        fin = _slotwise_finite(rows)
+                        if with_sampling:
+                            tok = _sample_per_slot(rows, temp, top_p, top_k,
+                                                   do_sample,
+                                                   generator=generator)
+                        else:
+                            tok = ops.argmax(rows, axis=-1)
                 return tok, fin
 
             fused_step.__name__ = "fused_step" + self._program_tag
@@ -994,13 +997,15 @@ class ServingEngine:
         finished requests (their pages free immediately).  A crashed or
         stalled step never escapes: the implicated requests end FAILED and
         the engine recovers.  Returns this step's metrics."""
-        with self._lock, self._eval_mode(), _ttrace.span("serve.step"):
+        with self._lock, self._eval_mode(), \
+                _ttrace.span("serve.step") as step_span:
             # under the lock: close() also serializes on it, so a racing
             # close cannot delete the pool between this check and the
             # fused dispatch
             self._check_open()
             t0 = time.perf_counter()
             self._step_emitted = 0
+            self._step_prefill = 0
             with _ttrace.span("serve.plan"):
                 now = time.monotonic()
                 self._reap(now)
@@ -1010,7 +1015,12 @@ class ServingEngine:
             if work:
                 self._dispatch_step(work)
             with _ttrace.span("serve.commit"):
-                return self._commit_step_metrics(t0)
+                m = self._commit_step_metrics(t0)
+                if step_span is not None:
+                    # whether this step carried a prompt chunk: what
+                    # engine.step_ms_decode_only/_with_prefill group by
+                    step_span.set(prefill_tokens=self._step_prefill)
+                return m
 
     def _dispatch_step(self, work):
         """Pack -> dispatch (supervised, retried once) -> harvest for one
@@ -1178,7 +1188,8 @@ class ServingEngine:
         # the span records on the CALLING thread — under a watchdog this
         # is the supervised _StepWorker, so the exported trace shows the
         # device-dispatch range on the worker's row, interleaved with the
-        # dispatcher's serve.dispatch wait on its own row
+        # dispatcher's serve.dispatch wait on its own row (its parent all
+        # the same: _supervised hands it over)
         with _ttrace.span("serve.device_step"):
             return self._fused_thunk_body(fused, inputs, cancelled,
                                           extra_dev)
@@ -1260,8 +1271,9 @@ class ServingEngine:
         """Fold one dispatched plan's occupancy/padding tallies into the
         totals (shared by the base harvest and the speculative verify
         harvest)."""
-        self._totals["prefill_tokens"] += sum(
-            w.count for w in work if w.kind == "prefill")
+        prefill = sum(w.count for w in work if w.kind == "prefill")
+        self._step_prefill += prefill
+        self._totals["prefill_tokens"] += prefill
         self._totals["work_items"] += stats["n_items"]
         self._totals["work_capacity"] += stats["wl_capacity"]
         self._totals["block_rows"] += stats["n_tokens"]
@@ -1542,6 +1554,11 @@ class ServingEngine:
                 # leaks per stall recovery)
                 self._worker.shutdown()
             self._worker = _StepWorker(f"serving-step-{id(self):x}")
+        tracer = _ttrace._tracer
+        if tracer is not None:
+            # the worker's spans (serve.device_step) take the span open
+            # HERE (serve.dispatch) as parent: the tree crosses the thread
+            fn = tracer.handing_over(fn)
         return self._worker.run(fn, budget, cleanup=self._zombie_cleanup())
 
     def _zombie_cleanup(self) -> Callable[[], None]:
@@ -1910,6 +1927,11 @@ class ServingEngine:
         """StableHLO text of the compiled fused-step programs (Mosaic
         custom calls included)."""
         return [t for f in self._static_fns for t in f.lowered_texts()]
+
+    def op_scopes(self):
+        """Per compiled fused-step program, its optimized instructions by
+        name with the scope each falls under (``jit.api.op_scopes``)."""
+        return [m for f in self._static_fns for m in f.op_scopes()]
 
     def lint_reports(self):
         """Graph-lint reports of the compiled fused-step programs

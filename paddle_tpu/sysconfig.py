@@ -40,3 +40,10 @@ def enable_compile_cache() -> str:
     path = os.path.join(os.path.dirname(_ROOT), ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path
+
+
+def compile_cache_dir():
+    """Where JAX's persistent compilation cache is right now (None: off)."""
+    import jax
+
+    return jax.config.jax_compilation_cache_dir

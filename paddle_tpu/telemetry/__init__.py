@@ -7,37 +7,31 @@ Two cooperating halves (docs/observability.md):
   Prometheus text exposition).  The serving engine's fault/shed/
   occupancy counters and the per-request SLO histograms (TTFT,
   inter-token latency, queue wait, end-to-end) live here.
-- :mod:`trace` — a ring-buffered, thread-aware host span tracer
-  (context manager + decorator) exporting Chrome-trace/Perfetto JSON,
-  with each span nesting a ``jax.profiler.TraceAnnotation`` so host
-  phases align with the device timeline when an XLA capture is active.
+- :mod:`trace` — a ring-buffered, thread-aware host span tracer (a
+  context manager) exporting Chrome-trace/Perfetto JSON, with each span
+  nesting a ``jax.profiler.TraceAnnotation`` so host phases align with
+  the device timeline when an XLA capture is active.
+- :mod:`scopes` — the ``jax.named_scope`` vocabulary of the compiled
+  programs and the map from a device trace's operations back to it.
 
-Both are import-light (no jax at import time) so the disabled path
-stays near-zero; ``paddle_tpu.profiler`` is the user-facing facade.
-
-``PADDLE_TPU_TRACE=1`` in the environment enables span tracing at
-import (capacity via ``PADDLE_TPU_TRACE_CAPACITY``).
+All are import-light (no jax at import time) so the disabled path stays
+near-zero; ``paddle_tpu.profiler`` is the user-facing facade and
+``enable(capacity=)`` the one switch.
 """
 from __future__ import annotations
 
-import os as _os
-
-from . import metrics, trace  # noqa: F401
+from . import metrics, scopes, trace  # noqa: F401
 from .metrics import (  # noqa: F401
     Counter, CounterSet, Gauge, Histogram, Registry, registry,
 )
 from .trace import (  # noqa: F401
     Span, Tracer, active, disable, enable, export_chrome_trace, span,
-    summarize, traced,
+    summarize,
 )
 
 __all__ = [
-    "metrics", "trace",
+    "metrics", "scopes", "trace",
     "Counter", "CounterSet", "Gauge", "Histogram", "Registry", "registry",
     "Span", "Tracer", "active", "disable", "enable", "export_chrome_trace",
-    "span", "summarize", "traced",
+    "span", "summarize",
 ]
-
-if _os.environ.get("PADDLE_TPU_TRACE", "") not in ("", "0", "false", "False"):
-    enable(capacity=int(_os.environ.get("PADDLE_TPU_TRACE_CAPACITY",
-                                        "65536")))

@@ -20,8 +20,7 @@ Design constraints (docs/observability.md):
   min/max, so p50/p95/p99 are stable even with few samples.
 - **two export surfaces** — ``snapshot()`` (JSON-safe dict, the bench
   and tests consume it) and ``prometheus_text()`` (the standard text
-  exposition: ``_bucket{le=...}``/``_sum``/``_count`` for histograms),
-  validated by ``tools/obs_gate.py``.
+  exposition: ``_bucket{le=...}``/``_sum``/``_count`` for histograms).
 
 ``CounterSet`` is the migration shim for code that kept cumulative
 totals in a plain dict (the serving engine's fault/shed/occupancy

@@ -1,50 +1,45 @@
 """Host-side span tracer with Chrome-trace/Perfetto export.
 
-The reference framework's profiler records host ranges through the C++
-host tracer and merges them with CUPTI device activity into one
-chrome-trace JSON.  TPU-native analog: host spans are recorded here in a
-ring buffer, and each span *nests a* ``jax.profiler.TraceAnnotation`` —
-the XLA profiler's TraceMe — so when a device trace is being captured
-(``jax.profiler.start_trace``) the same named ranges appear on the
-TensorBoard/Perfetto device timeline, aligning host phases with the
-TensorCore stream.  Without an active XLA capture the annotation is a
-few-ns TraceMe no-op, so leaving ``annotate=True`` costs nothing.
+Host spans are recorded in a ring buffer, and each span nests a
+``jax.profiler.TraceAnnotation`` (the XLA profiler's TraceMe), so during a
+device capture (``jax.profiler.start_trace``) the same named ranges appear
+on the device timeline; without one the annotation is a few-ns no-op.
 
 Contract (docs/observability.md):
 
-- **near-zero disabled path** — ``span()`` reads ONE module global; when
-  no tracer is active it returns a shared no-op context manager.  The
-  hot callers (serving step phases, ``jit`` compiled dispatch, the
-  checkpoint writer) therefore pay ~100 ns per call-site when telemetry
-  is off (gated <3 % of an eager dispatch by ``tools/obs_gate.py``).
-- **thread-aware** — spans record the OS thread id + thread name at
-  exit, so the serving watchdog's ``_StepWorker`` spans and the
-  checkpoint writer thread interleave correctly with the dispatcher in
-  the exported trace (one Chrome-trace row per thread).
+- **near-zero disabled path** — ``span()`` reads ONE module global; with
+  no tracer it returns a shared no-op whose ``__enter__`` gives None.
+  Ids, parents and per-thread stacks exist only under a tracer.
+- **thread-aware** — spans record thread id + name, so the serving
+  watchdog's ``_StepWorker`` and the checkpoint writer get their own rows.
 - **ring-buffered** — a bounded deque (default 65536 spans); overflow
-  drops the OLDEST spans and counts them in ``Tracer.dropped`` (the
-  newest spans are the ones a post-mortem export wants).
-- **metadata** — ``span(name, **args)`` attaches JSON-safe args;
-  ``jit/api.py`` attaches each compiled program's CostReport digest
-  (gflop / HBM bytes / intensity / roofline-estimated ms) so the trace
-  shows measured-vs-roofline per fused step.
-
-Export: ``export_chrome_trace(path)`` writes the standard
-``{"traceEvents": [...]}`` JSON (``ph="X"`` complete events in
-microseconds + ``ph="M"`` thread-name metadata) that chrome://tracing
-and https://ui.perfetto.dev open directly.
+  drops the OLDEST spans and counts them in ``Tracer.dropped``.
+- **metadata** — ``span(name, **args)`` attaches JSON-safe args (``jit/
+  api.py``: each program's CostReport digest); ``with span(..) as s``
+  gives the open span (None when off) and ``s.set(..)`` adds args as the
+  work learns them: how ``serve.step`` records what its step carried.
+- **a tree** — every span has an ``id`` and the ``parent`` open on its
+  thread when it started; ``Tracer.handing_over`` carries the parent to
+  work another thread runs.
+- **the device's side** — the tracer remembers (weakly: it keeps no
+  weights or pools alive) each compiled program it saw dispatched
+  (``Tracer.programs``) and maps their operations to program scopes
+  (``telemetry/scopes.py``) when first asked, after the window
+  (``Tracer.program_scopes()``).
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
 
 __all__ = [
-    "Span", "Tracer", "enable", "disable", "active", "span", "traced",
+    "Span", "Tracer", "enable", "disable", "active", "span",
     "export_chrome_trace", "summarize", "format_summary",
 ]
 
@@ -52,16 +47,20 @@ __all__ = [
 class Span:
     """One completed host range."""
 
-    __slots__ = ("name", "t0_ns", "dur_ns", "tid", "thread_name", "args")
+    __slots__ = ("name", "t0_ns", "dur_ns", "tid", "thread_name", "args",
+                 "id", "parent")
 
     def __init__(self, name: str, t0_ns: int, dur_ns: int, tid: int,
-                 thread_name: str, args: Optional[Dict[str, Any]]):
+                 thread_name: str, args: Optional[Dict[str, Any]],
+                 id: int = 0, parent: Optional[int] = None):
         self.name = name
         self.t0_ns = t0_ns
         self.dur_ns = dur_ns
         self.tid = tid
         self.thread_name = thread_name
         self.args = args
+        self.id = id
+        self.parent = parent
 
     def __repr__(self):
         return (f"Span({self.name!r}, {self.dur_ns / 1e6:.3f} ms, "
@@ -69,8 +68,7 @@ class Span:
 
 
 class _NullSpan:
-    """Shared disabled-path context manager (no per-call allocation
-    beyond the kwargs dict python builds for ``span(**args)``)."""
+    """The shared disabled-path context manager: nothing is allocated."""
 
     __slots__ = ()
 
@@ -86,20 +84,18 @@ _NOOP = _NullSpan()
 #: the active tracer, or None — ONE global read is the disabled fast path
 _tracer: Optional["Tracer"] = None
 
-#: tid -> thread name, filled on first span per thread —
-#: ``threading.get_ident()`` is ~5x cheaper than ``current_thread()``
-#: and the enabled record path runs per span.  A rename after the first
-#: span keeps the old label; the trace cares about identity, not names.
-_thread_names: Dict[int, str] = {}
+#: (tid, thread name), read once a thread.  Thread-local, so a later thread
+#: given the same ident does not inherit the name; a rename after a thread's
+#: first span keeps the old label (the trace cares about identity).
+_thread = threading.local()
 
 
 def _thread_info() -> tuple:
-    tid = threading.get_ident()
-    name = _thread_names.get(tid)
-    if name is None:
-        name = threading.current_thread().name
-        _thread_names[tid] = name
-    return tid, name
+    info = getattr(_thread, "info", None)
+    if info is None:
+        info = _thread.info = (threading.get_ident(),
+                               threading.current_thread().name)
+    return info
 
 
 class Tracer:
@@ -111,20 +107,22 @@ class Tracer:
         self._lock = threading.Lock()
         self.dropped = 0
         self.annotate = bool(annotate)
+        self._ids = itertools.count(1)
+        self._open = threading.local()      # .stack: this thread's open ids
+        #: span name -> weak references to the compiled entries dispatched
+        #: under it (an entry holds its weights and pools: theirs to free)
+        self.programs: Dict[str, List[weakref.ref]] = {}
+        self._resolve: Optional[Callable] = None
+        self._program_scopes: Optional[Dict[str, List[Dict]]] = None
         self._ann_cls = None
         if self.annotate:
-            try:
-                import jax
+            import jax
 
-                self._ann_cls = jax.profiler.TraceAnnotation
-            except Exception:  # noqa: BLE001 — annotation is best-effort
-                self._ann_cls = None
+            self._ann_cls = jax.profiler.TraceAnnotation
 
     def record(self, s: Span):
-        # lock-free: deque.append with maxlen is atomic under the GIL
-        # and auto-evicts the oldest span; the dropped counter is
-        # best-effort under concurrent writers (the record path runs
-        # once per span on every instrumented hot loop)
+        # lock-free: deque.append with maxlen is atomic under the GIL and
+        # evicts the oldest; `dropped` is best-effort under concurrent writers
         buf = self._buf
         if len(buf) == self.capacity:
             self.dropped += 1
@@ -133,10 +131,59 @@ class Tracer:
     def spans(self) -> List[Span]:
         return list(self._buf)
 
+    def _stack(self) -> List[Optional[int]]:
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        return stack
+
+    def current_id(self) -> Optional[int]:
+        """The id of the span open on this thread."""
+        stack = getattr(self._open, "stack", None)
+        return stack[-1] if stack else None
+
+    def handing_over(self, fn: Callable) -> Callable:
+        """``fn`` for another thread to run: the spans it opens there take
+        the span open HERE, now, as their parent."""
+        parent = self.current_id()
+
+        def run(*args, **kwargs):
+            stack = self._stack()
+            stack.append(parent)
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                stack.pop()
+
+        return run
+
+    def saw_program(self, name: str, entry: Any, resolve: Callable):
+        """``jit/api.py`` reports each compiled entry it dispatches under
+        a ``jit.*`` span; ``resolve(entry)`` gives its scope map."""
+        seen = self.programs.setdefault(name, [])
+        if not any(ref() is entry for ref in seen):
+            seen.append(weakref.ref(entry))
+            self._resolve, self._program_scopes = resolve, None
+
+    def program_scopes(self) -> Dict[str, List[Dict]]:
+        """By span name, each seen program's ``{instruction name:
+        telemetry.scopes.OpScope}``.  Made when first asked (it compiles
+        text: seconds for a large step): after the window, never in a step.
+        A program whose owner is gone by then (an engine closed, a replica
+        scaled away) is left out."""
+        if self._program_scopes is None:
+            self._program_scopes = {
+                name: [self._resolve(e) for e in (ref() for ref in refs)
+                       if e is not None]
+                for name, refs in self.programs.items()}
+        return self._program_scopes
+
     def clear(self):
         with self._lock:
             self._buf.clear()
             self.dropped = 0
+            self.programs.clear()
+            self._program_scopes = None
 
     def __len__(self) -> int:
         with self._lock:
@@ -144,7 +191,10 @@ class Tracer:
 
 
 class _SpanCtx:
-    __slots__ = ("_tracer", "_name", "_args", "_t0", "_ann")
+    """An open span, with its ``id`` and its ``parent``."""
+
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_ann", "_stack",
+                 "id", "parent")
 
     def __init__(self, tracer: Tracer, name: str,
                  args: Optional[Dict[str, Any]]):
@@ -152,8 +202,20 @@ class _SpanCtx:
         self._name = name
         self._args = args or None
 
+    def set(self, **args):
+        """Add args to the span while it is open."""
+        if self._args is None:
+            self._args = args
+        else:
+            self._args.update(args)
+
     def __enter__(self):
-        ann_cls = self._tracer._ann_cls
+        tracer = self._tracer
+        self._stack = stack = tracer._stack()
+        self.id = next(tracer._ids)
+        self.parent = stack[-1] if stack else None
+        stack.append(self.id)
+        ann_cls = tracer._ann_cls
         if ann_cls is not None:
             self._ann = ann_cls(self._name)
             self._ann.__enter__()
@@ -166,9 +228,10 @@ class _SpanCtx:
         dur = time.perf_counter_ns() - self._t0
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
+        self._stack.pop()
         tid, tname = _thread_info()
-        self._tracer.record(Span(self._name, self._t0, dur,
-                                 tid, tname, self._args))
+        self._tracer.record(Span(self._name, self._t0, dur, tid, tname,
+                                 self._args, self.id, self.parent))
         return False
 
 
@@ -202,33 +265,6 @@ def span(name: str, **args):
     return _SpanCtx(t, name, args)
 
 
-def traced(name: Optional[str] = None) -> Callable:
-    """Decorator form of :func:`span`.
-
-    ``@traced()`` uses the function's qualified name; ``@traced("x")``
-    overrides it.  The disabled path adds one global read + one ``if``.
-    """
-
-    def deco(fn):
-        label = name or getattr(fn, "__qualname__",
-                                getattr(fn, "__name__", "fn"))
-
-        def wrapper(*a, **kw):
-            t = _tracer
-            if t is None:
-                return fn(*a, **kw)
-            with _SpanCtx(t, label, None):
-                return fn(*a, **kw)
-
-        wrapper.__name__ = getattr(fn, "__name__", "wrapper")
-        wrapper.__qualname__ = getattr(fn, "__qualname__", wrapper.__name__)
-        wrapper.__doc__ = fn.__doc__
-        wrapper.__wrapped__ = fn
-        return wrapper
-
-    return deco
-
-
 # ---------------------------------------------------------------------------
 # export + aggregation
 # ---------------------------------------------------------------------------
@@ -244,10 +280,7 @@ def export_chrome_trace(path: Optional[str] = None,
     spans = tr.spans() if tr is not None else []
     pid = os.getpid()
     events: List[Dict[str, Any]] = []
-    threads_seen: Dict[int, str] = {}
-    for s in spans:
-        if s.tid not in threads_seen:
-            threads_seen[s.tid] = s.thread_name
+    threads_seen = {s.tid: s.thread_name for s in reversed(spans)}
     for tid, tname in sorted(threads_seen.items()):
         events.append({"name": "thread_name", "ph": "M", "pid": pid,
                        "tid": tid, "args": {"name": tname}})
@@ -256,8 +289,7 @@ def export_chrome_trace(path: Optional[str] = None,
             "name": s.name, "ph": "X", "cat": "host", "pid": pid,
             "tid": s.tid, "ts": s.t0_ns / 1000.0, "dur": s.dur_ns / 1000.0,
         }
-        if s.args:
-            ev["args"] = s.args
+        ev["args"] = {**(s.args or {}), "id": s.id, "parent": s.parent}
         events.append(ev)
     doc = {"traceEvents": events, "displayTimeUnit": "ms",
            "otherData": {"dropped_spans": tr.dropped if tr else 0}}

@@ -41,18 +41,13 @@
 # gates; pure static analysis, never times; see docs/graph_lint.md
 # "v2: autotuner").  PADDLE_TPU_SKIP_AUTOTUNE_GATE=1 skips it.
 #
-# A telemetry gate runs sixth (tools/obs_gate.py — disabled-path span
-# overhead <3% of a compiled dispatch, Chrome-trace export valid with
-# nested serving-phase spans, Prometheus exposition parses; see
-# docs/observability.md).  PADDLE_TPU_SKIP_OBS_GATE=1 skips it.
-#
-# A train-perf gate runs seventh (tools/train_perf_gate.py — the fused
+# A train-perf gate runs sixth (tools/train_perf_gate.py — the fused
 # train step must stay ONE program with one dispatch per step, GL004-clean
 # donation over params/moments/masters, an accounting-exact device input
 # pipeline, and CPU tokens/sec above the recorded floor; see
 # docs/training_perf.md).  PADDLE_TPU_SKIP_TRAIN_PERF_GATE=1 skips it.
 #
-# A distributed fault-tolerance gate runs eighth (tools/dist_fault_gate.py
+# A distributed fault-tolerance gate runs seventh (tools/dist_fault_gate.py
 # — real multi-process scenarios: kill-a-rank mid-collective must raise a
 # typed PeerLostError within 2x the detector TTL, a restarted rank must
 # never consume a prior generation's store keys, randomized store-outage
@@ -60,7 +55,7 @@
 # restart -> resume must be bitwise-equal to the uninterrupted run; see
 # docs/distributed_faults.md).  PADDLE_TPU_SKIP_DIST_FAULT_GATE=1 skips it.
 #
-# An elastic-serving gate runs ninth (tools/elastic_gate.py — scripted
+# An elastic-serving gate runs eighth (tools/elastic_gate.py — scripted
 # load through the SLO-driven controller: scale-up on a load spike,
 # scale-down on idle with a BITWISE token-prefix drain, replica-kill
 # re-homing with exactly-once streams, the brownout ladder engaging in
@@ -68,7 +63,7 @@
 # under adversarial oscillation; see docs/serving.md "Elasticity &
 # degradation ladder").  PADDLE_TPU_SKIP_ELASTIC_GATE=1 skips it.
 #
-# A disaggregated-serving gate runs tenth (tools/disagg_gate.py —
+# A disaggregated-serving gate runs ninth (tools/disagg_gate.py —
 # prefill/decode role parity vs the colocated cluster and the oracle,
 # mid-transfer kills in BOTH directions with exact page audits on both
 # pools, and independent per-role elastic scaling under a long-prompt
@@ -120,15 +115,6 @@ if [ -z "$PADDLE_TPU_SKIP_AUTOTUNE_GATE" ]; then
     python "$(dirname "$0")/tools/autotune.py" --validate || {
         rc=$?
         echo "run_tests: autotune replay gate FAILED (rc=$rc)"
-        exit $rc
-    }
-fi
-
-if [ -z "$PADDLE_TPU_SKIP_OBS_GATE" ]; then
-    echo "run_tests: telemetry gate (tools/obs_gate.py)"
-    python "$(dirname "$0")/tools/obs_gate.py" || {
-        rc=$?
-        echo "run_tests: telemetry gate FAILED (rc=$rc)"
         exit $rc
     }
 fi

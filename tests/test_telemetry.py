@@ -288,18 +288,120 @@ def test_ring_buffer_overflow():
         tt.Tracer(capacity=0)
 
 
-def test_traced_decorator(tracer):
-    @tt.traced()
-    def work(x):
-        """doc"""
-        return x + 1
+def test_span_ids_and_parents(tracer):
+    """Every span has an id of the tracer's counter and the parent that was
+    open on ITS thread when it started; threads do not see each other."""
+    seen = {}
 
-    assert work(1) == 2
-    assert work.__name__ == "work" and work.__doc__ == "doc"
-    assert [s.name for s in tracer.spans()] == ["test_traced_decorator.<locals>.work"]
+    def worker():
+        with tt.span("w.outer") as o:
+            with tt.span("w.inner") as i:
+                seen["w"] = (o.id, o.parent, i.id, i.parent)
+
+    with tt.span("main.outer") as mo:
+        th = threading.Thread(target=worker)
+        th.start()
+        th.join()
+        with tt.span("main.inner") as mi:
+            assert tracer.current_id() == mi.id
+        assert tracer.current_id() == mo.id
+    assert tracer.current_id() is None
+    o_id, o_parent, i_id, i_parent = seen["w"]
+    assert o_parent is None and i_parent == o_id       # not main.outer
+    by = {s.name: s for s in tracer.spans()}
+    assert by["main.inner"].parent == by["main.outer"].id == mo.id
+    assert by["main.outer"].parent is None
+    assert by["w.inner"].id == i_id and by["w.inner"].parent == o_id
+    assert len({s.id for s in tracer.spans()}) == 4
+
+
+def test_handing_over_names_the_parent_across_threads(tracer):
+    """Work handed to another thread takes the span open where it was
+    handed over as parent (the serving watchdog's _StepWorker)."""
+    def work():
+        with tt.span("on.worker"):
+            pass
+        return threading.get_ident()
+
+    with tt.span("dispatching") as d:
+        handed = tracer.handing_over(work)
+        box = {}
+        th = threading.Thread(target=lambda: box.setdefault("tid", handed()))
+        th.start()
+        th.join()
+    by = {s.name: s for s in tracer.spans()}
+    assert by["on.worker"].parent == d.id
+    assert by["on.worker"].tid == box["tid"] != by["dispatching"].tid
+
+
+def test_span_set_adds_args_while_open(tracer):
+    with tt.span("a") as a:
+        a.set(step=3)
+        a.set(tokens=5)
+    with tt.span("b", k=1) as b:
+        b.set(step=4)
+    by = {s.name: s for s in tracer.spans()}
+    assert by["a"].args == {"step": 3, "tokens": 5}
+    assert by["b"].args == {"k": 1, "step": 4}
+
+
+def test_disabled_path_allocates_nothing():
+    """No tracer: span() is the shared no-op whose ``as`` target is None, and
+    no id counter, stack or scope map exists anywhere."""
     tt.disable()
-    assert work(2) == 3                                # passthrough
-    assert len(tracer.spans()) == 1
+    assert tt.active() is None
+    ctx = tt.span("x")
+    assert ctx is tt._NOOP and not hasattr(ctx, "__dict__")
+    with ctx as opened:
+        assert opened is None
+    assert not hasattr(tt, "_stack") and not hasattr(tt, "_ids")
+
+
+class _Entry:
+    """Stands for a compiled entry: something a weak reference can hold."""
+
+
+def test_program_scopes_wait_until_asked(tracer):
+    """The tracer only remembers what it saw dispatched; the scope map (a
+    compile of text) is made when first asked, once."""
+    calls = []
+
+    def resolve(entry):
+        calls.append(entry)
+        return {"fusion.1": entry}
+
+    e1, e2 = _Entry(), _Entry()
+    tracer.saw_program("jit.a", e1, resolve)
+    tracer.saw_program("jit.a", e1, resolve)            # every dispatch reports
+    tracer.saw_program("jit.b", e2, resolve)
+    assert {k: [r() for r in v] for k, v in tracer.programs.items()} == {
+        "jit.a": [e1], "jit.b": [e2]}
+    assert calls == []
+    got = tracer.program_scopes()
+    assert got == {"jit.a": [{"fusion.1": e1}], "jit.b": [{"fusion.1": e2}]}
+    assert tracer.program_scopes() is got and calls == [e1, e2]
+
+
+def test_the_tracer_keeps_no_program_alive(tracer):
+    """A compiled entry holds its weights and pools: the tracer remembers it
+    weakly, leaves out what is gone when the map is asked for, and
+    ``clear()`` forgets programs and map with the spans."""
+    import gc
+    import weakref
+
+    def resolve(entry):
+        return {"fusion.1": "scope"}
+
+    kept, gone = _Entry(), _Entry()
+    watch = weakref.ref(gone)
+    tracer.saw_program("jit.a", kept, resolve)
+    tracer.saw_program("jit.a", gone, resolve)
+    del gone
+    gc.collect()
+    assert watch() is None                              # only the tracer knew it
+    assert tracer.program_scopes() == {"jit.a": [{"fusion.1": "scope"}]}
+    tracer.clear()
+    assert tracer.programs == {} and tracer.program_scopes() == {}
 
 
 def test_chrome_trace_export_threads_and_nesting(tracer, tmp_path):
@@ -325,8 +427,10 @@ def test_chrome_trace_export_threads_and_nesting(tracer, tmp_path):
     assert len(tids) == 2                              # main + worker rows
     assert {m["args"]["name"] for m in metas} >= {"worker-0"}
     by_name = {e["name"]: e for e in comp}
-    assert by_name["main.span"]["args"] == {"meta": 1}
+    assert by_name["main.span"]["args"] == {
+        "meta": 1, "id": by_name["main.span"]["args"]["id"], "parent": None}
     inner, outer = by_name["w.inner"], by_name["w.outer"]
+    assert inner["args"]["parent"] == outer["args"]["id"]
     assert inner["tid"] == outer["tid"]
     assert outer["ts"] <= inner["ts"]
     assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 0.5
@@ -572,6 +676,42 @@ def test_step_phases_spanned(served):
                 "jit.fused_step"} <= names
     finally:
         tt.disable()
+
+
+def test_step_spans_know_their_step(served):
+    """serve.step records the prompt tokens its step carried (equal to what
+    the engine's totals moved by), nothing else, and serve.device_step, on
+    the watchdog's thread, reaches it through its parents."""
+    m, cfg, prompts = served
+    tt.disable()
+    tr = tt.enable(annotate=False)
+    try:
+        eng = _engine(m, prefill_token_budget=8, stall_budget_s=60.0)
+        eng.submit(prompts[4], 3)                      # 17 tokens: 3 chunks
+        steps = []
+        while eng.scheduler.active_slots or eng.queue.depth:
+            before = eng.metrics()["prefill_tokens"]
+            eng.step()
+            steps.append(eng.metrics()["prefill_tokens"] - before)
+        eng.close()
+    finally:
+        tt.disable()
+    spans = tr.spans()
+    by_id = {s.id: s for s in spans}
+    recorded = [s for s in spans if s.name == "serve.step"]
+    assert len(recorded) == len(steps) >= 5
+    assert [s.args for s in recorded] == [{"prefill_tokens": n} for n in steps]
+    assert steps[:4] == [8, 8, 1, 0]
+    device = [s for s in spans if s.name == "serve.device_step"]
+    assert len(device) == len(steps)
+    for d in device:
+        assert d.thread_name.startswith("serving-step-")
+        chain = [d]
+        while chain[-1].parent is not None:
+            chain.append(by_id[chain[-1].parent])
+        assert [c.name for c in chain] == ["serve.device_step", "serve.dispatch",
+                                           "serve.step"]
+        assert chain[-1].tid != d.tid
 
 
 def test_engine_close_drops_registry_series(served):
