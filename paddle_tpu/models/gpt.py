@@ -400,8 +400,11 @@ def _attend_paged_shard(qh, kh, vh, pkr, pvr, tables, posr, *, head_dim,
 
     Every write translates an absolute position through the page table:
     position p of slot s lands at ``pool[tables[s, p//page_size], :,
-    p%page_size]``.  Inactive slots and prefill padding carry null-page
-    table entries, so their writes sink into page 0 (never validly read).
+    p%page_size]``, written as one row of D per head on the pool's flat
+    ``[P*N*page_size, D]`` view so that the pool keeps its own layout and
+    is updated in place.  Inactive slots and prefill padding carry null-
+    page table entries, so their writes sink into page 0 (never validly
+    read; under the stacked decoder P is L*P and the sink layer l's own).
     C == 1 is the batched decode step: scatter one token per row, then
     the paged flash-decode kernel (XLA gather fallback off-TPU) over each
     row's own pages — or, with ``ragged_plan`` (the serving engine's fused
@@ -446,9 +449,15 @@ def _attend_paged_shard(qh, kh, vh, pkr, pvr, tables, posr, *, head_dim,
             vq, vs2 = quantize_kv_write(vq, page_ids, offs, vsr)
         else:
             ks2 = vs2 = None
-        # advanced indices split by the head slice: result dims [S, C, N, D]
-        pk2 = pkr.at[page_ids, :, offs, :].set(kq.astype(pkr.dtype))
-        pv2 = pvr.at[page_ids, :, offs, :].set(vq.astype(pvr.dtype))
+        # one row of D per (token, head) on the pool's free [P*N*page, D]
+        # view: the write's natural layout is the pool's own, so it updates
+        # the buffer in place (the sink rows repeat: never unique_indices)
+        rows = ((page_ids[..., None] * nh + jnp.arange(nh, dtype=jnp.int32))
+                * page_size + offs[..., None])               # [S, C, N]
+        pk2 = pkr.reshape(-1, d).at[rows].set(
+            kq.astype(pkr.dtype)).reshape(pkr.shape)
+        pv2 = pvr.reshape(-1, d).at[rows].set(
+            vq.astype(pvr.dtype)).reshape(pvr.shape)
     if c == 1 and ragged_plan is not None:
         out = ragged_paged_attention(qh[:, :, 0, :], pk2, pv2, tbl,
                                      pos + 1, ragged_plan, sm_scale=scale,
@@ -1197,16 +1206,21 @@ class GPTStackedDecoder(Layer):
     def _forward_paged(self, hidden: Tensor, paged_cache, page_tables,
                        cache_index, ragged_plan=None, lora=None) -> Tensor:
         """Serving step over the stacked parameters with a STACKED
-        [L, P, H, page_size, D] page pool: lax.scan carries the hidden
-        state and scans the per-layer pool slices as xs/ys, exactly like
-        _forward_cached scans the contiguous cache.  The updated pool is
-        written back in place (mutation-logged -> donated under
-        jit.to_static).  ``ragged_plan`` Tensors are scan constants: one
-        work list serves every layer of the fused mixed step.  ``lora``
-        is ``(LoRAAdapterPool, per-token adapter ids)``: the stacked
+        [L, P, H, page_size, D] page pool: lax.scan CARRIES each pool
+        (and an int8 pool's [L, P, H] scales) beside the hidden state as
+        one buffer viewed [L*P, H, page_size, D], and layer ``l`` (the
+        index rides xs) offsets its page ids by ``l*P``: the tables, and
+        the plan's ``wl_page``.  Donated under jit.to_static (mutation-
+        logged), the buffer is updated in place: no operation of a step
+        moves a layer's pool (tests/test_pool_in_place.py).  The other
+        ``ragged_plan`` Tensors are scan constants.  ``lora`` is
+        ``(LoRAAdapterPool, per-token adapter ids)``: the stacked
         ``[L, pages, ...]`` adapter slabs scan alongside the parameters,
         the ids ride as a scan constant."""
         from ..ops import dispatch
+        from ..ops.pallas_kernels.ragged_paged_attention import (
+            RAGGED_PLAN_FIELDS,
+        )
 
         pos = _as_pos(cache_index)
         block = self._paged_block_fn(int(paged_cache.page_size))
@@ -1220,10 +1234,13 @@ class GPTStackedDecoder(Layer):
         else:
             lora_in, lscale = (), 0.0
         n_lora = len(lora_in)
-        # int8 pool: the stacked [L, P, H] scale buffers scan alongside
-        # the pools — the per-layer tail of xs grows from 2 to 4 entries
-        quantized = bool(getattr(paged_cache, "quantized", False))
-        nt = 4 if quantized else 2
+        # int8 pool: the [L, P, H] scale buffers follow the pools
+        pool_in = (paged_cache.k, paged_cache.v)
+        if getattr(paged_cache, "quantized", False):
+            pool_in += (paged_cache.k_scale, paged_cache.v_scale)
+        nt = len(pool_in)
+        n_layers, n_pages = (int(n) for n in paged_cache.k.shape[:2])
+        i_page = RAGGED_PLAN_FIELDS.index("wl_page")
 
         def raw(h, posr, tbl, *rest):
             planr = rest[:n_plan] if n_plan else None
@@ -1234,37 +1251,32 @@ class GPTStackedDecoder(Layer):
             pools, stacked = rest[:nt], rest[nt:]
 
             def step(carry, xs):
+                base, xs = xs[0] * n_pages, xs[1:]
                 if n_lora:
-                    params, sl = xs[:-(8 + nt)], xs[-(8 + nt):-nt]
-                    lr = (tuple(sl), idsr, lscale)
+                    params, lr = xs[:-8], (tuple(xs[-8:]), idsr, lscale)
                 else:
-                    params, lr = xs[:-nt], None
-                kc, vc = xs[-nt], xs[-nt + 1]
-                kvs = (xs[-2], xs[-1]) if quantized else None
-                res = block(params, carry, kc, vc,
-                            tbl.astype(jnp.int32),
-                            posr.astype(jnp.int32),
-                            ragged_plan=planr, lora=lr, kv_scales=kvs)
-                return res[0], tuple(res[1:])
+                    params, lr = xs, None
+                plan_l = None if planr is None else (
+                    *planr[:i_page], planr[i_page] + base, *planr[i_page + 1:])
+                return block(params, *carry[:3],
+                             tbl.astype(jnp.int32) + base,
+                             posr.astype(jnp.int32), ragged_plan=plan_l,
+                             lora=lr, kv_scales=carry[3:] or None), None
 
-            xs = tuple(stacked) + (tuple(slabr) if n_lora else ()) + pools
+            xs = ((jnp.arange(n_layers, dtype=jnp.int32),) + tuple(stacked)
+                  + (tuple(slabr) if n_lora else ()))
+            flat = tuple(p.reshape((-1,) + p.shape[2:]) for p in pools)
             with jax.named_scope("layers"):
-                h2, new_pools = jax.lax.scan(step, h, xs)
-            return (h2,) + tuple(new_pools)
+                (h2, *new), _ = jax.lax.scan(step, (h,) + flat, xs)
+            return (h2,) + tuple(n.reshape(p.shape)
+                                 for n, p in zip(new, pools))
 
-        pool_in = (paged_cache.k, paged_cache.v)
-        if quantized:
-            pool_in = pool_in + (paged_cache.k_scale, paged_cache.v_scale)
         results = dispatch.apply(
             raw, hidden, pos, page_tables, *plan, *lora_in, *pool_in,
             *self._stacked(), op_name="gpt_stacked_decoder_paged")
-        out, pk_new, pv_new = results[:3]
-        if quantized:
-            paged_cache.k_scale._set_value(results[3]._value)
-            paged_cache.v_scale._set_value(results[4]._value)
-        paged_cache.k._set_value(pk_new._value)
-        paged_cache.v._set_value(pv_new._value)
-        return out
+        for t, new in zip(pool_in, results[1:]):
+            t._set_value(new._value)
+        return results[0]
 
     def _forward_cached(self, hidden: Tensor, kv_cache, cache_index) -> Tensor:
         """Decode/prefill over the stacked parameters with a STACKED

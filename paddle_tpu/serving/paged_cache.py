@@ -72,8 +72,13 @@ class PagedKVCache(_KVBuffers):
 
     ``stacked=False``: per-layer Tensor pairs ``k[i]/v[i]`` of shape
     ``[num_pages, H, page_size, D]`` (the layered ``GPTModel`` path).
-    ``stacked=True``: single Tensor pair ``[L, num_pages, H, page_size, D]``
-    scanned alongside the stacked decoder parameters.
+    ``stacked=True``: single Tensor pair ``[L, num_pages, H, page_size, D]``.
+    The fused step carries each through its layer loop as ONE donated
+    buffer, viewed as ``[L * num_pages, H, page_size, D]``: layer ``l``
+    addresses page ``l * num_pages + page_id`` and writes a token as rows of
+    ``[L * num_pages * H * page_size, D]``, in place
+    (``GPTStackedDecoder._forward_paged``).  The stored shape is what the
+    allocator, the prefix cache, page hand-off, sharding and checkpoints see.
 
     ``paged`` is the duck-type marker ``models/gpt.py`` dispatches on (a
     paged cache routes attention through the page-table write + paged
@@ -90,6 +95,10 @@ class PagedKVCache(_KVBuffers):
             raise ValueError(
                 f"num_pages={num_pages}: the pool needs the null page plus "
                 "at least one allocatable page")
+        if num_layers * num_pages * num_heads * page_size >= 2 ** 31:
+            raise ValueError(
+                f"a pool of {num_layers} x {num_pages} x {num_heads} x "
+                f"{page_size} rows: the write's row index is int32")
         jd = to_jax_dtype(dtype)
         self.num_layers = num_layers
         self.num_pages = num_pages

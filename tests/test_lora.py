@@ -89,8 +89,7 @@ class TestPoolAccounting:
 # ---------------------------------------------------------------------------
 
 class TestMergedWeightParity:
-    @pytest.mark.parametrize(
-        "stacked", [False, pytest.param(True, marks=pytest.mark.slow)])
+    @pytest.mark.parametrize("stacked", [False, True])
     def test_fp32_token_parity(self, stacked):
         m, cfg = _model(stacked)
         pool = LoRAAdapterPool(cfg, num_adapter_pages=3, rank=3,

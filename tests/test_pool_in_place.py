@@ -1,0 +1,158 @@
+"""The fused serving step updates the K/V pool in place (docs/serving.md
+"Page-table addressing"): the stacked pool rides the layer loop's carry as
+one donated buffer a side and each layer's tokens are written as rows of
+its flat view, so no operation of a step moves a layer's pool.
+
+Two readings of the program hold that, neither a measurement: the step
+compiled for the described v5e (``benchmark/aot_compile.py``, imported
+read-only; skipped where no topology can be described) and the step as it
+is lowered on the CPU."""
+import os
+import re
+import sys
+
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu.models import GPTStackedForPretraining, gpt_tiny
+from paddle_tpu.serving import ServingEngine
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark import aot_compile  # noqa: E402
+from benchmark.harness import manifest  # noqa: E402
+
+CELL = "gpt_1p3b.serve_chat_r80"
+# the cell's widths at a cut depth and pool: seconds to compile, and a
+# layer's pool (67 MB) larger than any weight slice or activation
+LAYERS, PAGES = 4, 128
+
+# results a step may hold at the size of a layer's pool without moving it
+STILL = ("parameter", "tuple", "get-tuple-element", "while", "bitcast", "scatter")
+_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2, "u16": 2,
+             "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8, "u64": 8}
+_INSTRUCTION = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
+_COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
+_ARRAY = re.compile(r"\b([a-z]+\d+|pred)\[([\d,]*)\]")
+
+
+def _largest_array_bytes(type_text: str) -> int:
+    sizes = [_ITEMSIZE.get(dt, 4) * int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+             for dt, dims in _ARRAY.findall(type_text)]
+    return max(sizes, default=0)
+
+
+def _instructions(hlo_text: str):
+    """(computation, name, opcode, largest array of the result in bytes,
+    called computation or None, is ROOT) of every instruction of an optimized
+    HLO module's text."""
+    computation = None
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if not m:
+            c = _COMPUTATION.match(line)
+            computation = c.group(1) if c else computation
+            continue
+        rest = re.sub(r"\{[^{}]*\}", "", m.group(3))      # layouts hold parentheses
+        if rest.startswith("("):                           # a tuple's type
+            depth = 0
+            for end, ch in enumerate(rest):
+                depth += (ch == "(") - (ch == ")")
+                if depth == 0:
+                    break
+            result, tail = rest[:end + 1], rest[end + 1:]
+        else:
+            result, _, tail = rest.partition(" ")
+        op = re.match(r"\s*([\w\-]+)\(", tail)
+        if op is None:
+            continue
+        calls = re.search(r"calls=%?([\w.\-]+)", line)
+        yield (computation, m.group(2), op.group(1), _largest_array_bytes(result),
+               calls.group(1) if calls else None, bool(m.group(1)))
+
+
+def pool_movers(hlo_text: str, layer_pool_bytes: int):
+    """The instructions whose result is a buffer as large as one layer's pool
+    and is neither plumbing nor an in-place scatter (a ``scatter``, or a fusion
+    whose root is one).  What a fused computation holds inside is no buffer."""
+    instructions = list(_instructions(hlo_text))
+    root_op = {comp: op for comp, _, op, _, _, is_root in instructions if is_root}
+    fused = {calls for _, _, op, _, calls, _ in instructions if op == "fusion"}
+    return [(name, op) for comp, name, op, nbytes, calls, _ in instructions
+            if nbytes >= layer_pool_bytes and op not in STILL and comp not in fused
+            and not (op == "fusion" and root_op.get(calls) == "scatter")]
+
+
+def test_the_reader_sees_a_copy_and_passes_an_in_place_scatter():
+    text = """HloModule m, is_scheduled=true
+
+%fused_scatter (p0: bf16[1024,128], p1: s32[8], p2: bf16[8,128]) -> bf16[1024,128] {
+  %p0 = bf16[1024,128]{1,0:T(8,128)(2,1)} parameter(0)
+  %p1 = s32[8]{0} parameter(1)
+  %p2 = bf16[8,128]{1,0} parameter(2)
+  %inside = bf16[1024,128]{1,0:T(8,128)(2,1)} copy(%p0)
+  ROOT %scatter.1 = bf16[1024,128]{1,0:T(8,128)(2,1)} scatter(%p0, %p1, %p2), to_apply=%add
+}
+
+ENTRY %main (a: bf16[2,4,128,128]) -> (s32[], bf16[2,4,128,128]) {
+  %a = bf16[2,4,128,128]{3,2,1,0:T(8,128)(2,1)} parameter(0)
+  %b = bf16[1024,128]{1,0:T(8,128)(2,1)} bitcast(%a)
+  %f = bf16[1024,128]{1,0:T(8,128)(2,1)} fusion(%b, %i, %u), kind=kCustom, calls=%fused_scatter
+  %copy.7 = bf16[4,128,128]{2,1,0:T(8,128)(2,1)} copy(%slice)
+  %w = (s32[]{:T(128)}, bf16[1024,128]{1,0:T(8,128)(2,1)}) while(%t), condition=%c, body=%d
+  ROOT %t = (s32[], bf16[2,4,128,128]{3,2,1,0}) tuple(%n, %f)
+}
+"""
+    assert pool_movers(text, 4 * 128 * 128 * 2) == [("copy.7", "copy")]
+    assert pool_movers(text, 4 * 128 * 128 * 2 + 1) == []
+
+
+@pytest.fixture(scope="module")
+def topo():
+    try:
+        return aot_compile.describe_topology("v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe means skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+def test_no_operation_of_the_compiled_step_moves_a_layers_pool(topo, monkeypatch):
+    ctx = manifest.resolve_cell(CELL)
+    ctx["config"]["model"]["num_layers"] = LAYERS
+    ctx["cell"]["engine"]["num_pages"] = PAGES
+    model, eng = ctx["config"]["model"], ctx["cell"]["engine"]
+    layer_pool = (PAGES * model["num_heads"] * eng["page_size"]
+                  * (model["hidden_size"] // model["num_heads"]) * 2)      # bf16
+    pool = 2 * LAYERS * layer_pool                                         # K and V
+    monkeypatch.setattr(aot_compile, "_report", lambda compiled: compiled)
+    compiled = aot_compile.serve_step(ctx, topo)
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert text.count("tpu_custom_call") == 1
+    assert pool_movers(text, layer_pool) == []
+    assert memory.temp_size_in_bytes < 0.05 * pool, memory.temp_size_in_bytes
+    assert memory.alias_size_in_bytes >= pool, memory.alias_size_in_bytes
+
+
+def test_the_lowered_loop_carries_the_pools():
+    """On the CPU: both flat pools are operands of the layer loop's ``while``
+    and nothing stacks a layer's pool back with ``dynamic_update_slice``."""
+    pt.seed(0)
+    model = GPTStackedForPretraining(gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0))
+    model.eval()
+    engine = ServingEngine(model, num_slots=2, page_size=16, max_context=64,
+                           cache_dtype="float32")
+    try:
+        engine.submit(np.arange(5), 2)
+        engine.step()
+        (text,) = engine.lowered_texts()
+        layers, pages, heads, page, dim = (int(n) for n in engine.cache.k.shape)
+    finally:
+        engine.close()
+    flat = f"tensor<{layers * pages}x{heads}x{page}x{dim}xf32>"
+    (loop,) = [ln for ln in text.splitlines() if "stablehlo.while" in ln]
+    assert loop.count(flat) == 2, loop[-600:]
+    pool_shapes = (flat, f"tensor<{pages}x{heads}x{page}x{dim}xf32>",
+                   f"tensor<{layers}x{pages}x{heads}x{page}x{dim}xf32>")
+    stacked_back = [ln.strip()[:200] for ln in text.splitlines()
+                    if "dynamic_update_slice" in ln and any(s in ln for s in pool_shapes)]
+    assert stacked_back == []

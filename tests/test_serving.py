@@ -460,43 +460,88 @@ def test_continuous_batching_churn_matches_generate():
     assert mets["tokens"] == sum(new_toks)
 
 
+# engine geometry, prompt lengths, new tokens a request.  Each case after
+# the first aims the pool write's indexing (one row per token and head at
+# ``((l*P + page)*H + h)*page_size + offset`` of the flat pool) at an edge
+_MIXED_CASES = {
+    # the tiny budget forces multi-step prefills to overlap other slots'
+    # decode: every step really mixes phases
+    "interleaved": (dict(num_slots=2, page_size=16, prefill_token_budget=6),
+                    (4, 17, 7, 21, 11, 5), 4),
+    # one chunk of 20 prompt tokens spans three 8-position pages
+    "chunk_crosses_pages": (
+        dict(num_slots=2, page_size=8, prefill_token_budget=20), (21, 13), 4),
+    # four slots of six never seat and most of the budget is padding: all
+    # of those rows sink into page 0 of every layer, indices repeating
+    "idle_slots_and_padding": (
+        dict(num_slots=6, page_size=16, prefill_token_budget=16), (5, 3), 4),
+    # a pool with no page to spare: both requests reserve four pages, so
+    # the last page of the pool (and of the last layer) is written
+    "last_page_of_last_layer": (
+        dict(num_slots=2, page_size=16, prefill_token_budget=16,
+             num_pages=9), (58, 59), 5),
+}
+
+
+def _pool_array(side):
+    """A pool side (stacked Tensor or per-layer list) as [L, P, H, ps, D]."""
+    if isinstance(side, (list, tuple)):
+        return np.stack([np.asarray(t.numpy(), np.float32) for t in side])
+    return np.asarray(side.numpy(), np.float32)
+
+
 @pytest.mark.parametrize("model_cls", [GPTForPretraining,
                                        GPTStackedForPretraining])
-@pytest.mark.parametrize("cache_dtype", ["float32", "bfloat16"])
-def test_fused_mixed_step_parity(model_cls, cache_dtype):
+@pytest.mark.parametrize("cache_dtype", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("case", list(_MIXED_CASES))
+def test_fused_mixed_step_parity(model_cls, cache_dtype, case):
     """The fused mixed prefill/decode step across interleaved arrivals:
     greedy output token-for-token equal to single-shot generate() on
-    fp32 AND bf16 pools, layered AND stacked layouts.  The tiny budget
-    forces multi-step prefills to overlap other slots' decode — every
-    step really mixes phases."""
+    fp32 AND bf16 pools, layered AND stacked layouts, in every geometry
+    of ``_MIXED_CASES``."""
+    eng_kw, lengths, n_new = _MIXED_CASES[case]
+    # an int8 pool (pages quantized on write, scale sidecars indexed by
+    # the same offset page ids) reproduces the fp32 reference
+    ref_dtype = "float32" if cache_dtype == "int8" else cache_dtype
     pt.seed(3)
     cfg = _tiny_cfg()
     m = model_cls(cfg)
     m.eval()
     rng = np.random.RandomState(2)
-    prompts = [rng.randint(0, cfg.vocab_size, (s,))
-               for s in (4, 17, 7, 21, 11, 5)]
+    prompts = [rng.randint(0, cfg.vocab_size, (s,)) for s in lengths]
     refs = [np.asarray(m.generate(pt.to_tensor(p[None, :], dtype="int64"),
-                                  max_new_tokens=4, max_seq_len=64,
-                                  cache_dtype=cache_dtype).numpy())[0]
+                                  max_new_tokens=n_new, max_seq_len=64,
+                                  cache_dtype=ref_dtype).numpy())[0]
             for p in prompts]
-    eng = ServingEngine(m, num_slots=2, page_size=16, max_context=64,
-                        cache_dtype=cache_dtype, prefill_token_budget=6)
-    reqs, it = [], iter(prompts)
+    eng = ServingEngine(m, max_context=64, cache_dtype=cache_dtype, **eng_kw)
+    pool = eng.cache
+    # the write's row index is int32
+    assert (pool.num_layers * pool.num_pages * pool.num_heads
+            * pool.page_size) < 2 ** 31
+    reqs, it, touched = [], iter(prompts), set()
     while len(reqs) < len(prompts) or eng.queue.depth \
             or eng.scheduler.active_slots:
         try:
-            reqs.append(eng.submit(next(it), 4))
+            reqs.append(eng.submit(next(it), n_new))
         except StopIteration:
             pass
         met = eng.step()
         assert met["pages_used"] <= eng.allocator.capacity
+        touched |= set(eng.allocator._allocated)
     for r, ref in zip(reqs, refs):
         assert r.finished
         assert np.array_equal(r.output_ids(), ref), (
             model_cls.__name__, cache_dtype, r.id)
     assert eng.compiled_programs == 1
     assert eng.allocator.used_pages == 0
+    # every layer wrote the same pages, all of them pages the allocator
+    # dealt (or the null page, the sink): a wrong offset lands elsewhere
+    for side in (pool.k, pool.v):
+        written = np.abs(_pool_array(side)).sum(axis=(2, 3, 4)) > 0  # [L, P]
+        assert written[0, 1:].any() and (written == written[0]).all(), case
+        assert set(np.flatnonzero(written[0, 1:]) + 1) <= touched, case
+    if case == "last_page_of_last_layer":
+        assert pool.num_pages - 1 in touched and written[-1, -1]
     eng.close()
 
 

@@ -84,8 +84,8 @@ def _fresh_model(model_cls):
 _ORACLES: dict = {}
 
 
-def _oracles(model_cls):
-    if model_cls not in _ORACLES:
+def _oracles(model_cls, kv_dtype="float32"):
+    if (model_cls, kv_dtype) not in _ORACLES:
         cfg = _tiny_cfg()
         prompts, new_toks = _workload(cfg)
         ref_model = _fresh_model(model_cls)
@@ -98,30 +98,35 @@ def _oracles(model_cls):
         # prefill compile.  The slow mirror below keeps the DIRECT
         # generate() comparison for every (dp, mp) config.
         chip = ServingEngine(ref_model, num_slots=2, page_size=16,
-                             max_context=64, cache_dtype="float32")
+                             max_context=64, cache_dtype=kv_dtype)
         chip_reqs = [chip.submit(p, n)
                      for p, n in zip(prompts, new_toks)]
         chip.run_until_idle()
         chip_out = [r.output_ids() for r in chip_reqs]
         chip.close()
-        _ORACLES[model_cls] = (ref_model, prompts, new_toks, chip_out)
-    return _ORACLES[model_cls]
+        _ORACLES[model_cls, kv_dtype] = (ref_model, prompts, new_toks,
+                                         chip_out)
+    return _ORACLES[model_cls, kv_dtype]
 
 
 # ---------------------------------------------------------------------------
 # parity: sharded greedy == single-chip generate() == single-chip engine
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("model_cls", [GPTForPretraining,
-                                       GPTStackedForPretraining])
-@pytest.mark.parametrize("dp,mp", MESHES)
-def test_sharded_greedy_parity(model_cls, dp, mp):
-    model, prompts, new_toks, chip_out = _oracles(model_cls)
+# the last case: an int8 pool's pages AND scale sidecars shard per head
+# under mp = 2 and reproduce the single-chip int8 engine
+@pytest.mark.parametrize("model_cls,dp,mp,kv_dtype", [
+    (cls, dp, mp, "float32")
+    for dp, mp in MESHES
+    for cls in (GPTForPretraining, GPTStackedForPretraining)
+] + [(GPTStackedForPretraining, 1, 2, "int8")])
+def test_sharded_greedy_parity(model_cls, dp, mp, kv_dtype):
+    model, prompts, new_toks, chip_out = _oracles(model_cls, kv_dtype)
 
     serving.reset_serve_trace_counts()
     eng = ShardedServingEngine(model, dp=dp, mp=mp,
                                num_slots=2, page_size=16, max_context=64,
-                               cache_dtype="float32")
+                               cache_dtype=kv_dtype)
     reqs = [eng.submit(p, n) for p, n in zip(prompts, new_toks)]
     eng.run_until_idle(max_steps=2000)
     tc = serving.serve_trace_counts()
