@@ -20,10 +20,11 @@ into token granularity:
   the scheduler's host mirrors (``build_ragged_plan``).  The work-list
   arrays ride as **scalar-prefetch** arguments so the KV index map
   resolves each entry's POOL page id before its DMA is issued;
-- entries past the real item count are clamped (the host repeats the last
-  real entry), so their block indices repeat and Pallas elides both the
-  copy and (via ``pl.when``) the compute — the same discipline as the
-  paged kernel's clamped page-slots, now applied to the whole launch;
+- the plan arrays pad to engine-constant maxima, the LAUNCH does not: the
+  grid's second dimension is the step's real item count (``n_items``, a
+  traced scalar), so one compiled program walks exactly its work list and
+  no grid step is spent on the arrays' tail (which repeats the last real
+  entry only so that every index it holds stays valid);
 - online softmax accumulates across a block's work items (running max m,
   denominator l, fp32 acc); per-item masking is causal at token
   granularity: row i of block b (absolute position ``blk_base[b] + i``)
@@ -75,7 +76,7 @@ RAGGED_PLAN_FIELDS = (
     "wl_blk",       # [WL]      work item -> token block
     "wl_page",      # [WL]      work item -> POOL page id (pre-translated)
     "wl_pageslot",  # [WL]      work item -> page-slot (for position math)
-    "n_items",      # [1]       real work items (tail entries are clamped)
+    "n_items",      # [1]       real work items: the launch's length
 )
 
 
@@ -114,7 +115,7 @@ def ragged_token_block(page_size: int, head_dim: int, dtype,
 
     ``local_heads``: the POST-SHARD head count when the pool is sharded
     per-head over ``mp`` (docs/serving.md "Sharded serving").  It joins
-    the shape key — the sharded launch's grid is ``(H/mp, WL)``, a
+    the shape key — the sharded launch's grid is ``(H/mp, n_items)``, a
     different specialization than the full-head pool, so a winner
     measured unsharded must not silently dispatch a shard and vice
     versa.  Unsharded lookups keep the historical key (committed table
@@ -150,17 +151,18 @@ def build_ragged_plan(runs: Sequence[Tuple[int, int, np.ndarray]], *,
     order (``stats["run_starts"]`` reports the starts).
 
     Every array is padded to its fixed maximum (``t_max``/``nb_max``/
-    ``wl_max``) so the compiled step never retraces; the work-list tail
-    REPEATS the last real entry — its block and page indices then repeat,
-    Pallas elides the DMAs, and ``pl.when(w < n_items)`` skips the
-    compute.  Padding block-gather rows point at the block's first token
-    (a valid index; the row is masked in-kernel and discarded by the
-    output gather).
+    ``wl_max``) so the compiled step never retraces; the kernel's grid
+    ends at ``n_items``, so the work-list tail is never walked: it
+    REPEATS the last real entry only to hold valid indices (the last
+    item's look-ahead reads one).  Padding block-gather rows point at the
+    block's first token (a valid index; the row is masked in-kernel and
+    discarded by the output gather).
 
     Returns ``(plan_arrays, stats)``: the arrays keyed by
     :data:`RAGGED_PLAN_FIELDS`, and stats with ``n_tokens``/``n_blocks``/
-    ``n_items``/``run_starts`` plus the grid-occupancy numerators the
-    serving metrics report."""
+    ``n_items``/``run_starts``, the occupancy numerators the serving
+    metrics report, and ``launched_items`` (the launch's second grid
+    dimension for this step)."""
     qb = int(token_block)
     blk_tok = np.zeros((nb_max, qb), np.int32)
     tok_blk = np.zeros((t_max,), np.int32)
@@ -222,10 +224,13 @@ def build_ragged_plan(runs: Sequence[Tuple[int, int, np.ndarray]], *,
     stats = {
         "n_tokens": t, "n_blocks": b, "n_items": n_items,
         "run_starts": run_starts,
-        # grid occupancy: the fraction of the fixed launch doing real work
-        # (items) and of the block rows carrying real queries (rows)
+        # occupancy: the fraction of the work-list arrays holding real
+        # items and of the block rows carrying real queries
         "wl_capacity": wl_max,
         "row_capacity": b * qb,
+        # the launch's second grid dimension for this step: the kernel's
+        # grid ends at n_items, whatever the arrays' capacity
+        "launched_items": n_items,
     }
     return plan, stats
 
@@ -245,61 +250,59 @@ def _ragged_kernel(blk_ref, page_ref, ps_ref, ni_ref, base_ref, rows_ref,
         ks_ref, vs_ref, o_ref, acc_sc, m_sc, l_sc = rest
     else:
         o_ref, acc_sc, m_sc, l_sc = rest
+    # the grid's second dimension is n_items, so every step is a real item
     w = pl.program_id(1)
     n = ni_ref[0]
     blk = blk_ref[w]
-    live = w < n
     # block boundaries derived from the prefetched work list: a block's
     # items are contiguous, so its first/last entries bracket its online-
-    # softmax accumulation.  The tail's clamped entries repeat the last
-    # real block, so `last` fires exactly at item n-1 (not in the tail).
+    # softmax accumulation.  The look-ahead at item n-1 may read the
+    # array's clamped tail (the last real block again), hence `w == n - 1`.
     first = jnp.logical_or(w == 0, blk_ref[jnp.maximum(w - 1, 0)] != blk)
     last = jnp.logical_or(w == n - 1,
                           blk_ref[jnp.minimum(w + 1, wl_max - 1)] != blk)
 
-    @pl.when(jnp.logical_and(live, first))
+    @pl.when(first)
     def _init():
         acc_sc[...] = jnp.zeros_like(acc_sc)
         m_sc[...] = jnp.full_like(m_sc, NEG_INF)
         l_sc[...] = jnp.zeros_like(l_sc)
 
-    @pl.when(live)
-    def _body():
-        q = q_ref[0, 0]                             # [QB, D]
-        if quantized:
-            # in-kernel dequant: int8 page x its (page, head) scale ->
-            # fp32 operands (q arrives fp32 on this path; the online-
-            # softmax accumulation below is fp32 regardless)
-            k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]
-            v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
-        else:
-            k = k_ref[0, 0]                         # [page_size, D]
-            v = v_ref[0, 0]
-        s = _dot(q, k, ((1,), (1,))) * np.float32(scale)   # [QB, page_size]
-        rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        cols = ps_ref[w] * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        # token-granular causality: row i sits at absolute position
-        # blk_base + i and may read every pool position <= its own; rows
-        # past blk_rows are block padding (masked everywhere — their
-        # output rows are finite garbage the host gather never reads)
-        row_pos = base_ref[blk] + rows
-        valid = jnp.logical_and(cols <= row_pos, rows < rows_ref[blk])
-        s = jnp.where(valid, s, NEG_INF)
+    q = q_ref[0, 0]                             # [QB, D]
+    if quantized:
+        # in-kernel dequant: int8 page x its (page, head) scale ->
+        # fp32 operands (q arrives fp32 on this path; the online-
+        # softmax accumulation below is fp32 regardless)
+        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]
+        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
+    else:
+        k = k_ref[0, 0]                         # [page_size, D]
+        v = v_ref[0, 0]
+    s = _dot(q, k, ((1,), (1,))) * np.float32(scale)   # [QB, page_size]
+    rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    cols = ps_ref[w] * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, s.shape, 1)
+    # token-granular causality: row i sits at absolute position
+    # blk_base + i and may read every pool position <= its own; rows
+    # past blk_rows are block padding (masked everywhere — their
+    # output rows are finite garbage the host gather never reads)
+    row_pos = base_ref[blk] + rows
+    valid = jnp.logical_and(cols <= row_pos, rows < rows_ref[blk])
+    s = jnp.where(valid, s, NEG_INF)
 
-        m_prev = m_sc[:, :1]                        # [QB, 1]
-        l_prev = l_sc[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        l_cur = jnp.sum(p, axis=-1, keepdims=True)
-        alpha = jnp.exp(m_prev - m_new)
-        acc_sc[...] = acc_sc[...] * alpha + _dot(p.astype(v.dtype), v,
-                                                 ((1,), (0,)))
-        m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
-        l_sc[...] = jnp.broadcast_to(alpha * l_prev + l_cur, l_sc.shape)
+    m_prev = m_sc[:, :1]                        # [QB, 1]
+    l_prev = l_sc[:, :1]
+    m_cur = jnp.max(s, axis=-1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_cur)
+    p = jnp.exp(s - m_new)
+    l_cur = jnp.sum(p, axis=-1, keepdims=True)
+    alpha = jnp.exp(m_prev - m_new)
+    acc_sc[...] = acc_sc[...] * alpha + _dot(p.astype(v.dtype), v,
+                                             ((1,), (0,)))
+    m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
+    l_sc[...] = jnp.broadcast_to(alpha * l_prev + l_cur, l_sc.shape)
 
-    @pl.when(jnp.logical_and(live, last))
+    @pl.when(last)
     def _finish():
         l = l_sc[:, :1]
         l_safe = jnp.where(l == 0.0, np.float32(1.0), l)
@@ -314,13 +317,14 @@ def _ragged_pallas(q_blocks, k_pool, v_pool, wl_blk, wl_page, wl_ps,
     :data:`RAGGED_PLAN_FIELDS` -> [NB, H, QB, D].  ``interpret=True`` runs
     the Pallas interpreter (CPU numerics check).
 
-    The grid is ``(H, WL)`` — heads parallel, work items sequential so a
-    block's online softmax accumulates across its pages.  All plan arrays
-    ride as scalar prefetch: the KV index map reads the work item's POOL
-    page id (pre-translated on host) before each DMA, the q/out index
-    maps its block.  Consecutive items of one block repeat the q/out block
-    index (copies elided); the clamped tail repeats the last real entry
-    (everything elided) and ``pl.when(w < n_items)`` skips its compute."""
+    The grid is ``(H, n_items)`` — heads parallel, work items sequential
+    so a block's online softmax accumulates across its pages — with
+    ``n_items`` the traced item count: the arrays keep their constant
+    ``wl_max`` length (one compile, no retrace), the launch is as long as
+    the step's work list.  All plan arrays ride as scalar prefetch: the
+    KV index map reads the work item's POOL page id (pre-translated on
+    host) before each DMA, the q/out index maps its block.  Consecutive
+    items of one block repeat the q/out block index (copies elided)."""
     nb, h, qb, d = q_blocks.shape
     page_size = k_pool.shape[2]
     wl_max = wl_blk.shape[0]
@@ -351,9 +355,10 @@ def _ragged_pallas(q_blocks, k_pool, v_pool, wl_blk, wl_page, wl_ps,
         in_specs += [pl.BlockSpec((1, 1), scale_index),
                      pl.BlockSpec((1, 1), scale_index)]
         operands += [k_scale, v_scale]
+    n_items = jnp.reshape(n_items, (1,)).astype(jnp.int32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
-        grid=(h, wl_max),
+        grid=(h, n_items[0]),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, qb, d), q_index),
         scratch_shapes=[
@@ -371,7 +376,7 @@ def _ragged_pallas(q_blocks, k_pool, v_pool, wl_blk, wl_page, wl_ps,
         ),
         interpret=interpret,
     )(wl_blk.astype(jnp.int32), wl_page.astype(jnp.int32),
-      wl_ps.astype(jnp.int32), jnp.reshape(n_items, (1,)).astype(jnp.int32),
+      wl_ps.astype(jnp.int32), n_items,
       blk_base.astype(jnp.int32), blk_rows.astype(jnp.int32),
       *operands)
     return out

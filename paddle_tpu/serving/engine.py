@@ -761,6 +761,7 @@ class ServingEngine:
                         # numerators/denominators (see metrics())
                         "fused_steps": 0, "prefill_tokens": 0,
                         "work_items": 0, "work_capacity": 0,
+                        "launched_items": 0,
                         "block_rows": 0, "block_row_capacity": 0,
                         # host-packing padding cost in GL002's units
                         # (analysis/cost_model.ragged_padding_waste): block
@@ -1276,6 +1277,7 @@ class ServingEngine:
         self._totals["prefill_tokens"] += prefill
         self._totals["work_items"] += stats["n_items"]
         self._totals["work_capacity"] += stats["wl_capacity"]
+        self._totals["launched_items"] += stats["launched_items"]
         self._totals["block_rows"] += stats["n_tokens"]
         self._totals["block_row_capacity"] += stats["row_capacity"]
         waste = ragged_padding_waste(
@@ -1850,9 +1852,12 @@ class ServingEngine:
     def metrics(self) -> dict:
         """Cumulative totals + the last step's gauges.  The ragged-launch
         occupancy means make the fused step's win measurable: how full the
-        fixed work-list grid ran (``mean_grid_occupancy``) and how many of
-        the packed query-block rows carried real tokens
-        (``mean_q_row_occupancy``) across every dispatched step."""
+        plan's fixed work-list arrays ran (``mean_grid_occupancy``), how
+        many of the kernel's grid steps along the work list were real
+        items (``mean_launch_occupancy``: 1.0 while the launch ends at
+        ``n_items``) and how many of the packed query-block rows carried
+        real tokens (``mean_q_row_occupancy``) across every dispatched
+        step."""
         out = dict(self._totals)
         out.update(self._last_metrics)
         out["queue_depth"] = self.queue.depth
@@ -1873,6 +1878,9 @@ class ServingEngine:
                                       if wc else 0.0)
         out["mean_q_row_occupancy"] = (self._totals["block_rows"] / rc
                                        if rc else 0.0)
+        li = self._totals["launched_items"]
+        out["mean_launch_occupancy"] = (self._totals["work_items"] / li
+                                        if li else 0.0)
         # per-request SLO digests (seconds): count/sum/mean/min/max +
         # p50/p95/p99 per histogram — TTFT, inter-token latency, queue
         # wait, end-to-end (docs/observability.md "SLO definitions")

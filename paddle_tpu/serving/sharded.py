@@ -429,7 +429,8 @@ class ShardedServingEngine:
                     "timed_out", "shed", "quarantined", "recoveries",
                     "rebuilds", "pages_used", "pages_capacity",
                     "active_slots", "queue_depth", "cache_bytes",
-                    "work_items", "work_capacity", "block_rows",
+                    "work_items", "work_capacity", "launched_items",
+                    "block_rows",
                     "block_row_capacity", "padded_rows", "padded_flops",
                     # per-replica prefix caches (docs/serving.md "Prefix
                     # cache"): hits/misses sum exactly; hit RATE is

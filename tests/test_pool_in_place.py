@@ -6,7 +6,13 @@ its flat view, so no operation of a step moves a layer's pool.
 Two readings of the program hold that, neither a measurement: the step
 compiled for the described v5e (``benchmark/aot_compile.py``, imported
 read-only; skipped where no topology can be described) and the step as it
-is lowered on the CPU."""
+is lowered on the CPU.
+
+The same compiled step holds what PR 30 made of its ragged launch: the
+kernel's grid ends at the step's item count (docs/serving.md "Fused mixed
+step"), which the Mosaic module inside the ``tpu_custom_call`` states as a
+dynamic iteration bound."""
+import base64
 import os
 import re
 import sys
@@ -116,21 +122,84 @@ def topo():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
-def test_no_operation_of_the_compiled_step_moves_a_layers_pool(topo, monkeypatch):
+@pytest.fixture(scope="module")
+def compiled_step(topo):
+    """The cell's fused step at its cut depth and pool, compiled once for
+    the described chip: ``(context, compiled)``."""
     ctx = manifest.resolve_cell(CELL)
     ctx["config"]["model"]["num_layers"] = LAYERS
     ctx["cell"]["engine"]["num_pages"] = PAGES
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(aot_compile, "_report", lambda compiled: compiled)
+        return ctx, aot_compile.serve_step(ctx, topo)
+
+
+def test_no_operation_of_the_compiled_step_moves_a_layers_pool(compiled_step):
+    ctx, compiled = compiled_step
     model, eng = ctx["config"]["model"], ctx["cell"]["engine"]
     layer_pool = (PAGES * model["num_heads"] * eng["page_size"]
                   * (model["hidden_size"] // model["num_heads"]) * 2)      # bf16
     pool = 2 * LAYERS * layer_pool                                         # K and V
-    monkeypatch.setattr(aot_compile, "_report", lambda compiled: compiled)
-    compiled = aot_compile.serve_step(ctx, topo)
     text, memory = compiled.as_text(), compiled.memory_analysis()
     assert text.count("tpu_custom_call") == 1
     assert pool_movers(text, layer_pool) == []
     assert memory.temp_size_in_bytes < 0.05 * pool, memory.temp_size_in_bytes
     assert memory.alias_size_in_bytes >= pool, memory.alias_size_in_bytes
+
+
+_DYNAMIC = -2 ** 63         # MLIR's ShapedType::kDynamic
+
+
+def mosaic_iteration_bounds(text: str):
+    """The iteration bounds of every Mosaic kernel in a program's text,
+    StableHLO or optimized HLO: the custom call carries its module as MLIR
+    bytecode in base64 under ``body``."""
+    from jaxlib.mlir import ir
+
+    out = []
+    for body in re.findall(r'body(?:\\22|"): ?(?:\\22|")([A-Za-z0-9+/=]+)', text):
+        context = ir.Context()
+        context.allow_unregistered_dialects = True
+        with context:
+            module = ir.Module.parse(base64.b64decode(body))
+            asm = module.operation.get_asm(print_generic_op_form=True)
+        out += [[int(b) for b in bounds.split(",")]
+                for bounds in re.findall(r"iteration_bounds = array<i64: ([^>]*)>", asm)]
+    return out
+
+
+def test_the_reader_of_iteration_bounds_reads_a_static_and_a_dynamic_launch():
+    """Two kernels lowered for the TPU platform from here (no topology, no
+    compile): a static grid reads as its numbers, a traced length as dynamic."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    def copy_kernel(x_ref, o_ref):
+        o_ref[...] = x_ref[...]
+
+    def launch(x, n):
+        spec = pl.BlockSpec((8, 128), lambda i, j: (i, 0))
+        return pl.pallas_call(copy_kernel, grid=(4, n), in_specs=[spec], out_specs=spec,
+                              out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype))(x)
+
+    x = jax.ShapeDtypeStruct((32, 128), jnp.float32)
+    n = jax.ShapeDtypeStruct((), jnp.int32)
+    static = jax.export.export(jax.jit(lambda x: launch(x, 3)), platforms=["tpu"])(x)
+    dynamic = jax.export.export(jax.jit(launch), platforms=["tpu"])(x, n)
+    assert mosaic_iteration_bounds(static.mlir_module()) == [[4, 3]]
+    assert mosaic_iteration_bounds(dynamic.mlir_module()) == [[4, _DYNAMIC]]
+
+
+def test_the_compiled_steps_ragged_launch_ends_at_the_item_count(compiled_step):
+    """The step's one Mosaic call (``_ragged_kernel``, the only kernel the
+    cell names) runs a grid of the model's heads by a DYNAMIC second
+    dimension: the day the launch is again as long as ``wl_max`` (48 x 8 =
+    384 in this cell) that reads 384."""
+    ctx, compiled = compiled_step
+    assert ctx["cell"]["mosaic_kernels"] == ["_ragged_kernel"]
+    heads = ctx["config"]["model"]["num_heads"]
+    assert mosaic_iteration_bounds(compiled.as_text()) == [[heads, _DYNAMIC]]
 
 
 def test_the_lowered_loop_carries_the_pools():

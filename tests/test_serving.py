@@ -202,6 +202,207 @@ def test_ragged_kernel_parity_interpret():
                                    atol=tol)
 
 
+def _static_grid_kernel(blk_ref, page_ref, ps_ref, ni_ref, base_ref, rows_ref,
+                        q_ref, k_ref, v_ref, *rest, scale, page_size, wl_max,
+                        quantized=False):
+    """The kernel as it was while the launch was ``wl_max`` items long
+    (before PR 30): every grid step past ``n_items`` is skipped by its
+    ``live`` guards.  Kept here as the oracle of the launch that ends at
+    ``n_items``: the same operations in the same order for every real item."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    from paddle_tpu.ops.pallas_kernels.decode_attention import NEG_INF, _dot
+
+    if quantized:
+        ks_ref, vs_ref, o_ref, acc_sc, m_sc, l_sc = rest
+    else:
+        o_ref, acc_sc, m_sc, l_sc = rest
+    w = pl.program_id(1)
+    n = ni_ref[0]
+    blk = blk_ref[w]
+    live = w < n
+    first = jnp.logical_or(w == 0, blk_ref[jnp.maximum(w - 1, 0)] != blk)
+    last = jnp.logical_or(w == n - 1,
+                          blk_ref[jnp.minimum(w + 1, wl_max - 1)] != blk)
+
+    @pl.when(jnp.logical_and(live, first))
+    def _init():
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+
+    @pl.when(live)
+    def _body():
+        q = q_ref[0, 0]
+        if quantized:
+            k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]
+            v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
+        else:
+            k = k_ref[0, 0]
+            v = v_ref[0, 0]
+        s = _dot(q, k, ((1,), (1,))) * np.float32(scale)
+        rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        cols = ps_ref[w] * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        row_pos = base_ref[blk] + rows
+        valid = jnp.logical_and(cols <= row_pos, rows < rows_ref[blk])
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_sc[:, :1]
+        l_prev = l_sc[:, :1]
+        m_cur = jnp.max(s, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        p = jnp.exp(s - m_new)
+        l_cur = jnp.sum(p, axis=-1, keepdims=True)
+        alpha = jnp.exp(m_prev - m_new)
+        acc_sc[...] = acc_sc[...] * alpha + _dot(p.astype(v.dtype), v,
+                                                 ((1,), (0,)))
+        m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
+        l_sc[...] = jnp.broadcast_to(alpha * l_prev + l_cur, l_sc.shape)
+
+    @pl.when(jnp.logical_and(live, last))
+    def _finish():
+        l = l_sc[:, :1]
+        l_safe = jnp.where(l == 0.0, np.float32(1.0), l)
+        o_ref[0, 0] = (acc_sc[...] / l_safe).astype(o_ref.dtype)
+
+
+def _static_grid_ragged_pallas(q_blocks, k_pool, v_pool, wl_blk, wl_page,
+                               wl_ps, n_items, blk_base, blk_rows, scale,
+                               interpret=False, k_scale=None, v_scale=None):
+    """``_ragged_pallas`` with the static grid ``(H, wl_max)`` it had."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    nb, h, qb, d = q_blocks.shape
+    page_size = k_pool.shape[2]
+    wl_max = wl_blk.shape[0]
+    quantized = k_scale is not None
+
+    def q_index(hh, w, blk_ref, *_):
+        return (blk_ref[w], hh, np.int32(0), np.int32(0))
+
+    def kv_index(hh, w, blk_ref, page_ref, *_):
+        return (page_ref[w], hh, np.int32(0), np.int32(0))
+
+    def scale_index(hh, w, blk_ref, page_ref, *_):
+        return (page_ref[w], hh)
+
+    in_specs = [pl.BlockSpec((1, 1, qb, d), q_index),
+                pl.BlockSpec((1, 1, page_size, d), kv_index),
+                pl.BlockSpec((1, 1, page_size, d), kv_index)]
+    operands = [q_blocks, k_pool, v_pool]
+    if quantized:
+        in_specs += [pl.BlockSpec((1, 1), scale_index)] * 2
+        operands += [k_scale, v_scale]
+    return pl.pallas_call(
+        functools.partial(_static_grid_kernel, scale=scale,
+                          page_size=page_size, wl_max=wl_max,
+                          quantized=quantized),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6, grid=(h, wl_max), in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, 1, qb, d), q_index),
+            scratch_shapes=[pltpu.VMEM((qb, d), jnp.float32),
+                            pltpu.VMEM((qb, 128), jnp.float32),
+                            pltpu.VMEM((qb, 128), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((nb, h, qb, d), q_blocks.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(wl_blk.astype(jnp.int32), wl_page.astype(jnp.int32),
+      wl_ps.astype(jnp.int32), jnp.reshape(n_items, (1,)).astype(jnp.int32),
+      blk_base.astype(jnp.int32), blk_rows.astype(jnp.int32), *operands)
+
+
+# the launch's length follows the work list: three fills of ONE geometry
+# (T_MAX 32, NB_MAX 8, WL_MAX 32, four pages a slot) for one jitted function
+_LAUNCH_RUNS = {
+    # a single decode token on its first page: the grid is (H, 1)
+    1: [(5, 1, np.array([3, 0, 0, 0], np.int32))],
+    # the mixed step of test_ragged_kernel_parity_interpret
+    7: [(200, 1, np.array([4, 2, 9, 1], np.int32)),
+        (0, 1, np.array([3, 0, 0, 0], np.int32)),
+        (120, 16, np.array([7, 5, 8, 6], np.int32)),
+        (17, 5, np.array([10, 0, 0, 0], np.int32))],
+    # every block reads all four pages of its slot: the list is full and the
+    # launch is as long as the arrays, as every launch was before
+    32: [(384 + 9 * i, 1, np.roll(np.array([1, 2, 3, 4, 5, 6, 7, 8], np.int32),
+                                  i)[:4]) for i in range(8)],
+}
+_LAUNCH_POOLS = {"float32": 5e-6, "bfloat16": 2e-2, "int8": 5e-6}
+
+
+@pytest.fixture(scope="module")
+def launch_fns():
+    """One jitted ragged attention a pool dtype, shared by that dtype's
+    cases, so that its trace count spans their item counts."""
+    import jax
+
+    from paddle_tpu.ops.pallas_kernels import ragged_paged_attention as ra
+
+    def make():           # a function object each: jit counts traces by it
+        def fn(q, kp, vp, tables, lengths, plan, **scales):
+            return ra.ragged_paged_attention(
+                q, kp, vp, tables, lengths, plan, sm_scale=0.125,
+                interpret=True, **scales)
+        return jax.jit(fn)
+    return {pool: make() for pool in _LAUNCH_POOLS}
+
+
+@pytest.mark.parametrize("n_items", list(_LAUNCH_RUNS))
+@pytest.mark.parametrize("pool", list(_LAUNCH_POOLS))
+def test_ragged_launch_follows_the_work_list(pool, n_items, launch_fns,
+                                             monkeypatch):
+    """The launch that ends at ``n_items`` (1, a mid fill, ``wl_max``):
+    equal to the gather oracle within the pool's tolerance, BITWISE the
+    static-grid launch on the same plan, and one trace of the jitted
+    function for every item count; fp32, bf16 and int8 pools."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_kernels import ragged_paged_attention as ra
+
+    rng = np.random.RandomState(n_items)
+    P, H, PS, D, MP = 11, 2, 128, 64, 4
+    T_MAX, NB_MAX, WL_MAX = 32, 8, 32
+    plan_np, stats, tables, lengths = _mk_ragged_case(
+        _LAUNCH_RUNS[n_items], T_MAX, NB_MAX, WL_MAX, MP)
+    assert stats["n_items"] == stats["launched_items"] == n_items
+    real = stats["n_tokens"]
+    if pool == "int8":
+        q = jnp.array(rng.randn(T_MAX, H, D), jnp.float32)
+        kp, vp = (jnp.array(rng.randint(-127, 128, (P, H, PS, D)), jnp.int8)
+                  for _ in range(2))
+        ks, vs = (jnp.array(rng.uniform(0.005, 0.02, (P, H)), jnp.float32)
+                  for _ in range(2))
+        scales = dict(k_scale=ks, v_scale=vs)
+    else:
+        q, kp, vp = (jnp.array(rng.randn(*shape), pool)
+                     for shape in ((T_MAX, H, D),) + ((P, H, PS, D),) * 2)
+        scales = {}
+    plan = tuple(jnp.array(plan_np[k]) for k in ra.RAGGED_PLAN_FIELDS)
+    tables, lengths = jnp.array(tables), jnp.array(lengths)
+
+    fn = launch_fns[pool]
+    got = np.asarray(fn(q, kp, vp, tables, lengths, plan, **scales),
+                     np.float32)
+    assert fn._cache_size() == 1       # no retrace, whatever ran before
+    ref = np.asarray(ra._xla_ragged_reference(
+        q, kp, vp, tables, lengths, 0.125, **scales), np.float32)
+    np.testing.assert_allclose(got[:real], ref[:real],
+                               rtol=_LAUNCH_POOLS[pool],
+                               atol=_LAUNCH_POOLS[pool])
+    monkeypatch.setattr(ra, "_ragged_pallas", _static_grid_ragged_pallas)
+    static = np.asarray(ra.ragged_paged_attention(
+        q, kp, vp, tables, lengths, plan, sm_scale=0.125, interpret=True,
+        **scales), np.float32)
+    np.testing.assert_array_equal(got[:real], static[:real])
+
+
 def test_ragged_reference_zero_length_and_decode_equivalence():
     """The oracle's semantics: a zero-length token emits zeros, and a
     one-token-per-slot plan is bitwise the paged decode reference (the
@@ -235,11 +436,13 @@ def test_ragged_plan_builder_shapes_and_guards():
     assert stats["n_tokens"] == 11 and stats["n_blocks"] == 3
     # items: block0 (rows 0-7, 1 page) + block1 (rows 8-9, 1 page)
     #        + block2 (decode pos 130 -> 2 pages)
-    assert stats["n_items"] == 4
+    assert stats["n_items"] == stats["launched_items"] == 4
+    assert stats["wl_capacity"] == 8
     assert stats["run_starts"] == [0, 10]
     assert plan["blk_rows"].tolist()[:3] == [8, 2, 1]
     assert plan["blk_base"].tolist()[:3] == [0, 8, 130]
-    # work-list tail repeats the last real entry (clamped -> elided)
+    # the arrays' tail repeats the last real entry (valid indices the
+    # launch never reaches, but for the last item's look-ahead)
     assert plan["wl_blk"][stats["n_items"]:].tolist() == [2] * 4
     assert plan["wl_page"][3] == 3        # decode's second page-slot
     # overflow guards: the engine sizes the maxima so these never fire
@@ -612,8 +815,13 @@ def test_invocation_counters_exact():
         # every fused dispatch is a tick, but not every tick dispatched
         # (the idle tick above never ran the program)
         assert 0 < mets["fused_steps"] < mets["steps"]
-        assert 0.0 < mets["mean_grid_occupancy"] <= 1.0
+        assert 0.0 < mets["mean_grid_occupancy"] < 1.0
         assert 0.0 < mets["mean_q_row_occupancy"] <= 1.0
+        # the arrays pad to their capacity, the launch does not: every
+        # grid step of every dispatched kernel was a real work item
+        assert mets["launched_items"] == mets["work_items"] > 0
+        assert mets["launched_items"] < mets["work_capacity"]
+        assert mets["mean_launch_occupancy"] == 1.0
         # host-packing padding cost (cost_model.ragged_padding_waste):
         # a decode token fills 1 of token_block rows, so a decode-heavy
         # run must report padded rows and the matching padded-away flops

@@ -26,8 +26,8 @@ otherwise (the numbers are then about the SCHEDULER, not the chip).
 ``--lengths zipf`` draws prompt lengths from a bounded Zipf long-tail
 instead of the fixed cycle — the skewed regime production traffic shows
 and exactly where the ragged fused step beats the retired two-phase
-design; ``grid_occupancy`` / ``q_row_occupancy`` (work items per fixed
-launch, real query rows per packed block row) make that win measurable
+design; ``grid_occupancy`` / ``q_row_occupancy`` (work items per work-list
+capacity, real query rows per packed block row) make that win measurable
 rather than anecdotal.
 
 Gate mode (--gate, wired into run_tests.sh; PADDLE_TPU_SKIP_SERVING_GATE=1
