@@ -864,8 +864,8 @@ class _Acc:
 
 def _shard_count(eqn) -> int:
     """Shards a ``shard_map`` eqn's body runs as: the product of the mesh
-    axes the body handles manually (every mesh axis minus the ``auto``
-    set GSPMD keeps).  The body's jaxpr has PER-SHARD shapes, so its
+    axes the body handles manually (the eqn's ``manual_axes``; every mesh
+    axis where it names none).  The body's jaxpr has PER-SHARD shapes, so its
     costs multiply by this to stay in global units.  Defensive: any
     unreadable params count as 1 (never crash a lint/cost pass on an odd
     jax version — the satellite contract of ISSUE 14)."""
@@ -873,11 +873,11 @@ def _shard_count(eqn) -> int:
         mesh = eqn.params.get("mesh")
         if mesh is None:
             return 1
-        auto = eqn.params.get("auto") or frozenset()
         shape = dict(mesh.shape)
+        manual = eqn.params.get("manual_axes") or frozenset(shape)
         n = 1
         for name, size in shape.items():
-            if name not in auto:
+            if name in manual:
                 n *= int(size)
         return max(n, 1)
     except Exception:  # noqa: BLE001 — cost model must never crash a walk

@@ -328,7 +328,7 @@ def _gl002_cost(eqn, v) -> str:
 
 def _gl009_pass(eqn, ctx: "_Ctx", prov: str):
     """GL009 replication-blowup, evaluated AT a shard_map eqn: any large
-    input whose in_names entry omits a manual mesh axis (size > 1) is
+    input whose in_specs entry omits a manual mesh axis (size > 1) is
     materialized once per chip along that axis — the optimizer-moment /
     master-weight hazard ROADMAP item 1's ZeRO shard reclaims.  Shapes
     here are GLOBAL (the shard_map boundary), so per-chip bytes divide by
@@ -340,20 +340,23 @@ def _gl009_pass(eqn, ctx: "_Ctx", prov: str):
         mesh_axes = mesh_axis_sizes(eqn.params.get("mesh"))
         if not mesh_axes:
             return
-        auto = eqn.params.get("auto") or frozenset()
+        # the eqn carries ``manual_axes`` and one PartitionSpec an input
+        # (``in_specs``), as jax 0.9 writes it
+        manual_axes = eqn.params.get("manual_axes") or frozenset(mesh_axes)
         manual = {a: s for a, s in mesh_axes.items()
-                  if a not in auto and int(s) > 1}
+                  if a in manual_axes and int(s) > 1}
         if not manual:
             return
-        in_names = eqn.params.get("in_names") or ()
+        in_specs = eqn.params.get("in_specs") or ()
     except Exception:  # noqa: BLE001 — lint must never crash on odd params
         return
-    for opi, (v, names) in enumerate(zip(eqn.invars, in_names)):
+    for opi, (v, spec) in enumerate(zip(eqn.invars, in_specs)):
         try:
             used: Set[str] = set()
-            for axes in dict(names).values():
-                axes = (axes,) if isinstance(axes, str) else axes
-                used.update(str(a) for a in axes)
+            for axes in spec:
+                if axes is not None:
+                    axes = (axes,) if isinstance(axes, str) else axes
+                    used.update(str(a) for a in axes)
             missing = sorted(a for a in manual if a not in used)
             if not missing:
                 continue

@@ -158,38 +158,17 @@ def replicate_to_mesh(value, mesh):
 
 def _serving_param_specs(model) -> dict:
     """id(param) -> PartitionSpec names for the Megatron column/row
-    partition of the serving hot path.  QKV and fc1 are column-parallel
-    (output features over ``mp``), the post-attention projection and fc2
+    partition of the serving hot path: the stacked ``[L, ...]`` parameters
+    of ``GPTStackedDecoder``.  QKV and fc1 are column-parallel (output
+    features over ``mp``), the post-attention projection and fc2
     row-parallel (contraction dim over ``mp`` — GSPMD's all-reduce after
     them is the hot path's only cross-chip collective); embeddings, norms
-    and biases of row-parallel layers replicate.  Supports both flagship
-    GPT classes."""
-    specs: dict = {}
-    dec = getattr(model, "decoder", None)
-    if dec is not None and hasattr(dec, "_PARAM_NAMES"):
-        # stacked [L, ...] parameters (GPTStackedForPretraining)
-        tp = {"qkv_w": (None, None, "mp"), "qkv_b": (None, "mp"),
-              "fc1_w": (None, None, "mp"), "fc1_b": (None, "mp"),
-              "proj_w": (None, "mp", None), "fc2_w": (None, "mp", None)}
-        for name in dec._PARAM_NAMES:
-            spec = tp.get(name)
-            if spec is not None:
-                specs[id(getattr(dec, name))] = spec
-    body = getattr(model, "gpt", None)
-    if body is not None and hasattr(body, "layers"):
-        # layered GPTModel (GPTForPretraining)
-        for layer in body.layers:
-            for lin, col in ((layer.attn.qkv_proj, True),
-                             (layer.attn.out_proj, False),
-                             (layer.mlp.fc1, True),
-                             (layer.mlp.fc2, False)):
-                w = getattr(lin, "weight", None)
-                b = getattr(lin, "bias", None)
-                if w is not None:
-                    specs[id(w)] = (None, "mp") if col else ("mp", None)
-                if col and b is not None:
-                    specs[id(b)] = ("mp",)
-    return specs
+    and biases of row-parallel layers replicate."""
+    tp = {"qkv_w": (None, None, "mp"), "qkv_b": (None, "mp"),
+          "fc1_w": (None, None, "mp"), "fc1_b": (None, "mp"),
+          "proj_w": (None, "mp", None), "fc2_w": (None, "mp", None)}
+    return {id(getattr(model.decoder, name)): spec
+            for name, spec in tp.items()}
 
 
 def shard_model_for_serving(model, mesh):
@@ -206,31 +185,18 @@ def shard_model_for_serving(model, mesh):
 
 
 def shard_paged_cache(cache, mesh):
-    """Shard the paged KV pool per-head over ``mp``: the layered pool
-    ``[P, H, page_size, D]`` on axis 1, the stacked pool
-    ``[L, P, H, page_size, D]`` on axis 2 — per-chip pool bytes shrink to
-    ``nbytes / mp``.  Records the shard count on the cache
-    (``cache.mesh_shards``) for the per-chip accounting benches report."""
+    """Shard the paged KV pool ``[L, P, H, page_size, D]`` per-head over
+    ``mp`` (axis 2) — per-chip pool bytes shrink to ``nbytes / mp``.
+    Records the shard count on the cache (``cache.mesh_shards``) for the
+    per-chip accounting benches report."""
     mp = mp_size(mesh)
     if mp > 1:
         validate_head_sharding(cache.num_heads, mp)
-    head_axis = 2 if cache.stacked else 1
-    spec = [None] * (5 if cache.stacked else 4)
-    if mp > 1:
-        spec[head_axis] = "mp"
-    buffers = [cache.k, cache.v] if cache.stacked else [*cache.k, *cache.v]
-    for t in buffers:
-        _put(t, mesh, tuple(spec))
-    if getattr(cache, "quantized", False):
-        # int8 pool: the per-(page, head) scale buffers shard on the SAME
-        # head axis ([L, P, H] stacked / [P, H] layered)
-        sspec = [None] * (3 if cache.stacked else 2)
-        if mp > 1:
-            sspec[-1] = "mp"
-        sbuffers = ([cache.k_scale, cache.v_scale] if cache.stacked
-                    else [*cache.k_scale, *cache.v_scale])
-        for t in sbuffers:
-            _put(t, mesh, tuple(sspec))
+    head = "mp" if mp > 1 else None
+    # K, V and an int8 pool's [L, P, H] per-(page, head) scale buffers all
+    # carry the heads on axis 2
+    for t in cache._tensors():
+        _put(t, mesh, (None, None, head) + (None,) * (t.ndim - 3))
     cache.mesh_shards = mp
     return cache
 

@@ -1,8 +1,7 @@
 """Shared fault-injection harness (serving engine + distributed layer).
 
-Promoted from ``serving/faults.py`` (which keeps compatible re-exports)
-so the distributed fault-tolerance layer can drive the SAME
-occurrence-keyed injector: components call a test-only
+One occurrence-keyed injector for the serving engine and the distributed
+fault-tolerance layer alike: components call a test-only
 ``_fault_hook(point, ctx)`` at named points of their pipeline; an
 installed :class:`FaultInjector` acts there — raising, stalling, or
 mutating ``ctx`` — to force, deterministically and at chosen

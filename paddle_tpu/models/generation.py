@@ -5,7 +5,7 @@ over a decoder with cache) and the reference's fused_multi_transformer
 decode path.  TPU-native redesign:
 
 - **Static shapes everywhere.**  The KV cache is preallocated at
-  ``[B, H, max_seq, D]`` (bf16 by default) and written position-by-
+  ``[L, B, H, max_seq, D]`` (bf16 by default) and written position-by-
   position with ``jax.lax.dynamic_update_slice``; the *position* is a
   traced scalar, never a shape.  One prefill program (keyed on the prompt
   shape) and ONE decode program serve the whole generation loop — after
@@ -57,15 +57,14 @@ __all__ = [
 
 
 class _KVBuffers:
-    """Shared buffer bookkeeping for KV caches exposing ``k``/``v`` (+
-    ``stacked``): size accounting and eager release.  Used by both the
-    contiguous :class:`KVCache` and the serving page pool
+    """Shared buffer bookkeeping for KV caches exposing ``k``/``v``: size
+    accounting and eager release.  Used by both the contiguous
+    :class:`KVCache` and the serving page pool
     (``serving.paged_cache.PagedKVCache``) so release semantics cannot
     drift between them."""
 
     def _tensors(self) -> List[Tensor]:
-        return ([self.k, self.v] if self.stacked
-                else list(self.k) + list(self.v))
+        return [self.k, self.v]
 
     @property
     def nbytes(self) -> int:
@@ -88,12 +87,9 @@ class _KVBuffers:
 
 
 class KVCache(_KVBuffers):
-    """Preallocated static-shape KV cache.
-
-    ``stacked=False``: per-layer Tensor pairs ``k[i]/v[i]`` of shape
-    ``[B, H, max_seq, D]`` (the layered ``GPTModel`` path).
-    ``stacked=True``: single Tensor pair of shape ``[L, B, H, max_seq, D]``
-    scanned alongside the stacked decoder parameters.
+    """Preallocated static-shape KV cache: one Tensor pair ``k``/``v`` of
+    shape ``[L, B, H, max_seq, D]``, scanned alongside the stacked decoder
+    parameters (``GPTStackedDecoder._forward_cached``).
 
     The tensors are plain framework Tensors so in-place updates
     (``_set_value``) are mutation-logged — ``jit.to_static`` donates them
@@ -104,8 +100,7 @@ class KVCache(_KVBuffers):
     """
 
     def __init__(self, num_layers: int, batch_size: int, num_heads: int,
-                 max_seq: int, head_dim: int, dtype: str = "bfloat16",
-                 stacked: bool = False):
+                 max_seq: int, head_dim: int, dtype: str = "bfloat16"):
         jd = to_jax_dtype(dtype)
         self.num_layers = num_layers
         self.batch_size = batch_size
@@ -113,22 +108,9 @@ class KVCache(_KVBuffers):
         self.max_seq = max_seq
         self.head_dim = head_dim
         self.dtype = str(dtype)
-        self.stacked = stacked
-        if stacked:
-            shape = (num_layers, batch_size, num_heads, max_seq, head_dim)
-            self.k = Tensor(jnp.zeros(shape, jd))
-            self.v = Tensor(jnp.zeros(shape, jd))
-        else:
-            shape = (batch_size, num_heads, max_seq, head_dim)
-            self.k = [Tensor(jnp.zeros(shape, jd)) for _ in range(num_layers)]
-            self.v = [Tensor(jnp.zeros(shape, jd)) for _ in range(num_layers)]
-
-    def layer(self, i: int):
-        """(k, v) Tensors for layer ``i`` (layered layout only)."""
-        if self.stacked:
-            raise ValueError("layer() is for the per-layer cache layout; "
-                             "the stacked cache is scanned whole")
-        return self.k[i], self.v[i]
+        shape = (num_layers, batch_size, num_heads, max_seq, head_dim)
+        self.k = Tensor(jnp.zeros(shape, jd))
+        self.v = Tensor(jnp.zeros(shape, jd))
 
 
 # ---------------------------------------------------------------------------

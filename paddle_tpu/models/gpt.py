@@ -1,5 +1,9 @@
 """GPT model family — the flagship decoder-only LM.
 
+``GPTForPretraining`` runs layer by layer and only trains or runs a plain
+forward; ``GPTStackedForPretraining`` (one scanned block) trains AND is the
+one model that serves (``generate()``, the paged engine).
+
 Reference fixtures: test/auto_parallel/get_gpt_model.py and the hybrid
 parallel GPT used across test/collective/fleet/* (Megatron-style TP layers
 from fleet/layers/mpu/mp_layers.py, PP partitioning from
@@ -77,16 +81,14 @@ class GPTConfig:
     use_tensor_parallel: bool = False   # mpu layers over the 'mp' axis
     sequence_parallel: bool = False     # shard activations over 'sp'
     recompute_interval: int = 0         # 0 = off; k = remat every k blocks
-    # remat granularity when recompute_interval > 0 (reference analog:
-    # recompute(..., use_reentrant) is all-or-nothing; XLA lets us do
-    # better).  None/"full" = recompute the whole block in backward
-    # (min memory, +~fwd/3 hardware FLOPs); "dots" = save matmul outputs
-    # and recompute only elementwise/norm work (jax
-    # checkpoint_policies.dots_with_no_batch_dims_saveable — near-zero
-    # recompute FLOPs at the cost of the saved dot activations).  Applies
-    # to the compiled stacked/pipelined path (scan_blocks/pipeline_blocks);
-    # the eager per-layer fleet.recompute is an autograd-engine rerun
-    # where XLA checkpoint policies have no meaning.
+    # remat granularity when recompute_interval > 0.  None/"full" =
+    # recompute the whole block in backward (min memory, +~fwd/3 hardware
+    # FLOPs); "dots" = save matmul outputs and recompute only
+    # elementwise/norm work (jax dots_with_no_batch_dims_saveable — near-zero
+    # recompute FLOPs at the cost of the saved dot activations).  Applies to
+    # the compiled stacked/pipelined path (scan_blocks/pipeline_blocks); the
+    # eager per-layer fleet.recompute is an autograd-engine rerun where XLA
+    # checkpoint policies have no meaning.
     recompute_policy: Optional[str] = None
     virtual_pp_degree: int = 1          # interleaved virtual stages per device
     # Tri-state SDPA routing: None = defer to FLAGS_use_pallas_flash_attention
@@ -156,7 +158,7 @@ def _seq_shard(x: Tensor, cfg: GPTConfig) -> Tensor:
 
 
 # ---------------------------------------------------------------------------
-# KV-cache decode path (shared by the layered and stacked decoders)
+# KV-cache decode path of the stacked decoder
 # ---------------------------------------------------------------------------
 
 def _as_pos(cache_index) -> Tensor:
@@ -221,11 +223,13 @@ def _flash_over_mesh(q, k, v, scale):
                              out_specs=spec, check_vma=False)(q, k, v)
 
 
+def _dropout(x, rate, key):
+    mask = jax.random.bernoulli(key, 1.0 - rate, x.shape)
+    return jnp.where(mask, x / (1.0 - rate), jnp.zeros_like(x))
+
+
 def _ln_f32(x, g, b, eps):
-    """fp32 LayerNorm body shared by the train (_block_fn) and decode
-    (_cached_block_fn) stacked blocks — one numerics definition.  (Their
-    remaining block math is pinned together by the decode-vs-full-forward
-    parity tests in tests/test_generate.py.)"""
+    """fp32 LayerNorm of the stacked block (``GPTStackedDecoder._block_fn``)."""
     x = x.astype(jnp.float32)
     mu = x.mean(-1, keepdims=True)
     var = ((x - mu) ** 2).mean(-1, keepdims=True)
@@ -294,35 +298,7 @@ def _pos_is_static_zero(pos: Tensor) -> bool:
     traced or nonzero position routes S>1 calls to the general
     cache-masked path instead (chunked prefill stays correct)."""
     v = pos._value
-    if isinstance(v, jax.core.Tracer):
-        return False
-    try:
-        return int(np.asarray(v)) == 0
-    except Exception:
-        return False
-
-
-def _attend_with_cache(q: Tensor, k: Tensor, v: Tensor, ck_t: Tensor,
-                       cv_t: Tensor, pos: Tensor, cfg: GPTConfig) -> Tensor:
-    """Tensor-level cached attention for the layered decoder.  q/k/v:
-    [B, S, nh, hd]; mutates the cache Tensors in place (the mutation is
-    logged, so jit.to_static donates them)."""
-    use_flash = _resolve_use_flash(cfg)
-    pos_is_zero = _pos_is_static_zero(pos)
-
-    def raw(qr, kr, vr, ckr, cvr, posr):
-        qh, kh, vh = (jnp.swapaxes(t, 1, 2) for t in (qr, kr, vr))
-        out, ck2, cv2 = _raw_attend_with_cache(
-            qh, kh, vh, ckr, cvr, posr,
-            head_dim=cfg.head_dim, use_flash=use_flash,
-            pos_is_zero=pos_is_zero)
-        return jnp.swapaxes(out, 1, 2), ck2, cv2
-
-    out, ck_new, cv_new = ops.dispatch.apply(
-        raw, q, k, v, ck_t, cv_t, pos, op_name="cached_attention")
-    ck_t._set_value(ck_new._value)
-    cv_t._set_value(cv_new._value)
-    return out
+    return not isinstance(v, jax.core.Tracer) and int(np.asarray(v)) == 0
 
 
 def _raw_attend_paged(qh, kh, vh, pkr, pvr, tables, posr, *, head_dim,
@@ -488,48 +464,6 @@ def _attend_paged_shard(qh, kh, vh, pkr, pvr, tables, posr, *, head_dim,
     return out, pk2, pv2
 
 
-def _attend_paged(q: Tensor, k: Tensor, v: Tensor, pk_t: Tensor,
-                  pv_t: Tensor, tables: Tensor, pos: Tensor,
-                  cfg: GPTConfig, ragged_plan=None, scales=None) -> Tensor:
-    """Tensor-level paged attention for the layered decoder.  q/k/v:
-    [S, C, nh, hd]; mutates the pool Tensors in place (mutation-logged, so
-    jit.to_static donates them to the compiled serving step).
-    ``ragged_plan`` (a tuple of RAGGED_PLAN_FIELDS Tensors) routes the
-    C == 1 flat-token path through the ragged work-list kernel.
-    ``scales`` — the (k_scale, v_scale) [P, H] fp32 Tensors of an int8
-    pool — ride the same dispatch and are mutated in place alongside it."""
-    page_size = int(pk_t.shape[-2])
-    plan = tuple(ragged_plan) if ragged_plan is not None else ()
-    n_plan = len(plan)
-    sc = tuple(scales) if scales is not None else ()
-
-    def raw(qr, kr, vr, pkr, pvr, tbl, posr, *rest):
-        planr = rest[:n_plan]
-        scr = rest[n_plan:]
-        qh, kh, vh = (jnp.swapaxes(t, 1, 2) for t in (qr, kr, vr))
-        res = _raw_attend_paged(
-            qh, kh, vh, pkr, pvr, tbl, posr,
-            head_dim=cfg.head_dim, page_size=page_size,
-            ragged_plan=planr if planr else None,
-            ksr=scr[0] if scr else None,
-            vsr=scr[1] if scr else None)
-        out = jnp.swapaxes(res[0], 1, 2)
-        return (out,) + tuple(res[1:])
-
-    results = ops.dispatch.apply(
-        raw, q, k, v, pk_t, pv_t, tables, pos, *plan, *sc,
-        op_name="paged_attention")
-    if sc:
-        out, pk_new, pv_new, ks_new, vs_new = results
-        sc[0]._set_value(ks_new._value)
-        sc[1]._set_value(vs_new._value)
-    else:
-        out, pk_new, pv_new = results
-    pk_t._set_value(pk_new._value)
-    pv_t._set_value(pv_new._value)
-    return out
-
-
 class GPTEmbeddings(Layer):
     def __init__(self, cfg: GPTConfig):
         super().__init__()
@@ -572,94 +506,42 @@ class GPTAttention(Layer):
             self.out_proj = Linear(h, h, weight_attr=_winit(cfg))
         self.dropout = Dropout(cfg.hidden_dropout)
 
-    def forward(self, x: Tensor, attn_mask: Optional[Tensor] = None,
-                layer_kv=None, cache_index=None,
-                page_tables: Optional[Tensor] = None,
-                ragged_plan=None, lora=None) -> Tensor:
+    def forward(self, x: Tensor, attn_mask: Optional[Tensor] = None) -> Tensor:
         cfg = self._cfg
         b, s = x.shape[0], x.shape[1]
         nh, hd = cfg.num_heads, cfg.head_dim
         with jax.named_scope("attn.qkv"):
             qkv = self.qkv_proj(x)                          # [B, S, 3H]
-            if lora is not None:
-                # per-token gathered low-rank delta on the SAME input as
-                # the base projection (serving/lora.py; slabs[0:2] = qkv
-                # A/B)
-                slabs, ids, lscale = lora
-                qkv = qkv + ops.gathered_lora_matmul(x, slabs[0], slabs[1],
-                                                     ids, lscale)
             qkv = ops.reshape(qkv, [b, s, 3, nh, hd])
             q = ops.squeeze(ops.slice(qkv, [2], [0], [1]), 2)  # [B, S, nh, hd]
             k = ops.squeeze(ops.slice(qkv, [2], [1], [2]), 2)
             v = ops.squeeze(ops.slice(qkv, [2], [2], [3]), 2)
         with jax.named_scope("attn.core"):
-            out = self._attend(q, k, v, attn_mask, layer_kv, cache_index,
-                               page_tables, ragged_plan, lora)
+            out = self._attend(q, k, v, attn_mask)
         with jax.named_scope("attn.out"):
             out = ops.reshape(out, [b, s, nh * hd])
-            proj = self.out_proj(out)
-            if lora is not None:
-                slabs, ids, lscale = lora
-                proj = proj + ops.gathered_lora_matmul(out, slabs[2],
-                                                       slabs[3], ids, lscale)
-            return self.dropout(proj)
+            return self.dropout(self.out_proj(out))
 
-    def _attend(self, q, k, v, attn_mask, layer_kv, cache_index,
-                page_tables, ragged_plan, lora) -> Tensor:
-        """q/k/v [B, S, nh, hd] -> [B, S, nh, hd]: the cache write and the
-        attention itself, whichever path the arguments select."""
+    def _attend(self, q, k, v, attn_mask) -> Tensor:
+        """q/k/v [B, S, nh, hd] -> [B, S, nh, hd]."""
         cfg = self._cfg
-        if layer_kv is not None:
-            # serving path: write K/V into the preallocated cache at
-            # cache_index, attend over it (q-len-1 flash-decode kernel for
-            # single-token steps)
-            if attn_mask is not None:
-                raise ValueError(
-                    "attn_mask is not supported on the KV-cache path (it "
-                    "is causal+length-masked); left-padded batches would "
-                    "write pad positions into the cache — right-pad or "
-                    "serve per-sequence")
-            if len(layer_kv) == 4:
-                # int8 paged pool: (k, v, k_scale, v_scale) — the scale
-                # Tensors thread through the same dispatched op
-                ck_t, cv_t, ks_t, vs_t = layer_kv
-                scales = (ks_t, vs_t)
-            else:
-                ck_t, cv_t = layer_kv
-                scales = None
-            if page_tables is not None:
-                # continuous-batching path: page-table-translated write
-                # into the global pool, paged decode-attention kernel (or
-                # the ragged work-list kernel on the fused mixed step)
-                out = _attend_paged(q, k, v, ck_t, cv_t, page_tables,
-                                    _as_pos(cache_index), cfg,
-                                    ragged_plan=ragged_plan, scales=scales)
-            elif lora is not None:
-                raise ValueError(
-                    "per-request LoRA adapters ride the paged serving "
-                    "step (page_tables required)")
-            else:
-                out = _attend_with_cache(q, k, v, ck_t, cv_t,
-                                         _as_pos(cache_index), cfg)
         # sequence-parallel causal attention runs as a ring over 'sp'
         # (K/V rotate via ppermute; online-softmax merge) — the S axis stays
         # sharded instead of being all-gathered for the score matmul
-        elif (cfg.sequence_parallel and attn_mask is None
+        if (cfg.sequence_parallel and attn_mask is None
                 and cfg.attention_dropout == 0.0
                 and _mesh.has_mesh() and _mesh.axis_size("sp") > 1):
             from ..nn.functional.ring_attention import ring_attention
 
-            out = ring_attention(q, k, v, causal=True)
-        else:
-            out = F.scaled_dot_product_attention(
-                q, k, v,
-                attn_mask=attn_mask,
-                dropout_p=cfg.attention_dropout,
-                is_causal=attn_mask is None,
-                training=self.training,
-                use_flash=cfg.use_flash_attention,
-            )                                               # [B, S, nh, hd]
-        return out
+            return ring_attention(q, k, v, causal=True)
+        return F.scaled_dot_product_attention(
+            q, k, v,
+            attn_mask=attn_mask,
+            dropout_p=cfg.attention_dropout,
+            is_causal=attn_mask is None,
+            training=self.training,
+            use_flash=cfg.use_flash_attention,
+        )                                                   # [B, S, nh, hd]
 
 
 class GPTMLP(Layer):
@@ -675,17 +557,8 @@ class GPTMLP(Layer):
             self.fc2 = Linear(f, h, weight_attr=_winit(cfg))
         self.dropout = Dropout(cfg.hidden_dropout)
 
-    def forward(self, x: Tensor, lora=None) -> Tensor:
-        if lora is None:
-            return self.dropout(self.fc2(F.gelu(self.fc1(x),
-                                                approximate=True)))
-        slabs, ids, lscale = lora
-        u = self.fc1(x) + ops.gathered_lora_matmul(x, slabs[4], slabs[5],
-                                                   ids, lscale)
-        g = F.gelu(u, approximate=True)
-        y = self.fc2(g) + ops.gathered_lora_matmul(g, slabs[6], slabs[7],
-                                                   ids, lscale)
-        return self.dropout(y)
+    def forward(self, x: Tensor) -> Tensor:
+        return self.dropout(self.fc2(F.gelu(self.fc1(x), approximate=True)))
 
 
 class GPTDecoderLayer(Layer):
@@ -699,23 +572,14 @@ class GPTDecoderLayer(Layer):
         self.ln2 = LayerNorm(cfg.hidden_size, epsilon=cfg.layer_norm_eps)
         self.mlp = GPTMLP(cfg)
 
-    def forward(self, x: Tensor, attn_mask: Optional[Tensor] = None,
-                layer_kv=None, cache_index=None,
-                page_tables: Optional[Tensor] = None,
-                ragged_plan=None, lora=None) -> Tensor:
+    def forward(self, x: Tensor, attn_mask: Optional[Tensor] = None) -> Tensor:
         with jax.named_scope("attn.qkv"):
             h = self.ln1(x)
-        a = self.attn(h, attn_mask, layer_kv=layer_kv,
-                      cache_index=cache_index, page_tables=page_tables,
-                      ragged_plan=ragged_plan, lora=lora)
+        a = self.attn(h, attn_mask)
         with jax.named_scope("attn.out"):
             x = x + a
         with jax.named_scope("mlp"):
-            # pass lora only when active: subclasses swap self.mlp for
-            # layers with plain forward(x) signatures (ernie_moe's MoELayer)
-            h = self.ln2(x)
-            x = x + (self.mlp(h, lora=lora) if lora is not None
-                     else self.mlp(h))
+            x = x + self.mlp(self.ln2(x))
             return _seq_shard(x, self._cfg)
 
 
@@ -732,59 +596,24 @@ class GPTModel(Layer):
         self.final_ln = LayerNorm(cfg.hidden_size, epsilon=cfg.layer_norm_eps)
 
     def forward(self, input_ids: Tensor, position_ids: Optional[Tensor] = None,
-                attn_mask: Optional[Tensor] = None, kv_cache=None,
-                cache_index=None,
-                page_tables: Optional[Tensor] = None,
-                ragged_plan=None, lora=None) -> Tensor:
-        paged = bool(getattr(kv_cache, "paged", False))
-        if paged and page_tables is None:
-            raise ValueError("a paged KV cache needs page_tables "
-                             "([B, max_pages] int32 pool page ids)")
-        pos = _as_pos(cache_index) if kv_cache is not None else None
-        with jax.named_scope("embed"):
-            if kv_cache is not None and position_ids is None:
-                position_ids = _cache_position_ids(input_ids, pos)
-                if paged:
-                    # prefill padding may carry positions past the table;
-                    # the write already sinks them into the null page —
-                    # keep the embedding lookup in range too
-                    position_ids = ops.clip(
-                        position_ids, min=0,
-                        max=self.config.max_position_embeddings - 1)
-            h = self.embeddings(input_ids, position_ids)
+                attn_mask: Optional[Tensor] = None) -> Tensor:
+        h = self.embeddings(input_ids, position_ids)
         k = self.config.recompute_interval
         for i, layer in enumerate(self.layers):
-            lr = None
-            if lora is not None:
-                # lora = (pool, per-token adapter-page ids): unpack this
-                # layer's slab 8-tuple (serving/lora.py layout)
-                pool_, ids_ = lora
-                lr = (pool_.layer_slabs(i), ids_, pool_.scaling)
-            if kv_cache is not None:
-                lkv = tuple(kv_cache.layer(i))
-                if paged and getattr(kv_cache, "quantized", False):
-                    # int8 pool: ride the per-layer scale buffers along
-                    lkv = lkv + tuple(kv_cache.layer_scales(i))
-                h = layer(h, attn_mask, layer_kv=lkv,
-                          cache_index=pos,
-                          page_tables=page_tables if paged else None,
-                          ragged_plan=ragged_plan if paged else None,
-                          lora=lr)
-            elif k and (i % k == 0) and self.training:
+            if k and (i % k == 0) and self.training:
                 h = recompute(layer, h, attn_mask)
             else:
-                h = layer(h, attn_mask, lora=lr)
+                h = layer(h, attn_mask)
         with jax.named_scope("lm_head"):
             return self.final_ln(h)
 
 
-class GPTForPretraining(Layer, GenerationMixin):
-    """LM head tied to the word embedding (reference GPT fixtures tie
-    weights; logits = h @ E^T, a vocab-sharded matmul under TP).
-
-    Serving: inherits ``generate()`` (models/generation.py) — greedy /
-    temperature / top-k / top-p over a donated KV cache with zero
-    retraces after warmup."""
+class GPTForPretraining(Layer):
+    """The eager, layer-by-layer GPT for training and plain forward passes:
+    LM head tied to the word embedding (reference GPT fixtures tie
+    weights; logits = h @ E^T, a vocab-sharded matmul under TP).  It does
+    not serve: ``generate()`` and the engine's paged contract belong to
+    :class:`GPTStackedForPretraining`."""
 
     def __init__(self, cfg: GPTConfig):
         super().__init__()
@@ -792,71 +621,11 @@ class GPTForPretraining(Layer, GenerationMixin):
         self.config = cfg
 
     def forward(self, input_ids: Tensor, position_ids: Optional[Tensor] = None,
-                attn_mask: Optional[Tensor] = None, kv_cache=None,
-                cache_index=None,
-                page_tables: Optional[Tensor] = None,
-                ragged_plan=None, out_rows: Optional[Tensor] = None,
-                lora=None) -> Tensor:
-        h = self.gpt(input_ids, position_ids, attn_mask,
-                     kv_cache=kv_cache, cache_index=cache_index,
-                     page_tables=page_tables, ragged_plan=ragged_plan,
-                     lora=lora)
+                attn_mask: Optional[Tensor] = None) -> Tensor:
+        h = self.gpt(input_ids, position_ids, attn_mask)
         with jax.named_scope("lm_head"):
-            return self._lm_head(h, out_rows)
-
-    def _lm_head(self, h: Tensor, out_rows: Optional[Tensor]) -> Tensor:
-        if out_rows is not None:
-            # serving fused step: gather each slot's output row BEFORE the
-            # vocab projection, so the LM head projects [S] rows instead of
-            # the whole padded flat-token axis
-            h = ops.gather(h, out_rows, axis=0)
-        if getattr(self, "_weight_int8", False):
-            # quantize_for_serving stored the tied LM head transposed as
-            # int8 [H, V] with per-vocab-row scales — one int8 MXU matmul
-            from ..quantization.int8 import quantized_matmul
-
-            return quantized_matmul(h, self.lm_head_int8,
-                                    self.lm_head_scale)
-        w = self.gpt.embeddings.word_embeddings.weight  # [V, H]
-        logits = ops.matmul(h, w, transpose_y=True)     # [B, S, V]
-        return logits
-
-    # -- GenerationMixin cache contract ------------------------------------
-    def new_kv_cache(self, batch_size: int, max_seq: int,
-                     dtype: str = "bfloat16") -> KVCache:
-        cfg = self.config
-        return KVCache(cfg.num_layers, batch_size, cfg.num_heads, max_seq,
-                       cfg.head_dim, dtype=dtype, stacked=False)
-
-    def _cached_lm_logits(self, input_ids, kv_cache, cache_index):
-        return self.forward(input_ids, kv_cache=kv_cache,
-                            cache_index=cache_index)
-
-    # -- ServingEngine paged-cache contract --------------------------------
-    def new_paged_kv_cache(self, num_pages: int, page_size: int,
-                           dtype: str = "bfloat16"):
-        from ..serving.paged_cache import PagedKVCache
-
-        cfg = self.config
-        return PagedKVCache(cfg.num_layers, num_pages, cfg.num_heads,
-                            page_size, cfg.head_dim, dtype=dtype,
-                            stacked=False)
-
-    def _paged_lm_logits(self, input_ids, paged_cache, page_tables,
-                         positions, ragged_plan=None, out_rows=None,
-                         lora=None):
-        """[B, S, V] logits over the paged pool: ``positions`` is the
-        per-slot position vector [B], ``page_tables`` [B, max_pages].
-        With ``ragged_plan`` (the serving engine's fused mixed step),
-        B is the flat token axis (S == 1) and attention runs through the
-        ragged work-list kernel; ``out_rows`` [S] gathers each slot's
-        output row before the vocab projection (-> [S, 1, V]).  ``lora``
-        is ``(LoRAAdapterPool, per-token adapter-page ids)`` — the
-        multi-tenant gathered low-rank deltas (serving/lora.py)."""
-        return self.forward(input_ids, kv_cache=paged_cache,
-                            cache_index=positions, page_tables=page_tables,
-                            ragged_plan=ragged_plan, out_rows=out_rows,
-                            lora=lora)
+            w = self.gpt.embeddings.word_embeddings.weight  # [V, H]
+            return ops.matmul(h, w, transpose_y=True)       # [B, S, V]
 
 
 class GPTStackedDecoder(Layer):
@@ -917,15 +686,14 @@ class GPTStackedDecoder(Layer):
                     "ln2_g", "ln2_b", "fc1_w", "fc1_b", "fc2_w", "fc2_b")
     # post-quantize_weights() scan layout: each projection weight becomes
     # (int8 weight, per-(layer, out-channel) fp32 scale)
-    _PARAM_NAMES_INT8 = (
-        "ln1_g", "ln1_b", "qkv_w_int8", "qkv_w_s", "qkv_b",
-        "proj_w_int8", "proj_w_s", "proj_b", "ln2_g", "ln2_b",
-        "fc1_w_int8", "fc1_w_s", "fc1_b", "fc2_w_int8", "fc2_w_s", "fc2_b")
+    _PARAM_NAMES_INT8 = tuple(
+        m for n in _PARAM_NAMES
+        for m in ((n + "_int8", n + "_s") if n.endswith("_w") else (n,)))
 
     def _stacked(self):
-        if getattr(self, "_weight_int8", False):
-            return [getattr(self, n) for n in self._PARAM_NAMES_INT8]
-        return [getattr(self, n) for n in self._PARAM_NAMES]
+        int8 = getattr(self, "_weight_int8", False)
+        return [getattr(self, n) for n in
+                (self._PARAM_NAMES_INT8 if int8 else self._PARAM_NAMES)]
 
     def quantize_weights(self):
         """PTQ the stacked projection weights to int8 for serving
@@ -970,242 +738,129 @@ class GPTStackedDecoder(Layer):
             spec = spec + (None,) * (p.ndim - len(spec))
             shard_param(p, *spec)
 
-    def _block_fn(self):
+    def _refuse_int8(self, what: str):
         if getattr(self, "_weight_int8", False):
             raise ValueError(
                 "decoder was quantized for serving (quantize_weights); "
-                "the training block body needs the fp weights")
+                f"{what} needs the fp weights (int8 weights serve through "
+                "the paged engine)")
+
+    def _block_fn(self, with_dropout: bool = False):
+        """THE decoder block, written once: LayerNorm -> fused QKV ->
+        attention core -> projection -> LayerNorm -> GELU feed-forward.
+
+        Returns ``block(p, h, attend, drop_keys=None, lora=None) ->
+        (h, state)``: ``p`` one layer's slice of ``_stacked()``;
+        ``attend(q, k, v) -> (out, state)`` the attention core over
+        head-major ``[B, N, S, D]``, closed over whatever it carries
+        (nothing in training; a layer's contiguous cache and position; the
+        page pool, tables, positions, plan and scales).  What only one use
+        needs is a Python-level choice made when the block is built, so
+        each use traces its own operations and no other: hidden dropout
+        only ``with_dropout`` (``drop_keys``: two PRNG keys), int8 x int8
+        projections (fp32 activations in, fp32 dequant epilogue) only
+        after ``quantize_weights()``, a LoRA delta only when ``lora =
+        (8 slabs [P, dim, r], ids, scaling)`` is given.
+
+        AMP O1: matmuls/attention run in the amp dtype (MXU path),
+        LayerNorm/softmax/residual stay fp32 — the split the per-op lists
+        give the unfused model, here as explicit casts because the block
+        is a single dispatched op."""
         cfg = self._cfg
         nh, hd = cfg.num_heads, cfg.head_dim
-        eps = cfg.layer_norm_eps
-
-        attn_p = cfg.attention_dropout
-        hid_p = cfg.hidden_dropout
-        with_dropout = self.training and (attn_p > 0.0 or hid_p > 0.0)
-
-        # AMP O1 inside the fused block: matmuls/attention run in the amp
-        # dtype (MXU path), LayerNorm/softmax/residual stay fp32 — the same
-        # split the per-op white/black lists give the unfused model
-        # (reference amp_lists.py), applied here as explicit casts because
-        # the whole block is a single dispatched op.
+        eps, hid_p = cfg.layer_norm_eps, cfg.hidden_dropout
+        hid_drop = with_dropout and hid_p > 0.0
         from ..amp.auto_cast import _amp_state
-
-        cdt = _amp_state.dtype if (_amp_state.enabled and _amp_state.level == "O1") else None
-
-        use_flash = _resolve_use_flash(cfg)
-
-        def ln(x, g, b):
-            return _ln_f32(x, g, b, eps)
-
-        def drop(x, rate, key):
-            if not with_dropout or rate <= 0.0:
-                return x
-            keep = 1.0 - rate
-            mask = jax.random.bernoulli(key, keep, x.shape)
-            return jnp.where(mask, x / keep, jnp.zeros_like(x))
-
-        def sdpa(q, k, v, key, s):
-            # Pallas flash kernel when shape-eligible (no attention dropout
-            # path inside the kernel); else the XLA expression with fp32
-            # softmax.  Both see amp-dtype q/k/v.
-            from ..ops.pallas_kernels.flash_attention import (
-                _on_tpu, shape_supported,
-            )
-
-            if (use_flash and _on_tpu() and not (with_dropout and attn_p > 0.0)
-                    and shape_supported(s, hd)):
-                return _flash_over_mesh(q, k, v, float(1.0 / np.sqrt(hd)))
-            scores = jnp.einsum("bnqd,bnkd->bnqk", q, k,
-                                preferred_element_type=jnp.float32)
-            scores = scores * float(1.0 / np.sqrt(hd))
-            causal = jnp.tril(jnp.ones((s, s), jnp.bool_))
-            scores = jnp.where(causal, scores, jnp.asarray(-1e9, scores.dtype))
-            att = jax.nn.softmax(scores, axis=-1)
-            att = drop(att, attn_p, key)
-            return jnp.einsum("bnqk,bnkd->bnqd", att.astype(q.dtype), v)
-
-        def block(p, h):
-            if with_dropout:
-                *p, key = p
-                k1, k2, k3 = jax.random.split(key, 3)
-            else:
-                k1 = k2 = k3 = None
-            (l1g, l1b, qkvw, qkvb, pw, pb, l2g, l2b, f1w, f1b, f2w, f2b) = p
-            if cdt is not None:
-                qkvw, qkvb, pw, pb, f1w, f1b, f2w, f2b = (
-                    a.astype(cdt) for a in (qkvw, qkvb, pw, pb, f1w, f1b, f2w, f2b)
-                )
-            b, s, hidden = h.shape
-            # the fp32 LayerNorm output returns to the WEIGHT dtype before
-            # every projection (== cdt under AMP O1; == the storage dtype
-            # for a pure-bf16 model outside auto_cast) — otherwise jax
-            # silently promotes the bf16 weights and the matmuls leave the
-            # bf16 MXU path (graph_lint GL001)
-            with jax.named_scope("attn.qkv"):
-                x = ln(h, l1g, l1b).astype(qkvw.dtype)
-                qkv = (x @ qkvw + qkvb).reshape(b, s, 3, nh, hd)
-                q, k, v = (jnp.swapaxes(qkv[:, :, i], 1, 2) for i in range(3))  # [B,N,S,D]
-            with jax.named_scope("attn.core"):
-                out = sdpa(q, k, v, k1, s)                  # [B,N,S,D]
-            with jax.named_scope("attn.out"):
-                out = jnp.swapaxes(out, 1, 2).reshape(b, s, hidden)
-                h = h + drop(out.astype(pw.dtype) @ pw + pb, hid_p, k2).astype(h.dtype)
-            with jax.named_scope("mlp"):
-                y = ln(h, l2g, l2b).astype(f1w.dtype)
-                y = jax.nn.gelu(y @ f1w + f1b, approximate=True) @ f2w + f2b
-                return h + drop(y, hid_p, k3).astype(h.dtype)
-
-        return block, with_dropout
-
-    def _cached_block_fn(self, pos_is_zero=True):
-        """Decode-block body: like _block_fn but threading a per-layer KV
-        cache slice through the scan — (params, h, k_cache, v_cache, pos)
-        -> (h, k_cache, v_cache).  Inference-only: no dropout; AMP casts
-        follow _block_fn's discipline (matmuls in amp dtype, LayerNorm
-        fp32)."""
-        if getattr(self, "_weight_int8", False):
-            raise ValueError(
-                "decoder was quantized for serving (quantize_weights); "
-                "the contiguous-cache block body needs the fp weights — "
-                "serve through the paged engine")
-        cfg = self._cfg
-        nh, hd = cfg.num_heads, cfg.head_dim
-        eps = cfg.layer_norm_eps
-        from ..amp.auto_cast import _amp_state
-
-        cdt = _amp_state.dtype if (_amp_state.enabled
-                                   and _amp_state.level == "O1") else None
-        use_flash = _resolve_use_flash(cfg)
-
-        def ln(x, g, b):
-            return _ln_f32(x, g, b, eps)
-
-        def block(p, h, kc, vc, pos):
-            (l1g, l1b, qkvw, qkvb, pw, pb, l2g, l2b, f1w, f1b, f2w, f2b) = p
-            if cdt is not None:
-                qkvw, qkvb, pw, pb, f1w, f1b, f2w, f2b = (
-                    a.astype(cdt) for a in (qkvw, qkvb, pw, pb, f1w, f1b, f2w, f2b)
-                )
-            b, s, hidden = h.shape
-            # fp32 LayerNorm output returns to the weight dtype before the
-            # projections — generate() runs OUTSIDE auto_cast, so without
-            # this a pure-bf16 model decodes with every matmul silently
-            # promoted to fp32 (graph_lint GL001; serving hot path)
-            with jax.named_scope("attn.qkv"):
-                x = ln(h, l1g, l1b).astype(qkvw.dtype)
-                qkv = (x @ qkvw + qkvb).reshape(b, s, 3, nh, hd)
-                q, k, v = (jnp.swapaxes(qkv[:, :, i], 1, 2) for i in range(3))
-            with jax.named_scope("attn.core"):
-                out, kc, vc = _raw_attend_with_cache(
-                    q, k, v, kc, vc, pos, head_dim=hd, use_flash=use_flash,
-                    pos_is_zero=pos_is_zero)
-            with jax.named_scope("attn.out"):
-                out = jnp.swapaxes(out, 1, 2).reshape(b, s, hidden)
-                h = h + (out.astype(pw.dtype) @ pw + pb).astype(h.dtype)
-            with jax.named_scope("mlp"):
-                y = ln(h, l2g, l2b).astype(f1w.dtype)
-                y = jax.nn.gelu(y @ f1w + f1b, approximate=True) @ f2w + f2b
-                return h + y.astype(h.dtype), kc, vc
-
-        return block
-
-    def _paged_block_fn(self, page_size: int):
-        """Paged decode-block body: like _cached_block_fn but threading the
-        global page pool + page tables — (params, h, k_pool, v_pool,
-        tables, pos) -> (h, k_pool, v_pool).  Inference-only; AMP casts
-        follow _block_fn's discipline (matmuls in amp dtype, LayerNorm
-        fp32, fp32 LN output cast back to the weight dtype).
-
-        Two quantized-serving regimes compose here: ``kv_scales`` threads
-        an int8 pool's per-(page, head) scale buffers through the attend
-        (the return grows by the updated scales), and after
-        ``quantize_weights()`` the params tuple is the 16-entry int8
-        variant — each projection runs as an int8xint8 MXU matmul with a
-        fp32 dequant epilogue (quantization/int8.quantized_matmul_raw)."""
-        cfg = self._cfg
-        nh, hd = cfg.num_heads, cfg.head_dim
-        eps = cfg.layer_norm_eps
-        from ..amp.auto_cast import _amp_state
+        from ..quantization.int8 import quantized_matmul_raw
 
         cdt = _amp_state.dtype if (_amp_state.enabled
                                    and _amp_state.level == "O1") else None
         wq = bool(getattr(self, "_weight_int8", False))
-        if wq:
-            from ..quantization.int8 import quantized_matmul_raw
+        names = self._PARAM_NAMES_INT8 if wq else self._PARAM_NAMES
 
-            def proj(x_, w_, s_, b_):
-                return quantized_matmul_raw(x_, w_, s_, b_)
-        else:
-            def proj(x_, w_, s_, b_):
-                return x_ @ w_ + b_
+        def block(p, h, attend, drop_keys=None, lora=None):
+            p = dict(zip(names, p))
+            if cdt is not None and not wq:
+                p = {n: a if n.startswith("ln") else a.astype(cdt)
+                     for n, a in p.items()}
 
-        def ln(x, g, b):
-            return _ln_f32(x, g, b, eps)
-
-        def block(p, h, kc, vc, tbl, pos, ragged_plan=None, lora=None,
-                  kv_scales=None):
-            if wq:
-                (l1g, l1b, qkvw, qkvs, qkvb, pw, pws, pb, l2g, l2b,
-                 f1w, f1s, f1b, f2w, f2s, f2b) = p
-            else:
-                (l1g, l1b, qkvw, qkvb, pw, pb, l2g, l2b,
-                 f1w, f1b, f2w, f2b) = p
-                qkvs = pws = f1s = f2s = None
-                if cdt is not None:
-                    qkvw, qkvb, pw, pb, f1w, f1b, f2w, f2b = (
-                        a.astype(cdt) for a in (qkvw, qkvb, pw, pb, f1w, f1b, f2w, f2b)
-                    )
-            # int8 weights: projections take fp32 activations (the dynamic
-            # absmax quantizer + dequant epilogue live inside proj)
-            pdt = jnp.float32 if wq else qkvw.dtype
-            if lora is not None:
-                # per-token gathered low-rank deltas on the SAME inputs
-                # as the base projections (serving/lora.py slab layout)
-                (qa, qb, pa, pb2, f1a, f1b2, f2a, f2b2), ids, lsc = lora
+            def linear(i, name, x):
                 if wq:
-                    ldelta = lambda x_, a_, b_: lora_delta_raw(x_.astype(a_.dtype), a_, b_, ids, lsc).astype(jnp.float32)  # noqa: E731,E501
+                    x = x.astype(jnp.float32)
+                    y = quantized_matmul_raw(x, p[name + "_w_int8"],
+                                             p[name + "_w_s"], p[name + "_b"])
                 else:
-                    ldelta = lambda x_, a_, b_: lora_delta_raw(x_, a_, b_, ids, lsc)  # noqa: E731,E501
-            else:
-                ldelta = lambda x_, a_, b_: jnp.zeros((), x_.dtype)  # noqa: E731,E501
-                qa = qb = pa = pb2 = f1a = f1b2 = f2a = f2b2 = None
+                    # an fp32 LayerNorm output returns to the WEIGHT dtype
+                    # first (== cdt under AMP O1; == the storage dtype of a
+                    # pure-bf16 model, which runs OUTSIDE auto_cast): else
+                    # jax promotes the bf16 weights and the matmul leaves
+                    # the bf16 MXU path (graph_lint GL001)
+                    x = x.astype(p[name + "_w"].dtype)
+                    y = x @ p[name + "_w"] + p[name + "_b"]
+                if lora is not None:
+                    # per-token gathered low-rank delta on the SAME input
+                    # as the base projection (serving/lora.py slab order)
+                    slabs, ids, lscale = lora
+                    y = y + lora_delta_raw(x, slabs[2 * i], slabs[2 * i + 1],
+                                           ids, lscale)
+                return y
+
+            def drop(x, key):
+                return _dropout(x, hid_p, key) if hid_drop else x
+
+            k2, k3 = drop_keys or (None, None)
             b, s, hidden = h.shape
             with jax.named_scope("attn.qkv"):
-                x = ln(h, l1g, l1b).astype(pdt)
-                qkv = (proj(x, qkvw, qkvs, qkvb)
-                       + ldelta(x, qa, qb)).reshape(b, s, 3, nh, hd)
-                q, k, v = (jnp.swapaxes(qkv[:, :, i], 1, 2) for i in range(3))
+                x = _ln_f32(h, p["ln1_g"], p["ln1_b"], eps)
+                qkv = linear(0, "qkv", x).reshape(b, s, 3, nh, hd)
+                q, k, v = (jnp.swapaxes(qkv[:, :, i], 1, 2) for i in range(3))  # [B,N,S,D]
             with jax.named_scope("attn.core"):
-                if kv_scales is not None:
-                    kss, vss = kv_scales
-                    out, kc, vc, kss, vss = _raw_attend_paged(
-                        q, k, v, kc, vc, tbl, pos, head_dim=hd,
-                        page_size=page_size, ragged_plan=ragged_plan,
-                        ksr=kss, vsr=vss)
-                else:
-                    out, kc, vc = _raw_attend_paged(
-                        q, k, v, kc, vc, tbl, pos, head_dim=hd,
-                        page_size=page_size, ragged_plan=ragged_plan)
+                out, state = attend(q, k, v)                # [B,N,S,D]
             with jax.named_scope("attn.out"):
                 out = jnp.swapaxes(out, 1, 2).reshape(b, s, hidden)
-                oin = out.astype(pdt)
-                h = h + (proj(oin, pw, pws, pb)
-                         + ldelta(oin, pa, pb2)).astype(h.dtype)
+                h = h + drop(linear(1, "proj", out), k2).astype(h.dtype)
             with jax.named_scope("mlp"):
-                y = ln(h, l2g, l2b).astype(pdt)
-                g = jax.nn.gelu(proj(y, f1w, f1s, f1b)
-                                + ldelta(y, f1a, f1b2), approximate=True)
-                y = proj(g, f2w, f2s, f2b) + ldelta(g, f2a, f2b2)
-                h = h + y.astype(h.dtype)
-            if kv_scales is not None:
-                return h, kc, vc, kss, vss
-            return h, kc, vc
+                y = linear(2, "fc1", _ln_f32(h, p["ln2_g"], p["ln2_b"], eps))
+                y = linear(3, "fc2", jax.nn.gelu(y, approximate=True))
+                return h + drop(y, k3).astype(h.dtype), state
 
         return block
 
+    def _train_core(self, with_dropout: bool):
+        """The training attention core ``(q, k, v, key) -> out``: the
+        Pallas flash kernel when shape-eligible (there is no attention
+        dropout inside the kernel), else the XLA expression with an fp32
+        softmax.  Both see amp-dtype q/k/v."""
+        cfg = self._cfg
+        hd, attn_p = cfg.head_dim, cfg.attention_dropout
+        attn_drop = with_dropout and attn_p > 0.0
+        use_flash = _resolve_use_flash(cfg)
+        scale = float(1.0 / np.sqrt(hd))
+
+        def core(q, k, v, key):
+            from ..ops.pallas_kernels.flash_attention import (
+                _on_tpu, shape_supported,
+            )
+
+            s = q.shape[2]
+            if (use_flash and _on_tpu() and not attn_drop
+                    and shape_supported(s, hd)):
+                return _flash_over_mesh(q, k, v, scale)
+            scores = jnp.einsum("bnqd,bnkd->bnqk", q, k,
+                                preferred_element_type=jnp.float32)
+            scores = scores * scale
+            causal = jnp.tril(jnp.ones((s, s), jnp.bool_))
+            scores = jnp.where(causal, scores, jnp.asarray(-1e9, scores.dtype))
+            att = jax.nn.softmax(scores, axis=-1)
+            if attn_drop:
+                att = _dropout(att, attn_p, key)
+            return jnp.einsum("bnqk,bnkd->bnqd", att.astype(q.dtype), v)
+
+        return core
+
     def _forward_paged(self, hidden: Tensor, paged_cache, page_tables,
                        cache_index, ragged_plan=None, lora=None) -> Tensor:
-        """Serving step over the stacked parameters with a STACKED
+        """Serving step over the stacked parameters with the
         [L, P, H, page_size, D] page pool: lax.scan CARRIES each pool
         (and an int8 pool's [L, P, H] scales) beside the hidden state as
         one buffer viewed [L*P, H, page_size, D], and layer ``l`` (the
@@ -1214,7 +869,7 @@ class GPTStackedDecoder(Layer):
         logged), the buffer is updated in place: no operation of a step
         moves a layer's pool (tests/test_pool_in_place.py).  The other
         ``ragged_plan`` Tensors are scan constants.  ``lora`` is
-        ``(LoRAAdapterPool, per-token adapter ids)``: the stacked
+        ``(LoRAAdapterPool, per-token adapter ids)``: the
         ``[L, pages, ...]`` adapter slabs scan alongside the parameters,
         the ids ride as a scan constant."""
         from ..ops import dispatch
@@ -1223,20 +878,20 @@ class GPTStackedDecoder(Layer):
         )
 
         pos = _as_pos(cache_index)
-        block = self._paged_block_fn(int(paged_cache.page_size))
+        block = self._block_fn()
+        hd, page_size = self._cfg.head_dim, int(paged_cache.page_size)
         plan = tuple(ragged_plan) if ragged_plan is not None else ()
         n_plan = len(plan)
         if lora is not None:
             pool_, ids_ = lora
-            slabs = tuple(pool_.stacked_slabs())     # 8 x [L, P, dim, r]
+            lora_in = (ids_,) + tuple(pool_.stacked_slabs())  # 8 x [L,P,dim,r]
             lscale = pool_.scaling
-            lora_in = (ids_,) + slabs
         else:
             lora_in, lscale = (), 0.0
         n_lora = len(lora_in)
         # int8 pool: the [L, P, H] scale buffers follow the pools
         pool_in = (paged_cache.k, paged_cache.v)
-        if getattr(paged_cache, "quantized", False):
+        if paged_cache.quantized:
             pool_in += (paged_cache.k_scale, paged_cache.v_scale)
         nt = len(pool_in)
         n_layers, n_pages = (int(n) for n in paged_cache.k.shape[:2])
@@ -1258,10 +913,19 @@ class GPTStackedDecoder(Layer):
                     params, lr = xs, None
                 plan_l = None if planr is None else (
                     *planr[:i_page], planr[i_page] + base, *planr[i_page + 1:])
-                return block(params, *carry[:3],
-                             tbl.astype(jnp.int32) + base,
-                             posr.astype(jnp.int32), ragged_plan=plan_l,
-                             lora=lr, kv_scales=carry[3:] or None), None
+                h_, pk, pv, *scales = carry
+                ks, vs = scales or (None, None)
+
+                def attend(q, k, v):
+                    out, *new = _raw_attend_paged(
+                        q, k, v, pk, pv, tbl.astype(jnp.int32) + base,
+                        posr.astype(jnp.int32), head_dim=hd,
+                        page_size=page_size, ragged_plan=plan_l,
+                        ksr=ks, vsr=vs)
+                    return out, new
+
+                h2, new = block(params, h_, attend, lora=lr)
+                return (h2, *new), None
 
             xs = ((jnp.arange(n_layers, dtype=jnp.int32),) + tuple(stacked)
                   + (tuple(slabr) if n_lora else ()))
@@ -1279,23 +943,32 @@ class GPTStackedDecoder(Layer):
         return results[0]
 
     def _forward_cached(self, hidden: Tensor, kv_cache, cache_index) -> Tensor:
-        """Decode/prefill over the stacked parameters with a STACKED
+        """Decode/prefill over the stacked parameters with the
         [L, B, H, max_seq, D] cache: lax.scan carries the hidden state and
-        scans the per-layer cache slices as xs/ys.  The updated stacked
-        cache is written back in place (mutation-logged -> donated under
+        scans the per-layer cache slices as xs/ys.  The updated cache is
+        written back in place (mutation-logged -> donated under
         jit.to_static).  The pp pipeline does not apply to serving steps —
         decode always scans."""
         from ..ops import dispatch
 
+        self._refuse_int8("the contiguous-cache decode")
         pos = _as_pos(cache_index)
-        block = self._cached_block_fn(pos_is_zero=_pos_is_static_zero(pos))
+        block = self._block_fn()
+        hd = self._cfg.head_dim
+        use_flash = _resolve_use_flash(self._cfg)
+        pos_is_zero = _pos_is_static_zero(pos)
 
         def raw(h, posr, ck, cv, *stacked):
             def step(carry, xs):
-                params, kc, vc = xs[:-2], xs[-2], xs[-1]
-                h2, kc2, vc2 = block(params, carry, kc, vc,
-                                     posr.astype(jnp.int32))
-                return h2, (kc2, vc2)
+                *params, kc, vc = xs
+
+                def attend(q, k, v):
+                    out, kc2, vc2 = _raw_attend_with_cache(
+                        q, k, v, kc, vc, posr.astype(jnp.int32), head_dim=hd,
+                        use_flash=use_flash, pos_is_zero=pos_is_zero)
+                    return out, (kc2, vc2)
+
+                return block(params, carry, attend)
 
             with jax.named_scope("layers"):
                 h2, (ck2, cv2) = jax.lax.scan(step, h,
@@ -1332,9 +1005,23 @@ class GPTStackedDecoder(Layer):
         if lora is not None:
             raise ValueError("per-request LoRA adapters ride the paged "
                              "serving step (kv_cache + page_tables)")
+        self._refuse_int8("the training block")
 
         cfg = self._cfg
-        block, with_dropout = self._block_fn()
+        with_dropout = self.training and (cfg.attention_dropout > 0.0
+                                          or cfg.hidden_dropout > 0.0)
+        body = self._block_fn(with_dropout)
+        core = self._train_core(with_dropout)
+
+        def block(p, h):
+            if with_dropout:
+                *p, key = p
+                k1, *k23 = jax.random.split(key, 3)
+            else:
+                k1 = k23 = None
+            return body(p, h, lambda q, k, v: (core(q, k, v, k1), None),
+                        drop_keys=k23)[0]
+
         mesh = _mesh.get_mesh() if _mesh.has_mesh() else None
         pp = mesh.shape["pp"] if (mesh and "pp" in mesh.axis_names) else 1
         remat = cfg.recompute_interval > 0 and self.training
@@ -1344,11 +1031,10 @@ class GPTStackedDecoder(Layer):
         if with_dropout:
             # one key per layer, scanned alongside the stacked params
             from ..ops.random import default_generator
-            from ..tensor import Tensor as _T
 
             base = default_generator.split()
             keys = jax.random.split(base, cfg.num_layers)
-            stacked_in.append(_T(keys, stop_gradient=True))
+            stacked_in.append(Tensor(keys, stop_gradient=True))
 
         if pp > 1:
             lps = cfg.num_layers // pp
@@ -1362,16 +1048,15 @@ class GPTStackedDecoder(Layer):
             else:
                 block_mb = None
 
-            def raw(h, *stacked):
+            def run(h, stacked):
                 b = h.shape[0]
                 mb = b // n_micro
                 xm = h.reshape(n_micro, mb, *h.shape[1:])
-                with jax.named_scope("layers"):
-                    out = pp_spmd.pipeline_blocks(
-                        block_mb or block, stacked, xm, layers_per_stage=lps,
-                        remat=remat, remat_policy=remat_policy,
-                        block_takes_index=block_mb is not None,
-                        n_virtual=cfg.virtual_pp_degree)
+                out = pp_spmd.pipeline_blocks(
+                    block_mb or block, stacked, xm, layers_per_stage=lps,
+                    remat=remat, remat_policy=remat_policy,
+                    block_takes_index=block_mb is not None,
+                    n_virtual=cfg.virtual_pp_degree)
                 return out.reshape(b, *h.shape[1:])
         else:
             # recompute_interval > 1 groups the remat boundary on the
@@ -1384,11 +1069,14 @@ class GPTStackedDecoder(Layer):
                     f"recompute_interval={k_remat} must divide "
                     f"num_layers={cfg.num_layers} on the stacked scan")
 
-            def raw(h, *stacked):
-                with jax.named_scope("layers"):
-                    return pp_spmd.scan_blocks(block, stacked, h, remat=remat,
-                                               remat_policy=remat_policy,
-                                               remat_interval=k_remat)
+            def run(h, stacked):
+                return pp_spmd.scan_blocks(block, stacked, h, remat=remat,
+                                           remat_policy=remat_policy,
+                                           remat_interval=k_remat)
+
+        def raw(h, *stacked):
+            with jax.named_scope("layers"):
+                return run(h, stacked)
 
         return dispatch.apply(raw, hidden, *stacked_in,
                               op_name="gpt_stacked_decoder")
@@ -1461,7 +1149,7 @@ class GPTStackedForPretraining(Layer, GenerationMixin):
                      dtype: str = "bfloat16") -> KVCache:
         cfg = self.config
         return KVCache(cfg.num_layers, batch_size, cfg.num_heads, max_seq,
-                       cfg.head_dim, dtype=dtype, stacked=True)
+                       cfg.head_dim, dtype=dtype)
 
     def _cached_lm_logits(self, input_ids, kv_cache, cache_index):
         return self.forward(input_ids, kv_cache=kv_cache,
@@ -1474,8 +1162,7 @@ class GPTStackedForPretraining(Layer, GenerationMixin):
 
         cfg = self.config
         return PagedKVCache(cfg.num_layers, num_pages, cfg.num_heads,
-                            page_size, cfg.head_dim, dtype=dtype,
-                            stacked=True)
+                            page_size, cfg.head_dim, dtype=dtype)
 
     def _paged_lm_logits(self, input_ids, paged_cache, page_tables,
                          positions, ragged_plan=None, out_rows=None,
@@ -1504,16 +1191,11 @@ def truncated_draft(model, num_layers: int = 1):
     dcfg = dataclasses.replace(cfg, num_layers=n)
     draft = type(model)(dcfg)
     src = model.state_dict()
-    out = {}
-    for k, dv in draft.state_dict().items():
-        sv = src.get(k)
-        if sv is None:
-            continue
-        a = np.asarray(sv.numpy())
-        if tuple(a.shape) != tuple(dv.shape):
-            a = a[: dv.shape[0]]             # stacked [L, ...] layer slice
-        out[k] = a
-    draft.set_state_dict(out)
+    # the stacked [L, ...] parameters keep their first n layers; every other
+    # entry has the draft's own leading size
+    draft.set_state_dict({k: np.asarray(src[k].numpy())[: dv.shape[0]]
+                          for k, dv in draft.state_dict().items()
+                          if k in src})
     draft.eval()
     return draft
 

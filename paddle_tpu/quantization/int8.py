@@ -117,11 +117,10 @@ def _quantize_lm_head(model, w):
 
 def quantize_for_serving(model):
     """PTQ entry point for ``weight_dtype="int8"`` serving: quantize the
-    decode hot path's projections (qkv/out_proj/fc1/fc2 per block + the
-    tied LM head) to int8 with per-output-channel absmax scales, in
-    place.  Supports both flagship GPT classes — the layered model's
-    Linear layers are swapped for :class:`Int8Linear`, the stacked
-    decoder switches its scan params to the int8 variant
+    decode hot path's projections (qkv/proj/fc1/fc2 per block + the tied
+    LM head) of a ``GPTStackedForPretraining`` to int8 with
+    per-output-channel absmax scales, in place: the stacked decoder
+    switches its scan params to the int8 variant
     (``GPTStackedDecoder.quantize_weights``).  Idempotent; refuses
     tensor-parallel models (per-channel scales over gathered shards are
     not meaningful — serve those with fp weights).  Returns ``model``.
@@ -135,22 +134,11 @@ def quantize_for_serving(model):
             "sharded — per-channel PTQ needs the unsharded weights; "
             "serve TP models with fp weights")
     dec = getattr(model, "decoder", None)
-    gpt = getattr(model, "gpt", None)
-    if dec is not None and hasattr(dec, "quantize_weights"):
-        # stacked flagship: int8 scan params + quantized tied LM head
-        dec.quantize_weights()
-        _quantize_lm_head(model, model.embeddings.word_embeddings.weight)
-    elif gpt is not None:
-        for layer in gpt.layers:
-            layer.attn.qkv_proj = Int8Linear(layer.attn.qkv_proj)
-            layer.attn.out_proj = Int8Linear(layer.attn.out_proj)
-            layer.mlp.fc1 = Int8Linear(layer.mlp.fc1)
-            layer.mlp.fc2 = Int8Linear(layer.mlp.fc2)
-        _quantize_lm_head(model, gpt.embeddings.word_embeddings.weight)
-    else:
+    if not hasattr(dec, "quantize_weights"):
         raise ValueError(
-            "quantize_for_serving: expected a GPTForPretraining or "
-            "GPTStackedForPretraining instance "
-            f"(got {type(model).__name__})")
+            "quantize_for_serving: expected a GPTStackedForPretraining "
+            f"instance (got {type(model).__name__})")
+    dec.quantize_weights()
+    _quantize_lm_head(model, model.embeddings.word_embeddings.weight)
     model._weight_int8 = True
     return model

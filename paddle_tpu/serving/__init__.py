@@ -29,8 +29,8 @@ request mix (PAPERS.md: "Ragged Paged Attention", arxiv 2604.15464).
   cancellation, watchdog-supervised steps with auto-recovery, bounded
   queues with typed ``Overloaded`` shedding, NaN-slot quarantine,
   streaming token callbacks, per-step metrics;
-- :mod:`faults` — deterministic fault-injection harness (step crashes,
-  stalls, NaN logits, pool exhaustion, callback errors) driving
+- ``paddle_tpu.faults`` — deterministic fault-injection harness (step
+  crashes, stalls, NaN logits, pool exhaustion, callback errors) driving
   tests/test_serving_faults.py and tools/serving_fault_gate.py;
 - :mod:`speculative` — ``SpeculativeEngine``: draft-model propose +
   ONE fused verify dispatch with in-graph accept/reject (greedy
@@ -95,7 +95,7 @@ from .engine import (  # noqa: F401
     serve_trace_counts,
     reset_serve_trace_counts,
 )
-from .faults import (  # noqa: F401
+from ..faults import (  # noqa: F401
     FaultInjector,
     FaultPlan,
     InjectedFault,
@@ -117,13 +117,11 @@ from .paged_cache import (  # noqa: F401
 )
 from .prefix_cache import PrefixCache  # noqa: F401
 from .speculative import SpeculativeEngine  # noqa: F401
-from .scheduler import (  # noqa: F401
-    AdmissionScheduler,
+from .admission import AdmissionScheduler, Slot  # noqa: F401
+from .placement import (  # noqa: F401
     LeastLoadedPlacement,
     PlacementScheduler,
     PrefixLocalityPlacement,
-    Scheduler,
-    Slot,
     replica_load,
 )
 from .sharded import ShardedServingEngine  # noqa: F401
@@ -143,7 +141,7 @@ __all__ = [
     "ROLE_PREFILL", "ROLE_DECODE", "ROLE_COLOCATED",
     "NULL_PAGE", "BlockAllocator", "PagedKVCache", "pages_for_tokens",
     "PrefixCache",
-    "AdmissionScheduler", "Scheduler", "Slot",
+    "AdmissionScheduler", "Slot",
     "PlacementScheduler", "LeastLoadedPlacement",
     "PrefixLocalityPlacement", "replica_load",
     "ElasticServingController", "ElasticConfig", "ClusterSignals",

@@ -5,8 +5,7 @@ One half of the PR-14 scheduler split (docs/serving.md "Sharded
 serving"): ADMISSION — pages, slots, queues, backpressure — is a
 per-replica concern and lives here; PLACEMENT — which ``dp`` replica
 seats a request at all — is a cluster-level concern and lives in
-``serving/placement.py``.  A single-replica engine uses this layer alone
-(``serving/scheduler.py`` re-exports both for compatibility).
+``serving/placement.py``.  A single-replica engine uses this layer alone.
 
 A fixed number of *slots* share one compiled fused step; the scheduler
 owns which request occupies which slot, each slot's page-table row,
@@ -42,7 +41,7 @@ import numpy as np
 
 from .paged_cache import NULL_PAGE, BlockAllocator, pages_for_tokens
 
-__all__ = ["Slot", "AdmissionScheduler", "Scheduler", "StepWork"]
+__all__ = ["Slot", "AdmissionScheduler", "StepWork"]
 
 
 class Slot:
@@ -284,9 +283,3 @@ class AdmissionScheduler:
             else:
                 work.append(StepWork(i, "decode", 1, slot.pos, False))
         return work
-
-
-# Historical name: before the placement/admission split (PR 14) this class
-# WAS serving/scheduler.py's ``Scheduler``.  Kept as an alias — engine
-# internals, tests, and external callers hold ``engine.scheduler``.
-Scheduler = AdmissionScheduler

@@ -144,28 +144,6 @@ class PageTransfer:
 
     # -- copy mechanics ----------------------------------------------------
     @staticmethod
-    def _pairs(src_cache, dst_cache):
-        """(src Tensor, dst Tensor, pages axis) for every pool buffer the
-        transfer must move — K/V per layer (or the stacked pair) plus the
-        int8 scale sidecars (a dequantizable page is page bytes AND its
-        scales)."""
-        if src_cache.stacked:
-            pairs = [(src_cache.k, dst_cache.k, 1),
-                     (src_cache.v, dst_cache.v, 1)]
-            if src_cache.quantized:
-                pairs += [(src_cache.k_scale, dst_cache.k_scale, 1),
-                          (src_cache.v_scale, dst_cache.v_scale, 1)]
-            return pairs
-        pairs = [(s, d, 0) for s, d in zip(src_cache.k, dst_cache.k)]
-        pairs += [(s, d, 0) for s, d in zip(src_cache.v, dst_cache.v)]
-        if src_cache.quantized:
-            pairs += [(s, d, 0)
-                      for s, d in zip(src_cache.k_scale, dst_cache.k_scale)]
-            pairs += [(s, d, 0)
-                      for s, d in zip(src_cache.v_scale, dst_cache.v_scale)]
-        return pairs
-
-    @staticmethod
     def _device_to_device(src_val):
         try:
             return all(d.platform != "cpu" for d in src_val.devices())
@@ -198,18 +176,18 @@ class PageTransfer:
                 [d_idx, np.full(bucket - n, d_idx[-1], np.int32)])
         s_idx = jnp.asarray(s_idx)
         d_idx = jnp.asarray(d_idx)
-        for s_t, d_t, axis in self._pairs(src_cache, dst_cache):
+        # every pool buffer moves — K, V and an int8 pool's scale sidecars (a
+        # dequantizable page is page bytes AND its scales); pages are axis 1
+        # of each ([L, P, ...])
+        for s_t, d_t in zip(src_cache._tensors(), dst_cache._tensors()):
             src_val = s_t._value
-            block = (src_val[:, s_idx] if axis == 1 else src_val[s_idx])
+            block = src_val[:, s_idx]
             if not self._device_to_device(src_val):
                 # host-staged fallback (CPU, or pools whose meshes the
                 # backend cannot bridge in one expression): numpy round
                 # trip is bit-exact for every pool dtype incl. bf16/int8
                 block = jnp.asarray(np.asarray(block), src_val.dtype)
-            if axis == 1:
-                d_t._set_value(d_t._value.at[:, d_idx].set(block))
-            else:
-                d_t._set_value(d_t._value.at[d_idx].set(block))
+            d_t._set_value(d_t._value.at[:, d_idx].set(block))
 
     # -- the protocol ------------------------------------------------------
     def transfer(self, src: ServingEngine, src_idx: int,

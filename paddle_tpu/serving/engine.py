@@ -507,8 +507,8 @@ class _StepWorker:
 
 class ServingEngine:
     """Continuous-batching front end over a model exposing the paged-cache
-    contract (``new_paged_kv_cache`` + ``_paged_lm_logits`` — both GPT
-    flagship classes implement it).
+    contract (``new_paged_kv_cache`` + ``_paged_lm_logits``):
+    ``GPTStackedForPretraining``, the one model that serves.
 
     ``num_pages`` defaults to full capacity (every slot can hold
     ``max_context`` tokens, plus the null page); size it DOWN to
@@ -542,6 +542,13 @@ class ServingEngine:
                  kv_dtype: Optional[str] = None,
                  weight_dtype: Optional[str] = None,
                  role: Optional[str] = None):
+        if not (hasattr(model, "new_paged_kv_cache")
+                and hasattr(model, "_paged_lm_logits")):
+            raise TypeError(
+                "ServingEngine serves a model with the paged-cache contract "
+                "(new_paged_kv_cache + _paged_lm_logits), which "
+                f"{type(model).__name__} does not have: build a "
+                "GPTStackedForPretraining")
         cfg = model.config
         # disaggregated serving (serving/disagg.py): the replica's role
         # ("prefill" | "decode" | "colocated").  Passing it explicitly
@@ -695,7 +702,7 @@ class ServingEngine:
         self._worker: Optional[_StepWorker] = None
         # test-only fault injection: fn(point, ctx) may raise, stall, or
         # mutate ctx to simulate a fault at that point of the step pipeline
-        # (serving/faults.py; same discipline as checkpoint/manager.py)
+        # (paddle_tpu/faults.py; same discipline as checkpoint/manager.py)
         self._fault_hook: Optional[Callable] = None
 
         # host mirrors shipped to the jitted step each call (fixed shapes)

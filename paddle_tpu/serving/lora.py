@@ -25,11 +25,11 @@ tenant SEATED in a decode slot pins its page via a refcount: evicting it
 raises the typed :class:`AdapterInUse` instead of silently decoding with
 a recycled page's weights — no silent wrong-adapter decode.
 
-Target matrices (both GPT flagship classes): ``qkv_proj``, ``out_proj``,
-``fc1``, ``fc2``.  Slab layout per layer is the 8-tuple
-``(qkv_A, qkv_B, proj_A, proj_B, fc1_A, fc1_B, fc2_A, fc2_B)``; the
-stacked decoder scans ``[L, pages, dim, r]`` slabs alongside its stacked
-parameters.  See docs/serving.md "Speculative decoding & multi-tenant
+Target matrices (``GPTStackedDecoder``): ``qkv_w``, ``proj_w``,
+``fc1_w``, ``fc2_w``.  The slabs are the 8-tuple
+``(qkv_A, qkv_B, proj_A, proj_B, fc1_A, fc1_B, fc2_A, fc2_B)`` of
+``[L, pages, dim, r]`` Tensors, which the decoder scans alongside its
+stacked parameters.  See docs/serving.md "Speculative decoding & multi-tenant
 LoRA" for sizing (slab bytes = 2 * r * (4h + 3h + f + f + h + h) * L *
 pages * itemsize with the default targets).
 """
@@ -96,14 +96,12 @@ class LoRAAdapterPool:
     ``num_adapter_pages`` counts REGISTRABLE adapters (the null page is
     extra, allocator-style); ``rank`` is fixed per pool (one compiled
     step — a mixed-rank fleet runs one pool per rank bucket); ``alpha``
-    defaults to ``rank`` (scaling = alpha / rank = 1.0).  ``stacked``
-    selects the slab layout to match the model class (stacked GPT scans
-    ``[L, P, dim, r]`` slabs; layered gathers per-layer ``[P, dim, r]``
-    Tensors)."""
+    defaults to ``rank`` (scaling = alpha / rank = 1.0).  The slabs are
+    ``[L, P, dim, r]``: the layer axis leads, as in the decoder's
+    parameters, so the serving scan slices both alike."""
 
     def __init__(self, cfg, *, num_adapter_pages: int = 8, rank: int = 4,
-                 alpha: Optional[float] = None, dtype: str = "float32",
-                 stacked: bool = False):
+                 alpha: Optional[float] = None, dtype: str = "float32"):
         if rank < 1:
             raise ValueError(f"rank must be >= 1, got {rank}")
         if num_adapter_pages < 1:
@@ -113,7 +111,6 @@ class LoRAAdapterPool:
         self.alpha = float(alpha if alpha is not None else rank)
         self.scaling = self.alpha / self.rank
         self.dtype = str(dtype)
-        self.stacked = bool(stacked)
         self.num_pages = int(num_adapter_pages) + 1      # + null page
         self.allocator = BlockAllocator(self.num_pages)
         self._lock = threading.Lock()
@@ -125,38 +122,14 @@ class LoRAAdapterPool:
         self._slabs: Dict[str, Tuple[Tensor, Tensor]] = {}
         for m in TARGETS:
             din, dout = dims[m]
-            if stacked:
-                a = Tensor(jnp.zeros((L, P, din, r), jd))
-                b = Tensor(jnp.zeros((L, P, r, dout), jd))
-            else:
-                a = Tensor(jnp.zeros((P, L, din, r), jd))
-                b = Tensor(jnp.zeros((P, L, r, dout), jd))
-            self._slabs[m] = (a, b)
+            self._slabs[m] = (Tensor(jnp.zeros((L, P, din, r), jd)),
+                              Tensor(jnp.zeros((L, P, r, dout), jd)))
 
-    # -- slab views (models/gpt.py contract) -------------------------------
-    def layer_slabs(self, i: int):
-        """Per-layer 8-tuple of ``[P, dim, r]`` slab Tensors (layered
-        models).  The layered layout keeps the page axis LEADING so the
-        per-token gather stays one ``take``; the layer axis is sliced
-        here, at trace time."""
-        if self.stacked:
-            raise ValueError("layer_slabs() is for the layered layout; "
-                             "stacked models scan stacked_slabs()")
-        out = []
-        for m in TARGETS:
-            a, b = self._slabs[m]
-            out.extend((a[:, i], b[:, i]))
-        return tuple(out)
-
+    # -- slab view (models/gpt.py contract) --------------------------------
     def stacked_slabs(self):
-        """8-tuple of stacked ``[L, P, dim, r]`` slab Tensors, scanned
-        alongside the stacked decoder parameters."""
-        if not self.stacked:
-            raise ValueError("stacked_slabs() is for the stacked layout")
-        out = []
-        for m in TARGETS:
-            out.extend(self._slabs[m])
-        return tuple(out)
+        """8-tuple of ``[L, P, dim, r]`` slab Tensors, scanned alongside
+        the stacked decoder parameters."""
+        return tuple(t for m in TARGETS for t in self._slabs[m])
 
     @property
     def nbytes(self) -> int:
@@ -212,14 +185,8 @@ class LoRAAdapterPool:
                     f"(rank {r} pool)")
             at, bt = self._slabs[m]
             jd = at._value.dtype
-            if self.stacked:
-                at._set_value(at._value.at[:, page].set(
-                    jnp.asarray(a_np, jd)))
-                bt._set_value(bt._value.at[:, page].set(
-                    jnp.asarray(b_np, jd)))
-            else:
-                at._set_value(at._value.at[page].set(jnp.asarray(a_np, jd)))
-                bt._set_value(bt._value.at[page].set(jnp.asarray(b_np, jd)))
+            at._set_value(at._value.at[:, page].set(jnp.asarray(a_np, jd)))
+            bt._set_value(bt._value.at[:, page].set(jnp.asarray(b_np, jd)))
 
     def evict(self, name: str):
         """Free the adapter's page.  Typed :class:`AdapterInUse` while any
@@ -281,31 +248,13 @@ class LoRAAdapterPool:
                 raise UnknownAdapter(f"adapter {name!r} is not registered")
             page = ent[0]
         sd = {k: np.asarray(v.numpy()) for k, v in model.state_dict().items()}
-        L = self.cfg.num_layers
-        deltas = {}
+        names = {"qkv": "decoder.qkv_w", "out_proj": "decoder.proj_w",
+                 "fc1": "decoder.fc1_w", "fc2": "decoder.fc2_w"}
         for m in TARGETS:
             at, bt = self._slabs[m]
-            if self.stacked:
-                a = np.asarray(at._value[:, page], np.float32)
-                b = np.asarray(bt._value[:, page], np.float32)
-            else:
-                a = np.asarray(at._value[page], np.float32)
-                b = np.asarray(bt._value[page], np.float32)
-            deltas[m] = np.einsum("lir,lro->lio", a, b) * self.scaling
-        stacked_names = {"qkv": "decoder.qkv_w", "out_proj": "decoder.proj_w",
-                         "fc1": "decoder.fc1_w", "fc2": "decoder.fc2_w"}
-        layered_names = {"qkv": "qkv_proj.weight", "out_proj":
-                         "out_proj.weight", "fc1": "fc1.weight",
-                         "fc2": "fc2.weight"}
-        for m in TARGETS:
-            sname = stacked_names[m]
-            if sname in sd:                       # stacked model
-                sd[sname] = (sd[sname].astype(np.float32)
-                             + deltas[m]).astype(sd[sname].dtype)
-                continue
-            for li in range(L):                   # layered model
-                for k in sd:
-                    if k.endswith(layered_names[m]) and f"layer_{li}." in k:
-                        sd[k] = (sd[k].astype(np.float32)
-                                 + deltas[m][li]).astype(sd[k].dtype)
+            a = np.asarray(at._value[:, page], np.float32)
+            b = np.asarray(bt._value[:, page], np.float32)
+            delta = np.einsum("lir,lro->lio", a, b) * self.scaling
+            w = sd[names[m]]
+            sd[names[m]] = (w.astype(np.float32) + delta).astype(w.dtype)
         return sd

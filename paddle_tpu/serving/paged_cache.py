@@ -1,10 +1,10 @@
 """Paged KV cache: a global pool of fixed-size KV pages + free-list
 allocator.
 
-``models/generation.py``'s ``KVCache`` preallocates ``[B, H, max_seq, D]``
-per slot — HBM scales with ``batch * max_seq`` whether or not the tokens
+``models/generation.py``'s ``KVCache`` preallocates ``[L, B, H, max_seq, D]``
+— HBM scales with ``batch * max_seq`` whether or not the tokens
 exist.  The paged cache replaces that with ONE pool of
-``[num_pages, H, page_size, D]`` pages shared by every decode slot; a
+``[L, num_pages, H, page_size, D]`` pages shared by every decode slot; a
 slot's context is named by its *page table* (an int32 row of pool page
 ids), so memory scales with live tokens and short requests stop subsidizing
 long ones.
@@ -21,8 +21,8 @@ contract, unchanged).
 
 ``dtype="int8"`` selects the QUANTIZED pool regime (docs/serving.md
 "Quantized serving"): pages store int8 payloads and a parallel fp32
-``[num_pages, H]`` scale buffer per layer (``[L, num_pages, H]``
-stacked) holds one absmax scale per (page, head).  The scale buffers
+``[L, num_pages, H]`` scale buffer holds one absmax scale per (layer,
+page, head).  The scale buffers
 are indexed BY PAGE ID, so they ride the same BlockAllocator ledger as
 the pages themselves — alloc/free/share/spec-reserve/refcount semantics
 are untouched and prefix-cache COW, speculative rollback, and the
@@ -68,11 +68,8 @@ def pages_for_tokens(tokens: int, page_size: int) -> int:
 
 
 class PagedKVCache(_KVBuffers):
-    """Global KV page pool.
-
-    ``stacked=False``: per-layer Tensor pairs ``k[i]/v[i]`` of shape
-    ``[num_pages, H, page_size, D]`` (the layered ``GPTModel`` path).
-    ``stacked=True``: single Tensor pair ``[L, num_pages, H, page_size, D]``.
+    """Global KV page pool: one Tensor pair ``k``/``v`` of shape
+    ``[L, num_pages, H, page_size, D]``.
     The fused step carries each through its layer loop as ONE donated
     buffer, viewed as ``[L * num_pages, H, page_size, D]``: layer ``l``
     addresses page ``l * num_pages + page_id`` and writes a token as rows of
@@ -81,16 +78,15 @@ class PagedKVCache(_KVBuffers):
     allocator, the prefix cache, page hand-off, sharding and checkpoints see.
 
     ``paged`` is the duck-type marker ``models/gpt.py`` dispatches on (a
-    paged cache routes attention through the page-table write + paged
-    decode kernel instead of the contiguous ``dynamic_update_slice``
+    paged cache routes attention through the page-table write + the ragged
+    work-list kernel instead of the contiguous ``dynamic_update_slice``
     path).
     """
 
     paged = True
 
     def __init__(self, num_layers: int, num_pages: int, num_heads: int,
-                 page_size: int, head_dim: int, dtype: str = "bfloat16",
-                 stacked: bool = False):
+                 page_size: int, head_dim: int, dtype: str = "bfloat16"):
         if num_pages < 2:
             raise ValueError(
                 f"num_pages={num_pages}: the pool needs the null page plus "
@@ -106,48 +102,19 @@ class PagedKVCache(_KVBuffers):
         self.page_size = page_size
         self.head_dim = head_dim
         self.dtype = str(dtype)
-        self.stacked = stacked
         # quantized regime: int8 pages + per-(page, head) fp32 absmax
         # scales.  Scale buffers are keyed by POOL PAGE ID so they need
         # no allocator of their own — a page's scale travels with it
         # through every ledger transition (free/used/spec/shared).
         self.quantized = self.dtype == "int8"
         self.k_scale = self.v_scale = None
-        if stacked:
-            shape = (num_layers, num_pages, num_heads, page_size, head_dim)
-            self.k = Tensor(jnp.zeros(shape, jd))
-            self.v = Tensor(jnp.zeros(shape, jd))
-            if self.quantized:
-                ss = (num_layers, num_pages, num_heads)
-                self.k_scale = Tensor(jnp.zeros(ss, jnp.float32))
-                self.v_scale = Tensor(jnp.zeros(ss, jnp.float32))
-        else:
-            shape = (num_pages, num_heads, page_size, head_dim)
-            self.k = [Tensor(jnp.zeros(shape, jd)) for _ in range(num_layers)]
-            self.v = [Tensor(jnp.zeros(shape, jd)) for _ in range(num_layers)]
-            if self.quantized:
-                ss = (num_pages, num_heads)
-                self.k_scale = [Tensor(jnp.zeros(ss, jnp.float32))
-                                for _ in range(num_layers)]
-                self.v_scale = [Tensor(jnp.zeros(ss, jnp.float32))
-                                for _ in range(num_layers)]
-
-    def layer(self, i: int):
-        """(k, v) pool Tensors for layer ``i`` (layered layout only)."""
-        if self.stacked:
-            raise ValueError("layer() is for the per-layer pool layout; "
-                             "the stacked pool is scanned whole")
-        return self.k[i], self.v[i]
-
-    def layer_scales(self, i: int):
-        """(k_scale, v_scale) Tensors for layer ``i`` — ``(None, None)``
-        outside the quantized regime (layered layout only)."""
-        if self.stacked:
-            raise ValueError("layer_scales() is for the per-layer pool "
-                             "layout; the stacked pool is scanned whole")
-        if not self.quantized:
-            return None, None
-        return self.k_scale[i], self.v_scale[i]
+        shape = (num_layers, num_pages, num_heads, page_size, head_dim)
+        self.k = Tensor(jnp.zeros(shape, jd))
+        self.v = Tensor(jnp.zeros(shape, jd))
+        if self.quantized:
+            ss = (num_layers, num_pages, num_heads)
+            self.k_scale = Tensor(jnp.zeros(ss, jnp.float32))
+            self.v_scale = Tensor(jnp.zeros(ss, jnp.float32))
 
     def _tensors(self):
         """All device buffers, INCLUDING the scale buffers — so
@@ -155,10 +122,7 @@ class PagedKVCache(_KVBuffers):
         watchdog's zombie cleanup orphans them with the pages."""
         ts = super()._tensors()
         if self.quantized:
-            if self.stacked:
-                ts = ts + [self.k_scale, self.v_scale]
-            else:
-                ts = ts + list(self.k_scale) + list(self.v_scale)
+            ts = ts + [self.k_scale, self.v_scale]
         return ts
 
 
@@ -188,8 +152,8 @@ class BlockAllocator:
         # referenced — ``reclaim`` refuses refcount > 0).
         self.reclaimer = None
         # test-only fault injection: fn("alloc", ctx) may set
-        # ctx["force_none"] to simulate pool exhaustion (serving/faults.py;
-        # same discipline as checkpoint/manager.py's _fault_hook)
+        # ctx["force_none"] to simulate pool exhaustion (paddle_tpu/faults.py,
+        # the discipline of checkpoint/manager.py's _fault_hook)
         self._fault_hook = None
 
     @property

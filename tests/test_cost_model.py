@@ -631,10 +631,10 @@ def test_page_transfer_cost_is_gl_compatible_ppermute():
 
 
 def test_paged_pool_bytes_matches_real_pool():
-    from paddle_tpu.models import GPTForPretraining, gpt_tiny
+    from paddle_tpu.models import GPTStackedForPretraining, gpt_tiny
 
     cfg = gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0)
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     for dtype in ("float32", "bfloat16", "int8"):
         cache = m.new_paged_kv_cache(6, 16, dtype=dtype)
         want = cm.paged_pool_bytes(6, cfg.num_heads, 16, cfg.head_dim,

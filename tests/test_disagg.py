@@ -3,7 +3,7 @@ roles with page-granular KV hand-off.
 
 - greedy BITWISE parity: a disaggregated cluster — every decode token
   produced on a replica the request was NOT admitted to — emits exactly
-  the colocated cluster's ids (fp32 + bf16, layered + stacked pools);
+  the colocated cluster's ids (fp32 + bf16 pools);
 - trace discipline: hand-offs are eager pool writes, so each role still
   compiles one fused program with <= 2 python-body runs;
 - ownership protocol: both pools' free+used+spec+shared == capacity at
@@ -22,11 +22,7 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import serving
-from paddle_tpu.models import (
-    GPTForPretraining,
-    GPTStackedForPretraining,
-    gpt_tiny,
-)
+from paddle_tpu.models import GPTStackedForPretraining, gpt_tiny
 from paddle_tpu.serving import (
     ROLE_COLOCATED,
     ROLE_DECODE,
@@ -44,9 +40,9 @@ def _tiny_cfg():
     return gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0)
 
 
-def _fresh_model(model_cls):
+def _fresh_model():
     pt.seed(0)
-    m = model_cls(_tiny_cfg())
+    m = GPTStackedForPretraining(_tiny_cfg())
     m.eval()
     return m
 
@@ -72,8 +68,8 @@ def _assert_pool_invariants(cluster):
             f"cap={a.capacity}")
 
 
-def _run_parity(model_cls, cache_dtype):
-    model = _fresh_model(model_cls)
+def _run_parity(cache_dtype):
+    model = _fresh_model()
     cfg = _tiny_cfg()
     prompts, new_toks = _workload(cfg)
     kw = dict(num_slots=2, page_size=16, max_context=64,
@@ -118,29 +114,16 @@ def _run_parity(model_cls, cache_dtype):
 # parity: disagg greedy == colocated greedy, bitwise
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("model_cls,cache_dtype", [
-    (GPTForPretraining, "float32"),
-    (GPTStackedForPretraining, "bfloat16"),
-])
-def test_disagg_greedy_parity(model_cls, cache_dtype):
-    _run_parity(model_cls, cache_dtype)
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("model_cls,cache_dtype", [
-    (GPTForPretraining, "bfloat16"),
-    (GPTStackedForPretraining, "float32"),
-])
-def test_disagg_greedy_parity_slow(model_cls, cache_dtype):
-    """The remaining (pool layout x dtype) corner of the parity matrix."""
-    _run_parity(model_cls, cache_dtype)
+@pytest.mark.parametrize("cache_dtype", ["float32", "bfloat16"])
+def test_disagg_greedy_parity(cache_dtype):
+    _run_parity(cache_dtype)
 
 
 def test_disagg_int8_pages_transfer_with_scales():
     """Int8 pool: the hand-off must move the fp32 absmax scale sidecars
     along with the quantized pages, or the destination dequantizes
     garbage — parity against the colocated int8 cluster catches it."""
-    _run_parity(GPTForPretraining, "int8")
+    _run_parity("int8")
 
 
 # ---------------------------------------------------------------------------
@@ -149,7 +132,7 @@ def test_disagg_int8_pages_transfer_with_scales():
 
 def _run_fault_storm(seed, include_general=True):
     cfg = _tiny_cfg()
-    dis = DisaggServingEngine(_fresh_model(GPTForPretraining),
+    dis = DisaggServingEngine(_fresh_model(),
                               roles=(ROLE_PREFILL, ROLE_DECODE),
                               mp=1, num_slots=2, page_size=16,
                               max_context=64, cache_dtype="float32")
@@ -200,7 +183,7 @@ def test_transfer_error_rolls_back_source_retains():
     reservation rolled back and the source still owning the request —
     which then completes (re-routed or decoded in place) with bitwise
     the same ids as a fault-free run."""
-    model = _fresh_model(GPTForPretraining)
+    model = _fresh_model()
     cfg = _tiny_cfg()
     prompts, new_toks = _workload(cfg, n=2, seed=3)
 
@@ -251,11 +234,11 @@ def test_role_placement_ranks_decode_last():
 
 def test_all_decode_roles_rejected():
     with pytest.raises(ValueError, match="admit"):
-        DisaggServingEngine(_fresh_model(GPTForPretraining),
+        DisaggServingEngine(_fresh_model(),
                             roles=(ROLE_DECODE, ROLE_DECODE), mp=1,
                             num_slots=2, page_size=16, max_context=64)
     with pytest.raises(ValueError, match="unknown replica role"):
-        DisaggServingEngine(_fresh_model(GPTForPretraining),
+        DisaggServingEngine(_fresh_model(),
                             roles=("prefil",), mp=1, num_slots=2,
                             page_size=16, max_context=64)
 
@@ -274,7 +257,7 @@ def test_transfer_fault_kinds_validate_point():
 def test_transfer_metrics_reach_prometheus():
     from paddle_tpu.telemetry import metrics as tmetrics
 
-    model = _fresh_model(GPTForPretraining)
+    model = _fresh_model()
     cfg = _tiny_cfg()
     prompts, new_toks = _workload(cfg, n=2, seed=5)
     dis = DisaggServingEngine(model, roles=(ROLE_PREFILL, ROLE_DECODE),

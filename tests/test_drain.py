@@ -28,7 +28,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as pt
-from paddle_tpu.models import GPTForPretraining, gpt_tiny
+from paddle_tpu.models import GPTStackedForPretraining, gpt_tiny
 from paddle_tpu.serving import (
     LoRAAdapterPool,
     Overloaded,
@@ -52,7 +52,7 @@ N_NEW = 4
 def served():
     pt.seed(0)
     cfg = gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0)
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     rng = np.random.RandomState(1)
     prompts = [rng.randint(0, cfg.vocab_size, (s,))

@@ -32,7 +32,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as pt
-from paddle_tpu.models import GPTForPretraining, gpt_tiny
+from paddle_tpu.models import GPTStackedForPretraining, gpt_tiny
 from paddle_tpu.serving import (
     BROWNOUT_RUNGS,
     Brownout,
@@ -273,7 +273,7 @@ def test_queue_wait_shedding_immune_to_wall_clock_jump(monkeypatch):
     spuriously shed (nor a backwards jump keep a request alive)."""
     pt.seed(0)
     cfg = gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0)
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     prompts = [np.arange(5), np.arange(7)]
     eng = ServingEngine(m, num_slots=2, page_size=16, max_context=64,
@@ -320,7 +320,7 @@ def test_controller_actions_counter_and_gauge_exposition():
 def cluster_model():
     pt.seed(0)
     cfg = gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0)
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     return m, cfg
 

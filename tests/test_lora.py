@@ -12,9 +12,7 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import serving
-from paddle_tpu.models import (
-    GPTForPretraining, GPTStackedForPretraining, gpt_tiny,
-)
+from paddle_tpu.models import GPTStackedForPretraining, gpt_tiny
 from paddle_tpu.serving import (
     AdapterError, AdapterInUse, LoRAAdapterPool, RequestState,
     ServingEngine, UnknownAdapter, random_adapter,
@@ -24,16 +22,15 @@ ENG_KW = dict(num_slots=3, page_size=16, max_context=64,
               cache_dtype="float32")
 
 
-def _model(stacked=False, seed=0):
+def _model(seed=0):
     pt.seed(seed)
     cfg = gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0)
-    cls = GPTStackedForPretraining if stacked else GPTForPretraining
-    m = cls(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     return m, cfg
 
 
-def _merged_model(m, pool, name, stacked):
+def _merged_model(m, pool, name):
     cls = type(m)
     m2 = cls(m.config)
     m2.set_state_dict(pool.merged_state_dict(m, name))
@@ -89,14 +86,13 @@ class TestPoolAccounting:
 # ---------------------------------------------------------------------------
 
 class TestMergedWeightParity:
-    @pytest.mark.parametrize("stacked", [False, True])
-    def test_fp32_token_parity(self, stacked):
-        m, cfg = _model(stacked)
+    def test_fp32_token_parity(self):
+        m, cfg = _model()
         pool = LoRAAdapterPool(cfg, num_adapter_pages=3, rank=3,
-                               dtype="float32", stacked=stacked)
+                               dtype="float32")
         pool.register("t1", random_adapter(cfg, 3,
                                            np.random.RandomState(7)))
-        m2 = _merged_model(m, pool, "t1", stacked)
+        m2 = _merged_model(m, pool, "t1")
         prompts = _prompts(cfg)
         ref = ServingEngine(m2, **ENG_KW)
         want = ref.generate_batch(prompts, 6)
@@ -108,15 +104,14 @@ class TestMergedWeightParity:
         assert pool.refcount("t1") == 0           # released at retirement
         eng.close()
 
-    @pytest.mark.parametrize("stacked", [False, True])
     @pytest.mark.slow
-    def test_bf16_logits_close(self, stacked):
-        m, cfg = _model(stacked)
+    def test_bf16_logits_close(self):
+        m, cfg = _model()
         pool = LoRAAdapterPool(cfg, num_adapter_pages=2, rank=2,
-                               dtype="bfloat16", stacked=stacked)
+                               dtype="bfloat16")
         pool.register("t1", random_adapter(cfg, 2,
                                            np.random.RandomState(3)))
-        m2 = _merged_model(m, pool, "t1", stacked)
+        m2 = _merged_model(m, pool, "t1")
         kw = dict(ENG_KW, cache_dtype="bfloat16")
         prompts = _prompts(cfg, lengths=(6,))
         outs = []
@@ -161,7 +156,7 @@ class TestMergedWeightParity:
         prompts = _prompts(cfg)
         oracles = []
         for name in ("t1", "t2", None):
-            om = _merged_model(m, pool, name, False) if name else m
+            om = _merged_model(m, pool, name) if name else m
             ref = ServingEngine(om, **ENG_KW)
             oracles.append(ref.generate_batch([prompts[len(oracles)]],
                                               5)[0])
@@ -231,8 +226,8 @@ class TestLifecycle:
         wants = []
         for i, w in enumerate(weights):
             scratch.register(f"gen{i}", w)
-            ref = ServingEngine(_merged_model(m, scratch, f"gen{i}",
-                                              False), **ENG_KW)
+            ref = ServingEngine(_merged_model(m, scratch, f"gen{i}"),
+                                **ENG_KW)
             wants.append(ref.generate_batch(prompts, 4))
             ref.close()
         # the churned pool holds 2 pages for 3 generations: page REUSE
@@ -263,7 +258,7 @@ class TestLifecycle:
         pool = LoRAAdapterPool(cfg, num_adapter_pages=2, rank=2)
         pool.register("t1", random_adapter(cfg, 2,
                                            np.random.RandomState(9)))
-        m2 = _merged_model(m, pool, "t1", False)
+        m2 = _merged_model(m, pool, "t1")
         prompts = _prompts(cfg)
         ref = ServingEngine(m2, **ENG_KW)
         want = ref.generate_batch(prompts, 5)

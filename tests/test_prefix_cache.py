@@ -27,7 +27,7 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu.models import (
-    GPTForPretraining, GPTStackedForPretraining, gpt_tiny,
+    GPTStackedForPretraining, gpt_tiny,
 )
 from paddle_tpu.serving import (
     BlockAllocator,
@@ -284,10 +284,9 @@ def _shared_prefix_prompts(cfg, rng, page_size=16):
     ]
 
 
-def _parity_combo(dtype, stacked):
+def _parity_combo(dtype):
     cfg = _models()
-    model = (GPTStackedForPretraining(cfg) if stacked
-             else GPTForPretraining(cfg))
+    model = GPTStackedForPretraining(cfg)
     model.eval()
     rng = np.random.RandomState(5)
     prompts = _shared_prefix_prompts(cfg, rng)
@@ -312,22 +311,12 @@ def _parity_combo(dtype, stacked):
     eng.close()
 
 
-def test_cache_parity_fp32_layered():
-    _parity_combo("float32", stacked=False)
+def test_cache_parity_fp32_stacked():
+    _parity_combo("float32")
 
 
 def test_cache_parity_bf16_stacked():
-    _parity_combo("bfloat16", stacked=True)
-
-
-@pytest.mark.slow
-def test_cache_parity_bf16_layered():
-    _parity_combo("bfloat16", stacked=False)
-
-
-@pytest.mark.slow
-def test_cache_parity_fp32_stacked():
-    _parity_combo("float32", stacked=True)
+    _parity_combo("bfloat16")
 
 
 def test_eviction_under_pool_pressure_keeps_serving():
@@ -335,7 +324,7 @@ def test_eviction_under_pool_pressure_keeps_serving():
     zero-refcount cache pages BEFORE backpressuring — and accounting
     stays exact through it."""
     cfg = _models()
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     rng = np.random.RandomState(11)
     eng = ServingEngine(m, num_slots=1, page_size=16, max_context=48,
@@ -365,7 +354,7 @@ def test_speculative_engine_composes_with_prefix_cache():
     from paddle_tpu.serving import SpeculativeEngine
 
     cfg = _models()
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     rng = np.random.RandomState(3)
     prompts = _shared_prefix_prompts(cfg, rng)[:4]
@@ -436,7 +425,7 @@ def test_prefix_metrics_exist_with_cache_disabled():
     """metrics() keys and the Prometheus series exist whether or not the
     cache is on — dashboards and the sharded sum never KeyError."""
     cfg = _models()
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     eng = ServingEngine(m, num_slots=1, page_size=16, max_context=32,
                         cache_dtype="float32")

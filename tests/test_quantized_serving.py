@@ -26,7 +26,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as pt
-from paddle_tpu.models import GPTForPretraining, gpt_tiny
+from paddle_tpu.models import GPTStackedForPretraining, gpt_tiny
 from paddle_tpu.quantization.kv import (
     TINY_SCALE, dequant_pages, quantize_kv_write,
 )
@@ -42,7 +42,7 @@ N_NEW = 4
 def served():
     pt.seed(0)
     cfg = gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0)
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     rng = np.random.RandomState(1)
     prompts = [rng.randint(0, cfg.vocab_size, (s,))
@@ -64,8 +64,7 @@ def _engine(m, **kw):
 
 
 def _scale_tensors(cache):
-    return ([cache.k_scale, cache.v_scale] if cache.stacked
-            else list(cache.k_scale) + list(cache.v_scale))
+    return [cache.k_scale, cache.v_scale]
 
 
 # ---------------------------------------------------------------------------
@@ -222,8 +221,8 @@ def test_watchdog_rebuild_recreates_pool_and_scales(served):
         w = eng.submit(prompts[0], 2)
         eng.run_until_idle()
         assert w.finished
-        old_k = eng.cache.k[0]._value
-        old_ks = eng.cache.k_scale[0]._value
+        old_k = eng.cache.k._value
+        old_ks = eng.cache.k_scale._value
         FaultInjector().inject("before_decode", at=0, kind="step_stall",
                                duration=2.0).install(eng)
         reqs = [eng.submit(p, N_NEW) for p in prompts[:4]]
@@ -235,9 +234,10 @@ def test_watchdog_rebuild_recreates_pool_and_scales(served):
                     if isinstance(r.error, StepStalledError)]) == 3
         # the rebuilt pool is a FRESH int8 pool with fresh scale buffers
         assert eng.cache.quantized
-        assert eng.cache.k_scale[0]._value is not old_ks
+        assert eng.cache.k_scale._value is not old_ks
         for t in _scale_tensors(eng.cache):
-            assert t._value.shape == (eng.num_pages, cfg.num_heads)
+            assert t._value.shape == (cfg.num_layers, eng.num_pages,
+                                      cfg.num_heads)
         # zombie cleanup releases the suspect pool's pages AND scales
         deadline = time.monotonic() + 5.0
         while not old_ks.is_deleted() and time.monotonic() < deadline:

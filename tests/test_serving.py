@@ -5,8 +5,8 @@ Covers the acceptance criteria:
 - retrace-freedom under churn (>= 20 varying-length requests through a
   4-slot engine, the fused step compiles <= 1 program, outputs
   token-for-token equal to single-shot greedy generate());
-- fused mixed-step parity across interleaved arrivals for fp32+bf16 and
-  layered+stacked layouts;
+- fused mixed-step parity across interleaved arrivals for fp32, bf16 and
+  int8 pools, with the weights as built and quantized to int8;
 - ragged-kernel parity vs the per-token XLA gather oracle (interpret= on
   CPU), incl. page-straddling token blocks, shuffled work lists, zero
   lengths, and the plan builder's overflow guards;
@@ -26,7 +26,6 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu import inference, serving
 from paddle_tpu.models import (
-    GPTForPretraining,
     GPTStackedForPretraining,
     generation,
     gpt_tiny,
@@ -531,11 +530,11 @@ def test_plan_step_budget_oldest_admission_first():
     let a slot that churns through budget-sized prompts starve an older
     mid-prefill slot forever (its request would never see a token of
     budget while holding its reserved pages)."""
-    from paddle_tpu.serving.scheduler import Scheduler
+    from paddle_tpu.serving import AdmissionScheduler
 
     a = BlockAllocator(17)
-    sched = Scheduler(num_slots=2, max_pages_per_slot=4, page_size=16,
-                      allocator=a)
+    sched = AdmissionScheduler(num_slots=2, max_pages_per_slot=4,
+                               page_size=16, allocator=a)
     assert sched.try_admit(object(), 32) == 0       # seq 0 -> slot 0
     assert sched.try_admit(object(), 32) == 1       # seq 1 -> slot 1
     sched.slots[1].pending = np.arange(8, dtype=np.int64)
@@ -555,18 +554,15 @@ def test_plan_step_budget_oldest_admission_first():
 
 # ---------------------------------------------------------------------------
 # chunked prefill into non-contiguous pages (satellite): parity vs the
-# contiguous-cache path and vs the full forward, fp32+bf16, both layouts
+# contiguous-cache path and vs the full forward, fp32+bf16
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("model_cls", [GPTForPretraining,
-                                       GPTStackedForPretraining])
 @pytest.mark.parametrize("cache_dtype,atol", [("float32", 5e-5),
                                               ("bfloat16", 0.08)])
-def test_chunked_prefill_into_pages_matches_contiguous(model_cls,
-                                                       cache_dtype, atol):
+def test_chunked_prefill_into_pages_matches_contiguous(cache_dtype, atol):
     pt.seed(13)
     cfg = _tiny_cfg()
-    m = model_cls(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     ids_np = _prompt(cfg, s=12, seed=3)
     ids = pt.to_tensor(ids_np, dtype="int64")
@@ -612,7 +608,7 @@ def test_chunked_prefill_into_pages_matches_contiguous(model_cls,
 def test_continuous_batching_churn_matches_generate():
     pt.seed(0)
     cfg = _tiny_cfg()
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     rng = np.random.RandomState(1)
     lengths = [3, 17, 5, 9, 14, 4, 19, 7, 11, 6] * 2   # 20 varying lengths
@@ -686,36 +682,53 @@ _MIXED_CASES = {
 }
 
 
-def _pool_array(side):
-    """A pool side (stacked Tensor or per-layer list) as [L, P, H, ps, D]."""
-    if isinstance(side, (list, tuple)):
-        return np.stack([np.asarray(t.numpy(), np.float32) for t in side])
-    return np.asarray(side.numpy(), np.float32)
+def _alone_refs(m, prompts, n_new, cache_dtype):
+    """Each prompt served ALONE, prefilled in one chunk: what a model
+    whose weights ``quantize_for_serving`` took gives for it (its
+    contiguous-cache ``generate()`` needs the fp weights).  The int8
+    projections scale activations per row, so a token's result does not
+    depend on what shares its step."""
+    eng = ServingEngine(m, num_slots=1, page_size=16, max_context=64,
+                        cache_dtype=cache_dtype, prefill_token_budget=64)
+    refs = []
+    for p in prompts:
+        r = eng.submit(p, n_new)
+        eng.run_until_idle(max_steps=200)
+        assert r.finished
+        refs.append(r.output_ids())
+    eng.close()
+    return refs
 
 
-@pytest.mark.parametrize("model_cls", [GPTForPretraining,
-                                       GPTStackedForPretraining])
+@pytest.mark.parametrize("weights", ["as_built", "int8"])
 @pytest.mark.parametrize("cache_dtype", ["float32", "bfloat16", "int8"])
 @pytest.mark.parametrize("case", list(_MIXED_CASES))
-def test_fused_mixed_step_parity(model_cls, cache_dtype, case):
+def test_fused_mixed_step_parity(weights, cache_dtype, case):
     """The fused mixed prefill/decode step across interleaved arrivals:
-    greedy output token-for-token equal to single-shot generate() on
-    fp32 AND bf16 pools, layered AND stacked layouts, in every geometry
-    of ``_MIXED_CASES``."""
+    greedy output token-for-token equal to each request on its own
+    (single-shot generate(); for int8 weights an engine that serves one
+    request at a time) on fp32, bf16 AND int8 pools, with the weights as
+    built AND after ``quantize_for_serving``, in every geometry of
+    ``_MIXED_CASES``."""
+    from paddle_tpu.quantization import quantize_for_serving
+
     eng_kw, lengths, n_new = _MIXED_CASES[case]
     # an int8 pool (pages quantized on write, scale sidecars indexed by
     # the same offset page ids) reproduces the fp32 reference
     ref_dtype = "float32" if cache_dtype == "int8" else cache_dtype
     pt.seed(3)
     cfg = _tiny_cfg()
-    m = model_cls(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     rng = np.random.RandomState(2)
     prompts = [rng.randint(0, cfg.vocab_size, (s,)) for s in lengths]
-    refs = [np.asarray(m.generate(pt.to_tensor(p[None, :], dtype="int64"),
-                                  max_new_tokens=n_new, max_seq_len=64,
-                                  cache_dtype=ref_dtype).numpy())[0]
-            for p in prompts]
+    if weights == "int8":
+        refs = _alone_refs(quantize_for_serving(m), prompts, n_new, ref_dtype)
+    else:
+        refs = [np.asarray(
+            m.generate(pt.to_tensor(p[None, :], dtype="int64"),
+                       max_new_tokens=n_new, max_seq_len=64,
+                       cache_dtype=ref_dtype).numpy())[0] for p in prompts]
     eng = ServingEngine(m, max_context=64, cache_dtype=cache_dtype, **eng_kw)
     pool = eng.cache
     # the write's row index is int32
@@ -734,13 +747,14 @@ def test_fused_mixed_step_parity(model_cls, cache_dtype, case):
     for r, ref in zip(reqs, refs):
         assert r.finished
         assert np.array_equal(r.output_ids(), ref), (
-            model_cls.__name__, cache_dtype, r.id)
+            weights, cache_dtype, r.id)
     assert eng.compiled_programs == 1
     assert eng.allocator.used_pages == 0
     # every layer wrote the same pages, all of them pages the allocator
     # dealt (or the null page, the sink): a wrong offset lands elsewhere
     for side in (pool.k, pool.v):
-        written = np.abs(_pool_array(side)).sum(axis=(2, 3, 4)) > 0  # [L, P]
+        written = np.abs(np.asarray(side.numpy(), np.float32)).sum(
+            axis=(2, 3, 4)) > 0                                   # [L, P]
         assert written[0, 1:].any() and (written == written[0]).all(), case
         assert set(np.flatnonzero(written[0, 1:]) + 1) <= touched, case
     if case == "last_page_of_last_layer":
@@ -753,7 +767,7 @@ def test_out_of_pages_admission_backpressures():
     (never corrupt live slots) and still finish everything as pages free."""
     pt.seed(5)
     cfg = _tiny_cfg()
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     rng = np.random.RandomState(3)
     # 4 slots, but only 6 allocatable pages and every request reserves 2
@@ -794,7 +808,7 @@ def test_invocation_counters_exact():
     steps, and the ragged grid-occupancy means are populated."""
     pt.seed(0)
     cfg = _tiny_cfg()
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     rng = np.random.RandomState(0)
     eng = ServingEngine(m, num_slots=2, page_size=16, max_context=64,
@@ -837,7 +851,7 @@ def test_boundary_length_requests():
     generate()."""
     pt.seed(0)
     cfg = _tiny_cfg()
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     rng = np.random.RandomState(0)
     eng = ServingEngine(m, num_slots=2, page_size=16, max_context=64,
@@ -855,7 +869,7 @@ def test_boundary_length_requests():
 
 def test_requests_too_big_rejected_at_submit():
     cfg = _tiny_cfg()
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     eng = ServingEngine(m, num_slots=2, page_size=16, max_context=64,
                         num_pages=4, cache_dtype="float32")
@@ -872,7 +886,7 @@ def test_requests_too_big_rejected_at_submit():
 def test_eos_retires_slot_and_frees_pages():
     pt.seed(9)
     cfg = _tiny_cfg()
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     p = _prompt(cfg, s=6, seed=4)[0]
     base = np.asarray(m.generate(pt.to_tensor(p[None, :], dtype="int64"),
@@ -892,7 +906,7 @@ def test_eos_retires_slot_and_frees_pages():
 def test_streaming_token_callbacks_in_order():
     pt.seed(11)
     cfg = _tiny_cfg()
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     seen = []
     eng = ServingEngine(m, num_slots=2, page_size=16, max_context=64,
@@ -911,7 +925,7 @@ def test_per_request_sampling_mix_and_reproducibility():
     reproducible under the same global seed."""
     cfg = _tiny_cfg()
     pt.seed(0)
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     pg = _prompt(cfg, s=7, seed=8)[0]
     ps = _prompt(cfg, s=5, seed=9)[0]
@@ -939,14 +953,13 @@ def test_per_request_sampling_mix_and_reproducibility():
 
 def test_engine_close_releases_pool_and_rejects_use():
     cfg = _tiny_cfg()
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     eng = ServingEngine(m, num_slots=2, page_size=16, max_context=32,
                         cache_dtype="float32")
-    ks = eng.cache.k if isinstance(eng.cache.k, list) else [eng.cache.k]
+    k = eng.cache.k
     eng.close()
-    for t in ks:
-        assert t._value.is_deleted()
+    assert k._value.is_deleted()
     with pytest.raises(RuntimeError, match="closed"):
         eng.submit(np.zeros(4, np.int64), 2)
     with pytest.raises(RuntimeError, match="closed"):
@@ -994,7 +1007,7 @@ def test_serving_step_donates_pool_gl004_clean():
     try:
         pt.seed(0)
         cfg = _tiny_cfg()
-        m = GPTForPretraining(cfg)
+        m = GPTStackedForPretraining(cfg)
         m.eval()
         # 300 pages x 4 heads x 16 x 16 fp32 = ~1.2 MiB per pool tensor:
         # big enough for the linter's donation_min_bytes candidate floor
@@ -1018,13 +1031,13 @@ def test_serving_step_donates_pool_gl004_clean():
 def test_lru_eviction_releases_cache_buffers():
     pt.seed(14)
     cfg = _tiny_cfg()
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     ids = pt.to_tensor(_prompt(cfg, b=2, s=6), dtype="int64")
     m.generate(ids, max_new_tokens=2, max_seq_len=32, cache_dtype="float32")
     first = m.__dict__["_decode_engines"][(2, 32, "float32", False, 0,
                                            False)]
-    held = first.cache.k[0]._value    # buffer to be evicted, ref held here
+    held = first.cache.k._value    # buffer to be evicted, ref held here
     for b in (48, 64, 80, 96):        # four more shapes: evicts the first
         m.generate(ids, max_new_tokens=2, max_seq_len=b,
                    cache_dtype="float32")
@@ -1034,7 +1047,7 @@ def test_lru_eviction_releases_cache_buffers():
     assert held.is_deleted(), \
         "evicted engine's KV buffers must be deleted eagerly, not GC'd"
     # clear_decode_cache releases every remaining engine's buffers
-    remaining = [e.cache.k[0]._value for e in engines.values()]
+    remaining = [e.cache.k._value for e in engines.values()]
     m.clear_decode_cache()
     assert "_decode_engines" not in m.__dict__
     assert all(v.is_deleted() for v in remaining)
@@ -1046,7 +1059,7 @@ def test_generate_retries_on_engine_released_race():
     engine lock), not dispatch into deleted arrays."""
     pt.seed(7)
     cfg = _tiny_cfg()
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     ids = pt.to_tensor(_prompt(cfg, b=2, s=6), dtype="int64")
     ref = m.generate(ids, max_new_tokens=3, max_seq_len=32,
@@ -1055,7 +1068,7 @@ def test_generate_retries_on_engine_released_race():
     # (buffers deleted, flag set) while it is still in the registry
     eng = m.__dict__["_decode_engines"][(2, 32, "float32", False, 0, False)]
     eng.release()
-    assert eng.released and eng.cache.k[0]._value.is_deleted()
+    assert eng.released and eng.cache.k._value.is_deleted()
     out = m.generate(ids, max_new_tokens=3, max_seq_len=32,
                      cache_dtype="float32").numpy()
     assert np.array_equal(out, ref)
@@ -1063,11 +1076,11 @@ def test_generate_retries_on_engine_released_race():
 
 def test_kv_cache_release_idempotent():
     cfg = _tiny_cfg()
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     cache = m.new_kv_cache(1, 32, dtype="float32")
     cache.release()
     cache.release()                   # second release must not raise
-    assert cache.k[0]._value.is_deleted()
+    assert cache.k._value.is_deleted()
 
 
 # ---------------------------------------------------------------------------
@@ -1087,7 +1100,7 @@ def test_predictor_pool_concurrent_acquire_run_release():
     engine serializes on its cache lock), nothing deadlocks."""
     pt.seed(2)
     cfg = _tiny_cfg()
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     ids = _prompt(cfg, b=2, s=6)
     ref = m.generate(pt.to_tensor(ids, dtype="int64"), max_new_tokens=4,
@@ -1125,7 +1138,7 @@ def test_predictor_pool_concurrent_acquire_run_release():
 
 def test_predictor_pool_release_guards():
     pt.seed(2)
-    m = GPTForPretraining(_tiny_cfg())
+    m = GPTStackedForPretraining(_tiny_cfg())
     m.eval()
     pool = _decode_pool(m, 2)
     p = pool.acquire()
@@ -1151,7 +1164,7 @@ def test_predictor_pool_release_guards():
 def test_predictor_serving_mode_matches_generate():
     pt.seed(2)
     cfg = _tiny_cfg()
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     ids = _prompt(cfg, b=3, s=6)
     ref = m.generate(pt.to_tensor(ids, dtype="int64"), max_new_tokens=5,
@@ -1170,7 +1183,7 @@ def test_predictor_serving_mode_matches_generate():
 
 
 def test_serving_mode_validation():
-    m = GPTForPretraining(_tiny_cfg())
+    m = GPTStackedForPretraining(_tiny_cfg())
     config = inference.Config(str("/nonexistent"))
     config.enable_serving_mode(max_new_tokens=2)
     with pytest.raises(RuntimeError, match="live model"):

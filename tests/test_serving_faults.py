@@ -25,7 +25,7 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import inference, serving
-from paddle_tpu.models import GPTForPretraining, gpt_tiny
+from paddle_tpu.models import GPTStackedForPretraining, gpt_tiny
 from paddle_tpu.serving import (
     DeadlineExceeded,
     FaultInjector,
@@ -49,7 +49,7 @@ def served():
     refs pin parity for every containment test)."""
     pt.seed(0)
     cfg = gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0)
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     rng = np.random.RandomState(1)
     prompts = [rng.randint(0, cfg.vocab_size, (s,))
@@ -238,7 +238,7 @@ def test_non_intact_crash_rebuilds_pool_and_keeps_serving(served):
                                  kind="step_exception",
                                  state_intact=False).install(eng)
     reqs = [eng.submit(p, N_NEW) for p in prompts[:4]]
-    old_k = eng.cache.k[0]._value
+    old_k = eng.cache.k._value
     eng.run_until_idle()
     mt = eng.metrics()
     assert mt["recoveries"] == 1 and mt["rebuilds"] == 1
@@ -308,7 +308,7 @@ def test_watchdog_abandons_stalled_step_and_recovers(served):
     w = eng.submit(prompts[0], 2)       # warmup: compiles under the much
     eng.run_until_idle()                # larger compile budget, not the stall
     assert w.finished
-    old_k = eng.cache.k[0]._value
+    old_k = eng.cache.k._value
     old_worker = eng._worker
     inj = FaultInjector().inject("before_decode", at=0, kind="step_stall",
                                  duration=2.0).install(eng)
@@ -327,7 +327,7 @@ def test_watchdog_abandons_stalled_step_and_recovers(served):
     while not old_k.is_deleted() and time.monotonic() < deadline:
         time.sleep(0.05)
     assert old_k.is_deleted(), "zombie cleanup never released the old pool"
-    assert not eng.cache.k[0]._value.is_deleted()
+    assert not eng.cache.k._value.is_deleted()
     # the replaced (dead) worker's thread must exit once its zombie thunk
     # returns — one leaked daemon thread per recovery would be unbounded
     assert old_worker is not eng._worker and old_worker.dead
@@ -362,7 +362,7 @@ def test_real_nan_weights_trip_the_in_step_sentry():
     NaNLogitsError instead of streaming garbage tokens."""
     pt.seed(3)
     cfg = gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0)
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     w = next(iter(m.parameters()))
     w.set_value(np.full(w.shape, np.nan, np.float32))

@@ -9,7 +9,7 @@ Covers the acceptance criteria on the forced-8-device CPU mesh
   (fast tier; generate()-equality follows transitively from
   test_serving.py's engine parity) AND directly to single-chip
   ``generate()`` (slow mirror + the serving gate's sharded scenario),
-  for (dp, mp) in {(1,2),(2,1),(2,2)}, layered + stacked, with
+  for (dp, mp) in {(1,2),(2,1),(2,2)}, with
   ``serve_trace_counts()["fused"] <= 2`` per replica (retrace-free SPMD
   step per replica);
 - aggregate slot capacity and page-pool HBM scale linearly with dp;
@@ -27,11 +27,7 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import serving
-from paddle_tpu.models import (
-    GPTForPretraining,
-    GPTStackedForPretraining,
-    gpt_tiny,
-)
+from paddle_tpu.models import GPTStackedForPretraining, gpt_tiny
 from paddle_tpu.serving import (
     LeastLoadedPlacement,
     Overloaded,
@@ -68,14 +64,14 @@ def _generate_refs(model, prompts, new_toks):
     return refs
 
 
-def _fresh_model(model_cls):
+def _fresh_model():
     pt.seed(0)
-    m = model_cls(_tiny_cfg())
+    m = GPTStackedForPretraining(_tiny_cfg())
     m.eval()
     return m
 
 
-# shared per-class fixtures, computed once and reused by every (dp, mp)
+# shared per-dtype fixtures, computed once and reused by every (dp, mp)
 # parametrization — the parity matrix re-runs only the SHARDED side,
 # keeping the fast tier-1 suite's wall clock down.  Sharing the MODEL
 # across sequential engines is safe: each engine (re-)commits the
@@ -84,13 +80,13 @@ def _fresh_model(model_cls):
 _ORACLES: dict = {}
 
 
-def _oracles(model_cls, kv_dtype="float32"):
-    if (model_cls, kv_dtype) not in _ORACLES:
+def _oracles(kv_dtype="float32"):
+    if kv_dtype not in _ORACLES:
         cfg = _tiny_cfg()
         prompts, new_toks = _workload(cfg)
-        ref_model = _fresh_model(model_cls)
+        ref_model = _fresh_model()
         # the fast tier's oracle is the single-chip ENGINE: its
-        # generate()-parity is already pinned per class by
+        # generate()-parity is already pinned by
         # test_serving.py (churn + fused-mixed-step parity tests) and
         # re-proven directly against generate() every CI pass by the
         # serving gate's sharded scenario, so equality to generate()
@@ -104,9 +100,8 @@ def _oracles(model_cls, kv_dtype="float32"):
         chip.run_until_idle()
         chip_out = [r.output_ids() for r in chip_reqs]
         chip.close()
-        _ORACLES[model_cls, kv_dtype] = (ref_model, prompts, new_toks,
-                                         chip_out)
-    return _ORACLES[model_cls, kv_dtype]
+        _ORACLES[kv_dtype] = (ref_model, prompts, new_toks, chip_out)
+    return _ORACLES[kv_dtype]
 
 
 # ---------------------------------------------------------------------------
@@ -115,13 +110,10 @@ def _oracles(model_cls, kv_dtype="float32"):
 
 # the last case: an int8 pool's pages AND scale sidecars shard per head
 # under mp = 2 and reproduce the single-chip int8 engine
-@pytest.mark.parametrize("model_cls,dp,mp,kv_dtype", [
-    (cls, dp, mp, "float32")
-    for dp, mp in MESHES
-    for cls in (GPTForPretraining, GPTStackedForPretraining)
-] + [(GPTStackedForPretraining, 1, 2, "int8")])
-def test_sharded_greedy_parity(model_cls, dp, mp, kv_dtype):
-    model, prompts, new_toks, chip_out = _oracles(model_cls, kv_dtype)
+@pytest.mark.parametrize("dp,mp,kv_dtype", [
+    (dp, mp, "float32") for dp, mp in MESHES] + [(1, 2, "int8")])
+def test_sharded_greedy_parity(dp, mp, kv_dtype):
+    model, prompts, new_toks, chip_out = _oracles(kv_dtype)
 
     serving.reset_serve_trace_counts()
     eng = ShardedServingEngine(model, dp=dp, mp=mp,
@@ -148,19 +140,17 @@ def test_sharded_greedy_parity(model_cls, dp, mp, kv_dtype):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("model_cls", [GPTForPretraining,
-                                       GPTStackedForPretraining])
 @pytest.mark.parametrize("dp,mp", MESHES)
-def test_sharded_parity_vs_generate_direct(model_cls, dp, mp):
+def test_sharded_parity_vs_generate_direct(dp, mp):
     """The slow mirror: DIRECT single-shot generate() references for
-    every (dp, mp) x model class (the fast tier proves the same equality
+    every (dp, mp) (the fast tier proves the same equality
     transitively through the single-chip engine; the serving gate's
     sharded scenario also runs a direct generate() comparison every CI
     pass)."""
     cfg = _tiny_cfg()
     prompts, new_toks = _workload(cfg)
-    refs = _generate_refs(_fresh_model(model_cls), prompts, new_toks)
-    eng = ShardedServingEngine(_fresh_model(model_cls),
+    refs = _generate_refs(_fresh_model(), prompts, new_toks)
+    eng = ShardedServingEngine(_fresh_model(),
                                dp=dp, mp=mp, num_slots=2, page_size=16,
                                max_context=64, cache_dtype="float32")
     reqs = [eng.submit(p, n) for p, n in zip(prompts, new_toks)]
@@ -173,11 +163,11 @@ def test_sharded_parity_vs_generate_direct(model_cls, dp, mp):
 def test_sharded_pool_bytes_shrink_per_chip():
     """The head-sharded pool really is 1/mp per chip: asserted on the
     actual device shard sizes, not just the metrics arithmetic."""
-    eng = ShardedServingEngine(_fresh_model(GPTForPretraining), dp=1, mp=2,
+    eng = ShardedServingEngine(_fresh_model(), dp=1, mp=2,
                                num_slots=2, page_size=16, max_context=64,
                                cache_dtype="float32")
     rep = eng.replicas[0]
-    pool = rep.cache.k[0]._value
+    pool = rep.cache.k._value
     shard_bytes = [s.data.nbytes for s in pool.addressable_shards]
     assert len(shard_bytes) == 2
     assert all(b == pool.nbytes // 2 for b in shard_bytes), shard_bytes
@@ -192,7 +182,7 @@ def test_dp_scaling_is_linear():
     replica owns a full pool on its own devices)."""
     base = None
     for dp in (1, 2):
-        eng = ShardedServingEngine(_fresh_model(GPTForPretraining),
+        eng = ShardedServingEngine(_fresh_model(),
                                    dp=dp, mp=1, num_slots=3, page_size=16,
                                    max_context=64, cache_dtype="float32")
         mets = eng.metrics()
@@ -206,7 +196,7 @@ def test_dp_scaling_is_linear():
             assert (mets["cache_bytes_per_chip"]
                     == base["cache_bytes_per_chip"])
             # replica pools live on DISJOINT devices
-            devs = [set(d.id for d in rep.cache.k[0]._value.devices())
+            devs = [set(d.id for d in rep.cache.k._value.devices())
                     for rep in eng.replicas]
             assert devs[0].isdisjoint(devs[1]), devs
         eng.close()
@@ -231,7 +221,7 @@ def test_placement_least_loaded_routing():
     """A queued request loads a replica; the next submit must prefer the
     idle one (queue depth is the primary signal).  Placement is pure host
     bookkeeping — the test never dispatches a fused step."""
-    eng = ShardedServingEngine(_fresh_model(GPTForPretraining), dp=2, mp=1,
+    eng = ShardedServingEngine(_fresh_model(), dp=2, mp=1,
                                num_slots=1, page_size=16, max_context=64,
                                cache_dtype="float32")
     cfg = _tiny_cfg()
@@ -247,7 +237,7 @@ def test_placement_least_loaded_routing():
 def test_placement_sheds_only_when_all_replicas_backpressure():
     import time
 
-    eng = ShardedServingEngine(_fresh_model(GPTForPretraining), dp=2, mp=1,
+    eng = ShardedServingEngine(_fresh_model(), dp=2, mp=1,
                                num_slots=1, page_size=16, max_context=64,
                                cache_dtype="float32", max_queue_depth=1)
     cfg = _tiny_cfg()
@@ -281,7 +271,7 @@ def test_placement_sheds_only_when_all_replicas_backpressure():
 def test_placement_first_replica_validation_error_propagates():
     """Oversized requests are a validation error, not backpressure — they
     must raise once, not be retried across the fleet."""
-    eng = ShardedServingEngine(_fresh_model(GPTForPretraining), dp=2, mp=1,
+    eng = ShardedServingEngine(_fresh_model(), dp=2, mp=1,
                                num_slots=1, page_size=16, max_context=64,
                                cache_dtype="float32")
     with pytest.raises(ValueError):
@@ -293,7 +283,7 @@ def test_placement_first_replica_validation_error_propagates():
 def test_placement_capacity_never_exceeded_under_churn():
     """Random arrival churn across tight replicas: no replica's pool ever
     exceeds its capacity, and everything drains to zero pages."""
-    eng = ShardedServingEngine(_fresh_model(GPTForPretraining), dp=2, mp=1,
+    eng = ShardedServingEngine(_fresh_model(), dp=2, mp=1,
                                num_slots=2, page_size=16, max_context=64,
                                num_pages=5, cache_dtype="float32")
     cfg = _tiny_cfg()
@@ -319,7 +309,7 @@ def test_placement_scheduler_standalone_over_plain_engines():
     """The placement layer is policy + forwarding only — it composes over
     plain single-chip engines too (no mesh required; routing asserted
     without ever dispatching a step)."""
-    m = _fresh_model(GPTForPretraining)
+    m = _fresh_model()
     engines = [ServingEngine(m, num_slots=1, page_size=16, max_context=64,
                              cache_dtype="float32") for _ in range(2)]
     sched = PlacementScheduler(engines, policy=LeastLoadedPlacement())
@@ -339,16 +329,16 @@ def test_placement_scheduler_standalone_over_plain_engines():
 
 
 # ---------------------------------------------------------------------------
-# scheduler split compatibility
+# scheduler split: admission per replica, placement per cluster
 # ---------------------------------------------------------------------------
 
-def test_scheduler_module_split_compat():
-    from paddle_tpu.serving import admission, placement, scheduler
+def test_scheduler_module_split():
+    from paddle_tpu.serving import admission, placement
 
-    assert scheduler.Scheduler is admission.AdmissionScheduler
-    assert scheduler.PlacementScheduler is placement.PlacementScheduler
+    assert serving.AdmissionScheduler is admission.AdmissionScheduler
+    assert serving.PlacementScheduler is placement.PlacementScheduler
     # the engine's scheduler attribute is the ADMISSION layer
-    eng = ServingEngine(_fresh_model(GPTForPretraining), num_slots=1,
+    eng = ServingEngine(_fresh_model(), num_slots=1,
                         page_size=16, max_context=32, cache_dtype="float32")
     assert isinstance(eng.scheduler, admission.AdmissionScheduler)
     eng.close()
@@ -381,7 +371,7 @@ def test_mesh_shard_gate_reasons():
 
 
 def test_engine_rejects_indivisible_head_shard():
-    m = _fresh_model(GPTForPretraining)   # gpt_tiny: 4 heads
+    m = _fresh_model()   # gpt_tiny: 4 heads
     with pytest.raises(ValueError, match="num_heads=4.*mp=3"):
         ShardedServingEngine(m, dp=1, mp=3, num_slots=1, page_size=16,
                              max_context=32, cache_dtype="float32")
@@ -513,7 +503,7 @@ def test_sharded_page_accounting_exact_under_random_faults():
 
     cfg = _tiny_cfg()
     for seed in (0,):   # more seeds ride in the slow variant below
-        eng = ShardedServingEngine(_fresh_model(GPTForPretraining),
+        eng = ShardedServingEngine(_fresh_model(),
                                    dp=2, mp=1, num_slots=2, page_size=16,
                                    max_context=64, cache_dtype="float32")
         for i, rep in enumerate(eng.replicas):
@@ -543,7 +533,7 @@ def test_sharded_faults_more_seeds():
 
     cfg = _tiny_cfg()
     for seed in (1, 2):
-        eng = ShardedServingEngine(_fresh_model(GPTForPretraining),
+        eng = ShardedServingEngine(_fresh_model(),
                                    dp=2, mp=1, num_slots=2, page_size=16,
                                    max_context=64, cache_dtype="float32")
         for i, rep in enumerate(eng.replicas):
@@ -569,7 +559,7 @@ def test_sharded_sampling_requests_complete():
     from paddle_tpu.serving import SamplingParams
 
     cfg = _tiny_cfg()
-    eng = ShardedServingEngine(_fresh_model(GPTStackedForPretraining),
+    eng = ShardedServingEngine(_fresh_model(),
                                dp=2, mp=2, num_slots=2, page_size=16,
                                max_context=64, cache_dtype="float32")
     rng = np.random.RandomState(7)

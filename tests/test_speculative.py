@@ -6,7 +6,7 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu import serving
 from paddle_tpu.models import (
-    GPTForPretraining, GPTStackedForPretraining, gpt_tiny, truncated_draft,
+    GPTStackedForPretraining, gpt_tiny, truncated_draft,
 )
 from paddle_tpu.serving import (
     BlockAllocator, SamplingParams, ServingEngine, SpeculativeEngine,
@@ -16,11 +16,10 @@ ENG_KW = dict(num_slots=3, page_size=16, max_context=64,
               cache_dtype="float32")
 
 
-def _model(stacked=False):
+def _model():
     pt.seed(0)
     cfg = gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0)
-    cls = GPTStackedForPretraining if stacked else GPTForPretraining
-    m = cls(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     return m, cfg
 
@@ -75,7 +74,7 @@ class TestSpecReservations:
 # ---------------------------------------------------------------------------
 
 class TestGreedyParity:
-    def test_same_model_draft_layered(self):
+    def test_same_model_draft(self):
         m, cfg = _model()
         prompts = _prompts(cfg)
         ref = ServingEngine(m, **ENG_KW)
@@ -114,7 +113,7 @@ class TestGreedyParity:
     def test_truncated_draft_parity(self):
         m, cfg = _model()
         d = truncated_draft(m, 1)
-        assert len(d.gpt.layers) == 1
+        assert d.decoder.qkv_w.shape[0] == 1
         prompts = _prompts(cfg, lengths=(5, 18, 9))
         ref = ServingEngine(m, **ENG_KW)
         want = ref.generate_batch(prompts, 6)
@@ -143,20 +142,6 @@ class TestGreedyParity:
         for g, w in zip(r_got, r_ref):
             assert g.tokens == w.tokens
         assert eng.allocator.used_pages == 0
-        eng.close()
-
-    @pytest.mark.slow
-    def test_same_model_draft_stacked(self):
-        m, cfg = _model(stacked=True)
-        prompts = _prompts(cfg, lengths=(5, 18, 9))
-        ref = ServingEngine(m, **ENG_KW)
-        want = ref.generate_batch(prompts, 6)
-        ref.close()
-        eng = SpeculativeEngine(m, m, spec_k=3, **ENG_KW)
-        got = eng.generate_batch(prompts, 6)
-        for g, w in zip(got, want):
-            assert np.array_equal(g, w)
-        assert eng.metrics()["spec_acceptance_rate"] == 1.0
         eng.close()
 
 
@@ -332,7 +317,7 @@ class TestSpecAccounting:
         eng.close()
 
     def test_randomized_fault_schedules_drain_exact(self):
-        from paddle_tpu.serving.faults import random_schedule
+        from paddle_tpu.faults import random_schedule
 
         m, cfg = _model()
         prompts = _prompts(cfg)
@@ -407,7 +392,7 @@ class TestSpecAccounting:
         m, _cfg = _model()
         cfg2 = gpt_tiny(vocab_size=512, hidden_dropout=0.0,
                         attention_dropout=0.0)
-        d = GPTForPretraining(cfg2)
+        d = GPTStackedForPretraining(cfg2)
         with pytest.raises(ValueError, match="vocab"):
             SpeculativeEngine(m, d, spec_k=2, **ENG_KW)
 

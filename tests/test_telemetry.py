@@ -27,7 +27,7 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import profiler
-from paddle_tpu.models import GPTForPretraining, gpt_tiny
+from paddle_tpu.models import GPTStackedForPretraining, gpt_tiny
 from paddle_tpu.serving import (
     FaultInjector, RequestState, ServingEngine, random_schedule,
 )
@@ -514,7 +514,7 @@ def test_record_event_records_span():
 def served():
     pt.seed(0)
     cfg = gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0)
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     rng = np.random.RandomState(1)
     prompts = [rng.randint(0, cfg.vocab_size, (s,))

@@ -60,12 +60,12 @@ class _Clock:
 
 def _build():
     import paddle_tpu as pt
-    from paddle_tpu.models import GPTForPretraining, gpt_tiny
+    from paddle_tpu.models import GPTStackedForPretraining, gpt_tiny
     from paddle_tpu.serving import ShardedServingEngine
 
     pt.seed(0)
     cfg = gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0)
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     rng = np.random.RandomState(0)
     prompts = [rng.randint(0, cfg.vocab_size, (n,)) for n in PROMPT_LENS]

@@ -192,8 +192,7 @@ def _lint_serve(pt, np):
     model2 = _build_model(pt, cfg)
     model2.eval()
     pool = LoRAAdapterPool(cfg, num_adapter_pages=2, rank=4,
-                           dtype="bfloat16",
-                           stacked=hasattr(model2, "decoder"))
+                           dtype="bfloat16")
     pool.register("tenant", random_adapter(cfg, 4, rng))
     eng = SpeculativeEngine(model2, model2, spec_k=2,
                             num_slots=_SRV_SLOTS, page_size=_SRV_PAGE,

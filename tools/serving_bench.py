@@ -40,7 +40,7 @@ skips): a fast correctness gate in the crash/lint-gate mold —
     end, backpressure observed (the pool is sized to force it).
 
 Chaos mode (--chaos): drives the engine at a fixed offered load while
-serving/faults.py injects step crashes, NaN logits, and allocator
+paddle_tpu/faults.py injects step crashes, NaN logits, and allocator
 exhaustion mid-run (and one stall when the watchdog is armed).  Prints
 one JSON line per measurement window:
 
@@ -188,8 +188,7 @@ def sweep(loads=(0.5, 1.0, 2.0, 4.0), n_requests: int = 24,
         slab_dtype = ("float32" if kw["cache_dtype"] == "int8"
                       else kw["cache_dtype"])
         pool = LoRAAdapterPool(cfg, num_adapter_pages=max(n_tenants, 1),
-                               rank=rank, dtype=slab_dtype,
-                               stacked=hasattr(model, "decoder"))
+                               rank=rank, dtype=slab_dtype)
         arng = np.random.RandomState(42)
         tenants = [f"tenant{i}" for i in range(n_tenants)]
         for t in tenants:
@@ -625,12 +624,12 @@ def prefix_sweep(prefix_spec: str, n_requests: int = 24,
 def gate() -> int:
     import paddle_tpu as pt
     from paddle_tpu import serving
-    from paddle_tpu.models import GPTForPretraining, gpt_tiny
+    from paddle_tpu.models import GPTStackedForPretraining, gpt_tiny
     from paddle_tpu.serving import ServingEngine
 
     pt.seed(0)
     cfg = gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0)
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     rng = np.random.RandomState(1)
     lengths = [5, 18, 9, 26, 13, 7, 21, 11, 16, 6, 24, 8]
@@ -717,7 +716,7 @@ def _gate_speculative(pt, serving, model, prompts, new_toks, refs) -> int:
     import numpy as _np
 
     from paddle_tpu.serving import SpeculativeEngine
-    from paddle_tpu.serving.faults import random_schedule
+    from paddle_tpu.faults import random_schedule
 
     serving.reset_serve_trace_counts()
     eng = SpeculativeEngine(model, model, spec_k=3, num_slots=3,
@@ -845,7 +844,7 @@ def _gate_quantized(pt, serving, cfg, model, prompts, new_toks, refs) -> int:
     import math
 
     from paddle_tpu.analysis.cost_model import paged_pool_bytes
-    from paddle_tpu.models import GPTForPretraining
+    from paddle_tpu.models import GPTStackedForPretraining
     from paddle_tpu.serving import ServingEngine, SpeculativeEngine
 
     H, D, L, ps = cfg.num_heads, cfg.head_dim, cfg.num_layers, 16
@@ -927,9 +926,7 @@ def _gate_quantized(pt, serving, cfg, model, prompts, new_toks, refs) -> int:
             print(f"serving_gate: FAIL int8-KV leaked "
                   f"{eng.allocator.used_pages} pages")
             return 1
-        scales = ([eng.cache.k_scale, eng.cache.v_scale]
-                  if eng.cache.stacked
-                  else [*eng.cache.k_scale, *eng.cache.v_scale])
+        scales = [eng.cache.k_scale, eng.cache.v_scale]
         if not all(np.isfinite(np.asarray(s.numpy())).all()
                    for s in scales):
             print("serving_gate: FAIL int8-KV scale sidecars non-finite "
@@ -940,7 +937,7 @@ def _gate_quantized(pt, serving, cfg, model, prompts, new_toks, refs) -> int:
 
     # int8 KV + int8 weights: quantize_for_serving mutates the model in
     # place, so the weight scenario runs on its OWN copy
-    m8 = GPTForPretraining(cfg)
+    m8 = GPTStackedForPretraining(cfg)
     m8.set_state_dict(model.state_dict())
     m8.eval()
     serving.reset_serve_trace_counts()

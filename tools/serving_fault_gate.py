@@ -52,11 +52,11 @@ N_NEW = 4
 
 def _build():
     import paddle_tpu as pt
-    from paddle_tpu.models import GPTForPretraining, gpt_tiny
+    from paddle_tpu.models import GPTStackedForPretraining, gpt_tiny
 
     pt.seed(0)
     cfg = gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0)
-    m = GPTForPretraining(cfg)
+    m = GPTStackedForPretraining(cfg)
     m.eval()
     rng = np.random.RandomState(1)
     prompts = [rng.randint(0, cfg.vocab_size, (s,))

@@ -377,14 +377,14 @@ def main() -> int:
     # must finish token-for-token equal to single-shot generate() ---------
     def serving_faults():
         import paddle_tpu as pt
-        from paddle_tpu.models import GPTForPretraining, gpt_tiny
+        from paddle_tpu.models import GPTStackedForPretraining, gpt_tiny
         from paddle_tpu.serving import (
             FaultInjector, RequestState, ServingEngine,
         )
 
         pt.seed(0)
         cfg = gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0)
-        m = GPTForPretraining(cfg)
+        m = GPTStackedForPretraining(cfg)
         m.eval()
         srng = np.random.RandomState(5)
         prompts = [srng.randint(0, cfg.vocab_size, (s,))
@@ -423,7 +423,7 @@ def main() -> int:
     # (docs/serving.md "Sharded serving") ---------------------------------
     def sharded_serving():
         import paddle_tpu as pt
-        from paddle_tpu.models import GPTForPretraining, gpt_tiny
+        from paddle_tpu.models import GPTStackedForPretraining, gpt_tiny
         from paddle_tpu.serving import ServingEngine, ShardedServingEngine
 
         n_dev = len(jax.devices())
@@ -434,7 +434,7 @@ def main() -> int:
         dp, mp = (2, 2) if n_dev >= 4 else (1, 2)
         pt.seed(0)
         cfg = gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0)
-        m = GPTForPretraining(cfg)
+        m = GPTStackedForPretraining(cfg)
         m.eval()
         srng = np.random.RandomState(11)
         prompts = [srng.randint(0, cfg.vocab_size, (s,))
@@ -477,12 +477,12 @@ def main() -> int:
     def speculative_serving():
         import paddle_tpu as pt
         from paddle_tpu import serving
-        from paddle_tpu.models import GPTForPretraining, gpt_tiny
+        from paddle_tpu.models import GPTStackedForPretraining, gpt_tiny
         from paddle_tpu.serving import ServingEngine, SpeculativeEngine
 
         pt.seed(0)
         cfg = gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0)
-        m = GPTForPretraining(cfg)
+        m = GPTStackedForPretraining(cfg)
         m.eval()
         srng = np.random.RandomState(13)
         prompts = [srng.randint(0, cfg.vocab_size, (s,))
@@ -524,7 +524,7 @@ def main() -> int:
     # cache") --------------------------------------------------------------
     def prefix_cache():
         import paddle_tpu as pt
-        from paddle_tpu.models import GPTForPretraining, gpt_tiny
+        from paddle_tpu.models import GPTStackedForPretraining, gpt_tiny
         from paddle_tpu.serving import RequestState, ServingEngine
 
         pt.seed(0)
@@ -532,7 +532,7 @@ def main() -> int:
         # page (the TPU-native page size) and still leave decode room
         cfg = gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0,
                        max_position_embeddings=256)
-        m = GPTForPretraining(cfg)
+        m = GPTStackedForPretraining(cfg)
         m.eval()
         srng = np.random.RandomState(17)
         sys_prompt = srng.randint(0, cfg.vocab_size, (128,))  # 1 full page
@@ -637,13 +637,13 @@ def main() -> int:
         import tempfile
 
         import paddle_tpu as pt
-        from paddle_tpu.models import GPTForPretraining, gpt_tiny
+        from paddle_tpu.models import GPTStackedForPretraining, gpt_tiny
         from paddle_tpu.serving import ServingEngine
         from paddle_tpu.telemetry import trace
 
         pt.seed(0)
         cfg = gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0)
-        m = GPTForPretraining(cfg)
+        m = GPTStackedForPretraining(cfg)
         m.eval()
         trng = np.random.RandomState(9)
         eng = ServingEngine(m, num_slots=2, page_size=128, max_context=128,
@@ -703,7 +703,7 @@ def main() -> int:
     # (docs/serving.md "Elasticity & degradation ladder") ----------------
     def elastic_serving():
         import paddle_tpu as pt
-        from paddle_tpu.models import GPTForPretraining, gpt_tiny
+        from paddle_tpu.models import GPTStackedForPretraining, gpt_tiny
         from paddle_tpu.serving import (
             ElasticConfig, ElasticServingController, ScaleDown, ScaleUp,
             ShardedServingEngine, SLOTargets,
@@ -714,7 +714,7 @@ def main() -> int:
             return
         pt.seed(0)
         cfg = gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0)
-        m = GPTForPretraining(cfg)
+        m = GPTStackedForPretraining(cfg)
         m.eval()
         erng = np.random.RandomState(5)
         prompts = [erng.randint(0, cfg.vocab_size, (s,))
@@ -790,7 +790,7 @@ def main() -> int:
     # and both pools' ledgers must drain to zero ------------------------
     def disagg_serving():
         import paddle_tpu as pt
-        from paddle_tpu.models import GPTForPretraining, gpt_tiny
+        from paddle_tpu.models import GPTStackedForPretraining, gpt_tiny
         from paddle_tpu.serving import DisaggServingEngine
 
         n_dev = len(jax.devices())
@@ -799,7 +799,7 @@ def main() -> int:
             return
         pt.seed(0)
         cfg = gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0)
-        m = GPTForPretraining(cfg)
+        m = GPTStackedForPretraining(cfg)
         m.eval()
         drng = np.random.RandomState(13)
         prompts = [drng.randint(0, cfg.vocab_size, (s,))
