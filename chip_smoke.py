@@ -273,11 +273,18 @@ def serve_phase(model, *, num_slots, page_size, max_context, prompt_lens,
             found = mosaic_kernels(rep.lowered_texts())
             assert RAGGED_KERNEL in found, \
                 f"replica {i}: ragged Pallas kernel missing: {found}"
+        # a work item moves all the replica's local heads of its page:
+        # one grid step an item (ops/pallas_kernels/ragged_paged_attention)
+        heads_an_item = m["ragged_heads_per_block"]
+        assert heads_an_item == model.config.num_heads // mp, \
+            (i, heads_an_item)
+        assert m["launched_grid_steps"] == m["launched_items"] > 0, i
         steps += m["fused_steps"]
         hits += m["prefix_hits"] + m["prefix_partial_hits"]
     say(f"serve dp={dp} mp={mp} slots={num_slots} page={page_size} "
         f"ctx={max_context}: {n_requests} requests x {new_tokens} tokens "
         f"DONE in {steps} fused steps, {hits} prefix hits, "
+        f"{heads_an_item} heads a work item, "
         f"<= 2 programs a replica; first tick (compile) {t_first:.1f}s, "
         f"all {t_all:.1f}s")
     no_fallback_noted()

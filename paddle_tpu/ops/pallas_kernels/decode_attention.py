@@ -62,14 +62,15 @@ def decode_shape_supported(max_seq: int, head_dim: int) -> bool:
     return reason is None
 
 
-def _dot(a, b, dims):
+def _dot(a, b, dims, batch=((), ())):
     """MXU dot, fp32 accumulation; same precision discipline as the flash
     kernel's _dot (HIGHEST only when both operands are fp32 — under
-    "highest" Mosaic rejects bf16 operands)."""
+    "highest" Mosaic rejects bf16 operands).  ``batch``: the operands'
+    batch dimensions (the ragged kernel's leading head axis)."""
     fp32 = (jnp.dtype(a.dtype) == jnp.float32
             and jnp.dtype(b.dtype) == jnp.float32)
     return jax.lax.dot_general(
-        a, b, (dims, ((), ())),
+        a, b, (dims, batch),
         precision=(jax.lax.Precision.HIGHEST if fp32
                    else jax.lax.Precision.DEFAULT),
         preferred_element_type=jnp.float32)

@@ -25,6 +25,15 @@ into token granularity:
   traced scalar), so one compiled program walks exactly its work list and
   no grid step is spent on the arrays' tail (which repeats the last real
   entry only so that every index it holds stays valid);
+- a work item carries ``hb`` heads of its page: the grid is
+  ``(H // hb, n_items)`` and a grid step moves ONE ``(1, hb, page, D)``
+  block of K and one of V (the heads of a page are contiguous in the
+  ``[P, H, page, D]`` pool) against ``(1, hb, QB, D)`` of queries, the
+  heads one batched chain under one mask.  ``hb`` follows the launch's own
+  shapes (:func:`ragged_head_block`: the largest divisor of the local
+  ``H`` whose double-buffered K and V blocks hold a fixed share of the
+  scoped VMEM); at every served geometry it is ``H``: one grid step, and
+  one DMA a side, an item;
 - online softmax accumulates across a block's work items (running max m,
   denominator l, fp32 acc); per-item masking is causal at token
   granularity: row i of block b (absolute position ``blk_base[b] + i``)
@@ -60,6 +69,7 @@ __all__ = [
     "ragged_shape_supported",
     "ragged_shape_unsupported_reason",
     "ragged_token_block",
+    "ragged_head_block",
     "build_ragged_plan",
     "RAGGED_PLAN_FIELDS",
 ]
@@ -115,7 +125,7 @@ def ragged_token_block(page_size: int, head_dim: int, dtype,
 
     ``local_heads``: the POST-SHARD head count when the pool is sharded
     per-head over ``mp`` (docs/serving.md "Sharded serving").  It joins
-    the shape key — the sharded launch's grid is ``(H/mp, n_items)``, a
+    the shape key — the sharded launch moves ``H/mp`` heads an item, a
     different specialization than the full-head pool, so a winner
     measured unsharded must not silently dispatch a shard and vice
     versa.  Unsharded lookups keep the historical key (committed table
@@ -131,6 +141,32 @@ def ragged_token_block(page_size: int, head_dim: int, dtype,
         if tb >= 8 and tb % 8 == 0:
             return tb
     return 8
+
+
+# the scoped VMEM a TPU kernel compiles with (v5e: 16 MiB) and the share of
+# it the K and V blocks of a grid step, double-buffered, may take: the rest
+# is the query/output blocks, the softmax scratch and the body's temporaries
+_SCOPED_VMEM_BYTES = 16 << 20
+_KV_BUFFER_SHARE = 0.5
+
+
+def ragged_head_block(num_heads: int, page_size: int, head_dim: int,
+                      dtype) -> int:
+    """How many heads of its page one work item moves a grid step (``hb``):
+    the largest divisor of ``num_heads`` — the LOCAL head count the launch
+    sees, ``H/mp`` under ``shard_map`` — whose K and V blocks
+    ``(1, hb, page_size, head_dim)`` of the pool's ``dtype``, two buffers
+    each, stay within ``_KV_BUFFER_SHARE`` of the scoped VMEM.  A pure
+    function of the launch's own shapes: the kernel and the engine's
+    ``ragged_heads_per_block`` gauge both ask it, nothing is configured.
+    16 heads of a 128 x 128 bf16 page are 2 MiB of buffers, 40 are 5 MiB:
+    every served geometry moves all its heads, one contiguous DMA of K and
+    one of V an item."""
+    num_heads = int(num_heads)
+    per_head = 4 * int(page_size) * int(head_dim) * jnp.dtype(dtype).itemsize
+    fit = max(int(_SCOPED_VMEM_BYTES * _KV_BUFFER_SHARE) // per_head, 1)
+    return max(hb for hb in range(1, num_heads + 1)
+               if num_heads % hb == 0 and hb <= fit)
 
 
 # ---------------------------------------------------------------------------
@@ -242,8 +278,8 @@ def build_ragged_plan(runs: Sequence[Tuple[int, int, np.ndarray]], *,
 def _ragged_kernel(blk_ref, page_ref, ps_ref, ni_ref, base_ref, rows_ref,
                    q_ref, k_ref, v_ref, *rest, scale, page_size, wl_max,
                    quantized=False):
-    # quantized pools carry two extra (1, 1) scale inputs whose index map
-    # mirrors the KV page index — each page's per-head absmax scale rides
+    # quantized pools carry two extra (1, hb) scale inputs whose index map
+    # mirrors the KV page index — each page's per-head absmax scales ride
     # the same scalar-prefetched translation, so the dequant multiply
     # happens right after the page DMA with no extra HBM round-trip
     if quantized:
@@ -268,71 +304,96 @@ def _ragged_kernel(blk_ref, page_ref, ps_ref, ni_ref, base_ref, rows_ref,
         m_sc[...] = jnp.full_like(m_sc, NEG_INF)
         l_sc[...] = jnp.zeros_like(l_sc)
 
-    q = q_ref[0, 0]                             # [QB, D]
+    qb = q_ref.shape[2]
+    # the mask is the item's, not a head's: computed once a step.
+    # Token-granular causality: row i sits at absolute position
+    # blk_base + i and may read every pool position <= its own; rows
+    # past blk_rows are block padding (masked everywhere — their
+    # output rows are finite garbage the host gather never reads)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (qb, page_size), 0)
+    cols = ps_ref[w] * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, (qb, page_size), 1)
+    row_pos = base_ref[blk] + rows
+    valid = jnp.logical_and(cols <= row_pos, rows < rows_ref[blk])
+
+    # the item's hb heads as ONE batched chain: each head's operations are
+    # those a grid step ran while a step moved one head, in the same order
+    # (the same bits), and the heads share nothing but the mask, so the
+    # scheduler has hb independent chains to interleave under the next
+    # item's DMA.  Batched, not a loop over k_ref[0, h] that updates the
+    # scratch a head: on a v5e the loop's stores order the heads and an
+    # item costs its arithmetic (2.3 us at 16 heads), where the batched
+    # chain costs its DMA (1.4 us; PERF.md section 6, PR 32)
+    q = q_ref[0]                                # [hb, QB, D]
     if quantized:
         # in-kernel dequant: int8 page x its (page, head) scale ->
         # fp32 operands (q arrives fp32 on this path; the online-
         # softmax accumulation below is fp32 regardless)
-        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]
-        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
+        k = k_ref[0].astype(jnp.float32) * ks_ref[0][:, None, None]
+        v = v_ref[0].astype(jnp.float32) * vs_ref[0][:, None, None]
     else:
-        k = k_ref[0, 0]                         # [page_size, D]
-        v = v_ref[0, 0]
-    s = _dot(q, k, ((1,), (1,))) * np.float32(scale)   # [QB, page_size]
-    rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    cols = ps_ref[w] * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 1)
-    # token-granular causality: row i sits at absolute position
-    # blk_base + i and may read every pool position <= its own; rows
-    # past blk_rows are block padding (masked everywhere — their
-    # output rows are finite garbage the host gather never reads)
-    row_pos = base_ref[blk] + rows
-    valid = jnp.logical_and(cols <= row_pos, rows < rows_ref[blk])
-    s = jnp.where(valid, s, NEG_INF)
+        k = k_ref[0]                            # [hb, page_size, D]
+        v = v_ref[0]
+    heads = ((0,), (0,))
+    s = _dot(q, k, ((2,), (2,)), heads) * np.float32(scale)
+    s = jnp.where(valid[None], s, NEG_INF)      # [hb, QB, page_size]
 
-    m_prev = m_sc[:, :1]                        # [QB, 1]
-    l_prev = l_sc[:, :1]
+    m_prev = m_sc[:, :, :1]                     # [hb, QB, 1]
+    l_prev = l_sc[:, :, :1]
     m_cur = jnp.max(s, axis=-1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
     p = jnp.exp(s - m_new)
     l_cur = jnp.sum(p, axis=-1, keepdims=True)
     alpha = jnp.exp(m_prev - m_new)
     acc_sc[...] = acc_sc[...] * alpha + _dot(p.astype(v.dtype), v,
-                                             ((1,), (0,)))
+                                             ((2,), (1,)), heads)
     m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
     l_sc[...] = jnp.broadcast_to(alpha * l_prev + l_cur, l_sc.shape)
 
     @pl.when(last)
     def _finish():
-        l = l_sc[:, :1]
+        l = l_sc[:, :, :1]
         l_safe = jnp.where(l == 0.0, np.float32(1.0), l)
-        o_ref[0, 0] = (acc_sc[...] / l_safe).astype(o_ref.dtype)
+        o_ref[0] = (acc_sc[...] / l_safe).astype(o_ref.dtype)
 
 
 def _ragged_pallas(q_blocks, k_pool, v_pool, wl_blk, wl_page, wl_ps,
                    n_items, blk_base, blk_rows, scale, interpret=False,
-                   k_scale=None, v_scale=None):
+                   k_scale=None, v_scale=None, head_block=None):
     """q_blocks: [NB, H, QB, D] host-packed token blocks; k/v pool:
     [P, H, page_size, D]; work-list + per-block arrays as documented on
     :data:`RAGGED_PLAN_FIELDS` -> [NB, H, QB, D].  ``interpret=True`` runs
     the Pallas interpreter (CPU numerics check).
 
-    The grid is ``(H, n_items)`` — heads parallel, work items sequential
-    so a block's online softmax accumulates across its pages — with
-    ``n_items`` the traced item count: the arrays keep their constant
+    The grid is ``(H // hb, n_items)`` — head blocks parallel, work items
+    sequential so a block's online softmax accumulates across its pages —
+    with ``hb`` = :func:`ragged_head_block` of the operands' own shapes
+    (``H`` at every geometry a cell runs: the grid is ``(1, n_items)``)
+    and ``n_items`` the traced item count: the arrays keep their constant
     ``wl_max`` length (one compile, no retrace), the launch is as long as
-    the step's work list.  All plan arrays ride as scalar prefetch: the
-    KV index map reads the work item's POOL page id (pre-translated on
-    host) before each DMA, the q/out index maps its block.  Consecutive
-    items of one block repeat the q/out block index (copies elided)."""
+    the step's work list.  A grid step moves ``hb`` heads of its item's
+    page — ONE ``(1, hb, page_size, D)`` block of K and one of V, which
+    are contiguous in the pool — against ``(1, hb, QB, D)`` of queries.
+    All plan arrays ride as scalar prefetch: the KV index map reads the
+    work item's POOL page id (pre-translated on host) before each DMA,
+    the q/out index maps its block.  Consecutive items of one block
+    repeat the q/out block index (copies elided).
+
+    ``head_block`` overrides ``hb`` (a divisor of ``H``) so that a test
+    can walk ``H // hb > 1`` at small shapes; nothing else passes it."""
     nb, h, qb, d = q_blocks.shape
     page_size = k_pool.shape[2]
     wl_max = wl_blk.shape[0]
     quantized = k_scale is not None
+    hb = (ragged_head_block(h, page_size, d, k_pool.dtype)
+          if head_block is None else int(head_block))
+    if hb < 1 or h % hb:
+        raise ValueError(f"head_block={hb} must divide num_heads={h}")
     kernel = functools.partial(_ragged_kernel, scale=scale,
                                page_size=page_size, wl_max=wl_max,
                                quantized=quantized)
 
+    # hh: the grid step's head BLOCK (heads hh*hb .. hh*hb + hb - 1)
     def q_index(hh, w, blk_ref, page_ref, ps_ref, ni_ref, base_ref,
                 rows_ref):
         return (blk_ref[w], hh, np.int32(0), np.int32(0))
@@ -346,25 +407,25 @@ def _ragged_pallas(q_blocks, k_pool, v_pool, wl_blk, wl_page, wl_ps,
         return (page_ref[w], hh)
 
     in_specs = [
-        pl.BlockSpec((1, 1, qb, d), q_index),
-        pl.BlockSpec((1, 1, page_size, d), kv_index),
-        pl.BlockSpec((1, 1, page_size, d), kv_index),
+        pl.BlockSpec((1, hb, qb, d), q_index),
+        pl.BlockSpec((1, hb, page_size, d), kv_index),
+        pl.BlockSpec((1, hb, page_size, d), kv_index),
     ]
     operands = [q_blocks, k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1), scale_index),
-                     pl.BlockSpec((1, 1), scale_index)]
+        in_specs += [pl.BlockSpec((1, hb), scale_index),
+                     pl.BlockSpec((1, hb), scale_index)]
         operands += [k_scale, v_scale]
     n_items = jnp.reshape(n_items, (1,)).astype(jnp.int32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
-        grid=(h, n_items[0]),
+        grid=(h // hb, n_items[0]),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, qb, d), q_index),
+        out_specs=pl.BlockSpec((1, hb, qb, d), q_index),
         scratch_shapes=[
-            pltpu.VMEM((qb, d), jnp.float32),
-            pltpu.VMEM((qb, 128), jnp.float32),
-            pltpu.VMEM((qb, 128), jnp.float32),
+            pltpu.VMEM((hb, qb, d), jnp.float32),
+            pltpu.VMEM((hb, qb, 128), jnp.float32),
+            pltpu.VMEM((hb, qb, 128), jnp.float32),
         ],
     )
     out = pl.pallas_call(

@@ -91,7 +91,8 @@ from ..ops import dispatch
 from ..telemetry import metrics as _tmetrics
 from ..telemetry import trace as _ttrace
 from ..ops.pallas_kernels.ragged_paged_attention import (
-    RAGGED_PLAN_FIELDS, build_ragged_plan, ragged_token_block,
+    RAGGED_PLAN_FIELDS, build_ragged_plan, ragged_head_block,
+    ragged_token_block,
 )
 from ..tensor import Tensor, to_tensor
 from .admission import AdmissionScheduler, StepWork
@@ -657,10 +658,16 @@ class ServingEngine:
         # specialization (ops/pallas_kernels/ragged_paged_attention.py) —
         # keyed on the LOCAL (post-shard) head count under mp sharding
         self.head_dim = int(cfg.head_dim)
+        local_heads = cfg.num_heads // self._mp
         self.token_block = ragged_token_block(
             self.page_size, cfg.head_dim, self.cache_dtype,
-            local_heads=(cfg.num_heads // self._mp if self._mp > 1
-                         else None))
+            local_heads=local_heads if self._mp > 1 else None)
+        # what the ragged launch makes of this geometry (it asks the same
+        # function of the same shapes): the heads of a page one work item
+        # moves a grid step, and so the grid steps an item costs a chip
+        self.ragged_heads_per_block = ragged_head_block(
+            local_heads, self.page_size, cfg.head_dim, self.cache_dtype)
+        self._grid_steps_per_item = local_heads // self.ragged_heads_per_block
         # sampling RNG: the global generator single-chip (bit-compat with
         # generate()); a PRIVATE stream per mesh-sharded engine — the
         # donated key state commits to the replica mesh, and one shared
@@ -768,7 +775,7 @@ class ServingEngine:
                         # numerators/denominators (see metrics())
                         "fused_steps": 0, "prefill_tokens": 0,
                         "work_items": 0, "work_capacity": 0,
-                        "launched_items": 0,
+                        "launched_items": 0, "launched_grid_steps": 0,
                         "block_rows": 0, "block_row_capacity": 0,
                         # host-packing padding cost in GL002's units
                         # (analysis/cost_model.ragged_padding_waste): block
@@ -1285,6 +1292,8 @@ class ServingEngine:
         self._totals["work_items"] += stats["n_items"]
         self._totals["work_capacity"] += stats["wl_capacity"]
         self._totals["launched_items"] += stats["launched_items"]
+        self._totals["launched_grid_steps"] += (
+            stats["launched_items"] * self._grid_steps_per_item)
         self._totals["block_rows"] += stats["n_tokens"]
         self._totals["block_row_capacity"] += stats["row_capacity"]
         waste = ragged_padding_waste(
@@ -1864,7 +1873,11 @@ class ServingEngine:
         items (``mean_launch_occupancy``: 1.0 while the launch ends at
         ``n_items``) and how many of the packed query-block rows carried
         real tokens (``mean_q_row_occupancy``) across every dispatched
-        step."""
+        step.  ``ragged_heads_per_block`` is the ``hb`` of this engine's
+        geometry (the heads of a page a work item moves a grid step) and
+        ``launched_grid_steps`` the grid steps a chip's launches walked:
+        ``launched_items x local heads // hb``, equal to
+        ``launched_items`` wherever an item moves all its heads."""
         out = dict(self._totals)
         out.update(self._last_metrics)
         out["queue_depth"] = self.queue.depth
@@ -1888,6 +1901,7 @@ class ServingEngine:
         li = self._totals["launched_items"]
         out["mean_launch_occupancy"] = (self._totals["work_items"] / li
                                         if li else 0.0)
+        out["ragged_heads_per_block"] = self.ragged_heads_per_block
         # per-request SLO digests (seconds): count/sum/mean/min/max +
         # p50/p95/p99 per histogram — TTFT, inter-token latency, queue
         # wait, end-to-end (docs/observability.md "SLO definitions")

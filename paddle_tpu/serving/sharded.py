@@ -430,6 +430,7 @@ class ShardedServingEngine:
                     "rebuilds", "pages_used", "pages_capacity",
                     "active_slots", "queue_depth", "cache_bytes",
                     "work_items", "work_capacity", "launched_items",
+                    "launched_grid_steps",
                     "block_rows",
                     "block_row_capacity", "padded_rows", "padded_flops",
                     # per-replica prefix caches (docs/serving.md "Prefix
@@ -460,6 +461,9 @@ class ShardedServingEngine:
         out["slot_capacity"] = sum(e.num_slots for e in self.replicas)
         out["cache_bytes_per_chip"] = (per[0]["cache_bytes_per_chip"]
                                        if per else 0)
+        # a gauge of the replicas' one geometry, not a sum
+        out["ragged_heads_per_block"] = (per[0]["ragged_heads_per_block"]
+                                         if per else 0)
         out["routed"] = list(self.placement.routed)
         # elastic lifecycle observability (PR 19)
         out["replica_states"] = self.replica_states()

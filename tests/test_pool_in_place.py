@@ -11,7 +11,9 @@ is lowered on the CPU.
 The same compiled step holds what PR 30 made of its ragged launch: the
 kernel's grid ends at the step's item count (docs/serving.md "Fused mixed
 step"), which the Mosaic module inside the ``tpu_custom_call`` states as a
-dynamic iteration bound."""
+dynamic iteration bound, and what PR 32 made of it: a grid step moves a
+block of heads of its item's page, all of them at every served geometry
+(the sharded ones, which no cell runs yet, are compiled here too)."""
 import base64
 import os
 import re
@@ -193,13 +195,58 @@ def test_the_reader_of_iteration_bounds_reads_a_static_and_a_dynamic_launch():
 
 def test_the_compiled_steps_ragged_launch_ends_at_the_item_count(compiled_step):
     """The step's one Mosaic call (``_ragged_kernel``, the only kernel the
-    cell names) runs a grid of the model's heads by a DYNAMIC second
+    cell names) runs a grid of the model's head BLOCKS by a DYNAMIC second
     dimension: the day the launch is again as long as ``wl_max`` (48 x 8 =
-    384 in this cell) that reads 384."""
+    384 in this cell) that reads 384.  A work item moves all sixteen heads
+    of its page in this cell, so the grid is one head block wide."""
+    from paddle_tpu.ops.pallas_kernels import ragged_paged_attention as ra
+
     ctx, compiled = compiled_step
     assert ctx["cell"]["mosaic_kernels"] == ["_ragged_kernel"]
-    heads = ctx["config"]["model"]["num_heads"]
-    assert mosaic_iteration_bounds(compiled.as_text()) == [[heads, _DYNAMIC]]
+    model, eng = ctx["config"]["model"], ctx["cell"]["engine"]
+    heads = model["num_heads"]
+    hb = ra.ragged_head_block(heads, eng["page_size"], model["hidden_size"] // heads,
+                              eng["cache_dtype"])
+    bounds = mosaic_iteration_bounds(compiled.as_text())
+    assert bounds == [[heads // hb, _DYNAMIC]]
+    assert bounds == [[1, _DYNAMIC]]
+
+
+@pytest.mark.parametrize("local_heads", [8, 20])
+def test_the_ragged_kernel_compiles_at_the_sharded_local_heads(local_heads, topo):
+    """What ``shard_map`` over ``mp`` 2 hands the kernel and no cell runs
+    yet: 8 local heads (``gpt_1p3b``) and 20 (``gpt_13b_cut``'s 40), at the
+    chat cell's pool and step geometry.  Each compiles for the described
+    v5e and moves all its local heads an item: grid ``(1, n_items)``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas_kernels import ragged_paged_attention as ra
+
+    ctx = manifest.resolve_cell(CELL)
+    model, eng = ctx["config"]["model"], ctx["cell"]["engine"]
+    page, dim = eng["page_size"], model["hidden_size"] // model["num_heads"]
+    qb = ra.ragged_token_block(page, dim, eng["cache_dtype"], local_heads=local_heads)
+    nb = eng["num_slots"] + eng["prefill_token_budget"] // qb
+    wl = nb * (eng["max_context"] // page)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = struct((PAGES, local_heads, page, dim), jnp.bfloat16)
+    i32 = jnp.int32
+    compiled = jax.jit(
+        lambda q, k, v, wb, wp, ws, n, bb, br: ra._ragged_pallas(
+            q, k, v, wb, wp, ws, n, bb, br, dim ** -0.5)
+    ).lower(struct((nb, local_heads, qb, dim), jnp.bfloat16), pool, pool,
+            struct((wl,), i32), struct((wl,), i32), struct((wl,), i32),
+            struct((1,), i32), struct((nb,), i32), struct((nb,), i32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert ra.ragged_head_block(local_heads, page, dim, jnp.bfloat16) == local_heads
+    assert mosaic_iteration_bounds(text) == [[1, _DYNAMIC]]
 
 
 def test_the_lowered_loop_carries_the_pools():

@@ -336,6 +336,24 @@ _LAUNCH_RUNS = {
 _LAUNCH_POOLS = {"float32": 5e-6, "bfloat16": 2e-2, "int8": 5e-6}
 
 
+def _launch_operands(pool, rng, t_max, pages, heads, page_size, dim):
+    """Queries, K and V pools and (for the int8 pool) the per-(page, head)
+    scales of one launch case: ``(q, k_pool, v_pool, scales)``."""
+    import jax.numpy as jnp
+
+    pool_shape = (pages, heads, page_size, dim)
+    if pool == "int8":
+        q = jnp.array(rng.randn(t_max, heads, dim), jnp.float32)
+        kp, vp = (jnp.array(rng.randint(-127, 128, pool_shape), jnp.int8)
+                  for _ in range(2))
+        ks, vs = (jnp.array(rng.uniform(0.005, 0.02, (pages, heads)),
+                            jnp.float32) for _ in range(2))
+        return q, kp, vp, dict(k_scale=ks, v_scale=vs)
+    q, kp, vp = (jnp.array(rng.randn(*shape), pool)
+                 for shape in ((t_max, heads, dim), pool_shape, pool_shape))
+    return q, kp, vp, {}
+
+
 @pytest.fixture(scope="module")
 def launch_fns():
     """One jitted ragged attention a pool dtype, shared by that dtype's
@@ -372,17 +390,7 @@ def test_ragged_launch_follows_the_work_list(pool, n_items, launch_fns,
         _LAUNCH_RUNS[n_items], T_MAX, NB_MAX, WL_MAX, MP)
     assert stats["n_items"] == stats["launched_items"] == n_items
     real = stats["n_tokens"]
-    if pool == "int8":
-        q = jnp.array(rng.randn(T_MAX, H, D), jnp.float32)
-        kp, vp = (jnp.array(rng.randint(-127, 128, (P, H, PS, D)), jnp.int8)
-                  for _ in range(2))
-        ks, vs = (jnp.array(rng.uniform(0.005, 0.02, (P, H)), jnp.float32)
-                  for _ in range(2))
-        scales = dict(k_scale=ks, v_scale=vs)
-    else:
-        q, kp, vp = (jnp.array(rng.randn(*shape), pool)
-                     for shape in ((T_MAX, H, D),) + ((P, H, PS, D),) * 2)
-        scales = {}
+    q, kp, vp, scales = _launch_operands(pool, rng, T_MAX, P, H, PS, D)
     plan = tuple(jnp.array(plan_np[k]) for k in ra.RAGGED_PLAN_FIELDS)
     tables, lengths = jnp.array(tables), jnp.array(lengths)
 
@@ -400,6 +408,94 @@ def test_ragged_launch_follows_the_work_list(pool, n_items, launch_fns,
         q, kp, vp, tables, lengths, plan, sm_scale=0.125, interpret=True,
         **scales), np.float32)
     np.testing.assert_array_equal(got[:real], static[:real])
+
+
+_HEAD_BLOCKS = {"all_heads": 4, "some_heads": 2, "one_head": 1}
+
+
+@pytest.mark.parametrize("head_block", list(_HEAD_BLOCKS))
+@pytest.mark.parametrize("pool", list(_LAUNCH_POOLS))
+def test_ragged_work_item_carries_a_block_of_heads(pool, head_block,
+                                                   monkeypatch):
+    """A grid step moves ``hb`` heads of its item's page: ``hb = H`` (what
+    every served geometry gets: grid ``(1, n_items)``), ``1 < hb < H`` and
+    ``hb = 1`` (grid ``(H // hb, n_items)``, the scratch re-initialised a
+    head block) on the mixed step, each equal to the gather oracle within
+    the pool's tolerance and BITWISE the launch that moves all heads."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_kernels import ragged_paged_attention as ra
+
+    hb = _HEAD_BLOCKS[head_block]
+    rng = np.random.RandomState(4)
+    P, H, PS, D, MP = 11, 4, 128, 64, 4
+    T_MAX, NB_MAX, WL_MAX = 32, 8, 32
+    plan_np, stats, tables, lengths = _mk_ragged_case(
+        _LAUNCH_RUNS[7], T_MAX, NB_MAX, WL_MAX, MP)
+    real = stats["n_tokens"]
+    q, kp, vp, scales = _launch_operands(pool, rng, T_MAX, P, H, PS, D)
+    assert ra.ragged_head_block(H, PS, D, kp.dtype) == H
+    plan = tuple(jnp.array(plan_np[k]) for k in ra.RAGGED_PLAN_FIELDS)
+    tables, lengths = jnp.array(tables), jnp.array(lengths)
+
+    def run():
+        return np.asarray(ra.ragged_paged_attention(
+            q, kp, vp, tables, lengths, plan, sm_scale=0.125, interpret=True,
+            **scales), np.float32)
+
+    whole = run()
+    launch = ra._ragged_pallas
+    seen = []
+
+    def with_head_block(*args, **kwargs):
+        seen.append(hb)
+        return launch(*args, head_block=hb, **kwargs)
+
+    monkeypatch.setattr(ra, "_ragged_pallas", with_head_block)
+    got = run()
+    assert seen == [hb]
+    ref = np.asarray(ra._xla_ragged_reference(
+        q, kp, vp, tables, lengths, 0.125, **scales), np.float32)
+    np.testing.assert_allclose(got[:real], ref[:real],
+                               rtol=_LAUNCH_POOLS[pool],
+                               atol=_LAUNCH_POOLS[pool])
+    np.testing.assert_array_equal(got[:real], whole[:real])
+    with pytest.raises(ValueError, match="must divide"):
+        launch(jnp.zeros((NB_MAX, H, 8, D), kp.dtype), kp, vp, *plan[5:8],
+               plan[8], plan[3], plan[4], 0.125, interpret=True, head_block=3)
+
+
+def test_ragged_head_block_follows_the_launchs_shapes():
+    """``hb`` is a pure function of the local head count, the page, the head
+    size and the pool's item size: it divides the heads, its K and V blocks,
+    double-buffered, hold the budget, every served geometry (the cells' 16
+    heads; 8, 20 and 40 local heads under ``mp``) moves all its heads, and a
+    shape past the budget moves fewer."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_kernels import ragged_paged_attention as ra
+
+    budget = ra._SCOPED_VMEM_BYTES * ra._KV_BUFFER_SHARE
+    for heads in (16, 8, 20, 40):
+        assert ra.ragged_head_block(heads, 128, 128, jnp.bfloat16) == heads
+    assert ra.ragged_head_block(16, 128, 128, "bfloat16") == 16   # by name too
+    assert 4 * 16 * 128 * 128 * 2 == 2 << 20                      # the cells: 2 MiB
+    for heads, page, dim, dtype in ((40, 256, 128, jnp.float32),
+                                    (16, 512, 256, jnp.bfloat16),
+                                    (12, 1024, 128, jnp.float32),
+                                    (7, 2048, 256, jnp.float32),
+                                    (16, 128, 128, jnp.int8)):
+        hb = ra.ragged_head_block(heads, page, dim, dtype)
+        per_head = 4 * page * dim * jnp.dtype(dtype).itemsize
+        assert heads % hb == 0 and hb >= 1
+        assert hb * per_head <= budget or hb == 1
+        # no larger divisor fits
+        assert all(heads % more or more * per_head > budget
+                   for more in range(hb + 1, heads + 1))
+    assert ra.ragged_head_block(40, 256, 128, jnp.float32) == 10
+    assert ra.ragged_head_block(16, 512, 256, jnp.bfloat16) == 8
+    assert ra.ragged_head_block(7, 2048, 256, jnp.float32) == 1
+    assert ra.ragged_head_block(16, 128, 128, jnp.int8) == 16
 
 
 def test_ragged_reference_zero_length_and_decode_equivalence():
@@ -836,6 +932,10 @@ def test_invocation_counters_exact():
         assert mets["launched_items"] == mets["work_items"] > 0
         assert mets["launched_items"] < mets["work_capacity"]
         assert mets["mean_launch_occupancy"] == 1.0
+        # a work item moves every head of its page (the tiny geometry is
+        # far inside the kernel's VMEM budget): a grid step an item
+        assert mets["ragged_heads_per_block"] == cfg.num_heads
+        assert mets["launched_grid_steps"] == mets["launched_items"]
         # host-packing padding cost (cost_model.ragged_padding_waste):
         # a decode token fills 1 of token_block rows, so a decode-heavy
         # run must report padded rows and the matching padded-away flops
