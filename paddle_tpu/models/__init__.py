@@ -18,6 +18,9 @@ from .gpt import (  # noqa: F401
     gpt_13b,
     truncated_draft,
 )
+from .lfm2 import (  # noqa: F401
+    Lfm2Config, Lfm2StackedForCausalLM, lfm2_tiny,
+)
 from .ernie_moe import (  # noqa: F401
     ErnieMoEConfig, ErnieMoEForPretraining, ErnieMoEModel, ernie_moe_tiny,
 )

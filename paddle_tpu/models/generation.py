@@ -77,13 +77,16 @@ class _KVBuffers:
         keep the Tensors alive anyway; jax's ``Array.delete()`` frees the
         buffers eagerly.  The cache is unusable afterwards."""
         for t in self._tensors():
-            v = t._value
-            delete = getattr(v, "delete", None)
-            if delete is not None:
-                try:
-                    delete()
-                except Exception:  # noqa: BLE001 — already deleted/donated
-                    pass
+            self._delete(t)
+
+    @staticmethod
+    def _delete(t: Tensor):
+        delete = getattr(t._value, "delete", None)
+        if delete is not None:
+            try:
+                delete()
+            except Exception:  # noqa: BLE001 — already deleted/donated
+                pass
 
 
 class KVCache(_KVBuffers):

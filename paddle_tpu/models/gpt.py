@@ -361,6 +361,8 @@ def _attend_paged_shard(qh, kh, vh, pkr, pvr, tables, posr, *, head_dim,
 
     qh/kh/vh: [S, N, C, D] head-major fresh projections (S decode slots —
     or, on the ragged fused-step path, S flat query TOKENS with C == 1);
+    kh/vh may carry fewer heads than qh (grouped queries, ragged path
+    only): N below is then THEIR count, the pool's;
     pkr/pvr: [P, N, page_size, D] global page pools; tables: [S, max_pages]
     int32 page tables (per-token rows on the ragged path); posr: [S]
     traced per-slot/per-token positions.  Returns
@@ -399,7 +401,8 @@ def _attend_paged_shard(qh, kh, vh, pkr, pvr, tables, posr, *, head_dim,
         ragged_paged_attention,
     )
 
-    s_, nh, c, d = qh.shape
+    s_, _, c, d = qh.shape
+    nh = kh.shape[1]            # the pool's head count is K's, not q's
     quantized = ksr is not None
     max_pages = tables.shape[1]
     scale = float(1.0 / np.sqrt(head_dim))

@@ -303,6 +303,10 @@ def _xla_paged_reference(q, k_pool, v_pool, page_tables, lengths, scale,
     length-0 slots return zeros (the kernel's inactive-slot semantics)."""
     k = gather_pages(k_pool, page_tables, k_scale)
     v = gather_pages(v_pool, page_tables, v_scale)
+    if q.shape[1] != k.shape[1]:
+        # grouped queries: pool head j serves query heads G*j .. G*j + G - 1
+        group = q.shape[1] // k.shape[1]
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     s = jnp.einsum("shd,shkd->shk", q, k,
                    preferred_element_type=jnp.float32) * np.float32(scale)
     lengths = lengths.astype(jnp.int32)

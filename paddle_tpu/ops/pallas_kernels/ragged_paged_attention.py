@@ -34,6 +34,12 @@ into token granularity:
   ``H`` whose double-buffered K and V blocks hold a fixed share of the
   scoped VMEM); at every served geometry it is ``H``: one grid step, and
   one DMA a side, an item;
+- a K/V head may serve a GROUP of query heads (grouped-query attention:
+  the pool holds ``Hkv`` heads, the queries ``Hkv * G``): the group's
+  heads are folded into the query block's rows, ``[NB, Hkv, G * QB, D]``,
+  so an item's K and V pages are still read once, whatever ``G``; a row's
+  token is ``row mod QB``.  With ``G == 1`` the launch, the kernel body and
+  the outputs are bit for bit those of a pool with as many heads as queries;
 - online softmax accumulates across a block's work items (running max m,
   denominator l, fp32 acc); per-item masking is causal at token
   granularity: row i of block b (absolute position ``blk_base[b] + i``)
@@ -277,7 +283,7 @@ def build_ragged_plan(runs: Sequence[Tuple[int, int, np.ndarray]], *,
 
 def _ragged_kernel(blk_ref, page_ref, ps_ref, ni_ref, base_ref, rows_ref,
                    q_ref, k_ref, v_ref, *rest, scale, page_size, wl_max,
-                   quantized=False):
+                   quantized=False, group=1):
     # quantized pools carry two extra (1, hb) scale inputs whose index map
     # mirrors the KV page index — each page's per-head absmax scales ride
     # the same scalar-prefetched translation, so the dequant multiply
@@ -309,8 +315,12 @@ def _ragged_kernel(blk_ref, page_ref, ps_ref, ni_ref, base_ref, rows_ref,
     # Token-granular causality: row i sits at absolute position
     # blk_base + i and may read every pool position <= its own; rows
     # past blk_rows are block padding (masked everywhere — their
-    # output rows are finite garbage the host gather never reads)
+    # output rows are finite garbage the host gather never reads).
+    # Under grouped queries the block's rows are the group's heads one
+    # after another, so a row's token is its index within its head's rows
     rows = jax.lax.broadcasted_iota(jnp.int32, (qb, page_size), 0)
+    if group > 1:
+        rows = jax.lax.rem(rows, jnp.full_like(rows, qb // group))
     cols = ps_ref[w] * page_size + jax.lax.broadcasted_iota(
         jnp.int32, (qb, page_size), 1)
     row_pos = base_ref[blk] + rows
@@ -359,11 +369,13 @@ def _ragged_kernel(blk_ref, page_ref, ps_ref, ni_ref, base_ref, rows_ref,
 
 def _ragged_pallas(q_blocks, k_pool, v_pool, wl_blk, wl_page, wl_ps,
                    n_items, blk_base, blk_rows, scale, interpret=False,
-                   k_scale=None, v_scale=None, head_block=None):
+                   k_scale=None, v_scale=None, head_block=None, group=1):
     """q_blocks: [NB, H, QB, D] host-packed token blocks; k/v pool:
     [P, H, page_size, D]; work-list + per-block arrays as documented on
     :data:`RAGGED_PLAN_FIELDS` -> [NB, H, QB, D].  ``interpret=True`` runs
-    the Pallas interpreter (CPU numerics check).
+    the Pallas interpreter (CPU numerics check).  ``group`` > 1: each of
+    the pool's ``H`` heads serves ``group`` query heads, folded into the
+    block's rows (``QB`` here is ``group`` x the token block).
 
     The grid is ``(H // hb, n_items)`` — head blocks parallel, work items
     sequential so a block's online softmax accumulates across its pages —
@@ -389,9 +401,13 @@ def _ragged_pallas(q_blocks, k_pool, v_pool, wl_blk, wl_page, wl_ps,
           if head_block is None else int(head_block))
     if hb < 1 or h % hb:
         raise ValueError(f"head_block={hb} must divide num_heads={h}")
+    if k_pool.shape[1] != h or qb % group:
+        raise ValueError(
+            f"query blocks {q_blocks.shape} do not fold group={group} "
+            f"query heads onto the pool's {k_pool.shape[1]} heads")
     kernel = functools.partial(_ragged_kernel, scale=scale,
                                page_size=page_size, wl_max=wl_max,
-                               quantized=quantized)
+                               quantized=quantized, group=int(group))
 
     # hh: the grid step's head BLOCK (heads hh*hb .. hh*hb + hb - 1)
     def q_index(hh, w, blk_ref, page_ref, ps_ref, ni_ref, base_ref,
@@ -454,8 +470,10 @@ def ragged_paged_attention(q, k_pool, v_pool, token_tables, lengths, plan,
     """Token-granular attention over the paged KV pool for one fused
     mixed prefill/decode step.
 
-    q:            [T, H, D]   — EVERY query token of the step, flat
-                  (decode tokens and prefill chunk tokens mixed)
+    q:            [T, Hq, D]  — EVERY query token of the step, flat
+                  (decode tokens and prefill chunk tokens mixed); ``Hq`` is
+                  the pool's ``H`` or a multiple ``G`` of it (pool head
+                  ``j`` then serves query heads ``G*j .. G*j + G - 1``)
     k_pool:       [P, H, page_size, D] — the global page pool
     v_pool:       [P, H, page_size, D]
     token_tables: [T, max_pages] int32 — each token's SLOT page-table row
@@ -468,12 +486,17 @@ def ragged_paged_attention(q, k_pool, v_pool, token_tables, lengths, plan,
                   pool is int8 (docs/serving.md "Quantized serving") —
                   dequant happens INSIDE the kernel right after each
                   page DMA; the output is then fp32
-    returns       [T, H, D]
+    returns       [T, Hq, D]
 
     Routes to the Pallas ragged kernel on TPU when the layout is eligible,
     else the XLA gather reference (identical numerics; also the CPU
     serving path)."""
     p_, h, page_size, d = k_pool.shape
+    hq = q.shape[1]
+    if hq % h:
+        raise ValueError(f"{hq} query heads over a pool of {h} heads: the "
+                         "pool's head count must divide the queries'")
+    group = hq // h
     scale = float(sm_scale if sm_scale is not None else 1.0 / (d ** 0.5))
     if k_scale is not None:
         # int8 pool: q joins the fp32 dequant epilogue, NOT the pool
@@ -490,12 +513,22 @@ def ragged_paged_attention(q, k_pool, v_pool, token_tables, lengths, plan,
     if use_kernel:
         nb = blk_tok.shape[0]
         qg = jnp.take(q, jnp.reshape(blk_tok, (-1,)), axis=0)
-        qg = jnp.transpose(qg.reshape(nb, qb, h, d), (0, 2, 1, 3))
+        if group == 1:
+            qg = jnp.transpose(qg.reshape(nb, qb, h, d), (0, 2, 1, 3))
+        else:       # [NB, H, G * QB, D]: a pool head's query heads as rows
+            qg = jnp.transpose(qg.reshape(nb, qb, h, group, d),
+                               (0, 2, 3, 1, 4)).reshape(nb, h, group * qb, d)
+        # one query head a pool head: the call the launch always was
+        grouped = {} if group == 1 else {"group": group}
         out = _ragged_pallas(qg, k_pool, v_pool, wl_blk, wl_page, wl_ps,
                              n_items, blk_base, blk_rows, scale,
                              interpret=interpret,
-                             k_scale=k_scale, v_scale=v_scale)
-        flat = jnp.transpose(out, (0, 2, 1, 3)).reshape(nb * qb, h, d)
+                             k_scale=k_scale, v_scale=v_scale, **grouped)
+        if group == 1:
+            flat = jnp.transpose(out, (0, 2, 1, 3)).reshape(nb * qb, h, d)
+        else:
+            flat = jnp.transpose(out.reshape(nb, h, group, qb, d),
+                                 (0, 3, 1, 2, 4)).reshape(nb * qb, hq, d)
         idx = tok_blk.astype(jnp.int32) * qb + tok_row.astype(jnp.int32)
         return jnp.take(flat, idx, axis=0)
     return _xla_ragged_reference(q, k_pool, v_pool, token_tables, lengths,

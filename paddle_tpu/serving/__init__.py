@@ -92,6 +92,7 @@ from .engine import (  # noqa: F401
     ServingError,
     StepBuildError,
     StepStalledError,
+    UnsupportedServingMode,
     serve_trace_counts,
     reset_serve_trace_counts,
 )
@@ -134,6 +135,7 @@ __all__ = [
     "serve_trace_counts", "reset_serve_trace_counts",
     "ServingError", "Overloaded", "DeadlineExceeded", "RequestCancelled",
     "StepStalledError", "StepBuildError", "NaNLogitsError",
+    "UnsupportedServingMode",
     "FaultInjector", "FaultPlan", "InjectedFault", "random_schedule",
     "random_transfer_schedule",
     "DisaggServingEngine", "DisaggElasticController", "RolePlacement",

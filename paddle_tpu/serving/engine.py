@@ -142,6 +142,24 @@ class StepBuildError(ServingError):
     boundary instead of leaving a server that answers nothing."""
 
 
+class UnsupportedServingMode(ServingError):
+    """A serving mode was asked of a model whose paged path does not have it
+    (the model names what it lacks in ``serving_unsupported``): refused at
+    construction, never served wrongly."""
+
+
+def refuse_unsupported(model, **asked):
+    """Raise :class:`UnsupportedServingMode` for the first mode in ``asked``
+    (mode -> whether it was asked for) that ``model.serving_unsupported``
+    (mode -> why) names.  A model without that table supports them all."""
+    lacks = getattr(model, "serving_unsupported", None) or {}
+    for mode, wanted in asked.items():
+        if wanted and mode in lacks:
+            raise UnsupportedServingMode(
+                f"{type(model).__name__} does not serve with {mode}: "
+                f"{lacks[mode]}")
+
+
 class NaNLogitsError(ServingError):
     """The finiteness sentry caught non-finite logits for this slot."""
 
@@ -508,8 +526,13 @@ class _StepWorker:
 
 class ServingEngine:
     """Continuous-batching front end over a model exposing the paged-cache
-    contract (``new_paged_kv_cache`` + ``_paged_lm_logits``):
-    ``GPTStackedForPretraining``, the one model that serves.
+    contract: ``config`` (``head_dim``, ``max_position_embeddings``,
+    ``num_heads`` or ``num_key_value_heads``), ``new_paged_kv_cache(num_pages,
+    page_size, dtype)`` and ``_paged_lm_logits(ids, cache, page_tables,
+    positions, ragged_plan=, out_rows=, lora=)``.  A model names the modes
+    its paged path lacks in ``serving_unsupported`` (they are refused
+    typed, :class:`UnsupportedServingMode`); what its cache counts on the
+    device (``cache.counts()``) is merged into :meth:`metrics`.
 
     ``num_pages`` defaults to full capacity (every slot can hold
     ``max_context`` tokens, plus the null page); size it DOWN to
@@ -546,11 +569,21 @@ class ServingEngine:
         if not (hasattr(model, "new_paged_kv_cache")
                 and hasattr(model, "_paged_lm_logits")):
             raise TypeError(
-                "ServingEngine serves a model with the paged-cache contract "
-                "(new_paged_kv_cache + _paged_lm_logits), which "
-                f"{type(model).__name__} does not have: build a "
-                "GPTStackedForPretraining")
+                "ServingEngine serves a model with the paged-cache contract: "
+                "new_paged_kv_cache(num_pages, page_size, dtype) and "
+                "_paged_lm_logits(ids, cache, page_tables, positions, "
+                "ragged_plan=, out_rows=, lora=); "
+                f"{type(model).__name__} lacks "
+                + " and ".join(n for n in ("new_paged_kv_cache",
+                                           "_paged_lm_logits")
+                               if not hasattr(model, n)))
         cfg = model.config
+        refuse_unsupported(
+            model, lora=lora is not None,
+            mp=mesh is not None and _srv_mesh.mp_size(mesh) > 1,
+            kv_int8=str(kv_dtype or cache_dtype) == "int8",
+            weight_int8=weight_dtype is not None,
+            disagg=role not in (None, "colocated"))
         # disaggregated serving (serving/disagg.py): the replica's role
         # ("prefill" | "decode" | "colocated").  Passing it explicitly
         # adds a ``role`` label to every per-engine metric child (the
@@ -594,10 +627,13 @@ class ServingEngine:
         # does both per dp replica.
         self.mesh = mesh
         self._mp = _srv_mesh.mp_size(mesh) if mesh is not None else 1
+        # the pool's heads: K/V's, where the model has fewer of them than
+        # of query heads (the launch folds a K/V head's queries into rows)
+        pool_heads = getattr(cfg, "num_key_value_heads", None) or cfg.num_heads
         if self._mp > 1:
             # hard precondition, typed: an indivisible head axis cannot be
             # sharded at all (GL002-formatted, not a shard_map crash)
-            _srv_mesh.validate_head_sharding(cfg.num_heads, self._mp)
+            _srv_mesh.validate_head_sharding(pool_heads, self._mp)
         max_context = int(max_context or cfg.max_position_embeddings)
         if max_context > cfg.max_position_embeddings:
             raise ValueError(
@@ -657,16 +693,18 @@ class ServingEngine:
         # token-block size comes from the autotune table for this pool
         # specialization (ops/pallas_kernels/ragged_paged_attention.py) —
         # keyed on the LOCAL (post-shard) head count under mp sharding
-        self.head_dim = int(cfg.head_dim)
-        local_heads = cfg.num_heads // self._mp
+        # (the row the kernel sees is the pool's: wider than a head where a
+        # cache keeps K and V side by side)
+        self.head_dim = int(getattr(self.cache, "row_dim", cfg.head_dim))
+        local_heads = pool_heads // self._mp
         self.token_block = ragged_token_block(
-            self.page_size, cfg.head_dim, self.cache_dtype,
+            self.page_size, self.head_dim, self.cache_dtype,
             local_heads=local_heads if self._mp > 1 else None)
         # what the ragged launch makes of this geometry (it asks the same
         # function of the same shapes): the heads of a page one work item
         # moves a grid step, and so the grid steps an item costs a chip
         self.ragged_heads_per_block = ragged_head_block(
-            local_heads, self.page_size, cfg.head_dim, self.cache_dtype)
+            local_heads, self.page_size, self.head_dim, self.cache_dtype)
         self._grid_steps_per_item = local_heads // self.ragged_heads_per_block
         # sampling RNG: the global generator single-chip (bit-compat with
         # generate()); a PRIVATE stream per mesh-sharded engine — the
@@ -1928,6 +1966,11 @@ class ServingEngine:
         out["prefix_cache_nodes"] = (self.prefix_cache.nodes
                                      if self.prefix_cache else 0)
         out["shared_pages"] = self.allocator.shared_pages
+        counts = getattr(self.cache, "counts", None)
+        if counts is not None and not self._closed:
+            # what the model's cache counts on the device (a routed layer's
+            # assignments, a convolution's tails): read here and only here
+            out.update(counts())
         if self.lora is not None:
             out["lora_adapters"] = len(self.lora.adapters())
             out["lora_pages_used"] = self.lora.allocator.used_pages

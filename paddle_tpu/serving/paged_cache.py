@@ -42,8 +42,8 @@ from ..core.dtype import to_jax_dtype
 from ..models.generation import _KVBuffers
 from ..tensor import Tensor
 
-__all__ = ["NULL_PAGE", "PagedKVCache", "BlockAllocator",
-           "pages_for_tokens"]
+__all__ = ["NULL_PAGE", "PagedKVCache", "HybridPagedCache",
+           "BlockAllocator", "pages_for_tokens"]
 
 # pool page 0: reserved sink for inactive-slot / padding writes
 NULL_PAGE = 0
@@ -65,6 +65,18 @@ def pages_for_tokens(tokens: int, page_size: int) -> int:
     if page_size < 1:
         raise ValueError(f"pages_for_tokens(page_size={page_size})")
     return -(-tokens // page_size)
+
+
+def _check_row_index(*dims: int):
+    """A pool is written as rows of its flat ``[rows, D]`` view (``dims`` are
+    the axes before ``D``); the write's row index is int32."""
+    rows = 1
+    for d in dims:
+        rows *= int(d)
+    if rows >= 2 ** 31:
+        raise ValueError(
+            f"a pool of {' x '.join(str(d) for d in dims)} rows: the "
+            "write's row index is int32")
 
 
 class PagedKVCache(_KVBuffers):
@@ -91,10 +103,7 @@ class PagedKVCache(_KVBuffers):
             raise ValueError(
                 f"num_pages={num_pages}: the pool needs the null page plus "
                 "at least one allocatable page")
-        if num_layers * num_pages * num_heads * page_size >= 2 ** 31:
-            raise ValueError(
-                f"a pool of {num_layers} x {num_pages} x {num_heads} x "
-                f"{page_size} rows: the write's row index is int32")
+        _check_row_index(num_layers, num_pages, num_heads, page_size)
         jd = to_jax_dtype(dtype)
         self.num_layers = num_layers
         self.num_pages = num_pages
@@ -124,6 +133,134 @@ class PagedKVCache(_KVBuffers):
         if self.quantized:
             ts = ts + [self.k_scale, self.v_scale]
         return ts
+
+
+class HybridPagedCache(_KVBuffers):
+    """Two kinds of state on the ONE page ledger, for a model whose layers
+    are of two kinds (docs/serving.md "Models with recurrent state"): a K/V
+    pool for the attention layers and a **tail pool** for the layers with a
+    short causal convolution, whose recurrent state is the last ``taps``
+    inputs of the sequence: ``tail [L_conv, P, taps * width / 128, 128]``, a
+    page's ``taps`` inputs as ONE slab of whole lane tiles (``[taps, width]``
+    with 2 taps would pad its sublanes eightfold and turn every flat view
+    into a copy).
+
+    ``kv [L_attn, P, Hkv, page_size, 2 * D]``: a row holds a token's K and V
+    of one head SIDE BY SIDE.  With heads of 64 a row of K alone is half a
+    lane tile: the compiler then keeps the flat ``[rows, 64]`` view the
+    write scatters into in another layout than the ``[P, H, page, 64]`` the
+    kernel reads, and copies the whole pool between them four times a layer
+    (the compiled step for the described v5e showed it).  A row of 128 is the
+    layout the dense GPT's pools have; one scatter writes both, and the
+    ragged kernel reads the pool as its K operand (queries zero-padded to
+    ``2 * D``, so the V half adds nothing to a score) and as its V operand
+    (the second half of its output is the attention's).
+
+    The recurrent state lives in the PAGES, not in the slots: after a step
+    has written position ``p``, the tail of the page holding ``p`` is the
+    convolution's inputs at ``p - taps + 1 .. p``.  Token ``p + 1`` finds its
+    predecessors among its step's own rows or in the tail of the page holding
+    ``p``, through its page table; a full page's tail never changes again.  A
+    prefix hit, an adopted shared page, a page hand-off (pages are axis 1 of
+    every tensor of ``_tensors()``) and a slot seated again at position 0 so
+    need no rule of their own, and the allocator, the scheduler and the
+    prefix cache keep one ledger.  Rows that end no page's run in a step
+    write the null page, as padding K/V rows do.
+
+    ``counters`` is a small device array the compiled step adds to in place
+    (donated like the pools; no step gains a host transfer):
+    :data:`COUNTER_NAMES` as ``[n, 2]`` int32 limbs, value ``hi * 2**30 +
+    lo``.  :meth:`counts` reads them, and ``ServingEngine.metrics()`` merges
+    what it returns.
+
+    ``routes`` is the routed layers' flight recorder, kept the same way: a
+    ring ``[routed_layers * top_k + 2, ROUTE_ROWS]`` int32 whose column is a
+    row of a step: the experts each routed layer sent it to, its position
+    and the page it wrote (0: padding).  A step writes its rows side by side
+    at ``route_cursor`` (one small block a step); :meth:`recent_routes`
+    reads it.  A routed layer's pick between two experts whose scores are
+    nearer than the hidden state's rounding is the one thing a comparison
+    with a higher-precision run cannot recompute: this is where it is
+    looked up."""
+
+    paged = True
+    quantized = False
+    COUNTER_NAMES = ("moe_assignments", "moe_experts_touched",
+                     "moe_expert_load_max", "conv_tail_rows")
+    LIMB = 1 << 30
+    ROUTE_ROWS = 16384
+
+    def __init__(self, attn_layers: int, conv_layers: int, num_pages: int,
+                 num_kv_heads: int, page_size: int, head_dim: int,
+                 conv_width: int, conv_taps: int, dtype: str = "bfloat16",
+                 routed_layers: int = 0, top_k: int = 0):
+        if str(dtype) == "int8":
+            raise ValueError(
+                "HybridPagedCache: an int8 pool is not supported (the tail "
+                "pool has no scale sidecar and the grouped-query kernel no "
+                "dequant path)")
+        if num_pages < 2:
+            raise ValueError(
+                f"num_pages={num_pages}: the pool needs the null page plus "
+                "at least one allocatable page")
+        _check_row_index(attn_layers, num_pages, num_kv_heads, page_size)
+        _check_row_index(conv_layers, num_pages, conv_taps)
+        jd = to_jax_dtype(dtype)
+        self.num_layers = attn_layers
+        self.conv_layers = conv_layers
+        self.num_pages = num_pages
+        self.num_heads = num_kv_heads
+        self.page_size = page_size
+        self.head_dim = head_dim
+        self.row_dim = 2 * head_dim     # what the ragged kernel sees as a head
+        self.conv_taps = conv_taps
+        self.conv_width = conv_width
+        self.dtype = str(dtype)
+        self.kv = Tensor(jnp.zeros(
+            (attn_layers, num_pages, num_kv_heads, page_size, 2 * head_dim),
+            jd))
+        state = conv_taps * conv_width
+        slab = (state // 128, 128) if state % 128 == 0 else (1, state)
+        self.tail = Tensor(jnp.zeros((conv_layers, num_pages) + slab, jd))
+        self.counters = Tensor(jnp.zeros((len(self.COUNTER_NAMES), 2),
+                                         jnp.int32))
+        self.routed_layers, self.top_k = routed_layers, top_k
+        self.routes = Tensor(jnp.zeros(
+            (routed_layers * top_k + 2, self.ROUTE_ROWS), jnp.int32))
+        self.route_cursor = Tensor(jnp.zeros((1,), jnp.int32))
+
+    def _tensors(self):
+        """The page-indexed buffers (pages on axis 1 of each)."""
+        return [self.kv, self.tail]
+
+    def release(self):
+        super().release()
+        for t in (self.counters, self.routes, self.route_cursor):
+            self._delete(t)
+
+    def counts(self) -> dict:
+        """The device counters as host integers (one small transfer)."""
+        import numpy as np
+
+        limbs = np.asarray(self.counters._value).astype(np.int64)
+        return {name: int(hi * self.LIMB + lo)
+                for name, (hi, lo) in zip(self.COUNTER_NAMES, limbs)}
+
+    def recent_routes(self):
+        """The log's real rows as host arrays (one transfer of the ring):
+        ``positions`` [N], ``pages`` [N] (the page each row wrote) and
+        ``experts`` [N, routed_layers, top_k]; None once released.  Rows of
+        one step stand in their order; once the ring has wrapped, steps do
+        not."""
+        import numpy as np
+
+        if self.routes._value.is_deleted():
+            return None
+        log = np.asarray(self.routes._value)
+        live = log[-1] != 0
+        experts = log[:-2, live].reshape(self.routed_layers, self.top_k, -1)
+        return {"positions": log[-2, live], "pages": log[-1, live],
+                "experts": experts.transpose(2, 0, 1)}
 
 
 class BlockAllocator:

@@ -77,6 +77,7 @@ from .engine import (
     _count_draft_trace,
     _drop_seq_axis,
     _state_intact,
+    refuse_unsupported,
 )
 from .paged_cache import NULL_PAGE, BlockAllocator, pages_for_tokens
 
@@ -458,6 +459,8 @@ class SpeculativeEngine(ServingEngine):
                  draft_num_pages: Optional[int] = None, **kw):
         if spec_k < 1:
             raise ValueError(f"spec_k must be >= 1, got {spec_k}")
+        for m in (model, draft_model):
+            refuse_unsupported(m, speculative=True)
         self.spec_k = int(spec_k)
         # brownout actuator (serving/elastic.py "disable_speculation"
         # rung): False skips the draft phase entirely — verify runs carry

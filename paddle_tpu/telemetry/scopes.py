@@ -23,6 +23,8 @@ SCOPES = (
     "kernel.flash_fwd", "kernel.flash_bwd_dkv", "kernel.flash_bwd_dq",
     "kernel.ragged", "kernel.paged_decode", "kernel.decode",
     "kernel.fused_adamw", "kernel.rms_norm",
+    "attn.rope", "conv.proj", "conv.mix", "conv.state_write",
+    "moe.route", "moe.experts", "kernel.gmm",
 )
 UNSCOPED = "unscoped"
 _VOCABULARY = frozenset(SCOPES)

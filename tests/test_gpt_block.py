@@ -171,7 +171,7 @@ def test_block_body_matches_plain_reference(core, dtype, tol):
 
 def test_layered_class_does_not_serve():
     """The layered class takes no cache, page table, plan or adapter and
-    has no ``generate()``; the engine names the class that serves."""
+    has no ``generate()``; the engine names the contract it lacks."""
     from paddle_tpu.serving import ServingEngine
 
     cfg = _cfg()
@@ -186,5 +186,6 @@ def test_layered_class_does_not_serve():
     for name in ("generate", "new_kv_cache", "new_paged_kv_cache",
                  "_cached_lm_logits", "_paged_lm_logits"):
         assert not hasattr(m, name), name
-    with pytest.raises(TypeError, match="GPTStackedForPretraining"):
+    with pytest.raises(TypeError, match="paged-cache contract.*GPTForPretraining "
+                                        "lacks new_paged_kv_cache and _paged_lm_logits"):
         ServingEngine(m, num_slots=1, page_size=16, max_context=32)

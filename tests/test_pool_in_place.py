@@ -136,6 +136,37 @@ def compiled_step(topo):
         return ctx, aot_compile.serve_step(ctx, topo)
 
 
+HYBRID_CELL = "lfm2_24b_a2b_cut.serve_reason_sat"
+
+
+def test_no_operation_of_the_hybrid_step_moves_a_layers_experts_or_a_pool(topo):
+    """The hybrid decoder's fused step at its published widths, 8 experts a
+    routed layer and 6 layers (2 dense + one period), compiled for the
+    described chip: the experts of every routed layer ride as ONE stacked
+    operand the layer loop closes over, so no operation's result is as large
+    as a layer's expert matrix stack (a slice of the stack would be); the K|V
+    pool and the tail pool are donated and aliased; the step holds
+    the ragged launch and three grouped products a routed layer."""
+    ctx = manifest.resolve_cell(HYBRID_CELL)
+    model, eng = ctx["config"]["model"], ctx["cell"]["engine"]
+    model["num_experts"], model["num_layers"] = 8, 6
+    eng["num_pages"] = PAGES
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(aot_compile, "_report", lambda compiled: compiled)
+        compiled = aot_compile.serve_step(ctx, topo)
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    one_matrix_stack = (model["num_experts"] * model["hidden_size"]
+                        * model["moe_intermediate_size"] * 2)               # bf16
+    kv_pool = PAGES * model["num_key_value_heads"] * eng["page_size"] * 128 * 2
+    tail_pool = 5 * PAGES * 2 * model["hidden_size"] * 2
+    assert one_matrix_stack > 50e6
+    assert pool_movers(text, one_matrix_stack) == []
+    # both pools are aliased into the step's outputs: written where they lie
+    assert text.count("tpu_custom_call") == 1 + 3 * 4
+    assert memory.alias_size_in_bytes >= kv_pool + tail_pool
+    assert memory.temp_size_in_bytes < 4 * one_matrix_stack, memory.temp_size_in_bytes
+
+
 def test_no_operation_of_the_compiled_step_moves_a_layers_pool(compiled_step):
     ctx, compiled = compiled_step
     model, eng = ctx["config"]["model"], ctx["cell"]["engine"]

@@ -31,6 +31,8 @@ VOCABULARY = (
     "kernel.flash_fwd", "kernel.flash_bwd_dkv", "kernel.flash_bwd_dq",
     "kernel.ragged", "kernel.paged_decode", "kernel.decode",
     "kernel.fused_adamw", "kernel.rms_norm",
+    "attn.rope", "conv.proj", "conv.mix", "conv.state_write",
+    "moe.route", "moe.experts", "kernel.gmm",
 )
 
 # where each scope must be found
@@ -39,6 +41,11 @@ IN_TRAIN = ("train.forward", "train.backward", "train.optimizer", "embed",
 IN_SERVE = ("serve.unpack", "serve.sample", "embed", "layers", "attn.qkv",
             "attn.core", "attn.pool_write", "kernel.ragged", "attn.out", "mlp",
             "lm_head")
+# the serving step of the hybrid conv / attention decoder with routed experts
+IN_HYBRID = ("serve.unpack", "serve.sample", "embed", "layers", "attn.qkv",
+             "attn.rope", "attn.core", "attn.pool_write", "kernel.ragged",
+             "attn.out", "conv.proj", "conv.mix", "conv.state_write", "mlp",
+             "moe.route", "moe.experts", "kernel.gmm", "lm_head")
 
 
 def _leaf(s):
@@ -81,6 +88,21 @@ def engine(tiny, train_step):
     yield eng
     eng.close()
     model.train()
+
+
+@pytest.fixture(scope="module")
+def hybrid_engine():
+    from paddle_tpu.models import Lfm2StackedForCausalLM, lfm2_tiny
+
+    pt.seed(0)
+    model = Lfm2StackedForCausalLM(lfm2_tiny(num_hidden_layers=10))   # two periods: a loop
+    model.eval()
+    eng = ServingEngine(model, num_slots=2, page_size=8, max_context=32,
+                        prefill_token_budget=8, cache_dtype="float32")
+    eng.submit(np.arange(11), 2)
+    eng.run_until_idle(max_steps=50)
+    yield eng
+    eng.close()
 
 
 @pytest.fixture(scope="module")
@@ -138,7 +160,8 @@ IN_KERNELS = {"kernel.flash_fwd": "flash", "kernel.flash_bwd_dkv": "flash",
 
 
 @pytest.mark.parametrize("scope", VOCABULARY)
-def test_scope_is_in_the_programs(scope, train_step, engine, kernel_texts):
+def test_scope_is_in_the_programs(scope, train_step, engine, hybrid_engine,
+                                  kernel_texts):
     """Every name of the vocabulary is on the operations of the program it
     belongs to: in ``lowered_texts()`` of the train step and of the serving
     step, and for the kernels the CPU never calls, in their traces."""
@@ -149,6 +172,10 @@ def test_scope_is_in_the_programs(scope, train_step, engine, kernel_texts):
         found_somewhere = True
     if scope in IN_SERVE:
         text = "".join(engine.lowered_texts())
+        assert re.search(r'["/(]' + re.escape(scope) + r'[/)"]', text), scope
+        found_somewhere = True
+    if scope in IN_HYBRID:
+        text = "".join(hybrid_engine.lowered_texts())
         assert re.search(r'["/(]' + re.escape(scope) + r'[/)"]', text), scope
         found_somewhere = True
     if scope in IN_KERNELS:
@@ -183,6 +210,24 @@ def test_op_scopes_of_the_serving_step(engine):
     # the layer loop itself and what moves its state are its carry
     loop = [s for name, s in mapped.items() if name.startswith("while")]
     assert loop and all(s.scope == "layers" and s.carry for s in loop)
+    unscoped = [n for n, s in mapped.items() if s.scope == scopes.UNSCOPED]
+    assert len(unscoped) <= len(mapped) // 10, unscoped
+
+
+def test_op_scopes_of_the_hybrid_serving_step(hybrid_engine):
+    """The tail scatter is ``conv.state_write``'s, the K/V scatters
+    ``attn.pool_write``'s; the period loop is ``layers`` and its carry; the
+    routed layer's sort is ``moe.route``'s and its products ``moe.experts``'s."""
+    assert hybrid_engine.compiled_programs == 1
+    (mapped,) = hybrid_engine.op_scopes()
+    leaves = {_leaf(s) for s in mapped.values()}
+    assert {"conv.proj", "conv.mix", "conv.state_write", "attn.rope",
+            "attn.pool_write", "moe.route", "mlp", "lm_head"} <= leaves
+    assert any("moe.experts" in s.scope for s in mapped.values())
+    scatters = {_leaf(s) for name, s in mapped.items() if "scatter" in name}
+    assert {"conv.state_write", "attn.pool_write"} <= scatters
+    loop = [s for name, s in mapped.items() if name.startswith("while")]
+    assert loop and all(s.scope.startswith("layers") for s in loop)
     unscoped = [n for n, s in mapped.items() if s.scope == scopes.UNSCOPED]
     assert len(unscoped) <= len(mapped) // 10, unscoped
 

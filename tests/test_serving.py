@@ -410,6 +410,89 @@ def test_ragged_launch_follows_the_work_list(pool, n_items, launch_fns,
     np.testing.assert_array_equal(got[:real], static[:real])
 
 
+_GROUPS = {"float32": 5e-6, "bfloat16": 2e-2}
+
+
+@pytest.mark.parametrize("n_items", [1, 7])
+@pytest.mark.parametrize("pool", list(_GROUPS))
+def test_ragged_kernel_serves_a_group_of_query_heads_a_pool_head(pool, n_items):
+    """Grouped queries (8 query heads over a pool of 2, G = 4): the group's
+    heads are folded into the query block's rows.  Equal to the gather oracle
+    (which repeats K/V heads) within the pool's tolerance, and BITWISE the
+    launch with one query head a pool head over a pool whose heads are
+    repeated: a query head's chain of operations is the same in both."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_kernels import ragged_paged_attention as ra
+
+    rng = np.random.RandomState(3)
+    P, HKV, G, PS, D, MP = 11, 2, 4, 128, 64, 4
+    T_MAX, NB_MAX, WL_MAX = 32, 8, 32
+    plan_np, stats, tables, lengths = _mk_ragged_case(
+        _LAUNCH_RUNS[n_items], T_MAX, NB_MAX, WL_MAX, MP)
+    real = stats["n_tokens"]
+    q = jnp.array(rng.randn(T_MAX, HKV * G, D), pool)
+    kp, vp = (jnp.array(rng.randn(P, HKV, PS, D), pool) for _ in range(2))
+    plan = tuple(jnp.array(plan_np[k]) for k in ra.RAGGED_PLAN_FIELDS)
+    tables, lengths = jnp.array(tables), jnp.array(lengths)
+
+    got = np.asarray(ra.ragged_paged_attention(
+        q, kp, vp, tables, lengths, plan, sm_scale=0.125, interpret=True),
+        np.float32)
+    assert got.shape == (T_MAX, HKV * G, D)
+    ref = np.asarray(ra._xla_ragged_reference(q, kp, vp, tables, lengths, 0.125),
+                     np.float32)
+    np.testing.assert_allclose(got[:real], ref[:real], rtol=_GROUPS[pool],
+                               atol=_GROUPS[pool])
+    plain = np.asarray(ra.ragged_paged_attention(
+        q, jnp.repeat(kp, G, axis=1), jnp.repeat(vp, G, axis=1), tables,
+        lengths, plan, sm_scale=0.125, interpret=True), np.float32)
+    np.testing.assert_array_equal(got[:real], plain[:real])
+    with pytest.raises(ValueError, match="must divide"):
+        ra.ragged_paged_attention(q[:, :7], kp, vp, tables, lengths, plan,
+                                  sm_scale=0.125, interpret=True)
+
+
+def test_ragged_launch_with_one_query_head_a_pool_head_is_the_launch_it_was(
+        monkeypatch):
+    """``G == 1`` takes the launch's old path to the letter: ``_ragged_pallas``
+    is called with the arguments it always had (no ``group``), on query blocks
+    ``[NB, H, QB, D]``, and the kernel it traces holds no row-to-token
+    remainder; ``test_ragged_launch_follows_the_work_list`` holds its outputs
+    bitwise against the launch of before.  ``G == 4`` passes ``group`` and
+    folds the rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_kernels import ragged_paged_attention as ra
+
+    rng = np.random.RandomState(5)
+    P, PS, D, MP = 11, 128, 64, 4
+    T_MAX, NB_MAX, WL_MAX = 32, 8, 32
+    plan_np, _, tables, lengths = _mk_ragged_case(
+        _LAUNCH_RUNS[7], T_MAX, NB_MAX, WL_MAX, MP)
+    plan = tuple(jnp.array(plan_np[k]) for k in ra.RAGGED_PLAN_FIELDS)
+    launch, seen = ra._ragged_pallas, []
+
+    def spy(q_blocks, *args, **kwargs):
+        seen.append((q_blocks.shape, sorted(kwargs)))
+        return launch(q_blocks, *args, **kwargs)
+
+    monkeypatch.setattr(ra, "_ragged_pallas", spy)
+    texts = {}
+    for heads in (2, 8):
+        q = jnp.array(rng.randn(T_MAX, heads, D), jnp.float32)
+        kp, vp = (jnp.array(rng.randn(P, 2, PS, D), jnp.float32) for _ in range(2))
+        texts[heads] = str(jax.make_jaxpr(
+            lambda q_, k_, v_: ra.ragged_paged_attention(
+                q_, k_, v_, jnp.array(tables), jnp.array(lengths), plan,
+                sm_scale=0.125, interpret=True))(q, kp, vp))
+    assert seen[0] == ((NB_MAX, 2, 8, D), ["interpret", "k_scale", "v_scale"])
+    assert seen[1] == ((NB_MAX, 2, 32, D),
+                       ["group", "interpret", "k_scale", "v_scale"])
+    assert " rem " not in texts[2] and " rem " in texts[8]
+
+
 _HEAD_BLOCKS = {"all_heads": 4, "some_heads": 2, "one_head": 1}
 
 
