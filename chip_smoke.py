@@ -48,6 +48,7 @@ SERVE = dict(num_slots=8, page_size=128, max_context=1024,
 KERNEL_TOL = 0.05     # max-abs error, the tolerance tools/tpu_smoke.py uses
 FLASH_KERNELS = {"_fwd_kernel", "_bwd_dkv_kernel", "_bwd_dq_kernel"}
 RAGGED_KERNEL = "_ragged_kernel"
+POOL_WRITE_KERNEL = "_pool_write_kernel"
 
 
 def say(msg: str):
@@ -273,6 +274,8 @@ def serve_phase(model, *, num_slots, page_size, max_context, prompt_lens,
             found = mosaic_kernels(rep.lowered_texts())
             assert RAGGED_KERNEL in found, \
                 f"replica {i}: ragged Pallas kernel missing: {found}"
+            assert POOL_WRITE_KERNEL in found, \
+                f"replica {i}: the pool write's launch missing: {found}"
         # a work item moves all the replica's local heads of its page:
         # one grid step an item (ops/pallas_kernels/ragged_paged_attention)
         heads_an_item = m["ragged_heads_per_block"]

@@ -378,10 +378,15 @@ def _attend_paged_shard(qh, kh, vh, pkr, pvr, tables, posr, *, head_dim,
 
     Every write translates an absolute position through the page table:
     position p of slot s lands at ``pool[tables[s, p//page_size], :,
-    p%page_size]``, written as one row of D per head on the pool's flat
-    ``[P*N*page_size, D]`` view so that the pool keeps its own layout and
-    is updated in place.  Inactive slots and prefill padding carry null-
-    page table entries, so their writes sink into page 0 (never validly
+    p%page_size]``.  On a TPU, with the engine's plan and a bfloat16 or
+    float32 pool, that is ONE launch over the plan's write list
+    (ops/pallas_kernels/pool_write.py): the tile groups the step's real
+    tokens touch, K and V together, read-modify-written in place; padding
+    tokens write nothing.  Everywhere else (off the TPU, int8 pools, the
+    plan-less callers) it is one row of D per head scattered on the pool's
+    flat ``[P*N*page_size, D]`` view, which keeps the pool's own layout and
+    updates it in place: inactive slots and prefill padding carry null-
+    page table entries, so those rows sink into page 0 (never validly
     read; under the stacked decoder P is L*P and the sink layer l's own).
     C == 1 is the batched decode step: scatter one token per row, then
     the paged flash-decode kernel (XLA gather fallback off-TPU) over each
@@ -397,8 +402,9 @@ def _attend_paged_shard(qh, kh, vh, pkr, pvr, tables, posr, *, head_dim,
     from ..ops.pallas_kernels.paged_attention import (
         gather_pages, paged_attention,
     )
+    from ..ops.pallas_kernels.pool_write import pool_write, pool_write_runs
     from ..ops.pallas_kernels.ragged_paged_attention import (
-        ragged_paged_attention,
+        ragged_paged_attention, write_list_of,
     )
 
     s_, _, c, d = qh.shape
@@ -410,33 +416,42 @@ def _attend_paged_shard(qh, kh, vh, pkr, pvr, tables, posr, *, head_dim,
     tbl = tables.astype(jnp.int32)
     abs_pos = pos[:, None] + jax.lax.broadcasted_iota(
         jnp.int32, (s_, c), 1)                               # [S, C]
+    ks2 = vs2 = None
     with jax.named_scope("attn.pool_write"):
-        # the clip is defensive: the engine reserves every page a request
-        # can touch up front, so real token positions never run past the
-        # table
-        page_slot = jnp.clip(abs_pos // page_size, 0, max_pages - 1)
-        page_ids = jnp.take_along_axis(tbl, page_slot, axis=1)  # [S, C]
-        offs = abs_pos % page_size
-        kq = jnp.transpose(kh, (0, 2, 1, 3))                 # [S, C, N, D]
-        vq = jnp.transpose(vh, (0, 2, 1, 3))
-        if quantized:
-            # int8 pools: quantize the fresh rows in-graph and update the
-            # per-(page, head) scale buffers before the scatter
-            from ..quantization.kv import quantize_kv_write
-
-            kq, ks2 = quantize_kv_write(kq, page_ids, offs, ksr)
-            vq, vs2 = quantize_kv_write(vq, page_ids, offs, vsr)
+        if (c == 1 and ragged_plan is not None and not quantized
+                and pool_write_runs(page_size, pkr.dtype)):
+            # the step's own write list: one launch, the tile groups its
+            # real tokens touch, K and V together, in place
+            pk2, pv2 = pool_write(
+                (pkr, pvr), (kh[:, :, 0, :], vh[:, :, 0, :]),
+                write_list_of(ragged_plan))
         else:
-            ks2 = vs2 = None
-        # one row of D per (token, head) on the pool's free [P*N*page, D]
-        # view: the write's natural layout is the pool's own, so it updates
-        # the buffer in place (the sink rows repeat: never unique_indices)
-        rows = ((page_ids[..., None] * nh + jnp.arange(nh, dtype=jnp.int32))
-                * page_size + offs[..., None])               # [S, C, N]
-        pk2 = pkr.reshape(-1, d).at[rows].set(
-            kq.astype(pkr.dtype)).reshape(pkr.shape)
-        pv2 = pvr.reshape(-1, d).at[rows].set(
-            vq.astype(pvr.dtype)).reshape(pvr.shape)
+            # the clip is defensive: the engine reserves every page a
+            # request can touch up front, so real token positions never
+            # run past the table
+            page_slot = jnp.clip(abs_pos // page_size, 0, max_pages - 1)
+            page_ids = jnp.take_along_axis(tbl, page_slot, axis=1)  # [S, C]
+            offs = abs_pos % page_size
+            kq = jnp.transpose(kh, (0, 2, 1, 3))             # [S, C, N, D]
+            vq = jnp.transpose(vh, (0, 2, 1, 3))
+            if quantized:
+                # int8 pools: quantize the fresh rows in-graph and update
+                # the per-(page, head) scale buffers before the scatter
+                from ..quantization.kv import quantize_kv_write
+
+                kq, ks2 = quantize_kv_write(kq, page_ids, offs, ksr)
+                vq, vs2 = quantize_kv_write(vq, page_ids, offs, vsr)
+            # one row of D per (token, head) on the pool's free
+            # [P*N*page, D] view: the write's natural layout is the pool's
+            # own, so it updates the buffer in place (the sink rows
+            # repeat: never unique_indices)
+            rows = ((page_ids[..., None] * nh
+                     + jnp.arange(nh, dtype=jnp.int32))
+                    * page_size + offs[..., None])           # [S, C, N]
+            pk2 = pkr.reshape(-1, d).at[rows].set(
+                kq.astype(pkr.dtype)).reshape(pkr.shape)
+            pv2 = pvr.reshape(-1, d).at[rows].set(
+                vq.astype(pvr.dtype)).reshape(pvr.shape)
     if c == 1 and ragged_plan is not None:
         out = ragged_paged_attention(qh[:, :, 0, :], pk2, pv2, tbl,
                                      pos + 1, ragged_plan, sm_scale=scale,
@@ -868,7 +883,7 @@ class GPTStackedDecoder(Layer):
         (and an int8 pool's [L, P, H] scales) beside the hidden state as
         one buffer viewed [L*P, H, page_size, D], and layer ``l`` (the
         index rides xs) offsets its page ids by ``l*P``: the tables, and
-        the plan's ``wl_page``.  Donated under jit.to_static (mutation-
+        the plan's ``wl_page`` and ``wr_page``.  Donated under jit.to_static (mutation-
         logged), the buffer is updated in place: no operation of a step
         moves a layer's pool (tests/test_pool_in_place.py).  The other
         ``ragged_plan`` Tensors are scan constants.  ``lora`` is
@@ -876,9 +891,7 @@ class GPTStackedDecoder(Layer):
         ``[L, pages, ...]`` adapter slabs scan alongside the parameters,
         the ids ride as a scan constant."""
         from ..ops import dispatch
-        from ..ops.pallas_kernels.ragged_paged_attention import (
-            RAGGED_PLAN_FIELDS,
-        )
+        from ..ops.pallas_kernels.ragged_paged_attention import plan_at_layer
 
         pos = _as_pos(cache_index)
         block = self._block_fn()
@@ -898,7 +911,6 @@ class GPTStackedDecoder(Layer):
             pool_in += (paged_cache.k_scale, paged_cache.v_scale)
         nt = len(pool_in)
         n_layers, n_pages = (int(n) for n in paged_cache.k.shape[:2])
-        i_page = RAGGED_PLAN_FIELDS.index("wl_page")
 
         def raw(h, posr, tbl, *rest):
             planr = rest[:n_plan] if n_plan else None
@@ -914,8 +926,7 @@ class GPTStackedDecoder(Layer):
                     params, lr = xs[:-8], (tuple(xs[-8:]), idsr, lscale)
                 else:
                     params, lr = xs, None
-                plan_l = None if planr is None else (
-                    *planr[:i_page], planr[i_page] + base, *planr[i_page + 1:])
+                plan_l = None if planr is None else plan_at_layer(planr, base)
                 h_, pk, pv, *scales = carry
                 ks, vs = scales or (None, None)
 

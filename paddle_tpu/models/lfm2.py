@@ -246,13 +246,8 @@ class _PagedStep:
     slot.  Built once a step from positions and page tables alone."""
 
     def __init__(self, pos, tbl, plan, page_size: int, n_pages: int):
-        from ..ops.pallas_kernels.ragged_paged_attention import (
-            RAGGED_PLAN_FIELDS,
-        )
-
         self.pos, self.tbl, self.plan = pos, tbl, plan
         self.page_size, self.n_pages = page_size, n_pages
-        self._i_page = RAGGED_PLAN_FIELDS.index("wl_page")
         last = tbl.shape[1] - 1
 
         def page_of(q):     # the pool page holding position q of the row's slot
@@ -286,26 +281,33 @@ class _PagedStep:
         """q [T, Hq, D], k/v [T, Hkv, D] against layer ``layer``'s pages of
         the flat K|V pool ``[L * P, Hkv, page, 2D]``; returns (out [T, Hq,
         D], pool).  A row of the pool is a token's K and V side by side:
-        one write, on the pool's flat ``[rows, 2D]`` view (its own layout,
-        so in place; padding rows sink into the layer's null page); the
+        one write.  On a TPU the plan's write list in one launch (the tile
+        groups the real rows touch, in place: pool_write.py); elsewhere a
+        row scatter on the pool's flat ``[rows, 2D]`` view (its own layout,
+        so in place; padding rows sink into the layer's null page).  The
         queries are zero-padded to ``2D`` so that the V half adds nothing
         to a score, and the attention is the second half of what the kernel
         returns (``HybridPagedCache``)."""
+        from ..ops.pallas_kernels.pool_write import (
+            pool_write, pool_write_runs,
+        )
         from ..ops.pallas_kernels.ragged_paged_attention import (
-            ragged_paged_attention,
+            plan_at_layer, ragged_paged_attention, write_list_of,
         )
 
         base = layer * self.n_pages
-        plan = self.plan
-        plan = (*plan[:self._i_page], plan[self._i_page] + base,
-                *plan[self._i_page + 1:])
+        plan = plan_at_layer(self.plan, base)
         hkv = k.shape[1]
         with jax.named_scope("attn.pool_write"):
-            rows = (((self.write_page + base)[:, None] * hkv
-                     + jnp.arange(hkv, dtype=jnp.int32)) * self.page_size
-                    + (self.pos % self.page_size)[:, None])      # [T, Hkv]
             row = jnp.concatenate([k, v], axis=-1).astype(kv.dtype)
-            kv = kv.reshape(-1, 2 * head_dim).at[rows].set(row).reshape(kv.shape)
+            if pool_write_runs(self.page_size, kv.dtype):
+                (kv,) = pool_write((kv,), (row,), write_list_of(plan))
+            else:
+                rows = (((self.write_page + base)[:, None] * hkv
+                         + jnp.arange(hkv, dtype=jnp.int32)) * self.page_size
+                        + (self.pos % self.page_size)[:, None])  # [T, Hkv]
+                kv = kv.reshape(-1, 2 * head_dim).at[rows].set(
+                    row).reshape(kv.shape)
         wide = jnp.concatenate([q, jnp.zeros_like(q)], axis=-1)
         out = ragged_paged_attention(
             wide, kv, kv, self.tbl + base, self.pos + 1, plan,

@@ -77,13 +77,20 @@ __all__ = [
     "ragged_token_block",
     "ragged_head_block",
     "build_ragged_plan",
+    "ragged_plan_shapes",
+    "ragged_write_capacity",
+    "plan_at_layer",
+    "write_list_of",
     "RAGGED_PLAN_FIELDS",
+    "RAGGED_ATTEND_FIELDS",
+    "RAGGED_WRITE_FIELDS",
 ]
 
 # the ordered field names of a ragged plan — the host builder emits them,
 # the serving engine ships them (as traced int32 Tensors) into the fused
-# step, and the kernel consumes them positionally
-RAGGED_PLAN_FIELDS = (
+# step, and the kernels consume them positionally: the attention launch its
+# work list, the pool write (pool_write.py) its write list
+RAGGED_ATTEND_FIELDS = (
     "blk_tok",      # [NB, QB]  flat token index feeding each block row
     "tok_blk",      # [T]       inverse map: token -> its block
     "tok_row",      # [T]       inverse map: token -> its row in the block
@@ -94,6 +101,51 @@ RAGGED_PLAN_FIELDS = (
     "wl_pageslot",  # [WL]      work item -> page-slot (for position math)
     "n_items",      # [1]       real work items: the launch's length
 )
+RAGGED_WRITE_FIELDS = (
+    "wr_page",      # [WR]      write item -> POOL page id (pre-translated)
+    "wr_group",     # [WR]      write item -> tile group within its page
+    "wr_tok",       # [WR, G]   flat token index feeding each group row
+    "wr_lo",        # [WR]      first new row of the group
+    "wr_n",         # [WR]      new rows of the group (lo .. lo + n - 1)
+    "n_writes",     # [1]       real write items: the write launch's length
+)
+RAGGED_PLAN_FIELDS = RAGGED_ATTEND_FIELDS + RAGGED_WRITE_FIELDS
+_PAGE_FIELDS = tuple(RAGGED_PLAN_FIELDS.index(f) for f in ("wl_page", "wr_page"))
+
+
+def plan_at_layer(plan, page_base):
+    """The plan of one layer of a stacked pool ``[L * P, ...]``: the pool
+    page ids of both lists offset by the layer's first page."""
+    return tuple(a + page_base if i in _PAGE_FIELDS else a
+                 for i, a in enumerate(plan))
+
+
+def write_list_of(plan):
+    """The write list (:data:`RAGGED_WRITE_FIELDS`) of a plan's arrays."""
+    return tuple(plan[len(RAGGED_ATTEND_FIELDS):])
+
+
+def ragged_write_capacity(t_max: int, write_group: int, num_runs: int) -> int:
+    """The most write items a step of ``t_max`` tokens in ``num_runs`` runs
+    can hold: a run of ``c`` tokens touches at most ``c // g + 2`` tile
+    groups of ``g`` positions."""
+    return t_max // write_group + 2 * num_runs
+
+
+def ragged_plan_shapes(*, token_block: int, t_max: int, nb_max: int,
+                       wl_max: int, write_group: int, wr_max: int):
+    """``[(field, shape)]`` of a plan's arrays in :data:`RAGGED_PLAN_FIELDS`
+    order: what an engine's packed step input lays out."""
+    shapes = {
+        "blk_tok": (nb_max, token_block), "tok_blk": (t_max,),
+        "tok_row": (t_max,), "blk_base": (nb_max,), "blk_rows": (nb_max,),
+        "wl_blk": (wl_max,), "wl_page": (wl_max,), "wl_pageslot": (wl_max,),
+        "n_items": (1,),
+        "wr_page": (wr_max,), "wr_group": (wr_max,),
+        "wr_tok": (wr_max, write_group), "wr_lo": (wr_max,),
+        "wr_n": (wr_max,), "n_writes": (1,),
+    }
+    return [(f, shapes[f]) for f in RAGGED_PLAN_FIELDS]
 
 
 def ragged_shape_unsupported_reason(page_size: int, head_dim: int,
@@ -179,9 +231,49 @@ def ragged_head_block(num_heads: int, page_size: int, head_dim: int,
 # host-side plan construction (numpy; built from the scheduler mirrors)
 # ---------------------------------------------------------------------------
 
+def _build_write_list(bases, counts, starts, tables, *, g: int,
+                      page_size: int, t_max: int, wr_max: int):
+    """The write items of a step's runs (numpy over all runs at once): one
+    a tile group of ``g`` positions that a run's new positions touch."""
+    first = bases // g
+    per_run = (bases + counts - 1) // g - first + 1
+    n_writes = int(per_run.sum())
+    if n_writes > wr_max:
+        raise ValueError(f"plan overflow: {n_writes} write items > "
+                         f"wr_max={wr_max}")
+    run = np.repeat(np.arange(len(bases)), per_run)
+    ends = np.cumsum(per_run)
+    grp = first[run] + np.arange(n_writes) - (ends - per_run)[run]
+    p0 = np.maximum(grp * g, bases[run])                # first new position
+    p1 = np.minimum((grp + 1) * g, (bases + counts)[run])
+    groups_a_page = page_size // g
+    page = tables[run, grp // groups_a_page]
+    group = grp % groups_a_page
+    if len(np.unique(page * groups_a_page + group)) != n_writes:
+        raise ValueError("two write items of one step name one tile group: "
+                         "a page is written by the one slot that owns it")
+    lo = p0 - grp * g
+    tok = starts[run] + p0 - bases[run]
+    fields = {"wr_page": page, "wr_group": group, "wr_lo": lo,
+              "wr_n": p1 - p0}
+    out = {}
+    for name, real in fields.items():
+        # the tail repeats the last real item: valid indices, never walked
+        out[name] = np.full((wr_max,), real[-1], np.int32)
+        out[name][:n_writes] = real
+    # the token feeding each row of a group: consecutive tokens, clipped
+    # into the step (rows outside lo .. lo + n - 1 are masked in the launch)
+    out["wr_tok"] = np.zeros((wr_max, g), np.int32)
+    out["wr_tok"][:n_writes] = np.clip(
+        (tok - lo)[:, None] + np.arange(g), 0, t_max - 1)
+    out["n_writes"] = np.array([n_writes], np.int32)
+    return out
+
+
 def build_ragged_plan(runs: Sequence[Tuple[int, int, np.ndarray]], *,
                       token_block: int, page_size: int,
-                      t_max: int, nb_max: int, wl_max: int
+                      t_max: int, nb_max: int, wl_max: int,
+                      write_group: int = 8, wr_max: Optional[int] = None
                       ) -> Tuple[Dict[str, np.ndarray], Dict[str, int]]:
     """Flatten one fused step's work into the kernel's plan arrays.
 
@@ -200,12 +292,25 @@ def build_ragged_plan(runs: Sequence[Tuple[int, int, np.ndarray]], *,
     block's first token (a valid index; the row is masked in-kernel and
     discarded by the output gather).
 
+    Beside the work list the WRITE LIST (pool_write.py): one item a tile
+    group of ``write_group`` positions (the sublane tile of the pool's
+    dtype, ``pool_write_group``; a divisor of ``page_size``) that a run's
+    new positions touch, ``wr_max`` of them at most (by default
+    :func:`ragged_write_capacity` of ``nb_max`` runs, which bounds any
+    step: every run holds a block; an engine passes its tighter one, of a
+    run a slot).
+    No two items may name one group of one page: a repeat raises.
+
     Returns ``(plan_arrays, stats)``: the arrays keyed by
     :data:`RAGGED_PLAN_FIELDS`, and stats with ``n_tokens``/``n_blocks``/
-    ``n_items``/``run_starts``, the occupancy numerators the serving
-    metrics report, and ``launched_items`` (the launch's second grid
-    dimension for this step)."""
-    qb = int(token_block)
+    ``n_items``/``n_writes``/``run_starts``, the occupancy numerators the
+    serving metrics report, and ``launched_items`` (the launch's second
+    grid dimension for this step)."""
+    qb, g = int(token_block), int(write_group)
+    if g < 1 or page_size % g:
+        raise ValueError(f"write_group={g} must divide page_size={page_size}")
+    if wr_max is None:
+        wr_max = ragged_write_capacity(t_max, g, nb_max)
     blk_tok = np.zeros((nb_max, qb), np.int32)
     tok_blk = np.zeros((t_max,), np.int32)
     tok_row = np.zeros((t_max,), np.int32)
@@ -263,8 +368,15 @@ def build_ragged_plan(runs: Sequence[Tuple[int, int, np.ndarray]], *,
         "wl_blk": wl_blk, "wl_page": wl_page, "wl_pageslot": wl_ps,
         "n_items": np.array([n_items], np.int32),
     }
+    plan.update(_build_write_list(
+        np.array([r[0] for r in runs], np.int64),
+        np.array([r[1] for r in runs], np.int64),
+        np.array(run_starts, np.int64),
+        np.stack([np.asarray(r[2]) for r in runs]),
+        g=g, page_size=page_size, t_max=t_max, wr_max=int(wr_max)))
     stats = {
         "n_tokens": t, "n_blocks": b, "n_items": n_items,
+        "n_writes": int(plan["n_writes"][0]),
         "run_starts": run_starts,
         # occupancy: the fraction of the work-list arrays holding real
         # items and of the block rows carrying real queries
@@ -481,7 +593,8 @@ def ragged_paged_attention(q, k_pool, v_pool, token_tables, lengths, plan,
                   pool pages straight from the pre-translated work list)
     lengths:      [T] int32 — valid context per token (position + 1)
     plan:         the :data:`RAGGED_PLAN_FIELDS` arrays from
-                  :func:`build_ragged_plan`
+                  :func:`build_ragged_plan` (the :data:`RAGGED_ATTEND_FIELDS`
+                  lead; the write list behind them is not read here)
     k_scale/v_scale: [P, H] fp32 per-(page, head) absmax scales when the
                   pool is int8 (docs/serving.md "Quantized serving") —
                   dequant happens INSIDE the kernel right after each
@@ -506,7 +619,7 @@ def ragged_paged_attention(q, k_pool, v_pool, token_tables, lengths, plan,
     else:
         q = q.astype(k_pool.dtype)
     (blk_tok, tok_blk, tok_row, blk_base, blk_rows,
-     wl_blk, wl_page, wl_ps, n_items) = plan
+     wl_blk, wl_page, wl_ps, n_items) = plan[:len(RAGGED_ATTEND_FIELDS)]
     qb = int(blk_tok.shape[1])
     use_kernel = (_on_tpu() and ragged_shape_supported(page_size, d, qb)) \
         or interpret

@@ -72,6 +72,7 @@ See docs/serving.md for the architecture and slot/page lifecycle.
 from __future__ import annotations
 
 import itertools
+import math
 import queue as _queue
 import threading
 import time
@@ -92,8 +93,9 @@ from ..telemetry import metrics as _tmetrics
 from ..telemetry import trace as _ttrace
 from ..ops.pallas_kernels.ragged_paged_attention import (
     RAGGED_PLAN_FIELDS, build_ragged_plan, ragged_head_block,
-    ragged_token_block,
+    ragged_plan_shapes, ragged_token_block, ragged_write_capacity,
 )
+from ..ops.pallas_kernels.pool_write import pool_write_group
 from ..tensor import Tensor, to_tensor
 from .admission import AdmissionScheduler, StepWork
 from .paged_cache import BlockAllocator
@@ -730,6 +732,21 @@ class ServingEngine:
         # slot).
         self._t_max, self._nb_max = self._step_geometry()
         self._wl_max = self._nb_max * max_pages_per_slot
+        # the pool write's list (ops/pallas_kernels/pool_write.py): one
+        # item a tile group of g positions a run touches; a slot
+        # contributes one run a step.  (A pool whose pages g does not
+        # divide keeps the row scatter and never reads the list: it is
+        # built to the largest group that does.)
+        self._write_group = math.gcd(pool_write_group(self.cache_dtype),
+                                     self.page_size)
+        # the constant capacities every step's plan is built to: the
+        # keywords build_ragged_plan and ragged_plan_shapes share
+        self._plan_geometry = dict(
+            token_block=self.token_block, t_max=self._t_max,
+            nb_max=self._nb_max, wl_max=self._wl_max,
+            write_group=self._write_group,
+            wr_max=ragged_write_capacity(self._t_max, self._write_group,
+                                         num_slots))
 
         # fault-containment state
         self.stall_budget_s = (None if stall_budget_s is None
@@ -760,26 +777,19 @@ class ServingEngine:
         self._top_p = np.ones((num_slots,), np.float32)
         self._top_k = np.zeros((num_slots,), np.int32)
         self._do_sample = np.zeros((num_slots,), bool)
-        # all int32 step inputs (tables/positions/out_rows + the 9 ragged
-        # plan arrays) ship as ONE packed flat vector: one host->device
-        # transfer per step instead of twelve — at serving step rates the
-        # per-array device_put overhead dominates the tiny payloads.
-        # Layout is fixed at construction; the compiled step slices it
-        # back apart with static offsets (free under XLA).
+        # all int32 step inputs (tables/positions/out_rows + the ragged
+        # plan's arrays, work list and write list) ship as ONE packed flat
+        # vector: one host->device transfer per step instead of one an
+        # array — at serving step rates the per-array device_put overhead
+        # dominates the tiny payloads.  Layout is fixed at construction;
+        # the compiled step slices it back apart with static offsets (free
+        # under XLA).
         mp_ = max_pages_per_slot
         self._pack_layout = [
             ("tables", (self._t_max, mp_)),
             ("positions", (self._t_max,)),
             ("out_rows", (self.num_slots,)),
-            ("blk_tok", (self._nb_max, self.token_block)),
-            ("tok_blk", (self._t_max,)),
-            ("tok_row", (self._t_max,)),
-            ("blk_base", (self._nb_max,)),
-            ("blk_rows", (self._nb_max,)),
-            ("wl_blk", (self._wl_max,)),
-            ("wl_page", (self._wl_max,)),
-            ("wl_pageslot", (self._wl_max,)),
-            ("n_items", (1,)),
+            *ragged_plan_shapes(**self._plan_geometry),
         ]
         if self.lora is not None:
             # per-token adapter-page ids (0 = null adapter) — only when a
@@ -814,6 +824,9 @@ class ServingEngine:
                         "fused_steps": 0, "prefill_tokens": 0,
                         "work_items": 0, "work_capacity": 0,
                         "launched_items": 0, "launched_grid_steps": 0,
+                        # the pool write's items (tile groups the steps'
+                        # real tokens touched; see metrics())
+                        "write_items": 0,
                         "block_rows": 0, "block_row_capacity": 0,
                         # host-packing padding cost in GL002's units
                         # (analysis/cost_model.ragged_padding_waste): block
@@ -1231,8 +1244,7 @@ class ServingEngine:
             runs.append((w.base, w.count, row))
             t += w.count
         plan, stats = build_ragged_plan(
-            runs, token_block=self.token_block, page_size=self.page_size,
-            t_max=self._t_max, nb_max=self._nb_max, wl_max=self._wl_max)
+            runs, page_size=self.page_size, **self._plan_geometry)
         for k in RAGGED_PLAN_FIELDS:
             view(k)[...] = plan[k]
         return (ids[:, None], packed), stats
@@ -1332,6 +1344,7 @@ class ServingEngine:
         self._totals["launched_items"] += stats["launched_items"]
         self._totals["launched_grid_steps"] += (
             stats["launched_items"] * self._grid_steps_per_item)
+        self._totals["write_items"] += stats["n_writes"]
         self._totals["block_rows"] += stats["n_tokens"]
         self._totals["block_row_capacity"] += stats["row_capacity"]
         waste = ragged_padding_waste(
@@ -1915,7 +1928,11 @@ class ServingEngine:
         geometry (the heads of a page a work item moves a grid step) and
         ``launched_grid_steps`` the grid steps a chip's launches walked:
         ``launched_items x local heads // hb``, equal to
-        ``launched_items`` wherever an item moves all its heads."""
+        ``launched_items`` wherever an item moves all its heads.
+        ``pool_write_items`` is the mean length of a step's write list (the
+        tile groups of the pool its real tokens touched: the pool write's
+        launch on a TPU) and ``pool_tiles_per_token`` those items over the
+        real tokens: 1.0 for pure decode, 1/g for aligned chunks."""
         out = dict(self._totals)
         out.update(self._last_metrics)
         out["queue_depth"] = self.queue.depth
@@ -1940,6 +1957,10 @@ class ServingEngine:
         out["mean_launch_occupancy"] = (self._totals["work_items"] / li
                                         if li else 0.0)
         out["ragged_heads_per_block"] = self.ragged_heads_per_block
+        wi, fs = self._totals["write_items"], self._totals["fused_steps"]
+        out["pool_write_items"] = wi / fs if fs else 0.0
+        rows = self._totals["block_rows"]
+        out["pool_tiles_per_token"] = wi / rows if rows else 0.0
         # per-request SLO digests (seconds): count/sum/mean/min/max +
         # p50/p95/p99 per histogram — TTFT, inter-token latency, queue
         # wait, end-to-end (docs/observability.md "SLO definitions")

@@ -68,8 +68,8 @@ def pages_for_tokens(tokens: int, page_size: int) -> int:
 
 
 def _check_row_index(*dims: int):
-    """A pool is written as rows of its flat ``[rows, D]`` view (``dims`` are
-    the axes before ``D``); the write's row index is int32."""
+    """Off the TPU a pool is written as rows of its flat ``[rows, D]`` view
+    (``dims`` are the axes before ``D``); the write's row index is int32."""
     rows = 1
     for d in dims:
         rows *= int(d)
@@ -84,9 +84,10 @@ class PagedKVCache(_KVBuffers):
     ``[L, num_pages, H, page_size, D]``.
     The fused step carries each through its layer loop as ONE donated
     buffer, viewed as ``[L * num_pages, H, page_size, D]``: layer ``l``
-    addresses page ``l * num_pages + page_id`` and writes a token as rows of
-    ``[L * num_pages * H * page_size, D]``, in place
-    (``GPTStackedDecoder._forward_paged``).  The stored shape is what the
+    addresses page ``l * num_pages + page_id`` and writes its tokens in place
+    (``GPTStackedDecoder._forward_paged``): on a TPU the tile groups the
+    step's write list names in one launch (ops/pallas_kernels/pool_write.py),
+    elsewhere as rows of ``[L * num_pages * H * page_size, D]``.  The stored shape is what the
     allocator, the prefix cache, page hand-off, sharding and checkpoints see.
 
     ``paged`` is the duck-type marker ``models/gpt.py`` dispatches on (a

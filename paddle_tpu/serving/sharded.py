@@ -430,7 +430,7 @@ class ShardedServingEngine:
                     "rebuilds", "pages_used", "pages_capacity",
                     "active_slots", "queue_depth", "cache_bytes",
                     "work_items", "work_capacity", "launched_items",
-                    "launched_grid_steps",
+                    "launched_grid_steps", "write_items",
                     "block_rows",
                     "block_row_capacity", "padded_rows", "padded_flops",
                     # per-replica prefix caches (docs/serving.md "Prefix
@@ -464,6 +464,11 @@ class ShardedServingEngine:
         # a gauge of the replicas' one geometry, not a sum
         out["ragged_heads_per_block"] = (per[0]["ragged_heads_per_block"]
                                          if per else 0)
+        # the pool write's means, re-derived from the sums
+        out["pool_write_items"] = (out["write_items"] / out["fused_steps"]
+                                   if out["fused_steps"] else 0.0)
+        out["pool_tiles_per_token"] = (out["write_items"] / out["block_rows"]
+                                       if out["block_rows"] else 0.0)
         out["routed"] = list(self.placement.routed)
         # elastic lifecycle observability (PR 19)
         out["replica_states"] = self.replica_states()

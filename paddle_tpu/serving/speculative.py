@@ -64,7 +64,8 @@ from .. import ops
 from ..distributed import serving_mesh as _srv_mesh
 from ..ops import dispatch
 from ..ops.pallas_kernels.ragged_paged_attention import (
-    RAGGED_PLAN_FIELDS, build_ragged_plan,
+    RAGGED_PLAN_FIELDS, build_ragged_plan, ragged_plan_shapes,
+    ragged_write_capacity,
 )
 from ..telemetry import metrics as _tmetrics
 from ..telemetry import trace as _ttrace
@@ -259,6 +260,12 @@ class _DraftShadow:
         self.nb_max = (S * (-(-(k + 2) // qb)) + S
                        + e.prefill_token_budget // qb)
         self.wl_max = self.nb_max * self.max_pages_per_slot
+        # the plan's constant capacities (one run a slot: the write list's
+        # bound is the target engine's, at this shadow's t_max)
+        self._plan_geometry = dict(
+            token_block=qb, t_max=self.t_max, nb_max=self.nb_max,
+            wl_max=self.wl_max, write_group=e._write_group,
+            wr_max=ragged_write_capacity(self.t_max, e._write_group, S))
         # host mirrors (the target scheduler's discipline, shadow copies)
         self.tables = np.full((S, self.max_pages_per_slot), NULL_PAGE,
                               np.int32)
@@ -271,15 +278,7 @@ class _DraftShadow:
             ("tables", (self.t_max, self.max_pages_per_slot)),
             ("positions", (self.t_max,)),
             ("out_rows", (S,)),
-            ("blk_tok", (self.nb_max, qb)),
-            ("tok_blk", (self.t_max,)),
-            ("tok_row", (self.t_max,)),
-            ("blk_base", (self.nb_max,)),
-            ("blk_rows", (self.nb_max,)),
-            ("wl_blk", (self.wl_max,)),
-            ("wl_page", (self.wl_max,)),
-            ("wl_pageslot", (self.wl_max,)),
-            ("n_items", (1,)),
+            *ragged_plan_shapes(**self._plan_geometry),
         ]
         self._pack_slices = {}
         off = 0
@@ -429,9 +428,7 @@ class _DraftShadow:
             plan_runs.append((base, c, row))
             t += c
         plan, _stats = build_ragged_plan(
-            plan_runs, token_block=self.engine.token_block,
-            page_size=self.page_size, t_max=self.t_max,
-            nb_max=self.nb_max, wl_max=self.wl_max)
+            plan_runs, page_size=self.page_size, **self._plan_geometry)
         for kf in RAGGED_PLAN_FIELDS:
             view(kf)[...] = plan[kf]
         return ids[:, None], packed

@@ -1,7 +1,10 @@
 """The fused serving step updates the K/V pool in place (docs/serving.md
 "Page-table addressing"): the stacked pool rides the layer loop's carry as
-one donated buffer a side and each layer's tokens are written as rows of
-its flat view, so no operation of a step moves a layer's pool.
+one donated buffer a side and each layer's tokens are written into it where
+it lies, so no operation of a step moves a layer's pool: on a TPU by ONE
+Mosaic launch a layer over the step's write list, its pool operands aliased
+to its results (docs/serving.md "The pool write", PR 34); off it as rows of
+the pool's flat view.
 
 Two readings of the program hold that, neither a measurement: the step
 compiled for the described v5e (``benchmark/aot_compile.py``, imported
@@ -53,8 +56,8 @@ def _largest_array_bytes(type_text: str) -> int:
 
 def _instructions(hlo_text: str):
     """(computation, name, opcode, largest array of the result in bytes,
-    called computation or None, is ROOT) of every instruction of an optimized
-    HLO module's text."""
+    called computation or None, is ROOT, results alias operands) of every
+    instruction of an optimized HLO module's text."""
     computation = None
     for line in hlo_text.splitlines():
         m = _INSTRUCTION.match(line)
@@ -77,19 +80,22 @@ def _instructions(hlo_text: str):
             continue
         calls = re.search(r"calls=%?([\w.\-]+)", line)
         yield (computation, m.group(2), op.group(1), _largest_array_bytes(result),
-               calls.group(1) if calls else None, bool(m.group(1)))
+               calls.group(1) if calls else None, bool(m.group(1)),
+               "output_to_operand_aliasing={" in line)
 
 
 def pool_movers(hlo_text: str, layer_pool_bytes: int):
     """The instructions whose result is a buffer as large as one layer's pool
-    and is neither plumbing nor an in-place scatter (a ``scatter``, or a fusion
-    whose root is one).  What a fused computation holds inside is no buffer."""
+    and is neither plumbing nor written in place: a ``scatter`` (or a fusion
+    whose root is one), or a custom call whose results alias its operands (the
+    pool write's launch).  What a fused computation holds inside is no buffer."""
     instructions = list(_instructions(hlo_text))
-    root_op = {comp: op for comp, _, op, _, _, is_root in instructions if is_root}
-    fused = {calls for _, _, op, _, calls, _ in instructions if op == "fusion"}
-    return [(name, op) for comp, name, op, nbytes, calls, _ in instructions
+    root_op = {comp: op for comp, _, op, _, _, is_root, _ in instructions if is_root}
+    fused = {calls for _, _, op, _, calls, _, _ in instructions if op == "fusion"}
+    return [(name, op) for comp, name, op, nbytes, calls, _, aliased in instructions
             if nbytes >= layer_pool_bytes and op not in STILL and comp not in fused
-            and not (op == "fusion" and root_op.get(calls) == "scatter")]
+            and not (op == "fusion" and root_op.get(calls) == "scatter")
+            and not (op == "custom-call" and aliased)]
 
 
 def test_the_reader_sees_a_copy_and_passes_an_in_place_scatter():
@@ -114,6 +120,15 @@ ENTRY %main (a: bf16[2,4,128,128]) -> (s32[], bf16[2,4,128,128]) {
 """
     assert pool_movers(text, 4 * 128 * 128 * 2) == [("copy.7", "copy")]
     assert pool_movers(text, 4 * 128 * 128 * 2 + 1) == []
+    # a custom call with a pool-sized result moves it unless the result is
+    # an operand (the pool write's launch)
+    call = ('  %k = bf16[1024,128]{1,0} custom-call(%b, %u), '
+            'custom_call_target="tpu_custom_call"')
+    aliased = call + ", output_to_operand_aliasing={{}: (0, {})}"
+    for line, movers in ((call, [("copy.7", "copy"), ("k", "custom-call")]),
+                         (aliased, [("copy.7", "copy")])):
+        assert pool_movers(text.replace("  ROOT %t", line + "\n  ROOT %t"),
+                           4 * 128 * 128 * 2) == movers
 
 
 @pytest.fixture(scope="module")
@@ -162,9 +177,20 @@ def test_no_operation_of_the_hybrid_step_moves_a_layers_experts_or_a_pool(topo):
     assert one_matrix_stack > 50e6
     assert pool_movers(text, one_matrix_stack) == []
     # both pools are aliased into the step's outputs: written where they lie
-    assert text.count("tpu_custom_call") == 1 + 3 * 4
+    # (the ragged launch, the pool write's, and three grouped products a
+    # routed layer)
+    assert text.count("tpu_custom_call") == 2 + 3 * 4
     assert memory.alias_size_in_bytes >= kv_pool + tail_pool
     assert memory.temp_size_in_bytes < 4 * one_matrix_stack, memory.temp_size_in_bytes
+    # the K|V pool's write is the launch: its one pool operand is its result,
+    # nothing scatters into a K|V-pool-sized buffer (the tail pool's slabs
+    # still do, into theirs), and what the step holds beside its arguments
+    # stays under 5% of the pools
+    (write,) = pool_write_calls(text)
+    assert write["aliased"] == [f"bf16[{PAGES},{model['num_key_value_heads']},"
+                                f"{eng['page_size']},128]"]
+    assert pool_scatters(text, kv_pool) == []
+    assert memory.temp_size_in_bytes < 0.05 * (kv_pool + tail_pool) + 3.2 * one_matrix_stack
 
 
 def test_no_operation_of_the_compiled_step_moves_a_layers_pool(compiled_step):
@@ -174,10 +200,92 @@ def test_no_operation_of_the_compiled_step_moves_a_layers_pool(compiled_step):
                   * (model["hidden_size"] // model["num_heads"]) * 2)      # bf16
     pool = 2 * LAYERS * layer_pool                                         # K and V
     text, memory = compiled.as_text(), compiled.memory_analysis()
-    assert text.count("tpu_custom_call") == 1
+    assert text.count("tpu_custom_call") == 2       # the ragged launch, the write
     assert pool_movers(text, layer_pool) == []
     assert memory.temp_size_in_bytes < 0.05 * pool, memory.temp_size_in_bytes
     assert memory.alias_size_in_bytes >= pool, memory.alias_size_in_bytes
+
+
+def pool_write_calls(hlo_text: str):
+    """The Mosaic calls of a compiled step that write a pool where it lies:
+    for each custom call that aliases results to operands, the types of the
+    aliased operands (``output_to_operand_aliasing`` pairs result i with an
+    operand; the ragged launch and the grouped product alias nothing)."""
+    calls = []
+    for line in hlo_text.splitlines():
+        if "tpu_custom_call" not in line or "output_to_operand_aliasing={" not in line:
+            continue
+        pairs = re.findall(r"\{(\d*)\}: \((\d+), \{\}\)",
+                           line.split("output_to_operand_aliasing={", 1)[1])
+        types = re.findall(r"(\w+\[[\d,]*\])(?:\{[^{}]*\})?",
+                           line.split("operand_layout_constraints={", 1)[1])
+        calls.append({"aliased": [types[int(op)] for _, op in pairs],
+                      "results": [int(out or 0) for out, _ in pairs]})
+    return calls
+
+
+def pool_scatters(hlo_text: str, layer_pool_bytes: int):
+    """The scatters (or fusions rooted in one) of a compiled step whose result
+    is as large as a layer's pool: the row scatter the CPU path keeps."""
+    instructions = list(_instructions(hlo_text))
+    root_op = {comp: op for comp, _, op, _, _, is_root, _ in instructions if is_root}
+    fused = {calls for _, _, op, _, calls, _, _ in instructions if op == "fusion"}
+    return [name for comp, name, op, nbytes, calls, _, _ in instructions
+            if nbytes >= layer_pool_bytes and comp not in fused
+            and (op == "scatter" or (op == "fusion" and root_op.get(calls) == "scatter"))]
+
+
+def test_the_readers_of_the_write_see_an_aliased_call_and_a_pool_sized_scatter():
+    text = """HloModule m, is_scheduled=true
+
+%fused_scatter (p0: bf16[1024,128], p1: s32[8], p2: bf16[8,128]) -> bf16[1024,128] {
+  %p0 = bf16[1024,128]{1,0:T(8,128)(2,1)} parameter(0)
+  ROOT %scatter.1 = bf16[1024,128]{1,0:T(8,128)(2,1)} scatter(%p0, %p1, %p2), to_apply=%add
+}
+
+ENTRY %main (a: bf16[8,4,32,128]) -> bf16[8,4,32,128] {
+  %f = bf16[1024,128]{1,0:T(8,128)(2,1)} fusion(%b, %i, %u), kind=kCustom, calls=%fused_scatter
+  %r = bf16[16,4,8,128]{3,2,1,0} custom-call(%n, %q, %k), custom_call_target="tpu_custom_call", operand_layout_constraints={s32[]{:T(128)}, bf16[16,4,8,128]{3,2,1,0}, bf16[8,4,32,128]{3,2,1,0}}
+  ROOT %w = (bf16[8,4,32,128]{3,2,1,0}, bf16[8,4,32,128]{3,2,1,0}) custom-call(%n, %s, %k, %v, %x), custom_call_target="tpu_custom_call", operand_layout_constraints={s32[], s32[4]{0}, bf16[8,4,32,128]{3,2,1,0}, bf16[8,4,32,128]{3,2,1,0}, bf16[16,4,128]{2,1,0}}, output_to_operand_aliasing={{0}: (2, {}), {1}: (3, {})}
+}
+"""
+    assert pool_write_calls(text) == [
+        {"aliased": ["bf16[8,4,32,128]", "bf16[8,4,32,128]"], "results": [0, 1]}]
+    assert pool_scatters(text, 1024 * 128 * 2) == ["f"]
+    assert pool_scatters(text, 1024 * 128 * 2 + 1) == []
+
+
+def test_the_compiled_step_writes_the_pools_with_one_aliased_launch(compiled_step):
+    """The layer loop's body holds ONE Mosaic call whose results are its pool
+    operands (K and V, each the whole stacked pool ``[L * P, H, page, D]``),
+    and no scatter of the bfloat16 step has a pool-sized result: the row
+    scatter is off this path.  On the parent's tree the write was two such
+    scatters and no call aliased anything."""
+    ctx, compiled = compiled_step
+    model, eng = ctx["config"]["model"], ctx["cell"]["engine"]
+    heads = model["num_heads"]
+    stacked = (f"bf16[{LAYERS * PAGES},{heads},{eng['page_size']},"
+               f"{model['hidden_size'] // heads}]")
+    text = compiled.as_text()
+    assert pool_write_calls(text) == [{"aliased": [stacked, stacked],
+                                       "results": [0, 1]}]
+    layer_pool = PAGES * heads * eng["page_size"] * (model["hidden_size"] // heads) * 2
+    assert pool_scatters(text, layer_pool) == []
+    # the launch is attn.pool_write's (cache.pool_update_ms_per_step.* reads
+    # that scope by name), and the step leaves nothing unscoped
+    from paddle_tpu.telemetry import scopes
+
+    mapped = scopes.scopes_of_hlo_text(text)
+    launches = [s for name, s in mapped.items()
+                if re.search(rf"%?{re.escape(name)} = [^\n]*tpu_custom_call[^\n]*"
+                             r"output_to_operand_aliasing", text)]
+    assert [s.scope for s in launches] == ["layers/attn.core/attn.pool_write"]
+    assert not launches[0].carry
+    # what no rule gives a scope is the entry's prefetches of two arguments
+    # (the packed input, one weight), as before the launch: nothing of the write
+    unscoped = [n for n, s in mapped.items() if s.scope == scopes.UNSCOPED]
+    assert all(n.startswith(("copy-start", "copy-done")) for n in unscoped), unscoped
+    assert len(unscoped) <= 4, unscoped
 
 
 _DYNAMIC = -2 ** 63         # MLIR's ShapedType::kDynamic
@@ -238,9 +346,11 @@ def test_the_compiled_steps_ragged_launch_ends_at_the_item_count(compiled_step):
     heads = model["num_heads"]
     hb = ra.ragged_head_block(heads, eng["page_size"], model["hidden_size"] // heads,
                               eng["cache_dtype"])
-    bounds = mosaic_iteration_bounds(compiled.as_text())
-    assert bounds == [[heads // hb, _DYNAMIC]]
-    assert bounds == [[1, _DYNAMIC]]
+    # the step's other launch is the pool write's (PR 34), as long as the
+    # step's write list: one dynamic dimension
+    bounds = sorted(mosaic_iteration_bounds(compiled.as_text()), key=len)
+    assert bounds == [[_DYNAMIC], [heads // hb, _DYNAMIC]]
+    assert bounds[1] == [1, _DYNAMIC]
 
 
 @pytest.mark.parametrize("local_heads", [8, 20])
@@ -278,6 +388,45 @@ def test_the_ragged_kernel_compiles_at_the_sharded_local_heads(local_heads, topo
     assert text.count("tpu_custom_call") == 1
     assert ra.ragged_head_block(local_heads, page, dim, jnp.bfloat16) == local_heads
     assert mosaic_iteration_bounds(text) == [[1, _DYNAMIC]]
+
+
+@pytest.mark.parametrize("local_heads,pools", [(8, 2), (20, 2), (8, 1)])
+def test_the_pool_write_compiles_at_the_sharded_local_heads(local_heads, pools, topo):
+    """What ``shard_map`` over ``mp`` 2 hands the write (a block is ``(1,
+    H/mp, g, D)``: 8 local heads are half a bfloat16 tile of a fresh row)
+    and the hybrid cell's one K|V pool of 8 heads, at the chat cell's step
+    geometry: each compiles for the described v5e as one launch with a
+    dynamic length whose pool operands are its results."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas_kernels import pool_write as pw
+
+    ctx = manifest.resolve_cell(CELL)
+    model, eng = ctx["config"]["model"], ctx["cell"]["engine"]
+    page, dim = eng["page_size"], model["hidden_size"] // model["num_heads"]
+    g = pw.pool_write_group(jnp.bfloat16)
+    t_max = eng["num_slots"] + eng["prefill_token_budget"]
+    wr = t_max // g + 2 * eng["num_slots"]
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = struct((PAGES, local_heads, page, dim), jnp.bfloat16)
+    rows = struct((t_max, local_heads, dim), jnp.bfloat16)
+    i32 = jnp.int32
+    write_list = (struct((wr,), i32), struct((wr,), i32), struct((wr, g), i32),
+                  struct((wr,), i32), struct((wr,), i32), struct((1,), i32))
+    compiled = jax.jit(pw.pool_write, donate_argnums=(0,)).lower(
+        (pool,) * pools, (rows,) * pools, write_list).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert mosaic_iteration_bounds(text) == [[_DYNAMIC]]
+    (call,) = pool_write_calls(text)
+    assert call["aliased"] == [f"bf16[{PAGES},{local_heads},{page},{dim}]"] * pools
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 def test_the_lowered_loop_carries_the_pools():

@@ -214,6 +214,50 @@ def test_op_scopes_of_the_serving_step(engine):
     assert len(unscoped) <= len(mapped) // 10, unscoped
 
 
+def test_the_pool_writes_launch_is_attn_pool_writes(tiny, engine, monkeypatch):
+    """What a TPU makes of the write (PR 34): the models' own write takes the
+    tile-group launch (here interpreted, the choice patched in: the CPU never
+    observes a TPU).  Every instruction the launch leaves in the compiled
+    step resolves to ``layers/attn.core/attn.pool_write``, as the scatters it
+    replaces did (``cache.pool_update_ms_per_step.*`` reads that scope), and
+    no more of the step is unscoped than in the step that scatters
+    (``device.serve_unscoped_share.*``).  (That the step compiled for the
+    chip holds no pool-sized scatter is tests/test_pool_in_place.py's.)"""
+    from paddle_tpu.ops.pallas_kernels import pool_write as pw
+
+    cfg, model = tiny
+    launch = pw.pool_write
+    monkeypatch.setattr(pw, "pool_write_runs", pw.pool_write_supported)
+    monkeypatch.setattr(pw, "pool_write", lambda pools, rows, write_list:
+                        launch(pools, rows, write_list, interpret=True))
+    model.eval()
+    # the fixture's engine with pages a bfloat16 tile group divides
+    eng = ServingEngine(model, num_slots=2, page_size=16, max_context=32,
+                        prefill_token_budget=8)
+    try:
+        eng.submit(np.arange(11) % cfg.vocab_size, 2)
+        eng.run_until_idle(max_steps=50)
+        (mapped,) = eng.op_scopes()
+    finally:
+        eng.close()
+        model.train()
+    (scattering,) = engine.op_scopes()
+    write = {n: s for n, s in mapped.items() if _leaf(s) == "attn.pool_write"}
+    assert write
+    assert all(s.scope == "layers/attn.core/attn.pool_write" and not s.carry
+               for s in write.values())
+    # the interpreted launch is a loop over the write list inside the layer
+    # loop: it is the write's, not the carry's
+    inner = [s for n, s in mapped.items() if n.startswith("while")
+             and _leaf(s) == "attn.pool_write"]
+    assert inner
+
+    def unscoped(m):
+        return [n for n, s in m.items() if s.scope == scopes.UNSCOPED]
+
+    assert len(unscoped(mapped)) <= len(unscoped(scattering)), unscoped(mapped)
+
+
 def test_op_scopes_of_the_hybrid_serving_step(hybrid_engine):
     """The tail scatter is ``conv.state_write``'s, the K/V scatters
     ``attn.pool_write``'s; the period loop is ``layers`` and its carry; the
