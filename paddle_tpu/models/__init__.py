@@ -21,6 +21,9 @@ from .gpt import (  # noqa: F401
 from .lfm2 import (  # noqa: F401
     Lfm2Config, Lfm2StackedForCausalLM, lfm2_tiny,
 )
+from .phi4flash import (  # noqa: F401
+    Phi4FlashConfig, Phi4FlashForCausalLM, phi4flash_tiny,
+)
 from .ernie_moe import (  # noqa: F401
     ErnieMoEConfig, ErnieMoEForPretraining, ErnieMoEModel, ernie_moe_tiny,
 )

@@ -25,6 +25,7 @@ TPU-native design decisions:
 """
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 from typing import Optional
 
@@ -356,7 +357,8 @@ def _raw_attend_paged(qh, kh, vh, pkr, pvr, tables, posr, *, head_dim,
 
 
 def _attend_paged_shard(qh, kh, vh, pkr, pvr, tables, posr, *, head_dim,
-                        page_size, ragged_plan=None, ksr=None, vsr=None):
+                        page_size, ragged_plan=None, ksr=None, vsr=None,
+                        window=None, attend_scope=None):
     """Raw (traced) paged cache write + attend for continuous batching.
 
     qh/kh/vh: [S, N, C, D] head-major fresh projections (S decode slots —
@@ -366,7 +368,10 @@ def _attend_paged_shard(qh, kh, vh, pkr, pvr, tables, posr, *, head_dim,
     pkr/pvr: [P, N, page_size, D] global page pools; tables: [S, max_pages]
     int32 page tables (per-token rows on the ragged path); posr: [S]
     traced per-slot/per-token positions.  Returns
-    (out [S, N, C, D], new_k_pool, new_v_pool).
+    (out [S, N, C, D], new_k_pool, new_v_pool).  ``window`` (ragged path
+    only): the attention reads the newest ``window`` positions;
+    ``attend_scope`` names a scope around the ragged launch (a decoder with
+    several kinds of attention tells them apart by it).
 
     ``ksr``/``vsr`` ([P, N] fp32) switch on the int8-pool regime: the
     fresh K/V rows are quantized in-graph at scatter time
@@ -453,9 +458,14 @@ def _attend_paged_shard(qh, kh, vh, pkr, pvr, tables, posr, *, head_dim,
             pv2 = pvr.reshape(-1, d).at[rows].set(
                 vq.astype(pvr.dtype)).reshape(pvr.shape)
     if c == 1 and ragged_plan is not None:
-        out = ragged_paged_attention(qh[:, :, 0, :], pk2, pv2, tbl,
-                                     pos + 1, ragged_plan, sm_scale=scale,
-                                     k_scale=ks2, v_scale=vs2)
+        # the keyword only where a window is asked: the call every other
+        # decoder makes is the one it always was
+        windowed = {} if window is None else {"window": window}
+        with (jax.named_scope(attend_scope) if attend_scope
+              else contextlib.nullcontext()):
+            out = ragged_paged_attention(qh[:, :, 0, :], pk2, pv2, tbl,
+                                         pos + 1, ragged_plan, sm_scale=scale,
+                                         k_scale=ks2, v_scale=vs2, **windowed)
         out = out[:, :, None, :].astype(qh.dtype)
     elif c == 1:
         out = paged_attention(qh[:, :, 0, :], pk2, pv2, tbl, pos + 1,

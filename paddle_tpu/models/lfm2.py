@@ -53,6 +53,7 @@ from ..core.dtype import to_jax_dtype
 from ..nn.layer import Layer
 from ..ops import dispatch
 from ..tensor import Parameter, Tensor
+from .decoder_ops import gated_ffn as _gated_ffn, mm as _mm, rms_norm as _rms
 
 __all__ = ["Lfm2Config", "Lfm2StackedForCausalLM", "lfm2_tiny",
            "PUBLISHED_LAYER_TYPES"]
@@ -158,12 +159,6 @@ def lfm2_tiny(**kw) -> Lfm2Config:
 # the mathematics, on raw arrays
 # ---------------------------------------------------------------------------
 
-def _rms(x, g, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (y * g.astype(jnp.float32)).astype(x.dtype)
-
-
 def _rope(x, pos, theta):
     """Rotate-half rotary positions: ``x`` [..., heads, D] at ``pos`` [...]
     (dimension ``i`` pairs with ``i + D/2``)."""
@@ -176,16 +171,6 @@ def _rope(x, pos, theta):
     a, b = x32[..., :half], x32[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
                            axis=-1).astype(x.dtype)
-
-
-def _mm(x, w):
-    """``x @ w`` on the weights' dtype with float32 accumulation."""
-    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
-
-
-def _gated_ffn(u, w1, w3, w2):
-    y = (jax.nn.silu(_mm(u, w1)) * _mm(u, w3)).astype(u.dtype)
-    return _mm(y, w2).astype(u.dtype)
 
 
 def _route(cfg: Lfm2Config, u, router, bias):

@@ -295,12 +295,13 @@ def paged_attention(q, k_pool, v_pool, page_tables, lengths, *,
 
 
 def _xla_paged_reference(q, k_pool, v_pool, page_tables, lengths, scale,
-                         k_scale=None, v_scale=None):
+                         k_scale=None, v_scale=None, window=None):
     """jnp-composed reference: gather each slot's pages into a contiguous
     view, masked single-query attention, fp32 softmax (the fallback AND
     the parity oracle for tpu_smoke).  Matches
     ``decode_attention._xla_decode_reference`` on contiguous layouts;
-    length-0 slots return zeros (the kernel's inactive-slot semantics)."""
+    length-0 slots return zeros (the kernel's inactive-slot semantics).
+    ``window``: only the newest ``window`` of a slot's positions are read."""
     k = gather_pages(k_pool, page_tables, k_scale)
     v = gather_pages(v_pool, page_tables, v_scale)
     if q.shape[1] != k.shape[1]:
@@ -310,7 +311,10 @@ def _xla_paged_reference(q, k_pool, v_pool, page_tables, lengths, scale,
     s = jnp.einsum("shd,shkd->shk", q, k,
                    preferred_element_type=jnp.float32) * np.float32(scale)
     lengths = lengths.astype(jnp.int32)
-    valid = jnp.arange(k.shape[2], dtype=jnp.int32)[None, :] < lengths[:, None]
+    at = jnp.arange(k.shape[2], dtype=jnp.int32)[None, :]
+    valid = at < lengths[:, None]
+    if window is not None:
+        valid = valid & (at >= lengths[:, None] - np.int32(window))
     s = jnp.where(valid[:, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     p = jnp.where(lengths[:, None, None] > 0, p, jnp.zeros_like(p))

@@ -273,7 +273,8 @@ def _build_write_list(bases, counts, starts, tables, *, g: int,
 def build_ragged_plan(runs: Sequence[Tuple[int, int, np.ndarray]], *,
                       token_block: int, page_size: int,
                       t_max: int, nb_max: int, wl_max: int,
-                      write_group: int = 8, wr_max: Optional[int] = None
+                      write_group: int = 8, wr_max: Optional[int] = None,
+                      window: Optional[int] = None
                       ) -> Tuple[Dict[str, np.ndarray], Dict[str, int]]:
     """Flatten one fused step's work into the kernel's plan arrays.
 
@@ -301,6 +302,13 @@ def build_ragged_plan(runs: Sequence[Tuple[int, int, np.ndarray]], *,
     run a slot).
     No two items may name one group of one page: a repeat raises.
 
+    ``window``: the plan of a window layer.  A block's items are then only
+    the page-slots that hold a position one of its rows may read
+    (``> base_pos - window``), and ``table_row`` may be a RING: page-slot
+    ``j`` of a slot whose ring has ``R`` pages names ring page ``j mod R``
+    (``wl_pageslot`` stays the logical ``j``, which is what the kernel's
+    position arithmetic reads).
+
     Returns ``(plan_arrays, stats)``: the arrays keyed by
     :data:`RAGGED_PLAN_FIELDS`, and stats with ``n_tokens``/``n_blocks``/
     ``n_items``/``n_writes``/``run_starts``, the occupancy numerators the
@@ -311,57 +319,67 @@ def build_ragged_plan(runs: Sequence[Tuple[int, int, np.ndarray]], *,
         raise ValueError(f"write_group={g} must divide page_size={page_size}")
     if wr_max is None:
         wr_max = ragged_write_capacity(t_max, g, nb_max)
+    if not runs:
+        raise ValueError("empty plan: the fused step must not be "
+                         "dispatched with no runs")
+    # every run at once (numpy): a step of 64 decode slots and a long
+    # chunk is some hundred blocks and a thousand items, and a Python loop
+    # over them cost the host milliseconds a step
+    bases = np.array([r[0] for r in runs], np.int64)
+    counts = np.array([r[1] for r in runs], np.int64)
+    tables = np.stack([np.asarray(r[2]) for r in runs])
+    if (counts < 1).any():
+        raise ValueError(f"run with count={int(counts.min())}; every run "
+                         "must carry at least one token")
+    starts = np.cumsum(counts) - counts             # a run's first flat token
+    t = int(counts.sum())
+    if t > t_max:
+        raise ValueError(f"plan overflow: {t} tokens > t_max={t_max}")
+    run_blocks = -(-counts // qb)
+    b = int(run_blocks.sum())
+    if b > nb_max:
+        raise ValueError(f"plan overflow: {b} blocks > nb_max={nb_max}")
+    run_starts: List[int] = [int(x) for x in starts]
+    blk_run = np.repeat(np.arange(len(runs)), run_blocks)
+    first_blk = np.cumsum(run_blocks) - run_blocks
+    off = (np.arange(b) - first_blk[blk_run]) * qb  # a block's offset in its run
+    rows = np.minimum(qb, counts[blk_run] - off)
+    lane = np.arange(qb)
     blk_tok = np.zeros((nb_max, qb), np.int32)
-    tok_blk = np.zeros((t_max,), np.int32)
-    tok_row = np.zeros((t_max,), np.int32)
+    # a block's padding rows point at its first token
+    blk_tok[:b] = ((starts[blk_run] + off)[:, None]
+                   + np.where(lane[None, :] < rows[:, None], lane[None, :], 0))
     blk_base = np.zeros((nb_max,), np.int32)
+    blk_base[:b] = bases[blk_run] + off
     blk_rows = np.zeros((nb_max,), np.int32)
-    items: List[Tuple[int, int, int]] = []     # (block, pool page, page-slot)
-    t = 0
-    b = 0
-    run_starts: List[int] = []
-    for base, count, table in runs:
-        base, count = int(base), int(count)
-        if count < 1:
-            raise ValueError(f"run with count={count}; every run must "
-                             "carry at least one token")
-        run_starts.append(t)
-        if t + count > t_max:
-            raise ValueError(f"plan overflow: {t + count} tokens > "
-                             f"t_max={t_max}")
-        off = 0
-        while off < count:
-            rows = min(qb, count - off)
-            if b >= nb_max:
-                raise ValueError(f"plan overflow: block {b} >= "
-                                 f"nb_max={nb_max}")
-            blk_tok[b, :rows] = np.arange(t + off, t + off + rows, dtype=np.int32)
-            blk_tok[b, rows:] = t + off
-            blk_base[b] = base + off
-            blk_rows[b] = rows
-            tok_blk[t + off:t + off + rows] = b
-            tok_row[t + off:t + off + rows] = np.arange(rows, dtype=np.int32)
-            last_pos = base + off + rows - 1
-            n_pages = last_pos // page_size + 1
-            for ps_i in range(n_pages):
-                items.append((b, int(table[ps_i]), ps_i))
-            off += rows
-            b += 1
-        t += count
-    n_items = len(items)
+    blk_rows[:b] = rows
+    tok_run = np.repeat(np.arange(len(runs)), counts)
+    within = np.arange(t) - starts[tok_run]
+    tok_blk = np.zeros((t_max,), np.int32)
+    tok_blk[:t] = first_blk[tok_run] + within // qb
+    tok_row = np.zeros((t_max,), np.int32)
+    tok_row[:t] = within % qb
+    # a block's items: the page-slots up to its last row's, from the first
+    # its window reaches (the first of all without one)
+    last_slot = (blk_base[:b].astype(np.int64) + rows - 1) // page_size
+    first_slot = (np.zeros((b,), np.int64) if window is None else
+                  np.maximum(blk_base[:b] - int(window) + 1, 0) // page_size)
+    per_blk = last_slot - first_slot + 1
+    n_items = int(per_blk.sum())
     if n_items > wl_max:
         raise ValueError(f"plan overflow: {n_items} work items > "
                          f"wl_max={wl_max}")
-    if n_items == 0:
-        raise ValueError("empty plan: the fused step must not be "
-                         "dispatched with no runs")
-    wl_blk = np.full((wl_max,), items[-1][0], np.int32)
-    wl_page = np.full((wl_max,), items[-1][1], np.int32)
-    wl_ps = np.full((wl_max,), items[-1][2], np.int32)
-    for w, (bi, pg, psi) in enumerate(items):
-        wl_blk[w] = bi
-        wl_page[w] = pg
-        wl_ps[w] = psi
+    item_blk = np.repeat(np.arange(b), per_blk)
+    item_slot = (first_slot[item_blk] + np.arange(n_items)
+                 - (np.cumsum(per_blk) - per_blk)[item_blk])
+    item_page = tables[blk_run[item_blk], item_slot]
+    # the tail repeats the last real item: valid indices, never walked
+    wl_blk = np.full((wl_max,), item_blk[-1], np.int32)
+    wl_page = np.full((wl_max,), item_page[-1], np.int32)
+    wl_ps = np.full((wl_max,), item_slot[-1], np.int32)
+    wl_blk[:n_items] = item_blk
+    wl_page[:n_items] = item_page
+    wl_ps[:n_items] = item_slot
     plan = {
         "blk_tok": blk_tok, "tok_blk": tok_blk, "tok_row": tok_row,
         "blk_base": blk_base, "blk_rows": blk_rows,
@@ -369,10 +387,7 @@ def build_ragged_plan(runs: Sequence[Tuple[int, int, np.ndarray]], *,
         "n_items": np.array([n_items], np.int32),
     }
     plan.update(_build_write_list(
-        np.array([r[0] for r in runs], np.int64),
-        np.array([r[1] for r in runs], np.int64),
-        np.array(run_starts, np.int64),
-        np.stack([np.asarray(r[2]) for r in runs]),
+        bases, counts, starts, tables,
         g=g, page_size=page_size, t_max=t_max, wr_max=int(wr_max)))
     stats = {
         "n_tokens": t, "n_blocks": b, "n_items": n_items,
@@ -395,7 +410,7 @@ def build_ragged_plan(runs: Sequence[Tuple[int, int, np.ndarray]], *,
 
 def _ragged_kernel(blk_ref, page_ref, ps_ref, ni_ref, base_ref, rows_ref,
                    q_ref, k_ref, v_ref, *rest, scale, page_size, wl_max,
-                   quantized=False, group=1):
+                   quantized=False, group=1, window=None):
     # quantized pools carry two extra (1, hb) scale inputs whose index map
     # mirrors the KV page index — each page's per-head absmax scales ride
     # the same scalar-prefetched translation, so the dequant multiply
@@ -437,6 +452,10 @@ def _ragged_kernel(blk_ref, page_ref, ps_ref, ni_ref, base_ref, rows_ref,
         jnp.int32, (qb, page_size), 1)
     row_pos = base_ref[blk] + rows
     valid = jnp.logical_and(cols <= row_pos, rows < rows_ref[blk])
+    if window is not None:
+        # a window layer: the newest ``window`` positions, the row's own
+        # among them (static, so that without one the body is what it was)
+        valid = jnp.logical_and(valid, cols > row_pos - np.int32(window))
 
     # the item's hb heads as ONE batched chain: each head's operations are
     # those a grid step ran while a step moved one head, in the same order
@@ -481,13 +500,15 @@ def _ragged_kernel(blk_ref, page_ref, ps_ref, ni_ref, base_ref, rows_ref,
 
 def _ragged_pallas(q_blocks, k_pool, v_pool, wl_blk, wl_page, wl_ps,
                    n_items, blk_base, blk_rows, scale, interpret=False,
-                   k_scale=None, v_scale=None, head_block=None, group=1):
+                   k_scale=None, v_scale=None, head_block=None, group=1,
+                   window=None):
     """q_blocks: [NB, H, QB, D] host-packed token blocks; k/v pool:
     [P, H, page_size, D]; work-list + per-block arrays as documented on
     :data:`RAGGED_PLAN_FIELDS` -> [NB, H, QB, D].  ``interpret=True`` runs
     the Pallas interpreter (CPU numerics check).  ``group`` > 1: each of
     the pool's ``H`` heads serves ``group`` query heads, folded into the
-    block's rows (``QB`` here is ``group`` x the token block).
+    block's rows (``QB`` here is ``group`` x the token block).  ``window``
+    (a static int) masks keys at ``pos_k <= pos_q - window`` as well.
 
     The grid is ``(H // hb, n_items)`` — head blocks parallel, work items
     sequential so a block's online softmax accumulates across its pages —
@@ -519,7 +540,9 @@ def _ragged_pallas(q_blocks, k_pool, v_pool, wl_blk, wl_page, wl_ps,
             f"query heads onto the pool's {k_pool.shape[1]} heads")
     kernel = functools.partial(_ragged_kernel, scale=scale,
                                page_size=page_size, wl_max=wl_max,
-                               quantized=quantized, group=int(group))
+                               quantized=quantized, group=int(group),
+                               **({} if window is None
+                                  else {"window": int(window)}))
 
     # hh: the grid step's head BLOCK (heads hh*hb .. hh*hb + hb - 1)
     def q_index(hh, w, blk_ref, page_ref, ps_ref, ni_ref, base_ref,
@@ -578,7 +601,7 @@ def _ragged_pallas(q_blocks, k_pool, v_pool, wl_blk, wl_page, wl_ps,
 @jax.named_scope("kernel.ragged")
 def ragged_paged_attention(q, k_pool, v_pool, token_tables, lengths, plan,
                            *, sm_scale=None, interpret=False,
-                           k_scale=None, v_scale=None):
+                           k_scale=None, v_scale=None, window=None):
     """Token-granular attention over the paged KV pool for one fused
     mixed prefill/decode step.
 
@@ -599,6 +622,11 @@ def ragged_paged_attention(q, k_pool, v_pool, token_tables, lengths, plan,
                   pool is int8 (docs/serving.md "Quantized serving") —
                   dequant happens INSIDE the kernel right after each
                   page DMA; the output is then fp32
+    window:       a static int or None: a token attends the newest
+                  ``window`` positions up to its own only (keys at ``pos_k
+                  <= pos_q - window`` are masked); the plan then lists only
+                  the pages that hold them (``build_ragged_plan(window=)``)
+                  and ``token_tables`` may name any page elsewhere
     returns       [T, Hq, D]
 
     Routes to the Pallas ragged kernel on TPU when the layout is eligible,
@@ -633,6 +661,8 @@ def ragged_paged_attention(q, k_pool, v_pool, token_tables, lengths, plan,
                                (0, 2, 3, 1, 4)).reshape(nb, h, group * qb, d)
         # one query head a pool head: the call the launch always was
         grouped = {} if group == 1 else {"group": group}
+        if window is not None:
+            grouped["window"] = int(window)
         out = _ragged_pallas(qg, k_pool, v_pool, wl_blk, wl_page, wl_ps,
                              n_items, blk_base, blk_rows, scale,
                              interpret=interpret,
@@ -645,11 +675,12 @@ def ragged_paged_attention(q, k_pool, v_pool, token_tables, lengths, plan,
         idx = tok_blk.astype(jnp.int32) * qb + tok_row.astype(jnp.int32)
         return jnp.take(flat, idx, axis=0)
     return _xla_ragged_reference(q, k_pool, v_pool, token_tables, lengths,
-                                 scale, k_scale=k_scale, v_scale=v_scale)
+                                 scale, k_scale=k_scale, v_scale=v_scale,
+                                 window=window)
 
 
 def _xla_ragged_reference(q, k_pool, v_pool, token_tables, lengths, scale,
-                          k_scale=None, v_scale=None):
+                          k_scale=None, v_scale=None, window=None):
     """jnp-composed reference: the paged gather oracle applied per TOKEN —
     each flat query token gathers its slot's pages and runs masked
     single-query attention over its own ``length`` positions (fp32
@@ -663,4 +694,5 @@ def _xla_ragged_reference(q, k_pool, v_pool, token_tables, lengths, scale,
     from .paged_attention import _xla_paged_reference
 
     return _xla_paged_reference(q, k_pool, v_pool, token_tables, lengths,
-                                scale, k_scale=k_scale, v_scale=v_scale)
+                                scale, k_scale=k_scale, v_scale=v_scale,
+                                window=window)

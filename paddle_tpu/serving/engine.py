@@ -585,7 +585,8 @@ class ServingEngine:
             mp=mesh is not None and _srv_mesh.mp_size(mesh) > 1,
             kv_int8=str(kv_dtype or cache_dtype) == "int8",
             weight_int8=weight_dtype is not None,
-            disagg=role not in (None, "colocated"))
+            disagg=role not in (None, "colocated"),
+            prefix_cache=bool(prefix_cache))
         # disaggregated serving (serving/disagg.py): the replica's role
         # ("prefill" | "decode" | "colocated").  Passing it explicitly
         # adds a ``role`` label to every per-engine metric child (the
@@ -667,6 +668,10 @@ class ServingEngine:
         self.prefill_token_budget = prefill_token_budget
         self.cache_dtype = str(cache_dtype)
         self.num_pages = int(num_pages)
+        # a model whose cache keeps state by slot (SlotStateCache) says so:
+        # the cache is sized by the slots and a step's longest run, packs
+        # its own fields beside the plan and is told of every harvest
+        self._slot_state = bool(getattr(model, "slot_resident_state", False))
         self.cache = self._new_pool()
         self.allocator = BlockAllocator(num_pages)
         self.scheduler = AdmissionScheduler(num_slots, max_pages_per_slot,
@@ -698,7 +703,8 @@ class ServingEngine:
         # (the row the kernel sees is the pool's: wider than a head where a
         # cache keeps K and V side by side)
         self.head_dim = int(getattr(self.cache, "row_dim", cfg.head_dim))
-        local_heads = pool_heads // self._mp
+        local_heads = int(getattr(self.cache, "num_heads",
+                                  pool_heads)) // self._mp
         self.token_block = ragged_token_block(
             self.page_size, self.head_dim, self.cache_dtype,
             local_heads=local_heads if self._mp > 1 else None)
@@ -907,16 +913,24 @@ class ServingEngine:
 
     def _extra_pack_fields(self) -> list:
         """Extra (name, shape) int32 fields appended to the packed step
-        input (subclass hook; the speculative engine adds the draft
-        tokens and per-slot draft counts)."""
-        return []
+        input: what a cache with slot-resident state ships beside the plan
+        (its second work list, each row's slot, each run's state rows).
+        A subclass hook too (the speculative engine adds the draft tokens
+        and per-slot draft counts)."""
+        if not self._slot_state:
+            return []
+        return self.cache.pack_fields(**self._plan_geometry)
 
     def _new_pool(self):
         """A fresh page pool, committed to the replica mesh (per-head
         sharded over 'mp') when this engine is mesh-sharded.  Used at init
         and by ``_rebuild``."""
+        by_slot = (dict(num_slots=self.num_slots,
+                        max_run=self.prefill_token_budget)
+                   if self._slot_state else {})
         cache = self.model.new_paged_kv_cache(self.num_pages, self.page_size,
-                                              dtype=self.cache_dtype)
+                                              dtype=self.cache_dtype,
+                                              **by_slot)
         if self.mesh is not None:
             _srv_mesh.shard_paged_cache(cache, self.mesh)
         return cache
@@ -957,6 +971,9 @@ class ServingEngine:
         generator = self._generator
         lora_pool = self.lora
         n_plan = len(RAGGED_PLAN_FIELDS)
+        n_lora = int(lora_pool is not None)
+        by_slot = ([name for name, _ in self._extra_pack_fields()]
+                   if self._slot_state else [])
 
         def _mk_fused(with_sampling):
             def fused_step(ids, packed, temp, top_p, top_k, do_sample):
@@ -974,12 +991,15 @@ class ServingEngine:
                 # the serving-mesh context is TRACE-time state: the paged
                 # attention path reads it to shard_map the scatter+attend
                 # per head shard over 'mp' (no-op for mesh=None)
+                # what a cache with slot-resident state packed, by name
+                extra = ({"slot_state": dict(zip(
+                    by_slot, rest[n_plan + n_lora:]))} if by_slot else {})
                 with _srv_mesh.activate(mesh), dispatch.no_grad():
                     logits = model._paged_lm_logits(ids, cache,
                                                     token_tables, positions,
                                                     ragged_plan=plan,
                                                     out_rows=out_rows,
-                                                    lora=lora_in)
+                                                    lora=lora_in, **extra)
                     with jax.named_scope("serve.sample"):
                         rows = _drop_seq_axis(logits).astype("float32")
                         fin = _slotwise_finite(rows)
@@ -1247,6 +1267,11 @@ class ServingEngine:
             runs, page_size=self.page_size, **self._plan_geometry)
         for k in RAGGED_PLAN_FIELDS:
             view(k)[...] = plan[k]
+        if self._slot_state:
+            stats["slot_runs"] = [(w.slot, w.base, w.count) for w in work]
+            stats["window_items"] = self.cache.pack_step(
+                view, stats["slot_runs"], tables.shape[1],
+                self._plan_geometry)["n_items"]
         return (ids[:, None], packed), stats
 
     def _fused_thunk(self, fused, inputs, cancelled, extra_dev=()):
@@ -1300,6 +1325,9 @@ class ServingEngine:
         self._hook("after_decode", ctx)
         sched = self.scheduler
         self._fold_plan_stats(work, stats)
+        if self._slot_state:
+            # the step's results are in hand: its slots' state rows swap
+            self.cache.commit_step(stats["slot_runs"], stats["window_items"])
         for w in work:
             slot = sched.slots[w.slot]
             if slot is None:

@@ -34,16 +34,17 @@ attention kernels right after each page DMA.
 from __future__ import annotations
 
 from collections import deque
-from typing import List, Optional
+from typing import List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
 from ..core.dtype import to_jax_dtype
 from ..models.generation import _KVBuffers
 from ..tensor import Tensor
 
 __all__ = ["NULL_PAGE", "PagedKVCache", "HybridPagedCache",
-           "BlockAllocator", "pages_for_tokens"]
+           "SlotStateCache", "BlockAllocator", "pages_for_tokens"]
 
 # pool page 0: reserved sink for inactive-slot / padding writes
 NULL_PAGE = 0
@@ -262,6 +263,197 @@ class HybridPagedCache(_KVBuffers):
         experts = log[:-2, live].reshape(self.routed_layers, self.top_k, -1)
         return {"positions": log[-2, live], "pages": log[-1, live],
                 "experts": experts.transpose(2, 0, 1)}
+
+
+class SlotStateCache(_KVBuffers):
+    """Three kinds of state for a decoder of state-space, window-attention
+    and full-attention layers whose later layers read ONE layer's keys and
+    values (docs/serving.md "State that lives in the slot"):
+
+    - ``k`` / ``v`` ``[1, P, H, page_size, W]``: the one full-attention
+      layer's K and V, paged on the ``BlockAllocator`` ledger like any pool
+      (``P`` = ``num_pages``, page 0 the null page).  The only state that
+      grows with a request's context, and the only one the allocator, the
+      scheduler and ``pages_used`` see.  ``H`` and ``W`` are what the ragged
+      kernel sees as heads and head width (a model may fold two heads into
+      a row of 128: ``num_heads`` / ``row_dim`` say so to the engine).
+    - ``ring_k`` / ``ring_v`` ``[L_win, num_slots * R + 1, H, page_size,
+      W]``: a RING of ``R`` pages a slot and window layer, which the
+      allocator never sees.  Slot ``s`` owns ring pages ``s * R + 1 ..
+      s * R + R``; position ``p`` lies in its ring page ``(p // page_size)
+      mod R``; ring page 0 is the sink of padding rows.  ``R =
+      ceil((window - 1 + max_run) / page_size) + 1`` holds every position a
+      step's rows may read (the window behind a run's first row, and the
+      run, which is written before it is read) whatever the run's
+      alignment, so a page is overwritten only by positions ``R`` pages on,
+      which no row of the step reads (the window mask hides them).
+    - ``ssm`` ``[L_ssm, 2 * num_slots + 1, blocks, d_state, sub, lanes]``
+      float32 and ``conv`` ``[L_ssm, 2 * num_slots + 1, taps * d_inner /
+      128, 128]``: a state-space layer's recurrent state (the channels as
+      whole ``(sub, lanes)`` tiles a state index, the layout
+      ops/pallas_kernels/selective_scan.py holds in VMEM) and its
+      convolution's last ``taps`` inputs, a ROW a slot; row 0 is the sink.
+      A slot owns TWO rows and a step reads one and writes the other
+      (``state_rows``): the recurrence is not idempotent as a K/V row write
+      is, so a step that is dispatched again (the engine retries a failed
+      step once) must find the state the last COMPLETED step left, and it
+      does, because the rows swap only when a step's results are harvested
+      (``commit_step``).
+
+    A run that starts at position 0 starts from zero state and reads no
+    ring page older than itself (the window mask is by position), whatever
+    the slot held before: seating a slot again needs no clearing pass.
+
+    The host's part of a step lives here too (the engine calls it and knows
+    no layer kinds): :meth:`pack_fields` names what rides the packed step
+    input beside the plan, :meth:`pack_step` fills it (the second work list
+    over the ring, each row's slot, each run's rows and state rows) and
+    :meth:`commit_step` swaps the state rows of the slots a harvested step
+    served.  :meth:`counts` reports :data:`COUNTER_NAMES` (host integers:
+    ``ServingEngine.metrics()`` merges them)."""
+
+    paged = True
+    quantized = False
+    COUNTER_NAMES = ("window_work_items", "ssm_runs", "ssm_rows",
+                     "cross_rows")
+    #: the fields of the packed step input, in order
+    WINDOW_FIELDS = ("wl_blk", "wl_page", "wl_pageslot", "n_items",
+                     "wr_page")
+
+    def __init__(self, *, num_pages: int, page_size: int, num_slots: int,
+                 max_run: int, num_heads: int, row_dim: int, window: int,
+                 window_layers: int, ssm_layers: int, d_inner: int,
+                 d_state: int, conv_taps: int, dtype: str = "bfloat16"):
+        if str(dtype) == "int8":
+            raise ValueError(
+                "SlotStateCache: an int8 pool is not supported (the ring "
+                "and the state rows have no scale sidecar)")
+        if num_pages < 2:
+            raise ValueError(
+                f"num_pages={num_pages}: the pool needs the null page plus "
+                "at least one allocatable page")
+        jd = to_jax_dtype(dtype)
+        self.num_layers = 1
+        self.num_pages = num_pages
+        self.num_heads = num_heads
+        self.page_size = page_size
+        self.head_dim = self.row_dim = row_dim
+        self.dtype = str(dtype)
+        self.num_slots, self.max_run, self.window = num_slots, max_run, window
+        self.window_layers, self.ssm_layers = window_layers, ssm_layers
+        self.ring_pages = pages_for_tokens(window - 1 + max_run, page_size) + 1
+        ring = num_slots * self.ring_pages + 1
+        rows = 2 * num_slots + 1
+        _check_row_index(num_pages, num_heads, page_size)
+        _check_row_index(window_layers, ring, num_heads, page_size)
+        _check_row_index(ssm_layers, rows, conv_taps)
+        pool = (1, num_pages, num_heads, page_size, row_dim)
+        self.k = Tensor(jnp.zeros(pool, jd))
+        self.v = Tensor(jnp.zeros(pool, jd))
+        wide = (window_layers, ring, num_heads, page_size, row_dim)
+        self.ring_k = Tensor(jnp.zeros(wide, jd))
+        self.ring_v = Tensor(jnp.zeros(wide, jd))
+        lanes = 128 if d_inner % 128 == 0 else d_inner
+        sub = 8 if d_inner % (8 * lanes) == 0 else 1
+        self.ssm = Tensor(jnp.zeros(
+            (ssm_layers, rows, d_inner // (sub * lanes), d_state, sub, lanes),
+            jnp.float32))
+        tail = conv_taps * d_inner
+        slab = (tail // 128, 128) if tail % 128 == 0 else (1, tail)
+        self.conv = Tensor(jnp.zeros((ssm_layers, rows) + slab, jd))
+        # which of its two state rows a slot's next step READS (0 or 1)
+        self._flip = np.zeros((num_slots,), np.int64)
+        self._tables = None         # the slots' ring tables, made on first use
+        self._counts = dict.fromkeys(self.COUNTER_NAMES, 0)
+
+    def _tensors(self):
+        return [self.k, self.v, self.ring_k, self.ring_v, self.ssm, self.conv]
+
+    # -- the host's part of a step ------------------------------------------
+    def state_rows(self, slot: int) -> Tuple[int, int]:
+        """``(read, write)``: the state rows slot ``slot``'s next step loads
+        from and stores to."""
+        first = 1 + 2 * int(slot)
+        flip = int(self._flip[slot])
+        return first + flip, first + 1 - flip
+
+    def ring_table(self, slot: int, max_pages: int) -> np.ndarray:
+        """Slot ``slot``'s ring as a page-table row: page-slot ``j`` names
+        ring page ``j mod R`` of the slot's ``R``."""
+        return self._ring_tables(max_pages)[slot]
+
+    def _ring_tables(self, max_pages: int) -> np.ndarray:
+        """Every slot's ring table ``[num_slots, max_pages]``, made once."""
+        if self._tables is None or self._tables.shape[1] != max_pages:
+            r = self.ring_pages
+            self._tables = (
+                1 + np.arange(self.num_slots, dtype=np.int32)[:, None] * r
+                + np.arange(max_pages, dtype=np.int32)[None, :] % r)
+        return self._tables
+
+    def pack_fields(self, *, t_max: int, nb_max: int, wr_max: int, **_):
+        """``[(name, shape)]`` of what a step ships beside its plan."""
+        s = self.num_slots
+        wl = nb_max * self.ring_pages       # a block reads fewer than R pages
+        return [("win_wl_blk", (wl,)), ("win_wl_page", (wl,)),
+                ("win_wl_pageslot", (wl,)),
+                ("win_n_items", (1,)), ("win_wr_page", (wr_max,)),
+                ("row_slot", (t_max,)),
+                ("run_first", (s,)), ("run_count", (s,)),
+                ("run_src", (s,)), ("run_dst", (s,)), ("n_runs", (1,))]
+
+    def pack_step(self, view, runs: Sequence[Tuple[int, int, int]],
+                  max_pages: int, plan_geometry: dict) -> dict:
+        """Fill the fields of :meth:`pack_fields` (``view(name)`` is each
+        one's int32 array, zeroed) for a step of ``runs`` ``(slot, base,
+        count)`` in flat-token order; returns the second plan's stats."""
+        from ..ops.pallas_kernels.ragged_paged_attention import (
+            build_ragged_plan,
+        )
+
+        n = len(runs)
+        if n > self.num_slots:
+            raise ValueError(f"{n} runs in a step of {self.num_slots} "
+                             "slots: a slot has one")
+        geometry = dict(plan_geometry,
+                        wl_max=plan_geometry["nb_max"] * self.ring_pages)
+        plan, stats = build_ragged_plan(
+            [(base, count, self.ring_table(slot, max_pages))
+             for slot, base, count in runs],
+            page_size=self.page_size, window=self.window, **geometry)
+        for f in self.WINDOW_FIELDS:
+            view("win_" + f)[...] = plan[f]
+        slots = np.array([r[0] for r in runs], np.int64)
+        counts = np.array([r[2] for r in runs], np.int64)
+        first = np.cumsum(counts) - counts
+        src = 1 + 2 * slots + self._flip[slots]
+        dst = 1 + 2 * slots + 1 - self._flip[slots]
+        t = int(counts.sum())
+        of_row = np.repeat(np.arange(n), counts)
+        row_slot = view("row_slot")
+        row_slot[...] = -1
+        row_slot[:t] = slots[of_row]
+        view("run_first")[:n], view("run_count")[:n] = first, counts
+        view("run_src")[:n], view("run_dst")[:n] = src, dst
+        view("n_runs")[0] = n
+        return stats
+
+    def commit_step(self, runs: Sequence[Tuple[int, int, int]],
+                    window_items: int):
+        """A step over ``runs`` was harvested: its slots' state rows swap,
+        and the counters take what it did."""
+        rows = 0
+        for slot, _, count in runs:
+            self._flip[slot] ^= 1
+            rows += count
+        c = self._counts
+        c["window_work_items"] += int(window_items)
+        c["ssm_runs"] += len(runs)
+        c["ssm_rows"] += rows
+        c["cross_rows"] += rows
+
+    def counts(self) -> dict:
+        return dict(self._counts)
 
 
 class BlockAllocator:

@@ -25,6 +25,8 @@ SCOPES = (
     "kernel.fused_adamw", "kernel.rms_norm",
     "attn.rope", "conv.proj", "conv.mix", "conv.state_write",
     "moe.route", "moe.experts", "kernel.gmm",
+    "ssm.proj", "ssm.conv", "ssm.scan", "ssm.state_write", "kernel.ssm_scan",
+    "gmu", "attn.window", "attn.shared", "attn.diff",
 )
 UNSCOPED = "unscoped"
 _VOCABULARY = frozenset(SCOPES)

@@ -193,6 +193,59 @@ def test_no_operation_of_the_hybrid_step_moves_a_layers_experts_or_a_pool(topo):
     assert memory.temp_size_in_bytes < 0.05 * (kv_pool + tail_pool) + 3.2 * one_matrix_stack
 
 
+SLOT_STATE_CELL = "phi4_mini_flash.serve_longctx_sat"
+_RESULT = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(\(.*?\)|\S+)\s+([\w\-]+)\(")
+
+
+def test_no_operation_of_the_slot_state_step_moves_a_pool(topo):
+    """The decoder-hybrid-decoder's fused step at its published widths, 8
+    layers (every kind) and a paged pool of 1,024 pages, compiled for the
+    described chip: all six pools (the paged K and V, the window ring's K and
+    V, the state-space rows and the convolution's) are donated and aliased,
+    and every operation whose result has a pool's shape is plumbing, a
+    Mosaic launch whose pool operands are its results (the pool write, the
+    scan) or the state rows' scatter: nothing copies one.  (The convolution's
+    rows, 12 MB here and 36 MB in the cell, less than one activation, the
+    compiler stages through the chip's on-chip memory, layout ``S(1)``, and
+    back: that pool is held to its aliasing alone.)"""
+    pages = 1024
+    ctx = manifest.resolve_cell(SLOT_STATE_CELL)
+    model, eng = ctx["config"]["model"], ctx["cell"]["engine"]
+    model["num_layers"] = 8
+    eng["num_pages"] = pages
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(aot_compile, "_report", lambda compiled: compiled)
+        compiled = aot_compile.serve_step(ctx, topo)
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    slots, page = eng["num_slots"], eng["page_size"]
+    ring = slots * 7 + 1                    # 7 ring pages a slot: window + a run
+    rows = 2 * slots + 1
+    pools = {f"bf16[{pages},10,{page},128]": "kv", f"bf16[1,{pages},10,{page},128]": "kv",
+             f"bf16[{2 * ring},10,{page},128]": "ring", f"bf16[2,{ring},10,{page},128]": "ring",
+             f"f32[{3 * rows},5,16,8,128]": "ssm", f"f32[3,{rows},5,16,8,128]": "ssm",
+             f"bf16[{3 * rows},120,128]": "conv", f"bf16[3,{rows},120,128]": "conv"}
+    nbytes = (2 * pages * 10 * page * 128 * 2 + 2 * 2 * ring * 10 * page * 128 * 2
+              + 3 * rows * 5 * 16 * 8 * 128 * 4 + 3 * rows * 120 * 128 * 2)
+    assert memory.alias_size_in_bytes >= nbytes
+    assert memory.temp_size_in_bytes < 0.05 * nbytes, memory.temp_size_in_bytes
+    # the window layers' write, launch and scan in the loop; the memory layer's
+    # scan; the full-attention layer's write and launch; the cross layers' launch
+    assert text.count("tpu_custom_call") == 7
+    assert mosaic_iteration_bounds(text).count([_DYNAMIC]) >= 2      # the writes
+    movers = []
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if m is None or not any(shape in m.group(1) for shape in pools):
+            continue
+        op = m.group(2)
+        aliased = op == "custom-call" and "output_to_operand_aliasing={" in line
+        scatter = op == "fusion" and "ssm.state_write/scatter" in line
+        staged = pools[next(sh for sh in pools if sh in m.group(1))] == "conv"
+        if op not in STILL and not aliased and not scatter and not staged:
+            movers.append(line.strip()[:160])
+    assert movers == []
+
+
 def test_no_operation_of_the_compiled_step_moves_a_layers_pool(compiled_step):
     ctx, compiled = compiled_step
     model, eng = ctx["config"]["model"], ctx["cell"]["engine"]

@@ -33,6 +33,8 @@ VOCABULARY = (
     "kernel.fused_adamw", "kernel.rms_norm",
     "attn.rope", "conv.proj", "conv.mix", "conv.state_write",
     "moe.route", "moe.experts", "kernel.gmm",
+    "ssm.proj", "ssm.conv", "ssm.scan", "ssm.state_write", "kernel.ssm_scan",
+    "gmu", "attn.window", "attn.shared", "attn.diff",
 )
 
 # where each scope must be found
@@ -46,6 +48,14 @@ IN_HYBRID = ("serve.unpack", "serve.sample", "embed", "layers", "attn.qkv",
              "attn.rope", "attn.core", "attn.pool_write", "kernel.ragged",
              "attn.out", "conv.proj", "conv.mix", "conv.state_write", "mlp",
              "moe.route", "moe.experts", "kernel.gmm", "lm_head")
+
+
+# the serving step of the decoder-hybrid-decoder (state-space, window, full
+# and cross layers, gated memory units)
+IN_SLOT_STATE = ("serve.unpack", "serve.sample", "embed", "layers", "attn.qkv",
+                 "attn.window", "attn.shared", "attn.diff", "attn.pool_write",
+                 "kernel.ragged", "attn.out", "ssm.proj", "ssm.conv", "ssm.scan",
+                 "ssm.state_write", "kernel.ssm_scan", "gmu", "mlp", "lm_head")
 
 
 def _leaf(s):
@@ -96,6 +106,21 @@ def hybrid_engine():
 
     pt.seed(0)
     model = Lfm2StackedForCausalLM(lfm2_tiny(num_hidden_layers=10))   # two periods: a loop
+    model.eval()
+    eng = ServingEngine(model, num_slots=2, page_size=8, max_context=32,
+                        prefill_token_budget=8, cache_dtype="float32")
+    eng.submit(np.arange(11), 2)
+    eng.run_until_idle(max_steps=50)
+    yield eng
+    eng.close()
+
+
+@pytest.fixture(scope="module")
+def slot_state_engine():
+    from paddle_tpu.models import Phi4FlashForCausalLM, phi4flash_tiny
+
+    pt.seed(0)
+    model = Phi4FlashForCausalLM(phi4flash_tiny(num_hidden_layers=12))  # both loops loop
     model.eval()
     eng = ServingEngine(model, num_slots=2, page_size=8, max_context=32,
                         prefill_token_budget=8, cache_dtype="float32")
@@ -161,7 +186,7 @@ IN_KERNELS = {"kernel.flash_fwd": "flash", "kernel.flash_bwd_dkv": "flash",
 
 @pytest.mark.parametrize("scope", VOCABULARY)
 def test_scope_is_in_the_programs(scope, train_step, engine, hybrid_engine,
-                                  kernel_texts):
+                                  slot_state_engine, kernel_texts):
     """Every name of the vocabulary is on the operations of the program it
     belongs to: in ``lowered_texts()`` of the train step and of the serving
     step, and for the kernels the CPU never calls, in their traces."""
@@ -176,6 +201,10 @@ def test_scope_is_in_the_programs(scope, train_step, engine, hybrid_engine,
         found_somewhere = True
     if scope in IN_HYBRID:
         text = "".join(hybrid_engine.lowered_texts())
+        assert re.search(r'["/(]' + re.escape(scope) + r'[/)"]', text), scope
+        found_somewhere = True
+    if scope in IN_SLOT_STATE:
+        text = "".join(slot_state_engine.lowered_texts())
         assert re.search(r'["/(]' + re.escape(scope) + r'[/)"]', text), scope
         found_somewhere = True
     if scope in IN_KERNELS:
@@ -274,6 +303,35 @@ def test_op_scopes_of_the_hybrid_serving_step(hybrid_engine):
     assert loop and all(s.scope.startswith("layers") for s in loop)
     unscoped = [n for n, s in mapped.items() if s.scope == scopes.UNSCOPED]
     assert len(unscoped) <= len(mapped) // 10, unscoped
+
+
+def test_op_scopes_of_the_slot_state_serving_step(slot_state_engine):
+    """A window layer's launch is ``attn.window/kernel.ragged`` and a
+    shared-pool one ``attn.shared/kernel.ragged``, told apart by the path;
+    the recurrence is ``ssm.scan/kernel.ssm_scan``; the state rows' scatter
+    is ``ssm.state_write``'s and the K/V scatters ``attn.pool_write``'s,
+    never inside ``attn.window`` or ``attn.shared``; the counters the cache
+    keeps are in ``metrics()``."""
+    assert slot_state_engine.compiled_programs == 1
+    (mapped,) = slot_state_engine.op_scopes()
+    paths = {s.scope for s in mapped.values()}
+    assert any(p.endswith("attn.window/kernel.ragged") for p in paths)
+    assert any(p.endswith("attn.shared/kernel.ragged") for p in paths)
+    assert any(p.endswith("ssm.scan/kernel.ssm_scan") for p in paths)
+    assert not any("attn.window/attn.pool_write" in p or "attn.shared/attn.pool_write" in p
+                   for p in paths)
+    leaves = {_leaf(s) for s in mapped.values()}
+    assert {"ssm.proj", "ssm.conv", "ssm.state_write", "gmu", "attn.diff",
+            "attn.pool_write", "mlp", "lm_head"} <= leaves
+    scatters = {_leaf(s) for name, s in mapped.items() if "scatter" in name}
+    assert {"ssm.state_write", "attn.pool_write"} <= scatters
+    loops = [s for name, s in mapped.items() if name.startswith("while")]
+    assert len(loops) >= 2 and all(s.scope.startswith("layers") for s in loops)
+    unscoped = [n for n, s in mapped.items() if s.scope == scopes.UNSCOPED]
+    assert len(unscoped) <= len(mapped) // 10, unscoped
+    m = slot_state_engine.metrics()
+    assert m["ssm_rows"] == m["cross_rows"] == 12 and m["ssm_runs"] == 3
+    assert 0 < m["window_work_items"] <= m["work_items"]
 
 
 def test_op_scopes_of_a_train_step_with_recomputation(train_step):

@@ -493,6 +493,211 @@ def test_ragged_launch_with_one_query_head_a_pool_head_is_the_launch_it_was(
     assert " rem " not in texts[2] and " rem " in texts[8]
 
 
+@pytest.mark.parametrize("window", [1, 40, 70])
+@pytest.mark.parametrize("pool", list(_GROUPS))
+def test_ragged_kernel_masks_keys_below_a_window(pool, window):
+    """``window=W``: a token reads the newest ``W`` positions up to its own.
+    The launch (interpreter) against the gather oracle given the same bound,
+    over the whole work list and over the windowed one (only the pages that
+    hold a readable position); with grouped queries too."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_kernels import ragged_paged_attention as ra
+
+    rng = np.random.RandomState(window)
+    P, HKV, G, PS, D, MP = 11, 2, 2, 128, 64, 4
+    T_MAX, NB_MAX, WL_MAX = 32, 8, 32
+    runs = _LAUNCH_RUNS[7]
+    plan_np, stats, tables, lengths = _mk_ragged_case(
+        runs, T_MAX, NB_MAX, WL_MAX, MP)
+    real = stats["n_tokens"]
+    q = jnp.array(rng.randn(T_MAX, HKV * G, D), pool)
+    kp, vp = (jnp.array(rng.randn(P, HKV, PS, D), pool) for _ in range(2))
+    tables, lengths = jnp.array(tables), jnp.array(lengths)
+    ref = np.asarray(ra._xla_ragged_reference(
+        q, kp, vp, tables, lengths, 0.125, window=window), np.float32)
+    full = np.asarray(ra._xla_ragged_reference(
+        q, kp, vp, tables, lengths, 0.125), np.float32)
+    assert np.abs(ref[:real] - full[:real]).max() > 1e-2      # the bound binds
+    windowed, wstats = ra.build_ragged_plan(
+        runs, token_block=8, page_size=PS, t_max=T_MAX, nb_max=NB_MAX,
+        wl_max=WL_MAX, window=window)
+    assert wstats["n_items"] < stats["n_items"]
+    for arrays in (plan_np, windowed):
+        plan = tuple(jnp.array(arrays[k]) for k in ra.RAGGED_PLAN_FIELDS)
+        got = np.asarray(ra.ragged_paged_attention(
+            q, kp, vp, tables, lengths, plan, sm_scale=0.125, interpret=True,
+            window=window), np.float32)
+        np.testing.assert_allclose(got[:real], ref[:real], rtol=_GROUPS[pool],
+                                   atol=_GROUPS[pool])
+
+
+def test_ragged_launch_without_a_window_is_the_launch_it_was(monkeypatch):
+    """``window=None`` passes ``_ragged_pallas`` no keyword it did not have
+    and traces the program a call without the keyword traces, to the letter
+    (``test_ragged_launch_follows_the_work_list`` holds that launch's outputs
+    bitwise against the launch of before); a window adds one comparison."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_kernels import ragged_paged_attention as ra
+
+    rng = np.random.RandomState(6)
+    P, H, PS, D, MP = 11, 2, 128, 64, 4
+    T_MAX, NB_MAX, WL_MAX = 32, 8, 32
+    plan_np, _, tables, lengths = _mk_ragged_case(
+        _LAUNCH_RUNS[7], T_MAX, NB_MAX, WL_MAX, MP)
+    plan = tuple(jnp.array(plan_np[k]) for k in ra.RAGGED_PLAN_FIELDS)
+    q = jnp.array(rng.randn(T_MAX, H, D), jnp.float32)
+    kp, vp = (jnp.array(rng.randn(P, H, PS, D), jnp.float32) for _ in range(2))
+    launch, seen = ra._ragged_pallas, []
+
+    def spy(*args, **kwargs):
+        seen.append(sorted(kwargs))
+        return launch(*args, **kwargs)
+
+    monkeypatch.setattr(ra, "_ragged_pallas", spy)
+
+    def traced(**kw):
+        return str(jax.make_jaxpr(
+            lambda q_, k_, v_: ra.ragged_paged_attention(
+                q_, k_, v_, jnp.array(tables), jnp.array(lengths), plan,
+                sm_scale=0.125, interpret=True, **kw))(q, kp, vp))
+
+    as_it_was, none, some = traced(), traced(window=None), traced(window=64)
+    assert as_it_was == none != some
+    assert seen == [["interpret", "k_scale", "v_scale"]] * 2 + [
+        ["interpret", "k_scale", "v_scale", "window"]]
+    assert some.count(" gt ") == none.count(" gt ") + 1
+
+
+@pytest.mark.parametrize("base,count", [(0, 1), (511, 1), (512, 1), (700, 1),
+                                        (895, 256), (1000, 37), (130, 256)])
+def test_a_plan_over_a_ring_lists_the_pages_inside_the_window(base, count):
+    """``build_ragged_plan(window=W)`` over a slot's ring (page-slot ``j`` at
+    ring page ``j mod R``): a block's items are exactly the page-slots that
+    hold a position one of its rows reads, each at its ring page, and no two
+    of a step's page-slots share a ring page."""
+    from paddle_tpu.ops.pallas_kernels import ragged_paged_attention as ra
+
+    W, PS, QB, MP = 512, 128, 8, 64
+    R = -(-(W - 1 + 256) // PS) + 1
+    ring = (1 + 3 * R + np.arange(MP) % R).astype(np.int32)     # slot 3's
+    nb = -(-count // QB)
+    plan, stats = ra.build_ragged_plan(
+        [(base, count, ring)], token_block=QB, page_size=PS, t_max=256,
+        nb_max=nb, wl_max=nb * R, window=W)
+    n = stats["n_items"]
+    items = list(zip(plan["wl_blk"][:n], plan["wl_page"][:n],
+                     plan["wl_pageslot"][:n]))
+    want = []
+    for b in range(nb):
+        lo = base + b * QB
+        hi = min(lo + QB, base + count) - 1
+        for j in range(max(lo - W + 1, 0) // PS, hi // PS + 1):
+            want.append((b, ring[j], j))
+    assert items == want
+    slots = {j for _, _, j in items}
+    assert len({ring[j] for j in slots}) == len(slots) <= R
+    # the write list names the ring pages the run's positions land in
+    w = int(plan["n_writes"][0])
+    assert set(plan["wr_page"][:w]) == {
+        ring[p // PS] for p in range(base, base + count)}
+
+
+def _plan_by_the_loop(runs, *, token_block, page_size, t_max, nb_max, wl_max,
+                      window=None):
+    """The work list as PR 34's builder made it: a Python loop over runs,
+    blocks and page-slots (the oracle of the numpy builder; with a window a
+    block's first page-slot is the one holding ``base - window + 1``)."""
+    qb = token_block
+    blk_tok = np.zeros((nb_max, qb), np.int32)
+    tok_blk = np.zeros((t_max,), np.int32)
+    tok_row = np.zeros((t_max,), np.int32)
+    blk_base = np.zeros((nb_max,), np.int32)
+    blk_rows = np.zeros((nb_max,), np.int32)
+    items, run_starts, t, b = [], [], 0, 0
+    for base, count, table in runs:
+        run_starts.append(t)
+        off = 0
+        while off < count:
+            rows = min(qb, count - off)
+            blk_tok[b, :rows] = np.arange(t + off, t + off + rows)
+            blk_tok[b, rows:] = t + off
+            blk_base[b] = base + off
+            blk_rows[b] = rows
+            tok_blk[t + off:t + off + rows] = b
+            tok_row[t + off:t + off + rows] = np.arange(rows)
+            first = (0 if window is None
+                     else max(base + off - window + 1, 0) // page_size)
+            for ps_i in range(first, (base + off + rows - 1) // page_size + 1):
+                items.append((b, int(table[ps_i]), ps_i))
+            off += rows
+            b += 1
+        t += count
+    plan = {"blk_tok": blk_tok, "tok_blk": tok_blk, "tok_row": tok_row,
+            "blk_base": blk_base, "blk_rows": blk_rows,
+            "n_items": np.array([len(items)], np.int32)}
+    for col, name in enumerate(("wl_blk", "wl_page", "wl_pageslot")):
+        arr = np.full((wl_max,), items[-1][col], np.int32)
+        arr[:len(items)] = [it[col] for it in items]
+        plan[name] = arr
+    stats = {"n_tokens": t, "n_blocks": b, "n_items": len(items),
+             "run_starts": run_starts, "wl_capacity": wl_max,
+             "row_capacity": b * qb, "launched_items": len(items)}
+    return plan, stats
+
+
+@pytest.mark.parametrize("seed", range(4))
+@pytest.mark.parametrize("window", [None, 512, 200])
+def test_the_plan_builder_is_the_loop_it_replaced(window, seed):
+    """The numpy builder gives, array for array and dtype for dtype, what the
+    loop over runs, blocks and page-slots gave: random steps of decode rows
+    and chunks, over page tables (``window=None``) and over ring tables
+    (``window=W``: page-slot ``j`` at ring page ``j mod R``)."""
+    from paddle_tpu.ops.pallas_kernels import ragged_paged_attention as ra
+
+    rng = np.random.default_rng(100 * seed + (window or 0))
+    PS, MP, budget = 128, 64, 256
+    for _ in range(40):
+        qb = int(rng.choice([8, 16, 32]))
+        n = int(rng.integers(1, 20))
+        if window is None:
+            tables = rng.permutation(
+                np.arange(1, 1 + n * MP)).reshape(n, MP).astype(np.int32)
+        else:
+            R = -(-(window - 1 + budget) // PS) + 1
+            tables = (1 + R * np.arange(n)[:, None]
+                      + np.arange(MP)[None, :] % R).astype(np.int32)
+        runs, left = [], budget
+        for r in range(n):
+            count = 1
+            if rng.random() < 0.3 and left > 0:
+                count = int(rng.integers(1, left + 1))
+                left -= count
+            runs.append((int(rng.integers(0, MP * PS - count)), count,
+                         tables[r]))
+        geo = dict(token_block=qb, page_size=PS, t_max=n + budget,
+                   nb_max=n + budget // qb,
+                   wl_max=(n + budget // qb) * MP, window=window)
+        want, want_stats = _plan_by_the_loop(runs, **geo)
+        got, got_stats = ra.build_ragged_plan(runs, write_group=16, **geo)
+        assert set(ra.RAGGED_PLAN_FIELDS) == set(got) >= set(want)
+        for name, arr in want.items():
+            assert got[name].dtype == arr.dtype and got[name].shape == arr.shape
+            np.testing.assert_array_equal(got[name], arr, err_msg=name)
+        assert {k: got_stats[k] for k in want_stats} == want_stats
+        # the write list is `_build_write_list`'s, which PR 34 brought and
+        # this builder calls with the operands the loop gave it
+        w = got_stats["n_writes"]
+        groups = {(int(tables_r[p // PS]), p % PS // 16)
+                  for base, count, tables_r in runs
+                  for p in range(base, base + count)}
+        assert w == len(groups)
+        assert set(zip(got["wr_page"][:w].tolist(),
+                       got["wr_group"][:w].tolist())) == groups
+
+
 _HEAD_BLOCKS = {"all_heads": 4, "some_heads": 2, "one_head": 1}
 
 
