@@ -18,6 +18,9 @@ kind                    hook point             effect
 ``step_stall``          before_decode          ``time.sleep(duration)`` so
                                                the watchdog trips; the thunk
                                                then honors ``cancelled()``
+(both)                  await_decode           the same, at the WAIT for an
+                                               enqueued step's tokens — the
+                                               next step is enqueued by then
 ``nan_logits``          after_decode           flip ``ctx["finite"]`` for
                                                the chosen slots (simulating
                                                NaN-poisoned logits)
@@ -85,8 +88,10 @@ __all__ = ["InjectedFault", "FaultPlan", "FaultInjector", "random_schedule",
 
 KIND_POINTS = {
     # serving (engine/allocator hook points)
-    "step_exception": ("before_decode",),
-    "step_stall": ("before_decode",),
+    # ``await_decode`` is the wait for a step that was enqueued a tick
+    # earlier: a fault there is seen with the next step already enqueued
+    "step_exception": ("before_decode", "await_decode"),
+    "step_stall": ("before_decode", "await_decode"),
     "nan_logits": ("after_decode",),
     "alloc_exhausted": ("alloc",),
     "callback_error": ("callback",),

@@ -35,7 +35,7 @@ request mix churns.
 """
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -87,18 +87,30 @@ class StepWork:
     model's k proposals — ``count = 1 + k`` — whose accepted prefix the
     engine commits via ``advance(idx, n_accepted + 1)``; see
     serving/speculative.py).  ``drafts`` carries the proposed token ids
-    on verify runs (None otherwise)."""
+    on verify runs (None otherwise).
 
-    __slots__ = ("slot", "kind", "count", "base", "completes", "drafts")
+    ``seq`` is the admission number of the seating the run was planned for:
+    a run is harvested into its slot only while that seating still holds it
+    (a step is read one tick after it is enqueued, and the slot may have
+    been retired, or seated again, in between: the run is then VOID).
+    ``chained`` marks a decode run planned while the slot's previous run
+    was still in flight: its input id is the token that run samples, which
+    the fused step takes from the previous step's output on the device."""
+
+    __slots__ = ("slot", "kind", "count", "base", "completes", "drafts",
+                 "seq", "chained")
 
     def __init__(self, slot: int, kind: str, count: int, base: int,
-                 completes: bool, drafts=None):
+                 completes: bool, drafts=None, seq: int = 0,
+                 chained: bool = False):
         self.slot = slot
         self.kind = kind
         self.count = count
         self.base = base
         self.completes = completes
         self.drafts = drafts
+        self.seq = seq
+        self.chained = chained
 
     @property
     def has_output(self) -> bool:
@@ -256,7 +268,14 @@ class AdmissionScheduler:
         self.positions[idx] = slot.pos
 
     # -- variable tokens per step (the fused mixed prefill/decode plan) ----
-    def plan_step(self, prefill_token_budget: int) -> List[StepWork]:
+    def live(self, w: StepWork) -> Optional[Slot]:
+        """The slot run ``w`` was planned for, while the same seating still
+        holds it; None once it was retired or seated again (``w`` is void)."""
+        slot = self.slots[w.slot]
+        return slot if slot is not None and slot.seq == w.seq else None
+
+    def plan_step(self, prefill_token_budget: int,
+                  ahead: Sequence[StepWork] = ()) -> List[StepWork]:
         """Plan one fused step: every seated slot contributes a
         :class:`StepWork` — a run of up to the remaining shared
         ``prefill_token_budget`` pending-prompt tokens, or one decode
@@ -269,17 +288,42 @@ class AdmissionScheduler:
         state — it is pure bookkeeping the engine turns into the step's
         flat token arrays, and it only commits (``advance`` + pending
         consumption) after the step succeeds, which is what makes a
-        failed step's retry idempotent."""
+        failed step's retry idempotent.
+
+        ``ahead`` is the plan of a step that is enqueued and not harvested
+        yet: this plan is made against the state that step leaves IF IT
+        SUCCEEDS — its counts added to the positions, its prefill chunks
+        taken off the pending prompts, and a slot whose request emits its
+        last token in it (``max_new_tokens``: known here) left out.  What
+        only its results can say (an EOS, a non-finite row, a failure) is
+        not assumed: the run planned here for such a slot is void when it
+        is harvested.  The mirrors still move in harvest alone."""
         budget = int(prefill_token_budget)
+        in_flight = {w.slot: w for w in ahead}
         work: List[StepWork] = []
         for i, slot in sorted(self.seated(), key=lambda t: t[1].seq):
-            if slot.pending is not None and len(slot.pending):
+            pos = slot.pos
+            left = 0 if slot.pending is None else len(slot.pending)
+            prev = in_flight.get(i)
+            if prev is not None and prev.seq != slot.seq:
+                prev = None              # another seating's run: void there
+            if prev is not None:
+                req = slot.request
+                if (prev.has_output
+                        and len(req.tokens) + 1 >= req.max_new_tokens):
+                    continue             # ends by length in the step ahead
+                pos += prev.count
+                if prev.kind == "prefill":
+                    left -= prev.count
+            if left > 0:
                 if budget <= 0:
                     continue
-                k = min(budget, len(slot.pending))
-                work.append(StepWork(i, "prefill", k, slot.pos,
-                                     k == len(slot.pending)))
+                k = min(budget, left)
+                work.append(StepWork(i, "prefill", k, pos, k == left,
+                                     seq=slot.seq))
                 budget -= k
             else:
-                work.append(StepWork(i, "decode", 1, slot.pos, False))
+                work.append(StepWork(
+                    i, "decode", 1, pos, False, seq=slot.seq,
+                    chained=prev is not None and prev.has_output))
         return work

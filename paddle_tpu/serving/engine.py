@@ -23,6 +23,20 @@ process, plus one more only if sampling requests ever arrive:
   change as the mix churns — zero retraces, asserted by
   ``serve_trace_counts()`` exactly like ``models/generation``.
 
+- **one step in flight** — a tick plans, packs and ENQUEUES step N+1
+  while step N still runs, and only then waits for N's tokens and harvests
+  them, so the host's planning and the result's trip to the host hide
+  behind the chip's work.  N+1 is planned against the state N leaves if it
+  succeeds (``AdmissionScheduler.plan_step(ahead=)``), and its decode rows
+  take their ids from N's sampled tokens ON THE DEVICE (``id_src`` in the
+  packed input; the host has not read them).  Whatever only N's results
+  can say (an EOS, a non-finite row, a failure) makes N+1's run for that
+  slot VOID: never emitted, never counted, its writes confined to pages
+  the slot owned when N+1 was enqueued.  Mirrors, counters and state rows
+  move in harvest alone.  A step whose successor cannot be planned before
+  its result is read (the speculative engine's verify step) runs with
+  nothing enqueued ahead: the serial engine is this pipeline at depth 0.
+
 The step has a greedy variant (pure argmax — no full-vocab sort,
 softmax, or RNG traffic on the hot path) and a sampling variant (per-slot
 traced temperature/top-k/top-p vectors; greedy rows inside a mixed batch
@@ -526,6 +540,20 @@ class _StepWorker:
         return box.result
 
 
+class _Flight:
+    """One fused step that is enqueued and not read yet: its plan, the
+    plan's stats, its output still on the device (a slot's sampled token,
+    or -1 where its logits were not finite), the watchdog budget it was
+    dispatched under, whether its variant had never run before, and whether
+    its predecessor was still unread when it was enqueued."""
+
+    __slots__ = ("work", "stats", "out", "budget", "first", "overlapped")
+
+    def __init__(self, work, stats, out, budget, first, overlapped):
+        self.work, self.stats, self.out = work, stats, out
+        self.budget, self.first, self.overlapped = budget, first, overlapped
+
+
 class ServingEngine:
     """Continuous-batching front end over a model exposing the paged-cache
     contract: ``config`` (``head_dim``, ``max_position_embeddings``,
@@ -773,6 +801,12 @@ class ServingEngine:
         # (paddle_tpu/faults.py; same discipline as checkpoint/manager.py)
         self._fault_hook: Optional[Callable] = None
 
+        # the step that is enqueued and not harvested yet (None: the
+        # pipeline is empty), and the stand-in for a predecessor's output
+        # that a step with none takes (made on first use)
+        self._inflight: Optional[_Flight] = None
+        self._no_prev: Optional[Tensor] = None
+
         # host mirrors shipped to the jitted step each call (fixed shapes)
         self._tokens = np.zeros((num_slots,), np.int64)
         # per-slot adapter page (0 = null adapter) + the seated adapter
@@ -795,6 +829,9 @@ class ServingEngine:
             ("tables", (self._t_max, mp_)),
             ("positions", (self._t_max,)),
             ("out_rows", (self.num_slots,)),
+            # per flat row, the slot whose token sampled by the step ahead
+            # (still on the device) is this row's id, or -1: the host's id
+            ("id_src", (self._t_max,)),
             *ragged_plan_shapes(**self._plan_geometry),
         ]
         if self.lora is not None:
@@ -828,6 +865,11 @@ class ServingEngine:
                         # piggybacked, and the ragged grid-occupancy
                         # numerators/denominators (see metrics())
                         "fused_steps": 0, "prefill_tokens": 0,
+                        # fused steps enqueued while their predecessor was
+                        # still unread, and runs whose results were dropped
+                        # because only the predecessor's results could say
+                        # their slot was gone (docs/serving.md)
+                        "overlapped_steps": 0, "voided_rows": 0,
                         "work_items": 0, "work_capacity": 0,
                         "launched_items": 0, "launched_grid_steps": 0,
                         # the pool write's items (tile groups the steps'
@@ -959,9 +1001,10 @@ class ServingEngine:
         # RNG-state traffic) — all-greedy traffic, the common serving
         # case, never pays the sampling machinery.  Mixed batches take
         # the sampling variant, whose per-slot `do_sample` vector still
-        # reproduces greedy rows bit-exactly.  Both variants ALSO return
-        # the fused per-slot finiteness flags (the NaN sentry) gathered
-        # at each slot's output row — zero extra host syncs.
+        # reproduces greedy rows bit-exactly.  Both variants fold the
+        # fused per-slot finiteness flags (the NaN sentry) gathered at
+        # each slot's output row into their one output — zero extra host
+        # syncs — and take the previous step's output as ``prev``.
         slices = [self._pack_slices[name] for name, _ in self._pack_layout]
 
         def _unpack(p):
@@ -975,12 +1018,25 @@ class ServingEngine:
         by_slot = ([name for name, _ in self._extra_pack_fields()]
                    if self._slot_state else [])
 
+        def _chain_ids(ids, src, prev):
+            # a chained row continues the token the step ahead sampled for
+            # slot ``src``, which no host has read (-1 there: not finite,
+            # and the row is void)
+            last = jnp.maximum(prev, 0)[jnp.clip(src, 0, None)]
+            return jnp.where(src >= 0, last.astype(ids.dtype),
+                             ids[:, 0])[:, None]
+
+        def _token_or_void(tok, fin):
+            return jnp.where(fin, tok.astype(jnp.int64), -1)
+
         def _mk_fused(with_sampling):
-            def fused_step(ids, packed, temp, top_p, top_k, do_sample):
+            def fused_step(ids, packed, temp, top_p, top_k, do_sample, prev):
                 _count_fused_trace()
                 with jax.named_scope("serve.unpack"):
-                    (token_tables, positions, out_rows, *rest) = \
+                    (token_tables, positions, out_rows, id_src, *rest) = \
                         dispatch.apply_nondiff(_unpack, packed)
+                    ids = dispatch.apply_nondiff(_chain_ids, ids, id_src,
+                                                 prev)
                 plan = tuple(rest[:n_plan])
                 lora_in = None
                 if lora_pool is not None:
@@ -1009,7 +1065,11 @@ class ServingEngine:
                                                    generator=generator)
                         else:
                             tok = ops.argmax(rows, axis=-1)
-                return tok, fin
+                        # ONE output: the host reads tokens and finiteness
+                        # in one transfer, the next step reads it in place
+                        out = dispatch.apply_nondiff(_token_or_void, tok,
+                                                     fin)
+                return out
 
             fused_step.__name__ = "fused_step" + self._program_tag
             return fused_step
@@ -1077,12 +1137,16 @@ class ServingEngine:
     # -- the serving loop --------------------------------------------------
     def step(self) -> dict:
         """One scheduler tick: reap cancelled/expired requests, admit what
-        fits (admission only reserves pages and seats — no dispatch), then
-        run ONE fused mixed prefill/decode step over every seated slot's
-        work (supervised, retried once, finiteness-checked), retire
-        finished requests (their pages free immediately).  A crashed or
-        stalled step never escapes: the implicated requests end FAILED and
-        the engine recovers.  Returns this step's metrics."""
+        fits (admission only reserves pages and seats — no dispatch), plan
+        and pack ONE fused mixed prefill/decode step over every seated
+        slot's work and ENQUEUE it behind the step already in flight
+        (supervised, retried once); then wait for that earlier step's
+        tokens, harvest them (finiteness-checked) and retire finished
+        requests (their pages free immediately).  A tick with nothing in
+        flight enqueues and returns; one with nothing to enqueue harvests
+        what is in flight.  A crashed or stalled step never escapes: the
+        implicated requests end FAILED and the engine recovers.  Returns
+        this tick's metrics; counters move in harvest only."""
         with self._lock, self._eval_mode(), \
                 _ttrace.span("serve.step") as step_span:
             # under the lock: close() also serializes on it, so a racing
@@ -1096,50 +1160,121 @@ class ServingEngine:
                 now = time.monotonic()
                 self._reap(now)
                 self._admit(now)
-                sched = self.scheduler
-                work = sched.plan_step(self.prefill_token_budget)
-            if work:
+                work = self.scheduler.plan_step(self.prefill_token_budget,
+                                                self._ahead)
+            if work or self._inflight is not None:
                 self._dispatch_step(work)
             with _ttrace.span("serve.commit"):
                 m = self._commit_step_metrics(t0)
                 if step_span is not None:
-                    # whether this step carried a prompt chunk: what
-                    # engine.step_ms_decode_only/_with_prefill group by
+                    # whether the HARVESTED step carried a prompt chunk:
+                    # what engine.step_ms_decode_only/_with_prefill group by
                     step_span.set(prefill_tokens=self._step_prefill)
                 return m
 
     def _dispatch_step(self, work):
-        """Pack -> dispatch (supervised, retried once) -> harvest for one
-        tick's plan.  Overridden by the speculative engine (draft propose
-        phase + verify dispatch); the recovery semantics here are the
+        """One tick of the pipeline: pack ``work`` and enqueue it
+        (supervised, retried once) behind the step in flight, then wait for
+        THAT step and harvest it.  ``work`` was planned as if the step in
+        flight succeeds; what its results contradict is void at the next
+        harvest.  Overridden by the speculative engine (draft propose
+        phase + verify dispatch, nothing ever ahead: the accepted count
+        decides the next positions); the recovery semantics are the
         containment contract both share."""
-        # the step's flat inputs are a pure function of the host
-        # mirrors, which only advance on success — a retry after a
-        # transient failure rebuilds the SAME idempotent scatter
-        with _ttrace.span("serve.pack"):
-            inputs, stats = self._build_step_inputs(work)
+        prev, nxt = self._inflight, None
+        if work:
+            # the step's flat inputs are a pure function of the host
+            # mirrors and of the plan in flight; the mirrors only advance
+            # in harvest — a retry after a transient failure enqueues the
+            # SAME idempotent step
+            with _ttrace.span("serve.pack"):
+                inputs, stats = self._build_step_inputs(work)
+            try:
+                # the nested jit.fused_step span carries the program's
+                # CostReport digest (per compiled entry, so greedy and
+                # sampling variants each report their own cost)
+                with _ttrace.span("serve.dispatch"):
+                    nxt = self._run_fused(work, stats, inputs, prev)
+            except StepBuildError:
+                raise
+            except Exception as e:  # noqa: BLE001 — containment boundary
+                # the step in flight was enqueued whole: it lands first,
+                # then every request still seated is implicated
+                self._drain()
+                prev = None
+                self._contain(e)
+        self._inflight = nxt
+        if prev is not None:
+            self._land(prev)
+
+    @property
+    def _ahead(self):
+        """The plan of the step in flight (empty: nothing is)."""
+        return self._inflight.work if self._inflight is not None else ()
+
+    def _contain(self, e: BaseException):
+        """A step failed past its retry, or stalled: every seated request
+        is implicated, and the device state is rebuilt unless the fault
+        provably fired before any device work."""
+        stalled = isinstance(e, StepStalledError)
+        self._recover(e, rebuild=stalled or not _state_intact(e),
+                      stalled=stalled)
+
+    def _drain(self):
+        """Land the step in flight, if any: the pipeline is empty after."""
+        flight, self._inflight = self._inflight, None
+        if flight is not None:
+            self._land(flight)
+
+    def _settle(self):
+        """:meth:`_drain` for callers outside a tick (``close``, the drain
+        lifecycle): the tokens it emits are counted here, not by a tick's
+        commit."""
+        with self._lock:
+            if self._inflight is None or self._closed:
+                return
+            with self._eval_mode():
+                before = self._step_emitted
+                self._drain()
+                self._totals["tokens"] += self._step_emitted - before
+                self._step_emitted = before
+
+    def _land(self, flight: _Flight):
+        """Wait for an enqueued step's tokens (ONE transfer; the read is
+        tried once more after an exception) and harvest them.  A step whose
+        read fails or stalls takes its successor with it — enqueued on top
+        of state that cannot be trusted, it is dropped unread, every run of
+        it void — and every seated request is implicated."""
+        read = lambda cancelled: self._read_thunk(flight, cancelled)  # noqa: E731,E501
         try:
-            # the nested jit.fused_step span carries the program's
-            # CostReport digest (per compiled entry, so greedy and
-            # sampling variants each report their own cost)
-            with _ttrace.span("serve.dispatch"):
-                out = self._run_fused(inputs)
-        except StepStalledError as e:
-            self._recover(e, rebuild=True, stalled=True)
-            out = None
-        except StepBuildError:
-            raise
+            try:
+                got = self._supervised(read, flight.budget)
+            except StepStalledError:
+                raise
+            except Exception as e:  # noqa: BLE001 — transient: read again
+                if flight.first:
+                    raise StepBuildError(
+                        f"the fused step failed the first time it ran: "
+                        f"{type(e).__name__}: {e}") from e
+                self._totals["step_retries"] += 1
+                got = self._supervised(read, flight.budget)
         except Exception as e:  # noqa: BLE001 — containment boundary
-            self._recover(e, rebuild=not _state_intact(e))
-            out = None
-        if out is not None:
-            # exact count of fused program executions — bench.py's
-            # serving roofline denominator (ticks with no seated
-            # work / failed dispatches don't run one)
-            self._totals["fused_steps"] += 1
-            with _ttrace.span("serve.harvest"):
-                self._harvest_fused(work, stats, *out)
-            self._backoff_s = self.readmission_backoff_s
+            ahead, self._inflight = self._inflight, None
+            if ahead is not None:
+                self._totals["voided_rows"] += len(ahead.work)
+            if isinstance(e, StepBuildError):
+                raise
+            self._contain(e)
+            return
+        # exact count of fused program executions — bench.py's serving
+        # roofline denominator (ticks with no seated work / failed
+        # dispatches don't run one)
+        self._totals["fused_steps"] += 1
+        if flight.overlapped:
+            self._totals["overlapped_steps"] += 1
+        with _ttrace.span("serve.harvest"):
+            self._harvest_fused(flight.work, flight.stats, *got)
+        self._backoff_s = self.readmission_backoff_s
 
     def _commit_step_metrics(self, t0: float) -> dict:
         """Fold the step's tallies into totals + gauges and build the
@@ -1179,17 +1314,27 @@ class ServingEngine:
         g["pool_occupancy"].set(self._last_metrics["occupancy"])
         return dict(self._last_metrics)
 
-    def _run_fused(self, inputs) -> Tuple[np.ndarray, np.ndarray]:
-        """Dispatch the fused step under the watchdog; one immediate retry
-        on a (transient) exception.  A stall is never retried — the worker
-        is already wedged."""
+    def _run_fused(self, work, stats, inputs,
+                   behind: Optional[_Flight]) -> _Flight:
+        """Enqueue the fused step under the watchdog, behind ``behind`` (the
+        step in flight, whose output it reads on the device); one
+        immediate retry on a (transient) exception.  A stall is never
+        retried — the worker is already wedged."""
         fused = (self._fused_sample if self._do_sample.any()
                  else self._fused_greedy)
         budget = self._budget_for([fused])
         never_ran = not fused.code_cache
-        thunk = lambda cancelled: self._fused_thunk(fused, inputs, cancelled)  # noqa: E731,E501
+        if behind is not None:
+            prev = behind.out
+        else:
+            if self._no_prev is None:
+                self._no_prev = self._host_to_dev(
+                    np.zeros((self.num_slots,), np.int64))
+            prev = self._no_prev
+        thunk = lambda cancelled: self._enqueue_thunk(  # noqa: E731
+            fused, inputs, cancelled, (prev,))
         try:
-            toks, fin, built = self._supervised(thunk, budget)
+            out, built = self._supervised(thunk, budget)
         except StepStalledError:
             raise
         except Exception as e:  # noqa: BLE001 — transient device errors retry once
@@ -1198,13 +1343,14 @@ class ServingEngine:
                     f"{fused.__name__} failed on its first dispatch: "
                     f"{type(e).__name__}: {e}") from e
             self._totals["step_retries"] += 1
-            toks, fin, built = self._supervised(thunk, budget)
+            out, built = self._supervised(thunk, budget)
         if built is not None:
             # commit on THIS thread, under the step lock: _supervised only
             # returns results of non-abandoned runs, so a zombie's build
             # never lands here
             self._sampling_cache = built
-        return toks, fin
+        return _Flight(work, stats, out, budget, never_ran,
+                       behind is not None)
 
     def _budget_for(self, static_fns) -> Optional[float]:
         """Watchdog budget for one supervised dispatch: the stall budget
@@ -1220,9 +1366,10 @@ class ServingEngine:
     def _build_step_inputs(self, work) -> Tuple[tuple, dict]:
         """Flatten one tick's :class:`StepWork` plan into the fused step's
         fixed-shape numpy inputs: the flat token list (decode tokens from
-        the last-sampled mirrors, prefill tokens from each slot's pending
-        prompt), per-token positions and page-table rows, each slot's
-        output-row index, and the ragged work-list arrays from
+        the last-sampled mirrors, or named by slot in ``id_src`` where the
+        step in flight samples them; prefill tokens from each slot's
+        pending prompt), per-token positions and page-table rows, each
+        slot's output-row index, and the ragged work-list arrays from
         ``build_ragged_plan``.  Padding tokens carry id 0, position 0 and
         the null-page table row — their writes sink into page 0 and their
         output rows are never gathered."""
@@ -1239,18 +1386,25 @@ class ServingEngine:
         tables = view("tables")
         positions = view("positions")
         out_rows = view("out_rows")
+        id_src = view("id_src")
+        id_src[...] = -1
         adapters = view("adapters") if self.lora is not None else None
         runs = []
         t = 0
         for w in work:
             slot = sched.slots[w.slot]
             if w.kind == "prefill":
-                ids[t:t + w.count] = slot.pending[:w.count]
+                # pending starts at the harvested position; a chunk of the
+                # step in flight lies between that and this run's base
+                skip = w.base - slot.pos
+                ids[t:t + w.count] = slot.pending[skip:skip + w.count]
             elif w.kind == "verify":
                 # speculative verification run: the slot's last sampled
                 # token followed by the draft model's proposals
                 ids[t] = self._tokens[w.slot]
                 ids[t + 1:t + w.count] = w.drafts[:w.count - 1]
+            elif w.chained:
+                id_src[t] = w.slot       # sampled by the step in flight
             else:
                 ids[t] = self._tokens[w.slot]
             row = sched.tables[w.slot]
@@ -1268,28 +1422,23 @@ class ServingEngine:
         for k in RAGGED_PLAN_FIELDS:
             view(k)[...] = plan[k]
         if self._slot_state:
-            stats["slot_runs"] = [(w.slot, w.base, w.count) for w in work]
+            # a slot with a run in flight reads the state row that run
+            # writes (its rows swap when that run is harvested)
             stats["window_items"] = self.cache.pack_step(
-                view, stats["slot_runs"], tables.shape[1],
-                self._plan_geometry)["n_items"]
+                view, [(w.slot, w.base, w.count) for w in work],
+                tables.shape[1], self._plan_geometry,
+                in_flight=[w.slot for w in self._ahead
+                           if sched.live(w) is not None])["n_items"]
         return (ids[:, None], packed), stats
 
-    def _fused_thunk(self, fused, inputs, cancelled, extra_dev=()):
-        # the span records on the CALLING thread — under a watchdog this
-        # is the supervised _StepWorker, so the exported trace shows the
-        # device-dispatch range on the worker's row, interleaved with the
-        # dispatcher's serve.dispatch wait on its own row (its parent all
-        # the same: _supervised hands it over)
-        with _ttrace.span("serve.device_step"):
-            return self._fused_thunk_body(fused, inputs, cancelled,
-                                          extra_dev)
-
-    def _fused_thunk_body(self, fused, inputs, cancelled, extra_dev=()):
-        """Dispatch one compiled step: host inputs -> device, the cached
+    def _enqueue_thunk(self, fused, inputs, cancelled, extra_dev=()):
+        """Enqueue one compiled step: host inputs -> device, the cached
         sampling vectors appended, then ``extra_dev`` (already-on-device
-        Tensors — the speculative verify step's draft probability rows).
-        Returns the program outputs as numpy plus the sampling-cache
-        build (committed by the dispatching thread only)."""
+        Tensors — the output of the step in flight; the speculative verify
+        step's draft probability rows).  Returns the program's outputs,
+        still on the device and not waited for, plus the sampling-cache
+        build (committed by the dispatching thread only); None when
+        abandoned before the dispatch."""
         self._hook("before_decode")
         if cancelled():          # abandoned while the fault hook stalled:
             return None          # the result is discarded; skip dispatch
@@ -1310,28 +1459,46 @@ class ServingEngine:
         out = fused(
             *(self._host_to_dev(np.ascontiguousarray(a)) for a in inputs),
             *cache, *extra_dev)
-        toks, fin = out[0], out[-1]
-        mid = tuple(np.asarray(o.numpy()) for o in out[1:-1])
-        return (np.asarray(toks.numpy()),
-                np.array(np.asarray(fin.numpy()), bool), built, *mid)
+        return out, built
+
+    def _read_thunk(self, flight: _Flight, cancelled):
+        """Wait for ``flight``'s output and bring it to the host: each
+        slot's sampled token and whether its logits were finite, in the ONE
+        array the step returns.  The span records on the CALLING thread —
+        under a watchdog this is the supervised _StepWorker, so the
+        exported trace shows the wait on the worker's row (its parent all
+        the same, the tick's serve.step: _supervised hands it over)."""
+        with _ttrace.span("serve.device_step"):
+            self._hook("await_decode")
+            if cancelled():
+                return None
+            out = np.asarray(flight.out.numpy())
+            return np.maximum(out, 0), out >= 0
 
     def _harvest_fused(self, work, stats, toks_np: np.ndarray,
                        fin_np: np.ndarray):
         """Fold one fused step's results back into the request states:
         consume prefill runs, quarantine NaN-poisoned output slots,
         advance/emit the rest.  Mirrors and pending prompts only move
-        HERE — a failed dispatch leaves them untouched for the retry."""
+        HERE — a failed dispatch leaves them untouched for the retry, and
+        the step enqueued meanwhile was planned as if this one succeeds."""
         ctx = {"tokens": toks_np, "finite": fin_np}
         self._hook("after_decode", ctx)
         sched = self.scheduler
-        self._fold_plan_stats(work, stats)
+        # a run is harvested into the seating it was planned for; one whose
+        # slot was retired since (an EOS, a quarantine, a cancel: known
+        # only after it was enqueued) is void — nothing emitted or counted
+        live = [(w, slot) for w in work
+                for slot in (sched.live(w),) if slot is not None]
+        if len(live) < len(work):
+            self._totals["voided_rows"] += len(work) - len(live)
+        self._fold_plan_stats([w for w, _ in live], stats)
         if self._slot_state:
             # the step's results are in hand: its slots' state rows swap
-            self.cache.commit_step(stats["slot_runs"], stats["window_items"])
-        for w in work:
-            slot = sched.slots[w.slot]
-            if slot is None:
-                continue
+            self.cache.commit_step(
+                [(w.slot, w.base, w.count) for w, _ in live],
+                stats["window_items"])
+        for w, slot in live:
             if w.kind == "prefill":
                 slot.pending = slot.pending[w.count:]
             if w.has_output and not ctx["finite"][w.slot]:
@@ -1434,7 +1601,8 @@ class ServingEngine:
     def run_until_idle(self, max_steps: Optional[int] = None) -> dict:
         """Step until queue and slots drain; returns cumulative metrics."""
         steps = 0
-        while self.queue.depth or self.scheduler.active_slots:
+        while (self.queue.depth or self.scheduler.active_slots
+               or self._inflight is not None):
             met = self.step()
             steps += 1
             if max_steps is not None and steps >= max_steps:
@@ -1485,6 +1653,7 @@ class ServingEngine:
         ``checkpoint_seated()`` once its drain deadline passes."""
         with self._lock:
             self._check_open()
+            self._settle()
             self._draining = True
             return self.queue.remove_where(lambda r: True)
 
@@ -1514,6 +1683,8 @@ class ServingEngine:
         after."""
         with self._lock:
             self._check_open()
+            # what the step in flight emits belongs to the checkpoint
+            self._settle()
             return [self._checkpoint_slot(i)
                     for i, _slot in self.scheduler.seated()]
 
@@ -1555,6 +1726,7 @@ class ServingEngine:
         measures time in THIS queue, not lifetime (the deadline already
         bounds that)."""
         self._check_open()
+        self._settle()
         if self._draining:
             raise Overloaded(
                 f"engine draining: request {req.id} not requeued")
@@ -2061,15 +2233,20 @@ class ServingEngine:
         return [r for f in self._static_fns for r in f.lint_reports()]
 
     def close(self):
-        """Release the page pool's HBM eagerly.  Pending/active requests
-        are NOT drained — call ``run_until_idle`` first if they matter.
+        """Release the page pool's HBM eagerly.  The step in flight lands
+        first (its tokens are emitted); pending/active requests are NOT
+        drained — call ``run_until_idle`` first if they matter.
         Serializes on the step lock, so an in-flight step() finishes
         before the pool vanishes and later steps fail the open check
         cleanly instead of consuming deleted arrays."""
         with self._lock:
             if not self._closed:
-                self._closed = True
-                self.cache.release()
+                try:
+                    self._settle()
+                finally:
+                    self._inflight = None
+                    self._closed = True
+                    self.cache.release()
                 if self._worker is not None:
                     self._worker.shutdown()
                 # drop this engine's children from the process registry:

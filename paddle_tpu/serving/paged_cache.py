@@ -403,10 +403,15 @@ class SlotStateCache(_KVBuffers):
                 ("run_src", (s,)), ("run_dst", (s,)), ("n_runs", (1,))]
 
     def pack_step(self, view, runs: Sequence[Tuple[int, int, int]],
-                  max_pages: int, plan_geometry: dict) -> dict:
+                  max_pages: int, plan_geometry: dict,
+                  in_flight: Sequence[int] = ()) -> dict:
         """Fill the fields of :meth:`pack_fields` (``view(name)`` is each
         one's int32 array, zeroed) for a step of ``runs`` ``(slot, base,
-        count)`` in flat-token order; returns the second plan's stats."""
+        count)`` in flat-token order; returns the second plan's stats.
+        ``in_flight`` names the slots with a run enqueued and not harvested
+        yet: this step follows it on the device, so it loads the row that
+        run stores to and stores to the one it loads from (the rows swap,
+        here as ever, only in :meth:`commit_step`)."""
         from ..ops.pallas_kernels.ragged_paged_attention import (
             build_ragged_plan,
         )
@@ -426,8 +431,9 @@ class SlotStateCache(_KVBuffers):
         slots = np.array([r[0] for r in runs], np.int64)
         counts = np.array([r[2] for r in runs], np.int64)
         first = np.cumsum(counts) - counts
-        src = 1 + 2 * slots + self._flip[slots]
-        dst = 1 + 2 * slots + 1 - self._flip[slots]
+        flip = self._flip[slots] ^ np.isin(slots, list(in_flight))
+        src = 1 + 2 * slots + flip
+        dst = 1 + 2 * slots + 1 - flip
         t = int(counts.sum())
         of_row = np.repeat(np.arange(n), counts)
         row_slot = view("row_slot")

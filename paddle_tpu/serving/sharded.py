@@ -425,7 +425,8 @@ class ShardedServingEngine:
         per-replica metrics list."""
         per = [eng.metrics() for eng in self.replicas]
         sum_keys = ("steps", "tokens", "admitted", "completed",
-                    "fused_steps", "prefill_tokens", "failed", "cancelled",
+                    "fused_steps", "overlapped_steps", "voided_rows",
+                    "prefill_tokens", "failed", "cancelled",
                     "timed_out", "shed", "quarantined", "recoveries",
                     "rebuilds", "pages_used", "pages_capacity",
                     "active_slots", "queue_depth", "cache_bytes",

@@ -541,7 +541,9 @@ class SpeculativeEngine(ServingEngine):
                 from .engine import _count_fused_trace
 
                 _count_fused_trace()
-                (token_tables, positions, out_rows, *rest) = \
+                # (the base layout's ``id_src`` names rows that continue a
+                # step in flight; a verify step never has one ahead)
+                (token_tables, positions, out_rows, _id_src, *rest) = \
                     dispatch.apply_nondiff(_unpack, packed)
                 plan = tuple(rest[:n_plan])
                 rest = rest[n_plan:]
@@ -778,7 +780,8 @@ class SpeculativeEngine(ServingEngine):
             self._spec_last[w.slot]["n_draft"] = len(props)
             vwork.append(StepWork(w.slot, "verify", 1 + len(props),
                                   w.base, False,
-                                  drafts=np.asarray(props, np.int64)))
+                                  drafts=np.asarray(props, np.int64),
+                                  seq=w.seq))
         return vwork, (self._stack_qrows(qrows) if sampling else ())
 
     def _build_step_inputs(self, work):
@@ -850,14 +853,26 @@ class SpeculativeEngine(ServingEngine):
         return tok, q
 
     def _run_verify(self, inputs, qprobs):
-        """The verify dispatch: the base ``_run_fused`` contract (watchdog
-        + one retry) with the draft q-rows appended for the sampling
-        variant."""
+        """The verify dispatch, enqueued AND read in one supervised unit
+        (watchdog + one retry, as the base engine's enqueue) with the draft
+        q-rows appended for the sampling variant."""
         sampling = bool(self._do_sample.any())
         fused = self._fused_sample if sampling else self._fused_greedy
         budget = self._budget_for([fused])
         extra = qprobs if sampling else ()
-        thunk = lambda c: self._fused_thunk(fused, inputs, c, extra)  # noqa: E731,E501
+
+        def thunk(cancelled):
+            # dispatch AND read: the accepted counts decide the next
+            # step's positions, so nothing is enqueued behind a verify step
+            with _ttrace.span("serve.device_step"):
+                got = self._enqueue_thunk(fused, inputs, cancelled, extra)
+                if got is None:
+                    return None
+                (out_tok, n_acc, fin), built = got
+                return (np.asarray(out_tok.numpy()),
+                        np.array(np.asarray(fin.numpy()), bool), built,
+                        np.asarray(n_acc.numpy()))
+
         try:
             toks, fin, built, n_acc = self._supervised(thunk, budget)
         except StepStalledError:

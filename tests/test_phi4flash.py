@@ -174,16 +174,17 @@ def test_a_step_dispatched_twice_leaves_what_one_completed_step_leaves(model):
     clean = _engine(model, num_slots=2)
     (want,) = _served(clean, [prompt], 12)
     faulty = _engine(model, num_slots=2)
-    body, calls = faulty._fused_thunk_body, []
+    body, calls = faulty._enqueue_thunk, []
 
     def fails_after_running(fused, inputs, cancelled, extra_dev=()):
         out = body(fused, inputs, cancelled, extra_dev)
         calls.append(len(calls))
-        if len(calls) in (3, 9):        # a prefill step and a decode step
+        if len(calls) in (3, 9):        # a prefill step and a decode step,
+            # each enqueued behind a step that is still unread
             raise RuntimeError("lost after the program ran")
         return out
 
-    faulty._fused_thunk_body = fails_after_running
+    faulty._enqueue_thunk = fails_after_running
     (got,) = _served(faulty, [prompt], 12)
     assert faulty.metrics()["step_retries"] == 2
     assert got == want

@@ -1584,3 +1584,254 @@ def test_serving_mode_validation():
     config3.enable_causal_lm_decode(max_new_tokens=2)
     with pytest.raises(RuntimeError, match="mutually exclusive"):
         config3.enable_serving_mode(max_new_tokens=2)
+
+
+# ---------------------------------------------------------------------------
+# one step in flight: step N+1 is enqueued while step N runs
+# (docs/serving.md "One step in flight")
+# ---------------------------------------------------------------------------
+
+class _SerialEngine(ServingEngine):
+    """The serial oracle: the pipeline held at depth 0.  Every tick lands
+    the step it enqueued before it returns, so nothing is ever planned
+    against a step that is not harvested.  A test's subclass, not a mode of
+    the engine: the engine has none."""
+
+    def _dispatch_step(self, work):
+        super()._dispatch_step(work)
+        self._drain()
+
+
+def _family(name):
+    """(model, engine keywords) of one decoder family, all tiny."""
+    if name == "gpt":
+        pt.seed(0)
+        m = GPTStackedForPretraining(_tiny_cfg())
+        kw = dict(page_size=16, max_context=64)
+    elif name == "hybrid":               # HybridPagedCache: state in the pages
+        from paddle_tpu.models import Lfm2StackedForCausalLM, lfm2_tiny
+
+        pt.seed(11)
+        m = Lfm2StackedForCausalLM(lfm2_tiny())
+        kw = dict(page_size=8, max_context=64)
+    else:                                # SlotStateCache: state in the slots
+        from paddle_tpu.models import Phi4FlashForCausalLM, phi4flash_tiny
+
+        pt.seed(11)
+        m = Phi4FlashForCausalLM(phi4flash_tiny())
+        kw = dict(page_size=16, max_context=256)
+    m.eval()
+    return m, dict(kw, cache_dtype="float32", prefill_token_budget=5)
+
+
+def _drive(engine, plan, arrive=2):
+    """Submit ``plan`` (keywords of ``submit``) ``arrive`` a tick while the
+    engine steps, to the end; every request's tokens, in submission order."""
+    reqs, todo = [], list(plan)
+    while todo or engine.queue.depth or engine.scheduler.active_slots:
+        for kw in todo[:arrive]:
+            reqs.append(engine.submit(**kw))
+        del todo[:arrive]
+        engine.step()
+    assert all(r.state == "DONE" for r in reqs), [r.state for r in reqs]
+    assert engine.allocator.used_pages == 0
+    return [list(r.tokens) for r in reqs]
+
+
+_FAMILIES = ("gpt", "hybrid", "slot_state")
+
+
+@pytest.mark.parametrize("family", _FAMILIES)
+def test_one_step_in_flight_emits_the_serial_engines_tokens_greedy(family):
+    """Ten requests of unequal lengths through three slots, arriving while
+    others decode, a budget of 5 so that prefill chunks and decode rows share
+    every step: the engine that enqueues step N+1 before it reads step N
+    emits what the engine that reads each step first emits, token for token,
+    from the SAME one program."""
+    m, kw = _family(family)
+    rng = np.random.RandomState(3)
+    plan = [dict(prompt=rng.randint(0, m.config.vocab_size, (s,)),
+                 max_new_tokens=n)
+            for s, n in zip((3, 17, 5, 9, 14, 4, 19, 7, 11, 6),
+                            (4, 7, 2, 9, 5, 8, 3, 6, 1, 5))]
+    serial = _SerialEngine(m, num_slots=3, **kw)
+    want = _drive(serial, plan)
+    assert serial.metrics()["overlapped_steps"] == 0
+    serial.close()
+    serving.reset_serve_trace_counts()
+    eng = ServingEngine(m, num_slots=3, **kw)
+    got = _drive(eng, plan)
+    assert got == want
+    mt = eng.metrics()
+    assert mt["tokens"] == sum(len(t) for t in want)
+    assert mt["prefill_tokens"] == sum(len(p["prompt"]) for p in plan)
+    assert mt["voided_rows"] == 0          # every request ends by its length
+    assert mt["overlapped_steps"] >= mt["fused_steps"] - 3
+    assert serving.serve_trace_counts()["fused"] <= 2
+    assert eng.compiled_programs == 1
+    eng.close()
+
+
+@pytest.mark.parametrize("family", _FAMILIES)
+def test_one_step_in_flight_emits_the_serial_engines_tokens_sampled(family):
+    """Seeded sampling beside greedy rows: three requests seated together,
+    prompts of 4 to 19 tokens under a budget of 5 (so one decodes while
+    another still prefills) and unequal answer lengths.  Both engines run
+    the same steps in the same order, so each draw meets the same key."""
+    m, kw = _family(family)
+    rng = np.random.RandomState(5)
+    hot = SamplingParams(do_sample=True, temperature=0.8, top_k=50, top_p=0.9)
+    plan = [dict(prompt=rng.randint(0, m.config.vocab_size, (s,)),
+                 max_new_tokens=n, sampling=sp)
+            for s, n, sp in ((4, 9, hot), (19, 5, None), (11, 7, hot))]
+
+    def run(cls):
+        pt.seed(1234)
+        eng = cls(m, num_slots=3, **kw)
+        out = _drive(eng, plan, arrive=3)
+        mt = eng.metrics()
+        eng.close()
+        return out, mt
+
+    want, _ = run(_SerialEngine)
+    got, mt = run(ServingEngine)
+    assert got == want
+    assert mt["voided_rows"] == 0
+    assert mt["overlapped_steps"] == mt["fused_steps"] - 1
+
+
+@pytest.mark.parametrize("family", _FAMILIES)
+def test_requests_that_end_by_eos_match_the_serial_engine(family):
+    """Fourteen requests through three slots, most with an EOS drawn from
+    their own greedy answer: each such end leaves a void run in the step
+    enqueued behind it, whose writes (K/V, a page's tail, a slot's state
+    row and ring) must not reach the request that takes the slot and the
+    pages next.  The prefix cache is on where the model has one."""
+    m, kw = _family(family)
+    kw = dict(kw, num_slots=3, prefix_cache=family != "slot_state")
+    rng = np.random.RandomState(100)
+    plan = [dict(prompt=rng.randint(0, m.config.vocab_size,
+                                    (int(rng.randint(2, 24)),)),
+                 max_new_tokens=int(rng.randint(3, 14))) for _ in range(14)]
+    serial = _SerialEngine(m, **kw)
+    answers = _drive(serial, plan)
+    serial.close()
+    for p, toks in zip(plan, answers):
+        if rng.rand() < 0.6:
+            p["eos_token_id"] = int(toks[rng.randint(0, len(toks))])
+    serial = _SerialEngine(m, **kw)
+    want = _drive(serial, plan)
+    serial.close()
+    eng = ServingEngine(m, **kw)
+    assert _drive(eng, plan) == want
+    mt = eng.metrics()
+    assert mt["voided_rows"] >= 5, mt["voided_rows"]
+    assert mt["tokens"] == sum(len(t) for t in want)
+    eng.close()
+
+
+def test_an_eos_in_step_n_voids_the_slots_row_in_step_n_plus_1():
+    """Only step N's result can say that a request hit its EOS: step N+1 is
+    enqueued with a row for it.  That row is void: never emitted, never
+    counted, its pages returned once, and the request that gets those pages
+    next reads nothing the void row wrote."""
+    pt.seed(9)
+    cfg = _tiny_cfg()
+    m = GPTStackedForPretraining(cfg)
+    m.eval()
+
+    def ref(p, n):
+        return np.asarray(m.generate(pt.to_tensor(p[None, :], dtype="int64"),
+                                     max_new_tokens=n, max_seq_len=64,
+                                     cache_dtype="float32").numpy())[0]
+
+    pa, pb, pc = (_prompt(cfg, s=s, seed=k)[0]
+                  for s, k in ((6, 4), (20, 5), (9, 6)))
+    eos = int(ref(pa, 6)[6 + 2])              # a's greedy token at step 2
+    # a pool of four pages: a holds one, b three, so c waits for a's page
+    eng = ServingEngine(m, num_slots=2, page_size=16, max_context=64,
+                        cache_dtype="float32", num_pages=5)
+    ra = eng.submit(pa, 6, eos_token_id=eos)
+    rb = eng.submit(pb, 20)
+    rc = eng.submit(pc, 7)
+    freed = []
+    free = eng.allocator.free
+    eng.allocator.free = lambda pages: (freed.extend(pages), free(pages))[1]
+    eng.run_until_idle()
+    assert ra.finished and ra.tokens[-1] == eos and len(ra.tokens) <= 3
+    assert np.array_equal(rb.output_ids(), ref(pb, 20))
+    assert np.array_equal(rc.output_ids(), ref(pc, 7))
+    mt = eng.metrics()
+    assert mt["voided_rows"] == 1
+    assert mt["tokens"] == len(ra.tokens) + 20 + 7
+    # a's page went back once and came out again as c's: five pages freed
+    # by three retirements, the one page of a and c twice
+    assert len(freed) == 5 and len(set(freed)) == 4, freed
+    assert eng.allocator.used_pages == 0
+    eng.close()
+
+
+def test_a_saturated_run_overlaps_and_a_verify_step_never_does():
+    """With every slot seated every step has a successor: nearly all steps
+    are enqueued behind an unread one.  The speculative engine's verify
+    step decides the next positions by its accepted count, so nothing can
+    be planned ahead of it, and the code sees that by itself."""
+    from paddle_tpu.serving import SpeculativeEngine
+
+    pt.seed(0)
+    cfg = _tiny_cfg()
+    m = GPTStackedForPretraining(cfg)
+    m.eval()
+    rng = np.random.RandomState(2)
+    prompts = [rng.randint(0, cfg.vocab_size, (s,))
+               for s in (5, 9, 7, 12, 17, 4, 11, 6) * 3]
+    kw = dict(num_slots=4, page_size=16, max_context=64,
+              cache_dtype="float32")
+    eng = ServingEngine(m, **kw)
+    want = eng.generate_batch(prompts, 12)
+    mt = eng.metrics()
+    assert mt["overlapped_steps"] / mt["fused_steps"] > 0.9, mt
+    eng.close()
+    spec = SpeculativeEngine(m, m, spec_k=3, **kw)
+    got = spec.generate_batch(prompts, 12)
+    for g, w in zip(got, want):
+        assert np.array_equal(g, w)
+    mt = spec.metrics()
+    assert mt["fused_steps"] > 0 and mt["overlapped_steps"] == 0
+    assert mt["voided_rows"] == 0
+    spec.close()
+
+
+def test_a_plan_ahead_of_a_step_in_flight_takes_that_step_for_done():
+    """``plan_step(ahead=)``: positions moved on by the step's counts, its
+    chunk off the pending prompt, a request at its last token left out, a
+    run of another seating ignored; the mirrors untouched."""
+    from paddle_tpu.serving.admission import AdmissionScheduler
+
+    class Req:
+        def __init__(self, n_tokens, max_new):
+            self.tokens, self.max_new_tokens = [0] * n_tokens, max_new
+
+    sched = AdmissionScheduler(4, 4, 16, BlockAllocator(17))
+    a = sched.try_admit(Req(0, 8), 30)        # mid-prefill: 12 pending
+    sched.slots[a].pending = np.arange(12)
+    b = sched.try_admit(Req(3, 8), 30)        # decoding at position 9
+    sched.advance(b, 9)
+    c = sched.try_admit(Req(7, 8), 30)        # emits its last token next
+    sched.advance(c, 20)
+    first = sched.plan_step(8)
+    assert [(w.slot, w.kind, w.count, w.base, w.chained) for w in first] == [
+        (a, "prefill", 8, 0, False), (b, "decode", 1, 9, False),
+        (c, "decode", 1, 20, False)]
+    ahead = sched.plan_step(8, first)
+    assert [(w.slot, w.kind, w.count, w.base, w.completes, w.chained)
+            for w in ahead] == [(a, "prefill", 4, 8, True, False),
+                                (b, "decode", 1, 10, False, True)]
+    assert sched.slots[a].pos == 0 and len(sched.slots[a].pending) == 12
+    # slot b seated again while its run is in flight: nothing carries over
+    sched.retire(b)
+    b2 = sched.try_admit(Req(0, 8), 30)
+    sched.slots[b2].pending = np.arange(3)
+    assert b2 == b and sched.live(first[1]) is None
+    again = {w.slot: w for w in sched.plan_step(8, first)}
+    assert (again[b].kind, again[b].base, again[b].count) == ("prefill", 0, 3)

@@ -591,3 +591,146 @@ def test_step_metrics_expose_fault_counters(served):
     for key in ("quarantined", "step_retries", "rebuilds"):
         assert key in full
     eng.run_until_idle()
+
+
+# ---------------------------------------------------------------------------
+# one step in flight: step N+1 is enqueued before step N is read, so what
+# only N's result can say voids N+1's rows (docs/serving.md "One step in
+# flight").  Four tokens a request, both prompts whole in step 0: a seated
+# request emits its k-th token in the harvest of step k - 1.
+# ---------------------------------------------------------------------------
+
+def test_nan_row_in_step_n_voids_that_slots_row_in_step_n_plus_1(served):
+    m, cfg, prompts, refs = served
+    eng = _engine(m)
+    inj = FaultInjector().inject("after_decode", at=2, kind="nan_logits",
+                                 slots=[0]).install(eng)
+    reqs = [eng.submit(p, N_NEW) for p in prompts[:4]]
+    eng.run_until_idle()
+    assert inj.fired("nan_logits") == 1
+    mt = eng.metrics()
+    assert mt["quarantined"] == 1 and mt["recoveries"] == 0
+    # step 3 was enqueued with a row for the poisoned slot: dropped, uncounted
+    assert mt["voided_rows"] == 1
+    (poisoned,) = [r for r in reqs if isinstance(r.error, NaNLogitsError)]
+    assert poisoned.state == RequestState.FAILED
+    assert len(poisoned.tokens) == 2          # steps 0 and 1, nothing after
+    assert mt["tokens"] == sum(len(r.tokens) for r in reqs)
+    assert len([r for r in reqs if r.state == RequestState.DONE]) == 3
+    _check_done_parity(reqs, refs)
+    assert eng.allocator.used_pages == 0
+
+
+@pytest.mark.parametrize("times", [1, 2])
+def test_failure_at_the_wait_for_step_n_voids_step_n_plus_1(served, times):
+    """A device failure is seen where the host waits for the step's tokens,
+    with the next step enqueued already.  The read is tried once more; if
+    that fails too the step enqueued behind it is dropped unread and the
+    seated requests end FAILED, the queued ones untouched."""
+    m, cfg, prompts, refs = served
+    eng = _engine(m)
+    inj = FaultInjector().inject("await_decode", at=2, times=times,
+                                 kind="step_exception").install(eng)
+    reqs = [eng.submit(p, N_NEW) for p in prompts[:4]]
+    eng.run_until_idle()
+    assert inj.fired("step_exception") == times
+    mt = eng.metrics()
+    assert mt["step_retries"] == 1
+    if times == 1:                            # transient: nothing is lost
+        assert mt["recoveries"] == 0 and mt["failed"] == 0
+        assert mt["voided_rows"] == 0
+        assert [r.state for r in reqs] == [RequestState.DONE] * 4
+    else:
+        assert mt["recoveries"] == 1 and mt["rebuilds"] == 0
+        assert mt["voided_rows"] == 2         # both rows of step 3
+        failed = [r for r in reqs if r.state == RequestState.FAILED]
+        assert failed == reqs[:2]             # the seated pair
+        assert all(isinstance(r.error, InjectedFault) for r in failed)
+        assert [len(r.tokens) for r in failed] == [2, 2]
+        assert [r.state for r in reqs[2:]] == [RequestState.DONE] * 2
+    assert mt["tokens"] == sum(len(r.tokens) for r in reqs)
+    _check_done_parity(reqs, refs)
+    assert eng.allocator.used_pages == 0
+
+
+def test_stall_at_the_wait_for_step_n_voids_step_n_plus_1(served):
+    m, cfg, prompts, refs = served
+    eng = _engine(m, stall_budget_s=0.5)
+    _warm(eng, prompts)
+    old_k = eng.cache.k._value
+    inj = FaultInjector().inject("await_decode", at=2, kind="step_stall",
+                                 duration=2.0).install(eng)
+    reqs = [eng.submit(p, N_NEW) for p in prompts[:4]]
+    eng.run_until_idle()
+    assert inj.fired("step_stall") == 1
+    mt = eng.metrics()
+    assert mt["recoveries"] == 1 and mt["rebuilds"] == 1
+    assert mt["voided_rows"] == 2             # step 3, enqueued behind it
+    stalled = [r for r in reqs if isinstance(r.error, StepStalledError)]
+    assert stalled == reqs[:2]
+    assert [len(r.tokens) for r in stalled] == [2, 2]
+    assert [r.state for r in reqs[2:]] == [RequestState.DONE] * 2
+    _check_done_parity(reqs, refs)
+    assert eng.allocator.used_pages == 0
+    deadline = time.monotonic() + 5.0
+    while not old_k.is_deleted() and time.monotonic() < deadline:
+        time.sleep(0.05)
+    assert old_k.is_deleted(), "zombie cleanup never released the old pool"
+    assert not eng.cache.k._value.is_deleted()
+
+
+def test_dispatch_failure_behind_a_step_in_flight_lands_that_step_first(
+        served):
+    """The enqueue of step 2 fails twice while step 1 is unread: step 1 was
+    enqueued whole, so its tokens are emitted before the seated pair fails."""
+    m, cfg, prompts, refs = served
+    eng = _engine(m)
+    FaultInjector().inject("before_decode", at=2, times=2,
+                           kind="step_exception").install(eng)
+    reqs = [eng.submit(p, N_NEW) for p in prompts[:4]]
+    eng.run_until_idle()
+    mt = eng.metrics()
+    assert mt["recoveries"] == 1 and mt["step_retries"] == 1
+    assert mt["voided_rows"] == 0             # nothing was enqueued behind
+    failed = [r for r in reqs if r.state == RequestState.FAILED]
+    assert failed == reqs[:2]
+    assert [len(r.tokens) for r in failed] == [2, 2]      # steps 0 and 1
+    for r, ref in zip(failed, refs):
+        assert r.tokens == list(ref[len(r.prompt):][:2])
+    assert [r.state for r in reqs[2:]] == [RequestState.DONE] * 2
+    _check_done_parity(reqs, refs)
+    assert eng.allocator.used_pages == 0
+
+
+@pytest.mark.parametrize("how", ["cancel", "deadline"])
+def test_request_retired_while_its_row_is_in_flight(served, how):
+    """A cancel or a deadline is honored at the tick's reap, with a step that
+    carries the request's row still unread: that row is void (no token
+    after the terminal state), the other slot's is not."""
+    m, cfg, prompts, refs = served
+    eng = _engine(m)
+    _warm(eng, prompts)                       # the compile is no deadline's
+    base = eng.metrics()["tokens"]
+    ra = eng.submit(prompts[0], N_NEW,
+                    deadline_s=0.5 if how == "deadline" else None)
+    rb = eng.submit(prompts[1], N_NEW)
+    eng.step()                                # step 0 enqueued
+    eng.step()                                # step 1 enqueued, step 0 read
+    assert [len(r.tokens) for r in (ra, rb)] == [1, 1]
+    if how == "cancel":
+        assert ra.cancel()
+    else:
+        time.sleep(0.6)
+    eng.step()                                # reaped; step 1 read: ra void
+    assert ra.state == (RequestState.CANCELLED if how == "cancel"
+                        else RequestState.TIMED_OUT)
+    assert isinstance(ra.error, RequestCancelled if how == "cancel"
+                      else DeadlineExceeded)
+    assert len(ra.tokens) == 1 and len(rb.tokens) == 2
+    eng.run_until_idle()
+    mt = eng.metrics()
+    assert mt["voided_rows"] == 1
+    assert mt["tokens"] - base == 1 + N_NEW
+    assert rb.state == RequestState.DONE
+    assert np.array_equal(rb.output_ids(), refs[1])
+    assert eng.allocator.used_pages == 0

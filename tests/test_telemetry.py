@@ -679,9 +679,10 @@ def test_step_phases_spanned(served):
 
 
 def test_step_spans_know_their_step(served):
-    """serve.step records the prompt tokens its step carried (equal to what
-    the engine's totals moved by), nothing else, and serve.device_step, on
-    the watchdog's thread, reaches it through its parents."""
+    """serve.step records the prompt tokens of the step it HARVESTED (equal to
+    what the engine's totals moved by: a step is read one tick after it is
+    enqueued), nothing else, and serve.device_step, the wait for that step on
+    the watchdog's thread, reaches it through its parent."""
     m, cfg, prompts = served
     tt.disable()
     tr = tt.enable(annotate=False)
@@ -701,16 +702,16 @@ def test_step_spans_know_their_step(served):
     recorded = [s for s in spans if s.name == "serve.step"]
     assert len(recorded) == len(steps) >= 5
     assert [s.args for s in recorded] == [{"prefill_tokens": n} for n in steps]
-    assert steps[:4] == [8, 8, 1, 0]
+    assert steps[:5] == [0, 8, 8, 1, 0]     # the first tick only enqueues
     device = [s for s in spans if s.name == "serve.device_step"]
-    assert len(device) == len(steps)
-    for d in device:
+    assert len(device) == len(steps) - 1
+    for d, n in zip(device, steps[1:]):
         assert d.thread_name.startswith("serving-step-")
         chain = [d]
         while chain[-1].parent is not None:
             chain.append(by_id[chain[-1].parent])
-        assert [c.name for c in chain] == ["serve.device_step", "serve.dispatch",
-                                           "serve.step"]
+        assert [c.name for c in chain] == ["serve.device_step", "serve.step"]
+        assert chain[-1].args == {"prefill_tokens": n}
         assert chain[-1].tid != d.tid
 
 
