@@ -545,13 +545,43 @@ class _Flight:
     plan's stats, its output still on the device (a slot's sampled token,
     or -1 where its logits were not finite), the watchdog budget it was
     dispatched under, whether its variant had never run before, and whether
-    its predecessor was still unread when it was enqueued."""
+    its predecessor was still unread when it was enqueued.
 
-    __slots__ = ("work", "stats", "out", "budget", "first", "overlapped")
+    It also keeps its own account, on ``time.perf_counter_ns`` (the
+    tracer's clock): ``seq`` (the engine's count of flights), what it
+    carried (``rows``, ``prefill_tokens``, ``decode_rows``, as
+    ``_fold_plan_stats`` counts them), ``t_enq`` (taken as the enqueue call
+    returned), ``t_ready`` (taken as the blocking read returned) and
+    ``wait_ns`` (how long that read blocked), and three readings of an
+    output's non-blocking ``is_ready()``: ``late``, the PREDECESSOR's output
+    was complete when this flight's enqueue came (the device's queue was
+    empty: a bubble precedes this step); ``drained``, the same of THIS
+    flight when its successor's enqueue came; ``ready_at_read``, this
+    flight was complete when its read began (the host, not the device,
+    decided when the step "ended").  ``parent`` is the span open when it
+    was enqueued (``serve.dispatch``; None with tracing off)."""
 
-    def __init__(self, work, stats, out, budget, first, overlapped):
+    __slots__ = ("work", "stats", "out", "budget", "first", "overlapped",
+                 "seq", "rows", "prefill_tokens", "decode_rows", "t_enq",
+                 "t_ready", "wait_ns", "late", "drained", "ready_at_read",
+                 "parent")
+
+    def __init__(self, work, stats, out, budget, first, overlapped,
+                 seq, late, parent):
+        self.t_enq = time.perf_counter_ns()
         self.work, self.stats, self.out = work, stats, out
         self.budget, self.first, self.overlapped = budget, first, overlapped
+        self.seq, self.late, self.parent = seq, late, parent
+        self.rows = stats["n_tokens"]
+        self.prefill_tokens = sum(w.count for w in work
+                                  if w.kind == "prefill")
+        self.decode_rows = self.rows - self.prefill_tokens
+        self.drained = self.ready_at_read = False
+        self.t_ready = self.wait_ns = 0
+
+    def ready(self) -> bool:
+        """Whether the output is complete on the device; never blocks."""
+        return self.out._value.is_ready()
 
 
 class ServingEngine:
@@ -806,6 +836,10 @@ class ServingEngine:
         # that a step with none takes (made on first use)
         self._inflight: Optional[_Flight] = None
         self._no_prev: Optional[Tensor] = None
+        # flights made so far (the next one's ``seq``), and the (seq,
+        # t_ready) of the last one landed
+        self._flights = 0
+        self._last_landed: Tuple[int, Optional[int]] = (-1, None)
 
         # host mirrors shipped to the jitted step each call (fixed shapes)
         self._tokens = np.zeros((num_slots,), np.int64)
@@ -870,6 +904,13 @@ class ServingEngine:
                         # because only the predecessor's results could say
                         # their slot was gone (docs/serving.md)
                         "overlapped_steps": 0, "voided_rows": 0,
+                        # the flights' own account (docs/serving.md "One
+                        # step in flight"): fused steps enqueued behind a
+                        # predecessor that had already finished, the time
+                        # the tick was blocked reading a step's tokens, and
+                        # the time from a step's enqueue to its tokens
+                        "host_late_steps": 0, "land_wait_ns": 0,
+                        "flight_ns": 0,
                         "work_items": 0, "work_capacity": 0,
                         "launched_items": 0, "launched_grid_steps": 0,
                         # the pool write's items (tile groups the steps'
@@ -1187,13 +1228,13 @@ class ServingEngine:
             # mirrors and of the plan in flight; the mirrors only advance
             # in harvest — a retry after a transient failure enqueues the
             # SAME idempotent step
-            with _ttrace.span("serve.pack"):
+            with _ttrace.span("serve.pack", seq=self._flights):
                 inputs, stats = self._build_step_inputs(work)
             try:
                 # the nested jit.fused_step span carries the program's
                 # CostReport digest (per compiled entry, so greedy and
                 # sampling variants each report their own cost)
-                with _ttrace.span("serve.dispatch"):
+                with _ttrace.span("serve.dispatch", seq=self._flights):
                     nxt = self._run_fused(work, stats, inputs, prev)
             except StepBuildError:
                 raise
@@ -1272,9 +1313,40 @@ class ServingEngine:
         self._totals["fused_steps"] += 1
         if flight.overlapped:
             self._totals["overlapped_steps"] += 1
-        with _ttrace.span("serve.harvest"):
+        self._account_flight(flight)
+        with _ttrace.span("serve.harvest", flight=flight.seq):
             self._harvest_fused(flight.work, flight.stats, *got)
         self._backoff_s = self.readmission_backoff_s
+
+    def _account_flight(self, flight: _Flight):
+        """A landed flight's stamps into the totals and, under a tracer,
+        ONE ``serve.flight`` span from its enqueue to its tokens (its two
+        ends lie in different ticks: ``Tracer.record_interval``).  A step
+        with no predecessor in flight met an empty device because the
+        engine was empty: the span says ``drained`` of it, the counter of
+        steps the host was late for leaves it out."""
+        totals = self._totals
+        if flight.late:
+            totals.inc("host_late_steps")
+        totals.inc("land_wait_ns", flight.wait_ns)
+        totals.inc("flight_ns", flight.t_ready - flight.t_enq)
+        last_seq, last_ready = self._last_landed
+        self._last_landed = (flight.seq, flight.t_ready)
+        tracer = _ttrace._tracer
+        if tracer is not None:
+            # flights overlap their neighbours by one: two rows hold them
+            tracer.record_interval(
+                "serve.flight", flight.t_enq, flight.t_ready,
+                parent=flight.parent, row=f"serve.flight.{flight.seq % 2}",
+                seq=flight.seq, rows=flight.rows,
+                prefill_tokens=flight.prefill_tokens,
+                decode_rows=flight.decode_rows,
+                overlapped=flight.overlapped,
+                drained=flight.late or not flight.overlapped,
+                ready_at_read=flight.ready_at_read, wait_ns=flight.wait_ns,
+                prev_ready_ns=(last_ready if last_seq == flight.seq - 1
+                               else None),
+                landed_in=tracer.current_id())
 
     def _commit_step_metrics(self, t0: float) -> dict:
         """Fold the step's tallies into totals + gauges and build the
@@ -1293,7 +1365,6 @@ class ServingEngine:
             "occupancy": sched.occupancy,
             "tokens_this_step": emitted,
             "tokens_per_sec": emitted / dt if dt > 0 else 0.0,
-            "step_seconds": dt,
             # ragged-launch occupancy of the last dispatched step:
             # real work items / fixed work-list length, and real query
             # rows / packed block rows (the MXU-side figure)
@@ -1349,8 +1420,13 @@ class ServingEngine:
             # returns results of non-abandoned runs, so a zombie's build
             # never lands here
             self._sampling_cache = built
-        return _Flight(work, stats, out, budget, never_ran,
-                       behind is not None)
+        tracer = _ttrace._tracer
+        flight = _Flight(work, stats, out, budget, never_ran,
+                         behind is not None, self._flights,
+                         behind is not None and behind.drained,
+                         tracer.current_id() if tracer is not None else None)
+        self._flights += 1
+        return flight
 
     def _budget_for(self, static_fns) -> Optional[float]:
         """Watchdog budget for one supervised dispatch: the stall budget
@@ -1456,9 +1532,13 @@ class ServingEngine:
                 self._host_to_dev(self._top_p.copy()),
                 self._host_to_dev(self._top_k.copy()),
                 self._host_to_dev(self._do_sample.copy()))
-        out = fused(
-            *(self._host_to_dev(np.ascontiguousarray(a)) for a in inputs),
-            *cache, *extra_dev)
+        dev = [self._host_to_dev(np.ascontiguousarray(a)) for a in inputs]
+        behind = self._inflight
+        if behind is not None:
+            # the last look before the enqueue: a predecessor that is
+            # complete by now left the device's queue empty
+            behind.drained = behind.ready()
+        out = fused(*dev, *cache, *extra_dev)
         return out, built
 
     def _read_thunk(self, flight: _Flight, cancelled):
@@ -1468,11 +1548,15 @@ class ServingEngine:
         under a watchdog this is the supervised _StepWorker, so the
         exported trace shows the wait on the worker's row (its parent all
         the same, the tick's serve.step: _supervised hands it over)."""
-        with _ttrace.span("serve.device_step"):
+        with _ttrace.span("serve.device_step", flight=flight.seq):
             self._hook("await_decode")
             if cancelled():
                 return None
+            flight.ready_at_read = flight.ready()
+            t0 = time.perf_counter_ns()
             out = np.asarray(flight.out.numpy())
+            flight.t_ready = time.perf_counter_ns()
+            flight.wait_ns = flight.t_ready - t0
             return np.maximum(out, 0), out >= 0
 
     def _harvest_fused(self, work, stats, toks_np: np.ndarray,
@@ -1825,8 +1909,9 @@ class ServingEngine:
             self._worker = _StepWorker(f"serving-step-{id(self):x}")
         tracer = _ttrace._tracer
         if tracer is not None:
-            # the worker's spans (serve.device_step) take the span open
-            # HERE (serve.dispatch) as parent: the tree crosses the thread
+            # the worker's spans take the span open HERE as parent (the
+            # enqueue's jit.fused_step: serve.dispatch; serve.device_step:
+            # the tick's serve.step): the tree crosses the thread
             fn = tracer.handing_over(fn)
         return self._worker.run(fn, budget, cleanup=self._zombie_cleanup())
 
@@ -2119,13 +2204,12 @@ class ServingEngine:
     def metrics(self) -> dict:
         """Cumulative totals + the last step's gauges.  The ragged-launch
         occupancy means make the fused step's win measurable: how full the
-        plan's fixed work-list arrays ran (``mean_grid_occupancy``), how
-        many of the kernel's grid steps along the work list were real
-        items (``mean_launch_occupancy``: 1.0 while the launch ends at
-        ``n_items``) and how many of the packed query-block rows carried
-        real tokens (``mean_q_row_occupancy``) across every dispatched
-        step.  ``ragged_heads_per_block`` is the ``hb`` of this engine's
-        geometry (the heads of a page a work item moves a grid step) and
+        plan's fixed work-list arrays ran (``mean_grid_occupancy``) and
+        how many of the packed query-block rows carried real tokens
+        (``mean_q_row_occupancy``) across every dispatched step (the launch
+        itself ends at ``n_items``: ``launched_items == work_items``).
+        ``ragged_heads_per_block`` is the ``hb`` of this engine's geometry
+        (the heads of a page a work item moves a grid step) and
         ``launched_grid_steps`` the grid steps a chip's launches walked:
         ``launched_items x local heads // hb``, equal to
         ``launched_items`` wherever an item moves all its heads.
@@ -2153,9 +2237,6 @@ class ServingEngine:
                                       if wc else 0.0)
         out["mean_q_row_occupancy"] = (self._totals["block_rows"] / rc
                                        if rc else 0.0)
-        li = self._totals["launched_items"]
-        out["mean_launch_occupancy"] = (self._totals["work_items"] / li
-                                        if li else 0.0)
         out["ragged_heads_per_block"] = self.ragged_heads_per_block
         wi, fs = self._totals["write_items"], self._totals["fused_steps"]
         out["pool_write_items"] = wi / fs if fs else 0.0

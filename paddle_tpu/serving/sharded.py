@@ -426,6 +426,7 @@ class ShardedServingEngine:
         per = [eng.metrics() for eng in self.replicas]
         sum_keys = ("steps", "tokens", "admitted", "completed",
                     "fused_steps", "overlapped_steps", "voided_rows",
+                    "host_late_steps", "land_wait_ns", "flight_ns",
                     "prefill_tokens", "failed", "cancelled",
                     "timed_out", "shed", "quarantined", "recoveries",
                     "rebuilds", "pages_used", "pages_capacity",

@@ -21,6 +21,13 @@ Contract (docs/observability.md):
 - **a tree** — every span has an ``id`` and the ``parent`` open on its
   thread when it started; ``Tracer.handing_over`` carries the parent to
   work another thread runs.
+- **intervals** — ``Tracer.record_interval`` records a completed range
+  whose two ends were stamped (``time.perf_counter_ns``) at different
+  times, by whoever owns the thing it times: the serving engine's
+  ``serve.flight``, from a step's enqueue to its landing a tick later.
+- **the collector** — under a tracer, and only then, a ``gc.callbacks``
+  hook records each collection as an ordinary span ``host.gc`` on the
+  collecting thread, innermost wherever it strikes.
 - **the device's side** — the tracer remembers (weakly: it keeps no
   weights or pools alive) each compiled program it saw dispatched
   (``Tracer.programs``) and maps their operations to program scopes
@@ -29,6 +36,7 @@ Contract (docs/observability.md):
 """
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import os
@@ -98,6 +106,15 @@ def _thread_info() -> tuple:
     return info
 
 
+#: row name -> (tid, row name) of ``record_interval(row=)``: ids counted up
+#: from 1, far under any thread's ident (an address)
+_rows: Dict[str, tuple] = {}
+
+
+def _row_info(row: str) -> tuple:
+    return _rows.setdefault(row, (1 + len(_rows), row))
+
+
 class Tracer:
     def __init__(self, capacity: int = 65536, annotate: bool = True):
         if capacity < 1:
@@ -130,6 +147,25 @@ class Tracer:
 
     def spans(self) -> List[Span]:
         return list(self._buf)
+
+    def record_interval(self, name: str, t0_ns: int, t1_ns: int,
+                        parent: Optional[int] = None,
+                        row: Optional[str] = None, **args) -> Span:
+        """Record a completed span whose start and end (``perf_counter_ns``)
+        were stamped at different times, perhaps in different ticks, with
+        an id of its own and the ``parent`` the caller kept from its start.
+        No ``TraceAnnotation``: one cannot be back-dated, so the span is in
+        this buffer and the Chrome export and not in a device capture.
+        ``row`` names a row of its own in the export for spans that overlap
+        what the recording thread's own row shows."""
+        if row is None:
+            tid, tname = _thread_info()
+        else:
+            tid, tname = _row_info(row)
+        s = Span(name, t0_ns, t1_ns - t0_ns, tid, tname, args or None,
+                 next(self._ids), parent)
+        self.record(s)
+        return s
 
     def _stack(self) -> List[Optional[int]]:
         stack = getattr(self._open, "stack", None)
@@ -235,12 +271,35 @@ class _SpanCtx:
         return False
 
 
+#: the ``host.gc`` span of the collection that is running (collections do
+#: not nest: one slot serves every thread)
+_gc_open: Optional[_SpanCtx] = None
+
+
+def _on_gc(phase: str, info: Dict[str, int]):
+    """The ``gc.callbacks`` hook, installed by ``enable`` and removed by
+    ``disable``: a collection is a span ``host.gc`` on the thread it struck,
+    under whatever span was open there."""
+    global _gc_open
+    if phase == "start":
+        t, _gc_open = _tracer, None
+        if t is not None:
+            _gc_open = _SpanCtx(t, "host.gc",
+                                {"generation": info["generation"]})
+            _gc_open.__enter__()
+    elif _gc_open is not None:
+        ctx, _gc_open = _gc_open, None
+        ctx.set(collected=info["collected"])
+        ctx.__exit__(None, None, None)
+
+
 def enable(capacity: int = 65536, annotate: bool = True) -> Tracer:
     """Install a process-wide tracer (idempotent: an already-active
     tracer is returned unchanged so nested enables compose)."""
     global _tracer
     if _tracer is None:
         _tracer = Tracer(capacity=capacity, annotate=annotate)
+        gc.callbacks.append(_on_gc)
     return _tracer
 
 
@@ -249,6 +308,8 @@ def disable() -> Optional[Tracer]:
     spans stay readable/exportable after deactivation."""
     global _tracer
     t, _tracer = _tracer, None
+    if t is not None:
+        gc.callbacks.remove(_on_gc)
     return t
 
 

@@ -1219,7 +1219,6 @@ def test_invocation_counters_exact():
         # grid step of every dispatched kernel was a real work item
         assert mets["launched_items"] == mets["work_items"] > 0
         assert mets["launched_items"] < mets["work_capacity"]
-        assert mets["mean_launch_occupancy"] == 1.0
         # a work item moves every head of its page (the tiny geometry is
         # far inside the kernel's VMEM budget): a grid step an item
         assert mets["ragged_heads_per_block"] == cfg.num_heads

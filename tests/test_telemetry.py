@@ -16,6 +16,7 @@ instrumentation — including its behavior under injected faults:
   construction; counters stay exact across a watchdog rebuild and
   randomized fault schedules.
 """
+import gc
 import json
 import os
 import re
@@ -235,11 +236,17 @@ def test_counter_set_dict_compat():
 
 @pytest.fixture()
 def tracer():
-    """A fresh process-wide tracer, always detached at teardown."""
+    """A fresh process-wide tracer, always detached at teardown.  Automatic
+    collections are held off meanwhile: under a tracer each one is a span
+    (``host.gc``), and these tests count every span they record."""
     tt.disable()
+    was = gc.isenabled()
+    gc.disable()
     tr = tt.enable(capacity=1024, annotate=False)
     yield tr
     tt.disable()
+    if was:
+        gc.enable()
 
 
 def test_span_disabled_is_noop():
@@ -355,6 +362,70 @@ def test_disabled_path_allocates_nothing():
     with ctx as opened:
         assert opened is None
     assert not hasattr(tt, "_stack") and not hasattr(tt, "_ids")
+
+
+def test_record_interval_keeps_ids_and_parents(tracer, tmp_path):
+    """A span whose two ends were stamped at different times: its id comes
+    from the tracer's one counter, its parent is what the caller kept from
+    its start, its thread the recorder's unless it names a row of its own,
+    and the export carries all of it."""
+    with tt.span("tick.one") as one:
+        with tt.span("dispatch") as d:
+            t0, parent = time.perf_counter_ns(), tracer.current_id()
+        assert parent == d.id
+    with tt.span("tick.two") as two:
+        t1 = time.perf_counter_ns()
+        got = tracer.record_interval("flight", t0, t1, parent=parent, seq=7,
+                                     landed_in=tracer.current_id())
+        rowed = tracer.record_interval("flight", t0, t1, row="flights.1")
+    by = {s.name: s for s in tracer.spans() if s.name != "flight"}
+    assert (got.t0_ns, got.dur_ns) == (t0, t1 - t0)
+    assert got.parent == by["dispatch"].id and got.parent != one.id
+    assert got.args == {"seq": 7, "landed_in": two.id}
+    assert got.tid == by["tick.two"].tid and rowed.args is None
+    assert rowed.thread_name == "flights.1" and rowed.tid != got.tid
+    assert tracer.record_interval("flight", t0, t1, row="flights.1").tid == rowed.tid
+    assert tracer.record_interval("flight", t0, t1, row="flights.0").tid != rowed.tid
+    ids = [s.id for s in tracer.spans()]
+    assert len(set(ids)) == len(ids) == 7 and got.id > two.id
+    doc = tt.export_chrome_trace(str(tmp_path / "t.json"), tracer=tracer)
+    flights = [e for e in doc["traceEvents"] if e["name"] == "flight"]
+    assert flights[0]["args"] == {"seq": 7, "landed_in": two.id, "id": got.id,
+                                  "parent": by["dispatch"].id}
+    assert flights[0]["ts"] == t0 / 1000.0 and flights[0]["dur"] == (t1 - t0) / 1000.0
+    rows = {e["args"]["name"] for e in doc["traceEvents"] if e["ph"] == "M"}
+    assert {"flights.0", "flights.1"} <= rows
+
+
+def test_the_collector_is_a_span_under_a_tracer_and_nothing_without():
+    """With a tracer a collection is a span ``host.gc`` on the collecting
+    thread, innermost wherever it strikes; without one ``gc.callbacks`` is as
+    it was found and ``span()`` is still the shared no-op."""
+    tt.disable()
+    found = list(gc.callbacks)
+    tr = tt.enable(capacity=1024, annotate=False)
+    try:
+        assert tt.enable() is tr                        # nested: one hook
+        assert gc.callbacks == found + [tt._on_gc]
+        with tt.span("tick") as tick:
+            with tt.span("pack") as pack:
+                gc.collect()
+            gc.collect(0)
+    finally:
+        assert tt.disable() is tr
+    assert gc.callbacks == found
+    assert tt.disable() is None and gc.callbacks == found
+    gc.collect()                                        # no tracer: no span
+    pauses = [s for s in tr.spans() if s.name == "host.gc"]
+    # (a collection of its own may strike anywhere in the block besides)
+    asked = [s for s in pauses if s.args["generation"] == 2]
+    assert asked and asked[0].parent == pack.id
+    assert any(s.parent == tick.id and s.args["generation"] == 0 for s in pauses)
+    for s in pauses:
+        assert s.dur_ns > 0 and s.args["collected"] >= 0
+        assert s.tid == threading.get_ident()
+    assert len(pauses) == len([s for s in tr.spans() if s.name == "host.gc"])
+    assert tt.span("x") is tt._NOOP and tt._gc_open is None
 
 
 class _Entry:
@@ -501,7 +572,7 @@ def test_record_event_records_span():
         ev = profiler.RecordEvent("my.range")
         ev.begin()
         ev.end()
-        assert [s.name for s in tr.spans()] == ["my.range"]
+        assert [s.name for s in tr.spans() if s.name != "host.gc"] == ["my.range"]
     finally:
         tt.disable()
 
@@ -713,6 +784,113 @@ def test_step_spans_know_their_step(served):
         assert [c.name for c in chain] == ["serve.device_step", "serve.step"]
         assert chain[-1].args == {"prefill_tokens": n}
         assert chain[-1].tid != d.tid
+
+
+def _spans_of(tr, name):
+    return [s for s in tr.spans() if s.name == name]
+
+
+@pytest.mark.parametrize("watchdog", [False, True])
+def test_a_step_in_flight_is_one_recorded_span(served, watchdog):
+    """One ``serve.flight`` a fused step, from its enqueue in one tick to its
+    tokens in the next: consecutive ``seq``, the parent the ``serve.dispatch``
+    that enqueued it, ``landed_in`` the NEXT tick's ``serve.step``, what it
+    carried summing to the engine's own totals; every other span of the step
+    names the same flight, across both ticks."""
+    m, cfg, prompts = served
+    tt.disable()
+    tr = tt.enable(annotate=False)
+    try:
+        eng = _engine(m, prefill_token_budget=8,
+                      stall_budget_s=60.0 if watchdog else None)
+        eng.submit(prompts[4], 3)                      # 17 tokens: 3 chunks
+        eng.submit(prompts[0], 4)
+        eng.run_until_idle(max_steps=200)
+        mets = eng.metrics()
+        eng.close()
+    finally:
+        tt.disable()
+    by_id = {s.id: s for s in tr.spans()}
+    flights = _spans_of(tr, "serve.flight")
+    steps = _spans_of(tr, "serve.step")
+    assert len(flights) == mets["fused_steps"] >= 6
+    assert [f.args["seq"] for f in flights] == list(range(len(flights)))
+    assert sum(f.args["prefill_tokens"] for f in flights) == mets["prefill_tokens"] == 22
+    assert sum(f.args["rows"] for f in flights) == mets["block_rows"]
+    assert sum(f.dur_ns for f in flights) == mets["flight_ns"]
+    assert sum(f.args["wait_ns"] for f in flights) == mets["land_wait_ns"] > 0
+    assert sum(f.args["overlapped"] for f in flights) == mets["overlapped_steps"]
+    assert sum(f.args["overlapped"] and f.args["drained"] for f in flights) \
+        == mets["host_late_steps"]
+    step_ids = [s.id for s in steps]
+    for k, f in enumerate(flights):
+        a = f.args
+        assert set(a) == {"seq", "rows", "prefill_tokens", "decode_rows", "overlapped",
+                          "drained", "ready_at_read", "wait_ns", "prev_ready_ns",
+                          "landed_in"}
+        assert a["rows"] == a["prefill_tokens"] + a["decode_rows"] > 0
+        assert f.thread_name == f"serve.flight.{k % 2}"
+        dispatch = by_id[f.parent]
+        assert dispatch.name == "serve.dispatch" and dispatch.args == {"seq": k}
+        assert dispatch.t0_ns <= f.t0_ns <= dispatch.t0_ns + dispatch.dur_ns
+        # enqueued in one tick, read in the next
+        assert step_ids.index(a["landed_in"]) == step_ids.index(dispatch.parent) + 1
+        assert a["prev_ready_ns"] == (
+            None if k == 0 else flights[k - 1].t0_ns + flights[k - 1].dur_ns)
+        assert a["overlapped"] or a["drained"]          # an empty engine's device is empty
+        assert 0 <= a["wait_ns"] <= f.dur_ns
+    assert not flights[0].args["overlapped"]
+    for name, key in (("serve.pack", "seq"), ("serve.dispatch", "seq"),
+                      ("serve.device_step", "flight"), ("serve.harvest", "flight")):
+        assert [s.args[key] for s in _spans_of(tr, name)] == list(range(len(flights))), name
+    for d in _spans_of(tr, "serve.device_step"):
+        # the wait ends where the flight ends, on whichever thread it ran
+        flight = flights[d.args["flight"]]
+        assert d.t0_ns < flight.t0_ns + flight.dur_ns <= d.t0_ns + d.dur_ns
+        assert d.thread_name.startswith("serving-step-") == watchdog
+
+
+@pytest.mark.parametrize("case", ["device_never_done", "device_always_done",
+                                  "host_sleeps_past_a_step"])
+def test_host_late_steps_count_the_steps_enqueued_onto_an_empty_device(
+        served, monkeypatch, case):
+    """``host_late_steps`` counts the fused steps enqueued behind a step that
+    had ALREADY finished.  With a device that is never done by its successor's
+    enqueue (the probe held False: a chip slower than the tick) none is
+    counted; with a host that sleeps past a whole step before every enqueue
+    (the real probe) every step behind a predecessor is; a step with no
+    predecessor never is.  With tracing off nothing else is recorded."""
+    from paddle_tpu.serving import engine as engine_mod
+
+    m, cfg, prompts = served
+    tt.disable()
+    if case != "host_sleeps_past_a_step":
+        monkeypatch.setattr(engine_mod._Flight, "ready",
+                            lambda self: case == "device_always_done")
+    eng = _engine(m, prefill_token_budget=8)
+    if case == "host_sleeps_past_a_step":
+        def sleeps(point, ctx):
+            if point == "before_decode":
+                time.sleep(0.15)        # a tiny step on the CPU: a few ms
+        eng._fault_hook = sleeps
+    try:
+        eng.submit(prompts[4], 3)
+        eng.submit(prompts[0], 4)
+        eng.run_until_idle(max_steps=200)
+        mets = eng.metrics()
+        assert eng.step()["tokens_this_step"] == 0      # an idle tick moves nothing
+        assert eng.metrics()["host_late_steps"] == mets["host_late_steps"]
+    finally:
+        eng.close()
+    assert mets["fused_steps"] >= 6 and mets["overlapped_steps"] == mets["fused_steps"] - 1
+    late = 0 if case == "device_never_done" else mets["overlapped_steps"]
+    assert mets["host_late_steps"] == late
+    assert 0 < mets["land_wait_ns"] <= mets["flight_ns"]
+    if case == "host_sleeps_past_a_step":
+        # every flight but the last waited for the next tick's sleep to be read
+        assert mets["flight_ns"] >= (mets["fused_steps"] - 1) * 0.15e9
+        assert mets["land_wait_ns"] < 0.15e9
+    assert "mean_launch_occupancy" not in mets
 
 
 def test_engine_close_drops_registry_series(served):

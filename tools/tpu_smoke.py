@@ -675,7 +675,7 @@ def main() -> int:
             names = {e["name"] for e in doc["traceEvents"]
                      if e.get("ph") == "X"}
             need = {"serve.step", "serve.dispatch", "serve.device_step",
-                    "jit.fused_step"}
+                    "serve.flight", "jit.fused_step"}
             assert need <= names, f"missing host spans: {need - names}"
         finally:
             trace.disable()
