@@ -125,10 +125,16 @@ def kernel_numerics():
     runs = [(900, 1, table(8, 0)), (0, 1, table(1, 8)),
             (200, 128, table(3, 9)), (127, 1, table(1, 12)),
             (17, 5, table(1, 13))]
-    t_max, nb_max = 8 + 128, 8 + 128 // qb
+    t_max = 8 + 128
+    # the blocks the engine would build: the 128-token prefill rides wide
+    # blocks of the launch's own width
+    qw = ra.ragged_wide_block(heads, 1, page, dim, jnp.bfloat16, qb)
+    nb_max = 8 + 128 // qb
+    nbw_max = ra.ragged_wide_capacity(t_max, qb, qw)
     plan_np, stats = ra.build_ragged_plan(
         runs, token_block=qb, page_size=page, t_max=t_max, nb_max=nb_max,
-        wl_max=nb_max * per_slot)
+        wl_max=nb_max * per_slot, wide_block=qw, nbw_max=nbw_max)
+    assert 0 < stats["wide_items"] < stats["n_items"], stats
     tables = np.zeros((t_max, per_slot), np.int32)
     lengths = np.zeros((t_max,), np.int32)
     for (base, count, tbl), start in zip(runs, stats["run_starts"]):

@@ -505,31 +505,42 @@ def _eqn_padding_waste(eqn) -> int:
 
 def ragged_padding_waste(n_tokens: int, n_blocks: int, n_items: int,
                          token_block: int, page_size: int, head_dim: int,
-                         dtype="bfloat16") -> dict:
+                         dtype="bfloat16", *, wide_block: int = 0,
+                         wide_tokens: int = 0, wide_blocks: int = 0,
+                         wide_items: int = 0) -> dict:
     """The ragged fused step's HOST-PACKED padding cost — the GL002-style
     annotation for waste the jaxpr-level pass cannot see, because the
     padding lives in the kernel's work-list layout, not in any array's
     (8, 128) tile shape.
 
-    A work item computes one ``[token_block, page_size]`` score tile and
-    one ``[token_block, head_dim]`` accumulator pass whether or not every
+    A work item computes one ``[block rows, page_size]`` score tile and
+    one ``[block rows, head_dim]`` accumulator pass whether or not every
     block row carries a real token; decode tokens fill 1 row of
     ``token_block``.  Given one step's plan stats (``n_tokens`` real query
-    tokens, ``n_blocks`` packed blocks, ``n_items`` work items) this
-    quotes the padded-away MXU work and the padded q-row bytes with the
-    SAME units GL002's dot annotation uses (``dot_flops(padded=True)``
-    delta), so lint output and serving metrics describe one quantity.
+    tokens, ``n_blocks`` packed blocks, ``n_items`` work items: ALL of
+    them) this quotes the padded-away MXU work and the padded q-row bytes
+    with the SAME units GL002's dot annotation uses
+    (``dot_flops(padded=True)`` delta), so lint output and serving metrics
+    describe one quantity.  The part of the step that rode WIDE blocks
+    (``wide_tokens`` in ``wide_blocks`` of ``wide_block`` rows, over
+    ``wide_items``) is reckoned by that width, the rest by ``token_block``.
 
     Returns ``{"padded_rows", "wasted_flops", "wasted_q_bytes"}``."""
-    padded_rows = n_blocks * int(token_block) - int(n_tokens)
-    if padded_rows < 0:
-        raise ValueError(f"n_tokens={n_tokens} exceeds "
-                         f"{n_blocks} x {token_block} block rows")
-    # rows are padded uniformly across a block's work items; each item
-    # pays 2·D·page_size MXU flops per row (QK^T) + 2·D·page_size (P·V)
-    rows_frac = padded_rows / max(n_blocks * int(token_block), 1)
-    item_flops = 4 * int(head_dim) * int(page_size) * int(token_block)
-    wasted_flops = int(round(n_items * item_flops * rows_frac))
+    padded_rows = wasted_flops = 0
+    for width, tokens, blocks, items in (
+            (int(token_block), n_tokens - wide_tokens,
+             n_blocks - wide_blocks, n_items - wide_items),
+            (int(wide_block), wide_tokens, wide_blocks, wide_items)):
+        padded = blocks * width - int(tokens)
+        if padded < 0:
+            raise ValueError(f"n_tokens={tokens} exceeds "
+                             f"{blocks} x {width} block rows")
+        # rows are padded uniformly across a block's work items; each item
+        # pays 2·D·page_size MXU flops per row (QK^T) + 2·D·page_size (P·V)
+        rows_frac = padded / max(blocks * width, 1)
+        item_flops = 4 * int(head_dim) * int(page_size) * width
+        padded_rows += padded
+        wasted_flops += int(round(items * item_flops * rows_frac))
     try:
         itemsize = np.dtype(dtype).itemsize
     except TypeError:

@@ -11,10 +11,33 @@ into token granularity:
 
 - every query token of the step — decode tokens (q_len 1) and prefill
   chunk tokens (q_len > 1) alike — is one row of a flat ``[T, H, D]``
-  query buffer; the host packs rows into fixed-size **token blocks**
-  (``token_block`` sublane rows, one slot per block, consecutive
-  positions) so a prefill chunk fills an MXU pass that the old design
-  spent on a single broadcast decode row;
+  query buffer; the host packs rows into **token blocks** (one slot per
+  block, consecutive positions) so a prefill chunk fills an MXU pass that
+  the old design spent on a single broadcast decode row;
+- **the rows a block carries follow the run it belongs to** (PR 38).  A run
+  no longer than ``token_block`` (8) sublane rows, a decode row, is one
+  NARROW block: nothing else of that slot's pages is in the step, so its
+  items are already the need.  A longer run (a prefill chunk, a long
+  verify run) is cut into WIDE blocks of ``QW`` tokens, and its tail rides
+  one more wide block, its other rows masked, where it is longer than a
+  narrow block (else one narrow block): while every block was 8 rows a
+  512-token chunk was 64 blocks and each read every page of its context
+  again, 656 work items at context 1,500 where 8 blocks of 64 rows read 84.
+  ``QW`` is :func:`ragged_wide_block` of the launch's own shapes: the
+  widest multiple of 8 whose item still costs about its DMA (``G * QW``
+  rows a head: one score for every ``_KV_BYTES_PER_SCORE`` bytes of a
+  position's K and V) and whose buffers fit the scoped VMEM: 64 with one
+  query head a pool head of 128 bf16 (the GPT cells), 16 with four (the
+  hybrid and the long-context cell).  On the chip (TPU v5e, PR 38) no
+  width from 32 rows up costs just its DMA: a step of 15 decode rows and
+  a 497-token chunk at context 1,024 is 834 items and 1,244 us a layer in
+  blocks of 8, 342 and 588 at 32, 258 and 514 at 64, 216 and 476 at 128 (a
+  wide item costs 0.8 us and 41 ns a row and page, all 16 heads: the body's
+  vector work, not the MXU); the document cell gives 17,346 / 17,740 /
+  18,122 tokens/s at 32 / 64 / 128 (the narrow plan 13,500).  Sixteen
+  tokens of four query heads (64 rows) read 1,754 items in 1,989 us where
+  blocks of 8 read 1,962 in 2,142 at the long-context cell's geometry, 32
+  tokens 1,650 in 1,974;
 - the grid iterates a host-built **work list** of (token-block, page)
   tuples — one entry per page a block actually has to read, built from
   the scheduler's host mirrors (``build_ragged_plan``).  The work-list
@@ -25,10 +48,26 @@ into token granularity:
   traced scalar), so one compiled program walks exactly its work list and
   no grid step is spent on the arrays' tail (which repeats the last real
   entry only so that every index it holds stays valid);
+- the SAME kernel is launched once a width, each launch over its width's
+  own work list (``wl_*`` / ``ww_*``) and as long as it (``n_items`` /
+  ``n_wide``): a step with no chunk launches the wide one over no item.
+  What such a step still pays for the wide blocks is the wrapper's gather
+  over their CAPACITY, which is why that is a bound in tokens
+  (:func:`ragged_wide_capacity`) and a run whose wide blocks no longer fit
+  rides narrow blocks (a decode-only step of the document cell's geometry
+  pays 9.5 us a layer for them).  Two things measured and NOT in the tree:
+  one launch whose body branched on its item's width (the document cell's
+  device step 17.72 ms against 17.62 with two launches: nothing gained for
+  a second body), and a ``lax.cond`` around a launch to spare a decode-only
+  step those gathers: at the document cell's geometry with 32-token wide
+  blocks the compiled program HUNG the chip until its time limit, three
+  times of three, where 64-token blocks under the same ``cond``, and
+  32-token ones without it, ran.  No Mosaic call of this module sits
+  inside a conditional;
 - a work item carries ``hb`` heads of its page: the grid is
   ``(H // hb, n_items)`` and a grid step moves ONE ``(1, hb, page, D)``
   block of K and one of V (the heads of a page are contiguous in the
-  ``[P, H, page, D]`` pool) against ``(1, hb, QB, D)`` of queries, the
+  ``[P, H, page, D]`` pool) against ``(1, hb, rows, D)`` of queries, the
   heads one batched chain under one mask.  ``hb`` follows the launch's own
   shapes (:func:`ragged_head_block`: the largest divisor of the local
   ``H`` whose double-buffered K and V blocks hold a fixed share of the
@@ -36,15 +75,18 @@ into token granularity:
   one DMA a side, an item;
 - a K/V head may serve a GROUP of query heads (grouped-query attention:
   the pool holds ``Hkv`` heads, the queries ``Hkv * G``): the group's
-  heads are folded into the query block's rows, ``[NB, Hkv, G * QB, D]``,
-  so an item's K and V pages are still read once, whatever ``G``; a row's
-  token is ``row mod QB``.  With ``G == 1`` the launch, the kernel body and
-  the outputs are bit for bit those of a pool with as many heads as queries;
+  heads are folded into the query block's rows, ``[NB, Hkv, G * QB, D]``
+  (``G * QW`` in a wide block), so an item's K and V pages are still read
+  once, whatever ``G``; a row's token is ``row mod`` the block's width.
+  With ``G == 1`` the launch, the kernel body and the outputs are bit for
+  bit those of a pool with as many heads as queries;
 - online softmax accumulates across a block's work items (running max m,
   denominator l, fp32 acc); per-item masking is causal at token
   granularity: row i of block b (absolute position ``blk_base[b] + i``)
   attends pool positions ``<=`` its own, rows past ``blk_rows[b]`` are
   padding (masked everywhere, output rows discarded by the host gather).
+  A row's result does not depend on which other rows share its block: the
+  wide plan's outputs are the narrow plan's, bit for bit.
 
 Eligibility (``ragged_shape_supported``): the paged kernel's pool rules
 verbatim (``page_size`` a 128-multiple, ``head_dim`` a 64-multiple — a
@@ -76,6 +118,8 @@ __all__ = [
     "ragged_shape_unsupported_reason",
     "ragged_token_block",
     "ragged_head_block",
+    "ragged_wide_block",
+    "ragged_wide_capacity",
     "build_ragged_plan",
     "ragged_plan_shapes",
     "ragged_write_capacity",
@@ -91,15 +135,22 @@ __all__ = [
 # step, and the kernels consume them positionally: the attention launch its
 # work list, the pool write (pool_write.py) its write list
 RAGGED_ATTEND_FIELDS = (
-    "blk_tok",      # [NB, QB]  flat token index feeding each block row
-    "tok_blk",      # [T]       inverse map: token -> its block
+    "blk_tok",      # [NB, QB]  flat token index feeding each narrow block row
+    "wblk_tok",     # [NBW, QW] the same of each WIDE block
+    "tok_blk",      # [T]       inverse map: token -> its block (wide: NB + j)
     "tok_row",      # [T]       inverse map: token -> its row in the block
     "blk_base",     # [NB]      absolute position of each block's row 0
     "blk_rows",     # [NB]      valid rows per block (0 = padding block)
+    "wblk_base",    # [NBW]     the same two of the wide blocks
+    "wblk_rows",    # [NBW]
     "wl_blk",       # [WL]      work item -> token block
     "wl_page",      # [WL]      work item -> POOL page id (pre-translated)
     "wl_pageslot",  # [WL]      work item -> page-slot (for position math)
-    "n_items",      # [1]       real work items: the launch's length
+    "n_items",      # [1]       real work items: the narrow launch's length
+    "ww_blk",       # [WLW]     the wide blocks' own work list (block j),
+    "ww_page",      # [WLW]     page
+    "ww_pageslot",  # [WLW]     and page-slot
+    "n_wide",       # [1]       its real items: the wide launch's length
 )
 RAGGED_WRITE_FIELDS = (
     "wr_page",      # [WR]      write item -> POOL page id (pre-translated)
@@ -110,12 +161,13 @@ RAGGED_WRITE_FIELDS = (
     "n_writes",     # [1]       real write items: the write launch's length
 )
 RAGGED_PLAN_FIELDS = RAGGED_ATTEND_FIELDS + RAGGED_WRITE_FIELDS
-_PAGE_FIELDS = tuple(RAGGED_PLAN_FIELDS.index(f) for f in ("wl_page", "wr_page"))
+_PAGE_FIELDS = tuple(RAGGED_PLAN_FIELDS.index(f)
+                     for f in ("wl_page", "ww_page", "wr_page"))
 
 
 def plan_at_layer(plan, page_base):
     """The plan of one layer of a stacked pool ``[L * P, ...]``: the pool
-    page ids of both lists offset by the layer's first page."""
+    page ids of its lists offset by the layer's first page."""
     return tuple(a + page_base if i in _PAGE_FIELDS else a
                  for i, a in enumerate(plan))
 
@@ -132,15 +184,40 @@ def ragged_write_capacity(t_max: int, write_group: int, num_runs: int) -> int:
     return t_max // write_group + 2 * num_runs
 
 
+# the tails a step's wide blocks have room for beyond its full blocks: a step
+# holds the end of one prompt and the start of the next far more often than
+# three chunks (a run whose wide blocks do not fit rides narrow blocks)
+_WIDE_TAILS = 2
+
+
+def ragged_wide_capacity(t_max: int, token_block: int,
+                         wide_block: int) -> int:
+    """``nbw_max``: the wide blocks a step of ``t_max`` tokens has room for
+    (none where ``wide_block`` is no wider than ``token_block``): its full
+    blocks and ``_WIDE_TAILS`` tails.  A bound in TOKENS, not in runs: the
+    wrapper gathers every block the arrays hold, so capacity a step seldom
+    uses is paid for by every step."""
+    if int(wide_block) <= int(token_block):
+        return 0
+    return t_max // int(wide_block) + _WIDE_TAILS
+
+
 def ragged_plan_shapes(*, token_block: int, t_max: int, nb_max: int,
-                       wl_max: int, write_group: int, wr_max: int):
+                       wl_max: int, write_group: int, wr_max: int,
+                       wide_block: Optional[int] = None, nbw_max: int = 0,
+                       wlw_max: int = 0):
     """``[(field, shape)]`` of a plan's arrays in :data:`RAGGED_PLAN_FIELDS`
     order: what an engine's packed step input lays out."""
     shapes = {
-        "blk_tok": (nb_max, token_block), "tok_blk": (t_max,),
-        "tok_row": (t_max,), "blk_base": (nb_max,), "blk_rows": (nb_max,),
+        "blk_tok": (nb_max, token_block),
+        "wblk_tok": (nbw_max, wide_block or token_block),
+        "tok_blk": (t_max,), "tok_row": (t_max,),
+        "blk_base": (nb_max,), "blk_rows": (nb_max,),
+        "wblk_base": (nbw_max,), "wblk_rows": (nbw_max,),
         "wl_blk": (wl_max,), "wl_page": (wl_max,), "wl_pageslot": (wl_max,),
         "n_items": (1,),
+        "ww_blk": (wlw_max,), "ww_page": (wlw_max,),
+        "ww_pageslot": (wlw_max,), "n_wide": (1,),
         "wr_page": (wr_max,), "wr_group": (wr_max,),
         "wr_tok": (wr_max, write_group), "wr_lo": (wr_max,),
         "wr_n": (wr_max,), "n_writes": (1,),
@@ -227,6 +304,51 @@ def ragged_head_block(num_heads: int, page_size: int, head_dim: int,
                if num_heads % hb == 0 and hb <= fit)
 
 
+# what a wide item may compute for the bytes it moves: one score (a query
+# row against one pool position of one head) for every so many bytes of that
+# position's K and V rows.  8 is 64 rows a head of 128 in bf16: measured in
+# the document cell beside 16 (32 rows, 2.2% fewer tokens/s) and 4 (128
+# rows, 2.2% more, with twice the padded arithmetic in a tail's block and
+# not yet run in the other three cells); the module docstring has the table
+_KV_BYTES_PER_SCORE = 8
+
+
+def _wide_vmem_bytes(hb: int, rows: int, page_size: int, head_dim: int,
+                     itemsize: int) -> int:
+    """What a launch with ``rows`` query rows a wide block keeps in the
+    scoped VMEM: K and V and the q / out blocks double-buffered, the float32
+    accumulator, running max and denominator, and the body's score tiles
+    (scores, probabilities, and the probabilities in the pool's dtype)."""
+    kv = 4 * hb * page_size * head_dim * itemsize
+    q_out = 4 * hb * rows * head_dim * itemsize
+    scratch = hb * rows * (head_dim + 2 * 128) * 4
+    scores = hb * rows * page_size * (4 + 4 + itemsize)
+    return kv + q_out + scratch + scores
+
+
+def ragged_wide_block(num_heads: int, group: int, page_size: int,
+                      head_dim: int, dtype, token_block: int = 8) -> int:
+    """How many tokens a WIDE block carries (``QW``): the query rows of a
+    work item that belongs to a run longer than one narrow block.  A pure
+    function of the launch's own shapes, as :func:`ragged_head_block` is:
+    the widest multiple of 8 whose item still costs about its DMA,
+    ``group * QW`` rows a head at one score for every
+    ``_KV_BYTES_PER_SCORE`` bytes of a position's K and V, and whose
+    buffers (:func:`_wide_vmem_bytes`) fit the scoped VMEM.  16 heads of 128 in bf16, one query head a pool head: 64;
+    four query heads a pool head: 16.  ``token_block`` where nothing wider
+    fits: the plan then holds no wide block and the launch is the narrow
+    one alone."""
+    qb, group = int(token_block), int(group)
+    itemsize = jnp.dtype(dtype).itemsize
+    hb = ragged_head_block(num_heads, page_size, head_dim, dtype)
+    qw = 2 * int(head_dim) * itemsize // _KV_BYTES_PER_SCORE // group // 8 * 8
+    while qw > qb and (_wide_vmem_bytes(hb, group * qw, page_size,
+                                        head_dim, itemsize)
+                       > _SCOPED_VMEM_BYTES):
+        qw -= 8
+    return max(qw, qb)
+
+
 # ---------------------------------------------------------------------------
 # host-side plan construction (numpy; built from the scheduler mirrors)
 # ---------------------------------------------------------------------------
@@ -274,7 +396,9 @@ def build_ragged_plan(runs: Sequence[Tuple[int, int, np.ndarray]], *,
                       token_block: int, page_size: int,
                       t_max: int, nb_max: int, wl_max: int,
                       write_group: int = 8, wr_max: Optional[int] = None,
-                      window: Optional[int] = None
+                      window: Optional[int] = None,
+                      wide_block: Optional[int] = None, nbw_max: int = 0,
+                      wlw_max: Optional[int] = None
                       ) -> Tuple[Dict[str, np.ndarray], Dict[str, int]]:
     """Flatten one fused step's work into the kernel's plan arrays.
 
@@ -285,12 +409,29 @@ def build_ragged_plan(runs: Sequence[Tuple[int, int, np.ndarray]], *,
     occupy flat indices ``[start_r, start_r + count_r)`` in submission
     order (``stats["run_starts"]`` reports the starts).
 
+    **Blocks.**  Without ``wide_block`` (or with one no wider than
+    ``token_block``) a run is cut into narrow blocks of ``token_block``
+    rows.  With one, the rows a work item carries follow the run: a run no
+    longer than a narrow block (a decode row) is one narrow block; a longer
+    one is cut into WIDE blocks of ``wide_block`` rows, and its tail rides
+    one more wide block (its other rows masked) where it is longer than a
+    narrow block, else one narrow block: never more items than narrow
+    blocks alone would cost, and a function of the run's count alone,
+    until the step's ``nbw_max`` wide blocks are taken: a run (in
+    submission order) whose wide blocks no longer fit rides narrow blocks
+    throughout, as every run did.  The wide blocks have their own arrays
+    (``wblk_*``, numbered from 0; a token's ``tok_blk`` names wide block
+    ``j`` as ``nb_max + j``) and their own work list (``ww_*``, ``n_wide``
+    items, ``wlw_max`` at most: by default every wide block at every page
+    of a table row): the launch runs once a width.
+
     Every array is padded to its fixed maximum (``t_max``/``nb_max``/
-    ``wl_max``) so the compiled step never retraces; the kernel's grid
-    ends at ``n_items``, so the work-list tail is never walked: it
-    REPEATS the last real entry only to hold valid indices (the last
-    item's look-ahead reads one).  Padding block-gather rows point at the
-    block's first token (a valid index; the row is masked in-kernel and
+    ``nbw_max``/``wl_max``/``wlw_max``) so the compiled step never
+    retraces; a launch's grid ends at its list's item count, so a list's
+    tail is never walked: it REPEATS the last real entry only to hold valid
+    indices (the last item's look-ahead reads one; a list with no item
+    holds zeros: block 0, the null page).  Padding block-gather rows point at
+    the block's first token (a valid index; the row is masked in-kernel and
     discarded by the output gather).
 
     Beside the work list the WRITE LIST (pool_write.py): one item a tile
@@ -311,14 +452,21 @@ def build_ragged_plan(runs: Sequence[Tuple[int, int, np.ndarray]], *,
 
     Returns ``(plan_arrays, stats)``: the arrays keyed by
     :data:`RAGGED_PLAN_FIELDS`, and stats with ``n_tokens``/``n_blocks``/
-    ``n_items``/``n_writes``/``run_starts``, the occupancy numerators the
-    serving metrics report, and ``launched_items`` (the launch's second
-    grid dimension for this step)."""
+    ``n_items`` (ALL items, both lists)/``n_writes``/``run_starts``, the
+    occupancy numerators the serving metrics report (``row_capacity``: all
+    rows launched), what rode wide blocks (``wide_blocks``/``wide_items``/
+    ``wide_rows``), and ``launched_items`` (the launches' second grid
+    dimensions for this step, summed)."""
     qb, g = int(token_block), int(write_group)
+    qw = int(wide_block or qb)
+    wide = qw > qb and nbw_max > 0
+    nbw_max = int(nbw_max) if wide else 0
+    if qw % 8:
+        raise ValueError(f"wide_block={qw} must be a multiple of 8")
     if g < 1 or page_size % g:
         raise ValueError(f"write_group={g} must divide page_size={page_size}")
     if wr_max is None:
-        wr_max = ragged_write_capacity(t_max, g, nb_max)
+        wr_max = ragged_write_capacity(t_max, g, nb_max + nbw_max)
     if not runs:
         raise ValueError("empty plan: the fused step must not be "
                          "dispatched with no runs")
@@ -335,70 +483,105 @@ def build_ragged_plan(runs: Sequence[Tuple[int, int, np.ndarray]], *,
     t = int(counts.sum())
     if t > t_max:
         raise ValueError(f"plan overflow: {t} tokens > t_max={t_max}")
-    run_blocks = -(-counts // qb)
-    b = int(run_blocks.sum())
-    if b > nb_max:
-        raise ValueError(f"plan overflow: {b} blocks > nb_max={nb_max}")
+    # a run's blocks: its full wide blocks, then its tail (wide where longer
+    # than a narrow block), while the step's wide blocks last; what is left
+    # of a run, narrow blocks (with no wide block, all of it)
+    run_wide = np.zeros_like(counts)
+    if wide:
+        run_wide = counts // qw + (counts % qw > qb)
+        run_wide[np.cumsum(run_wide) > nbw_max] = 0
+    lead = run_wide * qw                            # a run's rows in wide blocks
+    run_narrow = -(-np.maximum(counts - lead, 0) // qb)
+    bn, bw = int(run_narrow.sum()), int(run_wide.sum())
+    if bn > nb_max:
+        raise ValueError(f"plan overflow: {bn} blocks > nb_max={nb_max}")
     run_starts: List[int] = [int(x) for x in starts]
-    blk_run = np.repeat(np.arange(len(runs)), run_blocks)
-    first_blk = np.cumsum(run_blocks) - run_blocks
-    off = (np.arange(b) - first_blk[blk_run]) * qb  # a block's offset in its run
-    rows = np.minimum(qb, counts[blk_run] - off)
-    lane = np.arange(qb)
-    blk_tok = np.zeros((nb_max, qb), np.int32)
-    # a block's padding rows point at its first token
-    blk_tok[:b] = ((starts[blk_run] + off)[:, None]
+
+    def one_width(per_run, width, lead_rows, cap, items_max, name):
+        """The blocks of one width, run-major, and their work list: a
+        run's first block, each block's real rows, the gather rows of all
+        ``cap`` blocks (a block's padding rows point at its first token),
+        the blocks' positions and valid rows, the list's three arrays and
+        its length.  A block's items are the page-slots up to its last
+        row's, from the first its window reaches (the first of all without
+        one)."""
+        n = int(per_run.sum())
+        run = np.repeat(np.arange(len(runs)), per_run)
+        first = np.cumsum(per_run) - per_run
+        off = lead_rows[run] + (np.arange(n) - first[run]) * width
+        rows = np.minimum(width, counts[run] - off)
+        lane = np.arange(width)
+        tok = np.zeros((cap, width), np.int32)
+        tok[:n] = ((starts[run] + off)[:, None]
                    + np.where(lane[None, :] < rows[:, None], lane[None, :], 0))
-    blk_base = np.zeros((nb_max,), np.int32)
-    blk_base[:b] = bases[blk_run] + off
-    blk_rows = np.zeros((nb_max,), np.int32)
-    blk_rows[:b] = rows
+        base = np.zeros((cap,), np.int32)
+        valid = np.zeros((cap,), np.int32)
+        base[:n], valid[:n] = bases[run] + off, rows
+        at = base[:n].astype(np.int64)
+        last_slot = (at + rows - 1) // page_size
+        first_slot = (np.zeros((n,), np.int64) if window is None else
+                      np.maximum(at - int(window) + 1, 0) // page_size)
+        per_blk = last_slot - first_slot + 1
+        items = int(per_blk.sum())
+        if items > items_max:
+            raise ValueError(f"plan overflow: {items} work items > "
+                             f"{name}={items_max}")
+        blk = np.repeat(np.arange(n), per_blk)
+        slot = (first_slot[blk] + np.arange(items)
+                - (np.cumsum(per_blk) - per_blk)[blk])
+        lists = []
+        for real in (blk, tables[run[blk], slot], slot):
+            # the tail repeats the last real item: valid indices, never
+            # walked
+            arr = np.full((items_max,), real[-1] if items else 0, np.int32)
+            arr[:items] = real
+            lists.append(arr)
+        return first, rows, tok, base, valid, lists, items
+
+    if wlw_max is None:
+        wlw_max = nbw_max * tables.shape[1]
+    (n_first, _, blk_tok, blk_base, blk_rows, (wl_blk, wl_page, wl_ps),
+     n_narrow) = one_width(run_narrow, qb, lead, nb_max, wl_max, "wl_max")
+    (w_first, w_rows, wblk_tok, wblk_base, wblk_rows,
+     (ww_blk, ww_page, ww_ps), n_wide) = one_width(
+         run_wide, qw, np.zeros_like(counts), nbw_max, int(wlw_max),
+         "wlw_max")
+    n_items = n_narrow + n_wide
     tok_run = np.repeat(np.arange(len(runs)), counts)
     within = np.arange(t) - starts[tok_run]
+    past = within - lead[tok_run]                   # rows past the wide blocks
     tok_blk = np.zeros((t_max,), np.int32)
-    tok_blk[:t] = first_blk[tok_run] + within // qb
+    tok_blk[:t] = np.where(past < 0,
+                           nb_max + w_first[tok_run] + within // qw,
+                           n_first[tok_run] + past // qb)
     tok_row = np.zeros((t_max,), np.int32)
-    tok_row[:t] = within % qb
-    # a block's items: the page-slots up to its last row's, from the first
-    # its window reaches (the first of all without one)
-    last_slot = (blk_base[:b].astype(np.int64) + rows - 1) // page_size
-    first_slot = (np.zeros((b,), np.int64) if window is None else
-                  np.maximum(blk_base[:b] - int(window) + 1, 0) // page_size)
-    per_blk = last_slot - first_slot + 1
-    n_items = int(per_blk.sum())
-    if n_items > wl_max:
-        raise ValueError(f"plan overflow: {n_items} work items > "
-                         f"wl_max={wl_max}")
-    item_blk = np.repeat(np.arange(b), per_blk)
-    item_slot = (first_slot[item_blk] + np.arange(n_items)
-                 - (np.cumsum(per_blk) - per_blk)[item_blk])
-    item_page = tables[blk_run[item_blk], item_slot]
-    # the tail repeats the last real item: valid indices, never walked
-    wl_blk = np.full((wl_max,), item_blk[-1], np.int32)
-    wl_page = np.full((wl_max,), item_page[-1], np.int32)
-    wl_ps = np.full((wl_max,), item_slot[-1], np.int32)
-    wl_blk[:n_items] = item_blk
-    wl_page[:n_items] = item_page
-    wl_ps[:n_items] = item_slot
+    tok_row[:t] = np.where(past < 0, within % qw, past % qb)
     plan = {
-        "blk_tok": blk_tok, "tok_blk": tok_blk, "tok_row": tok_row,
+        "blk_tok": blk_tok, "wblk_tok": wblk_tok,
+        "tok_blk": tok_blk, "tok_row": tok_row,
         "blk_base": blk_base, "blk_rows": blk_rows,
+        "wblk_base": wblk_base, "wblk_rows": wblk_rows,
         "wl_blk": wl_blk, "wl_page": wl_page, "wl_pageslot": wl_ps,
-        "n_items": np.array([n_items], np.int32),
+        "n_items": np.array([n_narrow], np.int32),
+        "ww_blk": ww_blk, "ww_page": ww_page, "ww_pageslot": ww_ps,
+        "n_wide": np.array([n_wide], np.int32),
     }
     plan.update(_build_write_list(
         bases, counts, starts, tables,
         g=g, page_size=page_size, t_max=t_max, wr_max=int(wr_max)))
     stats = {
-        "n_tokens": t, "n_blocks": b, "n_items": n_items,
+        "n_tokens": t, "n_blocks": bn + bw, "n_items": n_items,
         "n_writes": int(plan["n_writes"][0]),
         "run_starts": run_starts,
         # occupancy: the fraction of the work-list arrays holding real
         # items and of the block rows carrying real queries
         "wl_capacity": wl_max,
-        "row_capacity": b * qb,
-        # the launch's second grid dimension for this step: the kernel's
-        # grid ends at n_items, whatever the arrays' capacity
+        "row_capacity": bn * qb + bw * qw,
+        # what rode wide blocks: blocks, their items, their real rows
+        "wide_blocks": bw,
+        "wide_items": n_wide, "wide_rows": int(w_rows.sum()),
+        # the launches' second grid dimensions for this step: a grid ends
+        # at its list's item count, whatever the arrays' capacity
         "launched_items": n_items,
     }
     return plan, stats
@@ -646,34 +829,59 @@ def ragged_paged_attention(q, k_pool, v_pool, token_tables, lengths, plan,
         q = q.astype(jnp.float32)
     else:
         q = q.astype(k_pool.dtype)
-    (blk_tok, tok_blk, tok_row, blk_base, blk_rows,
-     wl_blk, wl_page, wl_ps, n_items) = plan[:len(RAGGED_ATTEND_FIELDS)]
-    qb = int(blk_tok.shape[1])
+    (blk_tok, wblk_tok, tok_blk, tok_row, blk_base, blk_rows, wblk_base,
+     wblk_rows, wl_blk, wl_page, wl_ps, n_items, ww_blk, ww_page, ww_ps,
+     n_wide) = plan[:len(RAGGED_ATTEND_FIELDS)]
+    nb, qb = (int(x) for x in blk_tok.shape)
+    nbw, qw = (int(x) for x in wblk_tok.shape)
     use_kernel = (_on_tpu() and ragged_shape_supported(page_size, d, qb)) \
         or interpret
     if use_kernel:
-        nb = blk_tok.shape[0]
-        qg = jnp.take(q, jnp.reshape(blk_tok, (-1,)), axis=0)
-        if group == 1:
-            qg = jnp.transpose(qg.reshape(nb, qb, h, d), (0, 2, 1, 3))
-        else:       # [NB, H, G * QB, D]: a pool head's query heads as rows
-            qg = jnp.transpose(qg.reshape(nb, qb, h, group, d),
-                               (0, 2, 3, 1, 4)).reshape(nb, h, group * qb, d)
         # one query head a pool head: the call the launch always was
         grouped = {} if group == 1 else {"group": group}
         if window is not None:
             grouped["window"] = int(window)
-        out = _ragged_pallas(qg, k_pool, v_pool, wl_blk, wl_page, wl_ps,
-                             n_items, blk_base, blk_rows, scale,
-                             interpret=interpret,
-                             k_scale=k_scale, v_scale=v_scale, **grouped)
-        if group == 1:
-            flat = jnp.transpose(out, (0, 2, 1, 3)).reshape(nb * qb, h, d)
-        else:
-            flat = jnp.transpose(out.reshape(nb, h, group, qb, d),
-                                 (0, 3, 1, 2, 4)).reshape(nb * qb, hq, d)
-        idx = tok_blk.astype(jnp.int32) * qb + tok_row.astype(jnp.int32)
-        return jnp.take(flat, idx, axis=0)
+
+        def launch(tok, work_list, length, base, rows):
+            """One width's blocks through the kernel: the rows ``tok [n,
+            width]`` names as q blocks ``[n, H, G * width, D]`` (a pool
+            head's query heads one after another), attended over the
+            width's own work list; its out blocks as flat rows ``[n * width,
+            Hq, D]``."""
+            n, width = tok.shape
+            qg = jnp.take(q, jnp.reshape(tok, (-1,)), axis=0)
+            if group == 1:
+                qg = jnp.transpose(qg.reshape(n, width, h, d), (0, 2, 1, 3))
+            else:
+                qg = jnp.transpose(qg.reshape(n, width, h, group, d),
+                                   (0, 2, 3, 1, 4)).reshape(
+                                       n, h, group * width, d)
+            out = _ragged_pallas(qg, k_pool, v_pool, *work_list, length,
+                                 base, rows, scale, interpret=interpret,
+                                 k_scale=k_scale, v_scale=v_scale, **grouped)
+            if group == 1:
+                return jnp.transpose(out, (0, 2, 1, 3)).reshape(
+                    n * width, h, d)
+            return jnp.transpose(out.reshape(n, h, group, width, d),
+                                 (0, 3, 1, 2, 4)).reshape(n * width, hq, d)
+
+        narrow = launch(blk_tok, (wl_blk, wl_page, wl_ps), n_items,
+                        blk_base, blk_rows)
+        tok_blk, tok_row = tok_blk.astype(jnp.int32), tok_row.astype(jnp.int32)
+        if not nbw:
+            return jnp.take(narrow, tok_blk * qb + tok_row, axis=0)
+        # the wide blocks: the same kernel over their own list, its grid
+        # as long as the step's wide items (none in a decode-only step)
+        wider = launch(wblk_tok, (ww_blk, ww_page, ww_ps), n_wide,
+                       wblk_base, wblk_rows)
+        # a token's row is in its own width's output (the other index is
+        # clamped into its array and not chosen)
+        return jnp.where(
+            (tok_blk >= nb)[:, None, None],
+            jnp.take(wider, jnp.maximum((tok_blk - nb) * qw + tok_row, 0),
+                     axis=0),
+            jnp.take(narrow, jnp.minimum(tok_blk * qb + tok_row,
+                                         nb * qb - 1), axis=0))
     return _xla_ragged_reference(q, k_pool, v_pool, token_tables, lengths,
                                  scale, k_scale=k_scale, v_scale=v_scale,
                                  window=window)

@@ -107,7 +107,8 @@ from ..telemetry import metrics as _tmetrics
 from ..telemetry import trace as _ttrace
 from ..ops.pallas_kernels.ragged_paged_attention import (
     RAGGED_PLAN_FIELDS, build_ragged_plan, ragged_head_block,
-    ragged_plan_shapes, ragged_token_block, ragged_write_capacity,
+    ragged_plan_shapes, ragged_token_block, ragged_wide_block,
+    ragged_wide_capacity, ragged_write_capacity,
 )
 from ..ops.pallas_kernels.pool_write import pool_write_group
 from ..tensor import Tensor, to_tensor
@@ -772,6 +773,14 @@ class ServingEngine:
         self.ragged_heads_per_block = ragged_head_block(
             local_heads, self.page_size, self.head_dim, self.cache_dtype)
         self._grid_steps_per_item = local_heads // self.ragged_heads_per_block
+        # and the rows of a WIDE block, which a run longer than one narrow
+        # block is cut into (a pool head's query heads share its rows)
+        query_heads = (getattr(cfg, "num_attention_heads", None)
+                       or cfg.num_heads)
+        self.wide_block = ragged_wide_block(
+            local_heads, query_heads // (local_heads * self._mp),
+            self.page_size, self.head_dim, self.cache_dtype,
+            self.token_block)
         # sampling RNG: the global generator single-chip (bit-compat with
         # generate()); a PRIVATE stream per mesh-sharded engine — the
         # donated key state commits to the replica mesh, and one shared
@@ -795,7 +804,11 @@ class ServingEngine:
         # speculative engine's verify runs are k+1 tokens per decode
         # slot).
         self._t_max, self._nb_max = self._step_geometry()
+        # (the narrow plan's bounds hold whatever the blocks: a wide block
+        # never lists more items than the narrow blocks of its rows would)
         self._wl_max = self._nb_max * max_pages_per_slot
+        self._nbw_max = ragged_wide_capacity(
+            self._t_max, self.token_block, self.wide_block)
         # the pool write's list (ops/pallas_kernels/pool_write.py): one
         # item a tile group of g positions a run touches; a slot
         # contributes one run a step.  (A pool whose pages g does not
@@ -808,6 +821,8 @@ class ServingEngine:
         self._plan_geometry = dict(
             token_block=self.token_block, t_max=self._t_max,
             nb_max=self._nb_max, wl_max=self._wl_max,
+            wide_block=self.wide_block, nbw_max=self._nbw_max,
+            wlw_max=self._nbw_max * max_pages_per_slot,
             write_group=self._write_group,
             wr_max=ragged_write_capacity(self._t_max, self._write_group,
                                          num_slots))
@@ -917,6 +932,9 @@ class ServingEngine:
                         # real tokens touched; see metrics())
                         "write_items": 0,
                         "block_rows": 0, "block_row_capacity": 0,
+                        # of those, what rode WIDE blocks (a run longer
+                        # than one narrow block): their items, their rows
+                        "wide_items": 0, "wide_block_rows": 0,
                         # host-packing padding cost in GL002's units
                         # (analysis/cost_model.ragged_padding_waste): block
                         # rows that carried no real token and the MXU flops
@@ -1500,11 +1518,13 @@ class ServingEngine:
         if self._slot_state:
             # a slot with a run in flight reads the state row that run
             # writes (its rows swap when that run is harvested)
-            stats["window_items"] = self.cache.pack_step(
+            window = self.cache.pack_step(
                 view, [(w.slot, w.base, w.count) for w in work],
                 tables.shape[1], self._plan_geometry,
                 in_flight=[w.slot for w in self._ahead
-                           if sched.live(w) is not None])["n_items"]
+                           if sched.live(w) is not None])
+            stats["window_items"] = window["n_items"]
+            stats["window_wide_items"] = window["wide_items"]
         return (ids[:, None], packed), stats
 
     def _enqueue_thunk(self, fused, inputs, cancelled, extra_dev=()):
@@ -1581,7 +1601,7 @@ class ServingEngine:
             # the step's results are in hand: its slots' state rows swap
             self.cache.commit_step(
                 [(w.slot, w.base, w.count) for w, _ in live],
-                stats["window_items"])
+                stats["window_items"], stats["window_wide_items"])
         for w, slot in live:
             if w.kind == "prefill":
                 slot.pending = slot.pending[w.count:]
@@ -1626,10 +1646,14 @@ class ServingEngine:
         self._totals["write_items"] += stats["n_writes"]
         self._totals["block_rows"] += stats["n_tokens"]
         self._totals["block_row_capacity"] += stats["row_capacity"]
+        self._totals["wide_items"] += stats["wide_items"]
+        self._totals["wide_block_rows"] += stats["wide_rows"]
         waste = ragged_padding_waste(
             stats["n_tokens"], stats["n_blocks"], stats["n_items"],
             self.token_block, self.page_size, self.head_dim,
-            dtype=self.cache_dtype)
+            dtype=self.cache_dtype, wide_block=self.wide_block,
+            wide_tokens=stats["wide_rows"], wide_blocks=stats["wide_blocks"],
+            wide_items=stats["wide_items"])
         self._totals["padded_rows"] += waste["padded_rows"]
         self._totals["padded_flops"] += waste["wasted_flops"]
         self._last_occupancy = (

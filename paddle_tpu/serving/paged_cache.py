@@ -314,11 +314,11 @@ class SlotStateCache(_KVBuffers):
 
     paged = True
     quantized = False
-    COUNTER_NAMES = ("window_work_items", "ssm_runs", "ssm_rows",
-                     "cross_rows")
+    COUNTER_NAMES = ("window_work_items", "window_wide_items", "ssm_runs",
+                     "ssm_rows", "cross_rows")
     #: the fields of the packed step input, in order
     WINDOW_FIELDS = ("wl_blk", "wl_page", "wl_pageslot", "n_items",
-                     "wr_page")
+                     "ww_blk", "ww_page", "ww_pageslot", "n_wide", "wr_page")
 
     def __init__(self, *, num_pages: int, page_size: int, num_slots: int,
                  max_run: int, num_heads: int, row_dim: int, window: int,
@@ -391,13 +391,17 @@ class SlotStateCache(_KVBuffers):
                 + np.arange(max_pages, dtype=np.int32)[None, :] % r)
         return self._tables
 
-    def pack_fields(self, *, t_max: int, nb_max: int, wr_max: int, **_):
+    def pack_fields(self, *, t_max: int, nb_max: int, wr_max: int,
+                    nbw_max: int = 0, **_):
         """``[(name, shape)]`` of what a step ships beside its plan."""
         s = self.num_slots
-        wl = nb_max * self.ring_pages       # a block reads fewer than R pages
+        # a block, narrow or wide, reads fewer than R pages
+        wl, wlw = nb_max * self.ring_pages, nbw_max * self.ring_pages
         return [("win_wl_blk", (wl,)), ("win_wl_page", (wl,)),
-                ("win_wl_pageslot", (wl,)),
-                ("win_n_items", (1,)), ("win_wr_page", (wr_max,)),
+                ("win_wl_pageslot", (wl,)), ("win_n_items", (1,)),
+                ("win_ww_blk", (wlw,)), ("win_ww_page", (wlw,)),
+                ("win_ww_pageslot", (wlw,)), ("win_n_wide", (1,)),
+                ("win_wr_page", (wr_max,)),
                 ("row_slot", (t_max,)),
                 ("run_first", (s,)), ("run_count", (s,)),
                 ("run_src", (s,)), ("run_dst", (s,)), ("n_runs", (1,))]
@@ -420,8 +424,10 @@ class SlotStateCache(_KVBuffers):
         if n > self.num_slots:
             raise ValueError(f"{n} runs in a step of {self.num_slots} "
                              "slots: a slot has one")
-        geometry = dict(plan_geometry,
-                        wl_max=plan_geometry["nb_max"] * self.ring_pages)
+        geometry = dict(
+            plan_geometry,
+            wl_max=plan_geometry["nb_max"] * self.ring_pages,
+            wlw_max=plan_geometry.get("nbw_max", 0) * self.ring_pages)
         plan, stats = build_ragged_plan(
             [(base, count, self.ring_table(slot, max_pages))
              for slot, base, count in runs],
@@ -445,7 +451,7 @@ class SlotStateCache(_KVBuffers):
         return stats
 
     def commit_step(self, runs: Sequence[Tuple[int, int, int]],
-                    window_items: int):
+                    window_items: int, window_wide_items: int = 0):
         """A step over ``runs`` was harvested: its slots' state rows swap,
         and the counters take what it did."""
         rows = 0
@@ -454,6 +460,7 @@ class SlotStateCache(_KVBuffers):
             rows += count
         c = self._counts
         c["window_work_items"] += int(window_items)
+        c["window_wide_items"] += int(window_wide_items)
         c["ssm_runs"] += len(runs)
         c["ssm_rows"] += rows
         c["cross_rows"] += rows

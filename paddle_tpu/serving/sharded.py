@@ -433,8 +433,9 @@ class ShardedServingEngine:
                     "active_slots", "queue_depth", "cache_bytes",
                     "work_items", "work_capacity", "launched_items",
                     "launched_grid_steps", "write_items",
-                    "block_rows",
-                    "block_row_capacity", "padded_rows", "padded_flops",
+                    "block_rows", "block_row_capacity",
+                    "wide_items", "wide_block_rows",
+                    "padded_rows", "padded_flops",
                     # per-replica prefix caches (docs/serving.md "Prefix
                     # cache"): hits/misses sum exactly; hit RATE is
                     # re-derived from the sums below

@@ -249,10 +249,10 @@ def test_the_ring_holds_a_window_and_a_run(model):
     assert list(cache.ring_table(1, 6)) == [1 + cache.ring_pages + j % cache.ring_pages
                                             for j in range(6)]
     assert cache.state_rows(2) == (5, 6)
-    cache.commit_step([(2, 0, 4)], window_items=3)
+    cache.commit_step([(2, 0, 4)], window_items=3, window_wide_items=1)
     assert cache.state_rows(2) == (6, 5) and cache.state_rows(1) == (3, 4)
-    assert cache.counts() == {"window_work_items": 3, "ssm_runs": 1, "ssm_rows": 4,
-                              "cross_rows": 4}
+    assert cache.counts() == {"window_work_items": 3, "window_wide_items": 1, "ssm_runs": 1,
+                              "ssm_rows": 4, "cross_rows": 4}
     # the cell's geometry: 7 ring pages a slot and window layer
     big = Phi4FlashConfig()
     assert -(-(big.sliding_window - 1 + 256) // 128) + 1 == 7

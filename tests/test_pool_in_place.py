@@ -177,9 +177,9 @@ def test_no_operation_of_the_hybrid_step_moves_a_layers_experts_or_a_pool(topo):
     assert one_matrix_stack > 50e6
     assert pool_movers(text, one_matrix_stack) == []
     # both pools are aliased into the step's outputs: written where they lie
-    # (the ragged launch, the pool write's, and three grouped products a
-    # routed layer)
-    assert text.count("tpu_custom_call") == 2 + 3 * 4
+    # (the ragged launch, once a block width; the pool write's; and three
+    # grouped products a routed layer)
+    assert text.count("tpu_custom_call") == 3 + 3 * 4
     assert memory.alias_size_in_bytes >= kv_pool + tail_pool
     assert memory.temp_size_in_bytes < 4 * one_matrix_stack, memory.temp_size_in_bytes
     # the K|V pool's write is the launch: its one pool operand is its result,
@@ -228,9 +228,10 @@ def test_no_operation_of_the_slot_state_step_moves_a_pool(topo):
               + 3 * rows * 5 * 16 * 8 * 128 * 4 + 3 * rows * 120 * 128 * 2)
     assert memory.alias_size_in_bytes >= nbytes
     assert memory.temp_size_in_bytes < 0.05 * nbytes, memory.temp_size_in_bytes
-    # the window layers' write, launch and scan in the loop; the memory layer's
-    # scan; the full-attention layer's write and launch; the cross layers' launch
-    assert text.count("tpu_custom_call") == 7
+    # the window layers' write, launch (once a block width) and scan in the
+    # loop; the memory layer's scan; the full-attention layer's write and
+    # launch; the cross layers' launch
+    assert text.count("tpu_custom_call") == 10
     assert mosaic_iteration_bounds(text).count([_DYNAMIC]) >= 2      # the writes
     movers = []
     for line in text.splitlines():
@@ -253,7 +254,8 @@ def test_no_operation_of_the_compiled_step_moves_a_layers_pool(compiled_step):
                   * (model["hidden_size"] // model["num_heads"]) * 2)      # bf16
     pool = 2 * LAYERS * layer_pool                                         # K and V
     text, memory = compiled.as_text(), compiled.memory_analysis()
-    assert text.count("tpu_custom_call") == 2       # the ragged launch, the write
+    # the ragged launch, once a block width, and the write
+    assert text.count("tpu_custom_call") == 3
     assert pool_movers(text, layer_pool) == []
     assert memory.temp_size_in_bytes < 0.05 * pool, memory.temp_size_in_bytes
     assert memory.alias_size_in_bytes >= pool, memory.alias_size_in_bytes
@@ -386,11 +388,12 @@ def test_the_reader_of_iteration_bounds_reads_a_static_and_a_dynamic_launch():
 
 
 def test_the_compiled_steps_ragged_launch_ends_at_the_item_count(compiled_step):
-    """The step's one Mosaic call (``_ragged_kernel``, the only kernel the
-    cell names) runs a grid of the model's head BLOCKS by a DYNAMIC second
-    dimension: the day the launch is again as long as ``wl_max`` (48 x 8 =
-    384 in this cell) that reads 384.  A work item moves all sixteen heads
-    of its page in this cell, so the grid is one head block wide."""
+    """The step's ``_ragged_kernel`` launches (the only kernel the cell names:
+    one over the narrow blocks' list, one over the wide blocks') each run a
+    grid of the model's head BLOCKS by a DYNAMIC second dimension: the day a
+    launch is again as long as ``wl_max`` (48 x 8 = 384 in this cell) that
+    reads 384.  A work item moves all sixteen heads of its page in this cell,
+    so the grids are one head block wide."""
     from paddle_tpu.ops.pallas_kernels import ragged_paged_attention as ra
 
     ctx, compiled = compiled_step
@@ -402,7 +405,7 @@ def test_the_compiled_steps_ragged_launch_ends_at_the_item_count(compiled_step):
     # the step's other launch is the pool write's (PR 34), as long as the
     # step's write list: one dynamic dimension
     bounds = sorted(mosaic_iteration_bounds(compiled.as_text()), key=len)
-    assert bounds == [[_DYNAMIC], [heads // hb, _DYNAMIC]]
+    assert bounds == [[_DYNAMIC]] + 2 * [[heads // hb, _DYNAMIC]]
     assert bounds[1] == [1, _DYNAMIC]
 
 

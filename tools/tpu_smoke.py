@@ -175,20 +175,30 @@ def main() -> int:
     # position 0 and an exact page edge ----------------------------------
     def ragged_attention():
         from paddle_tpu.ops.pallas_kernels import ragged_paged_attention as ra
-        P, H, PS, D = 11, 4, 128, 64
+        P, H, PS, D = 15, 4, 128, 64
         MP = 4
         assert ra.ragged_shape_supported(PS, D)
+        # the wide block of this launch's shapes (a run longer than one
+        # narrow block is cut into blocks of QW tokens)
+        QW = ra.ragged_wide_block(H, 1, PS, D, jnp.bfloat16)
+        assert QW > 8, QW
         runs = [
             (200, 1, np.array([4, 2, 9, 1], np.int32)),   # decode, 2 pages
             (0, 1, np.array([3, 0, 0, 0], np.int32)),     # decode at pos 0
             (120, 16, np.array([7, 5, 8, 6], np.int32)),  # straddles a page
             (127, 1, np.array([10, 6, 0, 0], np.int32)),  # exact page edge
             (17, 5, np.array([10, 0, 0, 0], np.int32)),   # short prefill
+            # longer than QW beside the decode rows: full wide blocks, a
+            # wide tail, three pages of context
+            (300, 2 * QW + 21, np.array([11, 12, 13, 14], np.int32)),
         ]
-        T_MAX, NB_MAX, WL_MAX = 32, 8, 32
+        T_MAX, WL_MAX = 32 + 2 * QW + 21, 64
+        NB_MAX, NBW_MAX = 16, ra.ragged_wide_capacity(T_MAX, 8, QW)
         plan_np, stats = ra.build_ragged_plan(
             runs, token_block=8, page_size=PS,
-            t_max=T_MAX, nb_max=NB_MAX, wl_max=WL_MAX)
+            t_max=T_MAX, nb_max=NB_MAX, wl_max=WL_MAX,
+            wide_block=QW, nbw_max=NBW_MAX)
+        assert 0 < stats["wide_items"] < stats["n_items"], stats
         tables = np.zeros((T_MAX, MP), np.int32)
         lengths = np.zeros((T_MAX,), np.int32)   # padding tokens: length 0
         for (base, count, tbl), start in zip(runs, stats["run_starts"]):
