@@ -69,6 +69,7 @@ from ..ops import dispatch
 from ..tensor import Parameter, Tensor
 from .decoder_ops import (
     gated_ffn_fused, kept_inputs, layer_norm, mm, rms_norm, rows_of_step,
+    runs_at, step_runs,
 )
 
 __all__ = ["Phi4FlashConfig", "Phi4FlashForCausalLM", "phi4flash_tiny"]
@@ -233,17 +234,9 @@ class _SlotStep:
         self.ring_tbl = jnp.where(
             slot[:, None] >= 0,
             1 + slot[:, None] * self.ring_pages + at % self.ring_pages, 0)
-        first = extra["run_first"]
-        self.run_first, self.run_count = first, extra["run_count"]
-        self.run_src, self.run_dst = extra["run_src"], extra["run_dst"]
-        self.n_runs = extra["n_runs"]
-        self.run_fresh = jnp.take(pos, first) == 0
-        # the run list by row, for the convolution: a row's index in its
-        # run, the state row its run loads and, for a run's last row, the
-        # one it stores to (row 0, a layer's sink, for every other row)
+        self.runs = step_runs(pos, extra)
         with jax.named_scope("ssm.conv"):
-            self.rows = rows_of_step(pos, first, self.run_count, self.run_src,
-                                     self.run_dst, self.n_runs)[:4]
+            self.rows = rows_of_step(pos, self.runs)
 
     def predecessors(self, a, conv, layer):
         """``(a_{t-1}, a_{t-2}, a_{t-3})`` of every row and the convolution's
@@ -251,15 +244,13 @@ class _SlotStep:
         earlier inputs are its run's own rows or, before the run's first,
         the three its slot kept (none where the run starts a sequence)."""
         return kept_inputs(a, conv, layer * self.state_rows, self.rows,
-                           ("ssm.conv", "ssm.state_write"))
+                           self.runs, ("ssm.conv", "ssm.state_write"))
 
     def scan(self, dt, x, b, c, a_t, d, ssm, layer):
         from ..ops.pallas_kernels.selective_scan import selective_scan
 
-        base = layer * self.state_rows
-        runs = (self.run_first, self.run_count, self.run_src + base,
-                self.run_dst + base, self.run_fresh, self.n_runs)
-        return selective_scan(dt, x, b, c, a_t, d, ssm, runs)
+        return selective_scan(dt, x, b, c, a_t, d, ssm,
+                              runs_at(self.runs, layer * self.state_rows))
 
     def attend(self, q, k, v, pools, *, head_dim, ring_layer=None):
         """Write the rows' K and V and attend: ``q [T, Hq, 2d]`` (wide), ``k``

@@ -56,7 +56,9 @@ from ..core.dtype import to_jax_dtype
 from ..nn.layer import Layer
 from ..ops import dispatch
 from ..tensor import Parameter, Tensor
-from .decoder_ops import gated_ffn, kept_inputs, mm, rms_norm, rows_of_step
+from .decoder_ops import (
+    gated_ffn, kept_inputs, mm, rms_norm, rows_of_step, runs_at, step_runs,
+)
 
 __all__ = ["SolarOpen2Config", "SolarOpen2ForCausalLM", "solar_open2_tiny",
            "PUBLISHED_GQA_LAYERS"]
@@ -226,30 +228,23 @@ class _SlotStep:
         self.page_size = int(cache.page_size)
         self.n_pages = int(cache.num_pages)
         self.state_rows = int(cache.kda.shape[1])
-        first = extra["run_first"]
-        self.run_first, self.run_count = first, extra["run_count"]
-        self.run_src, self.run_dst = extra["run_src"], extra["run_dst"]
-        self.n_runs = extra["n_runs"]
-        self.run_fresh = jnp.take(pos, first) == 0
+        self.runs = step_runs(pos, extra)
         with jax.named_scope("kda.conv"):
-            *self.rows, self.real = rows_of_step(
-                pos, first, self.run_count, self.run_src, self.run_dst,
-                self.n_runs)
+            self.rows = rows_of_step(pos, self.runs)
+        self.real = self.rows.real
         last = tbl.shape[1] - 1
         self.write_page = jnp.take_along_axis(
             tbl, jnp.clip(pos // self.page_size, 0, last)[:, None], axis=1)[:, 0]
 
     def predecessors(self, a, conv, layer):
         return kept_inputs(a, conv, layer * self.state_rows, self.rows,
-                           ("kda.conv", "kda.state_write"))
+                           self.runs, ("kda.conv", "kda.state_write"))
 
     def rule(self, q, k, v, g, beta, pool, layer):
         from ..ops.pallas_kernels.kda import kda
 
-        base = layer * self.state_rows
         return kda(q, k, v, g, beta, pool,
-                   (self.run_first, self.run_count, self.run_src + base,
-                    self.run_dst + base, self.run_fresh, self.n_runs))
+                   runs_at(self.runs, layer * self.state_rows))
 
     def attend(self, q, k, v, pools, layer, head_dim):
         from .gpt import _attend_paged_shard

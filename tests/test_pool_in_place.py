@@ -204,10 +204,9 @@ def test_no_operation_of_the_slot_state_step_moves_a_pool(topo):
     V, the state-space rows and the convolution's) are donated and aliased,
     and every operation whose result has a pool's shape is plumbing, a
     Mosaic launch whose pool operands are its results (the pool write, the
-    scan) or the state rows' scatter: nothing copies one.  (The convolution's
-    rows, 12 MB here and 36 MB in the cell, less than one activation, the
-    compiler stages through the chip's on-chip memory, layout ``S(1)``, and
-    back: that pool is held to its aliasing alone.)"""
+    scan, the convolution's kept rows) or the state rows' scatter: nothing
+    copies one: the convolution's rows too are written where they lie, not
+    staged through the chip's on-chip memory and back."""
     pages = 1024
     ctx = manifest.resolve_cell(SLOT_STATE_CELL)
     model, eng = ctx["config"]["model"], ctx["cell"]["engine"]
@@ -230,9 +229,9 @@ def test_no_operation_of_the_slot_state_step_moves_a_pool(topo):
     assert memory.temp_size_in_bytes < 0.05 * nbytes, memory.temp_size_in_bytes
     # the window layers' write, launch (once a block width) and scan in the
     # loop; the memory layer's scan; the full-attention layer's write and
-    # launch; the cross layers' launch
-    assert text.count("tpu_custom_call") == 10
-    assert mosaic_iteration_bounds(text).count([_DYNAMIC]) >= 2      # the writes
+    # launch; the cross layers' launch; the kept rows beside each scan
+    assert text.count("tpu_custom_call") == 12
+    assert mosaic_iteration_bounds(text).count([_DYNAMIC]) >= 4      # writes, kept rows
     movers = []
     for line in text.splitlines():
         m = _RESULT.match(line)
@@ -241,10 +240,65 @@ def test_no_operation_of_the_slot_state_step_moves_a_pool(topo):
         op = m.group(2)
         aliased = op == "custom-call" and "output_to_operand_aliasing={" in line
         scatter = op == "fusion" and "ssm.state_write/scatter" in line
-        staged = pools[next(sh for sh in pools if sh in m.group(1))] == "conv"
-        if op not in STILL and not aliased and not scatter and not staged:
+        if op not in STILL and not aliased and not scatter:
             movers.append(line.strip()[:160])
     assert movers == []
+
+
+DELTA_RULE_CELL = "solar_open2_cut.serve_longctx_sat"
+
+
+def test_no_operation_of_the_delta_rule_step_moves_the_kept_rows(topo):
+    """The delta-rule hybrid's fused step at its published widths (one period:
+    a GQA layer and three delta-rule layers), 8 experts held and a paged pool
+    of 256 pages, compiled for the described chip: the convolution's kept
+    rows (``[3 x 257, 576, 128]``, 113.7 MB) are plumbing or the result of
+    ONE aliased launch a delta-rule layer, under ``kernel.kept_rows`` inside
+    ``kda.state_write``; nothing stages, slices or copies them, no operation
+    holds a kept row for every row of the step, and nothing under the
+    convolution's two scopes but that launch is larger than the step's
+    float32 activations ``[T, 24576]``."""
+    from paddle_tpu.telemetry import scopes
+
+    ctx = manifest.resolve_cell(DELTA_RULE_CELL)
+    model, eng = ctx["config"]["model"], ctx["cell"]["engine"]
+    model["experts_held"] = [0, 8]
+    eng["num_pages"] = 256
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(aot_compile, "_report", lambda compiled: compiled)
+        compiled = aot_compile.serve_step(ctx, topo)
+    text = compiled.as_text()
+    rows, t = 2 * eng["num_slots"] + 1, eng["num_slots"] + eng["prefill_token_budget"]
+    width = 3 * 64 * 128
+    kept = (f"bf16[{3 * rows},576,128]", f"bf16[3,{rows},576,128]")
+    movers = []
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if m is None or not any(shape in m.group(1) for shape in kept):
+            continue
+        aliased = m.group(2) == "custom-call" and "output_to_operand_aliasing={" in line
+        if m.group(2) not in STILL and not aliased:
+            movers.append(line.strip()[:160])
+    assert movers == []
+    assert f"[{t},576,128]" not in text and f"[{t},3,{width}]" not in text
+    # the delta-rule layers' three launches, each writing the kept rows where
+    # they lie; the parent's 17 calls were the ragged launch, the grouped
+    # products and the rule's
+    assert text.count("tpu_custom_call") == 20
+    launches = [c for c in pool_write_calls(text) if c["aliased"] == [kept[0]]]
+    assert len(launches) == 3 and all(c["results"] == [0] for c in launches)
+    mapped = scopes.scopes_of_hlo_text(text)
+    names = [n for n, s in mapped.items()
+             if s.scope.endswith("kernel.kept_rows") and s.rule == "own"]
+    assert len(names) == 3 and all(n.startswith("kernel.kept_rows") for n in names)
+    assert {mapped[n].scope for n in names} == {"layers/kda.state_write/kernel.kept_rows"}
+    largest = 0
+    for comp, name, op, nbytes, _, _, aliased in _instructions(text):
+        scope = mapped.get(name)
+        if (scope is not None and re.search(r"kda\.(conv|state_write)", scope.scope)
+                and not aliased and op not in STILL):
+            largest = max(largest, nbytes)
+    assert 0 < largest <= t * width * 4, largest
 
 
 def test_no_operation_of_the_compiled_step_moves_a_layers_pool(compiled_step):
@@ -483,6 +537,38 @@ def test_the_pool_write_compiles_at_the_sharded_local_heads(local_heads, pools, 
     (call,) = pool_write_calls(text)
     assert call["aliased"] == [f"bf16[{PAGES},{local_heads},{page},{dim}]"] * pools
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("rows,layers,slots,t", [(576, 3, 128, 384), (120, 9, 64, 320)])
+def test_the_kept_rows_launch_compiles_at_the_cells_geometry(rows, layers, slots, t, topo):
+    """Solar-Open2's three delta-rule layers over 128 slots and 384 rows a
+    step; Phi-4-mini-flash's nine state-space layers over 64 slots and 320:
+    one call, a grid as long as the run list, the pool its own result, and
+    ``a`` and the old rows handed over as bitcasts, nothing copied."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas_kernels import kept_rows as kr
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    i32 = jnp.int32
+    pool = struct((layers * (2 * slots + 1), rows, 128), jnp.bfloat16)
+    compiled = jax.jit(lambda p, a, *runs: kr.kept_rows(p, a, runs),
+                       donate_argnums=(0,)).lower(
+        pool, struct((t, rows // 3 * 128), jnp.bfloat16),
+        *[struct((slots,), i32)] * 5, struct((1,), i32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert mosaic_iteration_bounds(text) == [[_DYNAMIC]]
+    (call,) = pool_write_calls(text)
+    assert call["aliased"] == [f"bf16[{pool.shape[0]},{rows},128]"]
+    assert all(ty.startswith("s32[") for ty in re.findall(r"= (\S+) copy\(", text))
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 def test_the_lowered_loop_carries_the_pools():

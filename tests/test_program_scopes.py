@@ -17,7 +17,8 @@ from paddle_tpu.jit import api as jit_api
 from paddle_tpu.models import GPTStackedForPretraining, gpt_tiny
 from paddle_tpu.models import gpt as gpt_mod
 from paddle_tpu.ops.pallas_kernels import (
-    decode_attention, flash_attention, fused_adamw, paged_attention, rms_norm,
+    decode_attention, flash_attention, fused_adamw, kept_rows, paged_attention,
+    rms_norm,
 )
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.telemetry import scopes
@@ -37,6 +38,7 @@ VOCABULARY = (
     "gmu", "attn.window", "attn.shared", "attn.diff",
     "kda.proj", "kda.conv", "kda.gate", "kda.scan", "kda.norm",
     "kda.state_write", "kernel.kda", "attn.gqa", "moe.shared",
+    "kernel.kept_rows",
 )
 
 # where each scope must be found
@@ -199,13 +201,18 @@ def kernel_texts():
     texts["rms"] = lowered(
         lambda x, w: rms_norm.fused_add_rms_norm(x, x, w)[0],
         jnp.ones((8, 128)), jnp.ones((128,)))
+    runs = (jnp.zeros((1,), jnp.int32),) * 5 + (jnp.ones((1,), jnp.int32),)
+    texts["kept"] = lowered(
+        lambda rows, a: kept_rows.kept_rows(rows, a, runs, interpret=True)[0],
+        jnp.zeros((3, 24, 128), jnp.float32), jnp.ones((8, 1024), jnp.float32))
     return texts
 
 
 IN_KERNELS = {"kernel.flash_fwd": "flash", "kernel.flash_bwd_dkv": "flash",
               "kernel.flash_bwd_dq": "flash", "shard.flash": "shard",
               "kernel.paged_decode": "paged", "kernel.decode": "decode",
-              "kernel.fused_adamw": "adamw", "kernel.rms_norm": "rms"}
+              "kernel.fused_adamw": "adamw", "kernel.rms_norm": "rms",
+              "kernel.kept_rows": "kept"}
 
 
 @pytest.mark.parametrize("scope", VOCABULARY)
