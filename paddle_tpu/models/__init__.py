@@ -27,6 +27,9 @@ from .phi4flash import (  # noqa: F401
 from .solar_open2 import (  # noqa: F401
     SolarOpen2Config, SolarOpen2ForCausalLM, solar_open2_tiny,
 )
+from .mellum2 import (  # noqa: F401
+    Mellum2Config, Mellum2ForCausalLM, mellum2_tiny,
+)
 from .ernie_moe import (  # noqa: F401
     ErnieMoEConfig, ErnieMoEForPretraining, ErnieMoEModel, ernie_moe_tiny,
 )

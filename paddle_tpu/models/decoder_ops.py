@@ -1,15 +1,21 @@
-"""What the stacked hybrid decoders (``lfm2.py``, ``phi4flash.py``) share, on
-raw arrays: the matmul on the weights' dtype, the norms and the gated
-feed-forwards.  One place, so that a decoder of new layer kinds copies none
-of it."""
+"""What the stacked hybrid decoders (``lfm2.py``, ``phi4flash.py``,
+``solar_open2.py``, ``mellum2.py``) share, on raw arrays: the matmul on the
+weights' dtype, the norms, the gated feed-forwards, rotary positions (with
+YaRN's frequencies where a layer asks for them), a step's runs and kept
+inputs, and where a step's rows find their K and V in a slot cache's pool
+and ring.  One place, so that a decoder of new layer kinds copies none of
+it."""
 from __future__ import annotations
 
-from typing import NamedTuple
+import math
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = ["mm", "rms_norm", "layer_norm", "gated_ffn", "gated_ffn_fused",
+           "rope", "yarn_frequencies", "SlotAttention",
            "StepRows", "step_runs", "runs_at", "rows_of_step",
            "kept_inputs"]
 
@@ -45,6 +51,116 @@ def gated_ffn_fused(u, w_in, w_out):
     the value in one product."""
     g, p = jnp.split(mm(u, w_in), 2, axis=-1)
     return mm((p * jax.nn.silu(g)).astype(u.dtype), w_out).astype(u.dtype)
+
+
+def rope(x, pos, theta, yarn=None):
+    """Rotate-half rotary positions: ``x`` [..., heads, D] at ``pos`` [...]
+    (dimension ``i`` pairs with ``i + D/2``), at frequencies ``theta^(-2i/D)``;
+    ``yarn`` (:func:`yarn_frequencies`): ``(frequencies [D/2], factor)``
+    instead, the cosine and sine both times ``factor``."""
+    half = x.shape[-1] // 2
+    if yarn is None:
+        freq = jnp.exp(jnp.arange(half, dtype=jnp.float32)
+                       * np.float32(-np.log(theta) / half))
+    else:
+        freq = jnp.asarray(yarn[0], jnp.float32)
+    ang = pos.astype(jnp.float32)[..., None, None] * freq      # [..., 1, D/2]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        cos, sin = cos * np.float32(yarn[1]), sin * np.float32(yarn[1])
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def yarn_frequencies(head_dim: int, theta: float, *, factor: float,
+                     original_max_position_embeddings: int, beta_fast: float,
+                     beta_slow: float, attention_factor: float
+                     ) -> Tuple[np.ndarray, float, Tuple[int, int]]:
+    """YaRN's rotary frequencies (arXiv:2309.00071, as transformers'
+    ``_compute_yarn_parameters`` reads a config): ``theta_i = theta^(-2i/D)``
+    kept below the correction range ``[low, high]`` (the dimensions that turn
+    more than ``beta_fast`` times over the original context), divided by
+    ``factor`` above it, and ramped linearly between.  Returns
+    ``(frequencies [D/2] float32, attention factor, (low, high))``; the
+    attention factor is ``0.1 ln(factor) + 1`` where none is given."""
+    half = head_dim // 2
+
+    def turns(n):          # the dimension that turns n times over the context
+        return (head_dim * math.log(original_max_position_embeddings
+                                    / (n * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns(beta_fast)), 0)
+    high = min(math.ceil(turns(beta_slow)), head_dim - 1)
+    base = theta ** (-np.arange(half, dtype=np.float64) * 2 / head_dim)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    freq = base * (1.0 - ramp) + base / factor * ramp
+    return freq.astype(np.float32), float(attention_factor), (low, high)
+
+
+class SlotAttention:
+    """Where a step's rows find their K and V in a ``SlotStateCache``: the
+    PAGED pool of its full-attention layers (the step's plan and page
+    tables; layer ``l`` of a stacked pool viewed flat at page ``l *
+    num_pages``) and, where the cache keeps one, the RING of its window
+    layers (the second work list the cache packed beside the plan, and each
+    row's ring as a page-table row; window layer ``l`` at ring page ``l *
+    ring size``).  Built once a step from the packed step input alone."""
+
+    def __init__(self, pos, tbl, plan, extra, cache):
+        from ..ops.pallas_kernels.ragged_paged_attention import (
+            RAGGED_PLAN_FIELDS,
+        )
+
+        self.pos, self.tbl, self.plan = pos, tbl, tuple(plan)
+        self.page_size = int(cache.page_size)
+        self.n_pages = int(cache.num_pages)
+        if not cache.window_layers:
+            return
+        self.window = int(cache.window)
+        self.ring_pages = int(cache.ring_pages)
+        self.ring_size = int(cache.ring_k.shape[1])
+        # the window layers' plan: the step's blocks, another work list
+        # (the ring pages inside the window) and the ring page each write
+        # item lands in
+        win = dict(zip(RAGGED_PLAN_FIELDS, self.plan))
+        for f in cache.WINDOW_FIELDS:
+            win[f] = extra["win_" + f]
+        self.win_plan = tuple(win[f] for f in RAGGED_PLAN_FIELDS)
+        slot = extra["row_slot"]
+        # a row's ring as a page-table row (padding rows: the sink)
+        at = jnp.arange(tbl.shape[1], dtype=jnp.int32)[None, :]
+        self.ring_tbl = jnp.where(
+            slot[:, None] >= 0,
+            1 + slot[:, None] * self.ring_pages + at % self.ring_pages, 0)
+
+    def attend(self, q, k, v, pools, *, head_dim, scope, layer=None,
+               ring_layer=None):
+        """Write the rows' K and V and attend: ``q`` [T, Hq, W], ``k`` / ``v``
+        [T, H, W] against the ring of window layer ``ring_layer``, else the
+        paged pool of full layer ``layer`` (None: ``pools`` are one layer's).
+        ``scope`` names the launch's scope.  Returns ``(out [T, Hq, W],
+        k_pool, v_pool)``."""
+        from .gpt import _attend_paged_shard
+        from ..ops.pallas_kernels.ragged_paged_attention import plan_at_layer
+
+        window = None
+        if ring_layer is not None:
+            base = ring_layer * self.ring_size
+            tbl, plan = self.ring_tbl + base, plan_at_layer(self.win_plan, base)
+            window = self.window
+        elif layer is not None:
+            base = layer * self.n_pages
+            tbl, plan = self.tbl + base, plan_at_layer(self.plan, base)
+        else:
+            tbl, plan = self.tbl, self.plan
+        out, pk, pv = _attend_paged_shard(
+            q[:, :, None, :], k[:, :, None, :], v[:, :, None, :], *pools,
+            tbl, self.pos, head_dim=head_dim, page_size=self.page_size,
+            ragged_plan=plan, window=window, attend_scope=scope)
+        return out[:, :, 0, :], pk, pv
 
 
 class StepRows(NamedTuple):

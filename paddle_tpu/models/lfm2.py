@@ -54,6 +54,7 @@ from ..nn.layer import Layer
 from ..ops import dispatch
 from ..tensor import Parameter, Tensor
 from .decoder_ops import gated_ffn as _gated_ffn, mm as _mm, rms_norm as _rms
+from .decoder_ops import rope as _rope
 
 __all__ = ["Lfm2Config", "Lfm2StackedForCausalLM", "lfm2_tiny",
            "PUBLISHED_LAYER_TYPES"]
@@ -159,25 +160,18 @@ def lfm2_tiny(**kw) -> Lfm2Config:
 # the mathematics, on raw arrays
 # ---------------------------------------------------------------------------
 
-def _rope(x, pos, theta):
-    """Rotate-half rotary positions: ``x`` [..., heads, D] at ``pos`` [...]
-    (dimension ``i`` pairs with ``i + D/2``)."""
-    half = x.shape[-1] // 2
-    freq = jnp.exp(jnp.arange(half, dtype=jnp.float32)
-                   * np.float32(-np.log(theta) / half))
-    ang = pos.astype(jnp.float32)[..., None, None] * freq      # [..., 1, D/2]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x32 = x.astype(jnp.float32)
-    a, b = x32[..., :half], x32[..., half:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
-
-
-def _route(cfg: Lfm2Config, u, router, bias):
+def _route(cfg: Lfm2Config, u, router, bias, scoring="sigmoid"):
     """Float32 routing of rows ``u`` [N, H]: the chosen experts [N, k] and
-    their weights [N, k].  The bias selects and does not weigh."""
+    their weights [N, k].  ``sigmoid``: the bias selects and does not weigh,
+    the weights are renormalised with an epsilon; ``softmax``: a softmax over
+    every expert, the top k by it, their weights renormalised to 1 (no
+    bias)."""
     logits = jnp.dot(u.astype(jnp.float32), router,
                      precision=jax.lax.Precision.HIGHEST)
+    if scoring == "softmax":
+        p, sel = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                               cfg.num_experts_per_tok)
+        return sel.astype(jnp.int32), p / jnp.sum(p, axis=-1, keepdims=True)
     s = jax.nn.sigmoid(logits)
     _, sel = jax.lax.top_k(s + bias, cfg.num_experts_per_tok)
     p = jnp.take_along_axis(s, sel, axis=-1)
@@ -185,7 +179,7 @@ def _route(cfg: Lfm2Config, u, router, bias):
 
 
 def _moe(cfg: Lfm2Config, u, router, bias, w1, w3, w2, group_base, real,
-         held=None):
+         held=None, scoring="sigmoid"):
     """The routed feed-forward of rows ``u`` [N, H] against the stacked
     experts ``w1``/``w3`` [L*E, H, F], ``w2`` [L*E, F, H], this layer's at
     ``group_base``.  ``real`` [N] bool (or None: every row): a row that is
@@ -193,16 +187,16 @@ def _moe(cfg: Lfm2Config, u, router, bias, w1, w3, w2, group_base, real,
     every expert): ``(first, count)``, the experts this layer HOLDS of the
     ``num_experts`` it routes over (the stacks hold ``count`` a layer): a
     pair whose expert lies outside them is another chip's and is dropped,
-    as a padding row's is, and nothing stands in for it.  Returns ``(y,
-    counts, sel)``: ``counts`` the int32 triple (token-expert pairs computed
-    here, experts with a token, the fullest expert's rows), ``sel`` [N, k]
-    the experts each row went to (``num_experts`` for a row that is not
-    real)."""
+    as a padding row's is, and nothing stands in for it.  ``scoring``: the
+    router's form (:func:`_route`).  Returns ``(y, counts, sel)``:
+    ``counts`` the int32 triple (token-expert pairs computed here, experts
+    with a token, the fullest expert's rows), ``sel`` [N, k] the experts
+    each row went to (``num_experts`` for a row that is not real)."""
     from ..ops.pallas_kernels.grouped_matmul import grouped_matmul
 
     n, k, e = u.shape[0], cfg.num_experts_per_tok, cfg.num_experts
     with jax.named_scope("moe.route"):
-        sel, p = _route(cfg, u, router, bias)
+        sel, p = _route(cfg, u, router, bias, scoring)
         if real is not None:
             sel = jnp.where(real[:, None], sel, e)      # past every expert
         flat = sel.reshape(-1)

@@ -68,8 +68,8 @@ from ..nn.layer import Layer
 from ..ops import dispatch
 from ..tensor import Parameter, Tensor
 from .decoder_ops import (
-    gated_ffn_fused, kept_inputs, layer_norm, mm, rms_norm, rows_of_step,
-    runs_at, step_runs,
+    SlotAttention, gated_ffn_fused, kept_inputs, layer_norm, mm, rms_norm,
+    rows_of_step, runs_at, step_runs,
 )
 
 __all__ = ["Phi4FlashConfig", "Phi4FlashForCausalLM", "phi4flash_tiny"]
@@ -204,36 +204,17 @@ def _conv_taps(a, prev, w, b):
 
 
 class _SlotStep:
-    """What one serving step's rows share across its layers: the two plans,
-    each row's ring table, and where a state-space layer finds and leaves a
-    run's state.  Rows are the step's flat tokens; a run (a decode token or
-    a prefill chunk) is consecutive rows at consecutive positions of one
-    slot.  Built once a step from the packed step input alone."""
+    """What one serving step's rows share across its layers: the two plans
+    and each row's ring table (``decoder_ops.SlotAttention``), and where a
+    state-space layer finds and leaves a run's state.  Rows are the step's
+    flat tokens; a run (a decode token or a prefill chunk) is consecutive
+    rows at consecutive positions of one slot.  Built once a step from the
+    packed step input alone."""
 
     def __init__(self, pos, tbl, plan, extra, cache):
-        from ..ops.pallas_kernels.ragged_paged_attention import (
-            RAGGED_PLAN_FIELDS,
-        )
-
         self.pos, self.tbl, self.plan = pos, tbl, tuple(plan)
-        self.page_size = int(cache.page_size)
-        self.window = int(cache.window)
-        self.ring_pages = int(cache.ring_pages)
-        self.ring_size = int(cache.ring_k.shape[1])
         self.state_rows = int(cache.ssm.shape[1])
-        # the window layers' plan: the step's blocks, another work list
-        # (the ring pages inside the window) and the ring page each write
-        # item lands in
-        win = dict(zip(RAGGED_PLAN_FIELDS, self.plan))
-        for f in cache.WINDOW_FIELDS:
-            win[f] = extra["win_" + f]
-        self.win_plan = tuple(win[f] for f in RAGGED_PLAN_FIELDS)
-        slot = extra["row_slot"]
-        # a row's ring as a page-table row (padding rows: the sink)
-        at = jnp.arange(tbl.shape[1], dtype=jnp.int32)[None, :]
-        self.ring_tbl = jnp.where(
-            slot[:, None] >= 0,
-            1 + slot[:, None] * self.ring_pages + at % self.ring_pages, 0)
+        self.kv = SlotAttention(pos, tbl, plan, extra, cache)
         self.runs = step_runs(pos, extra)
         with jax.named_scope("ssm.conv"):
             self.rows = rows_of_step(pos, self.runs)
@@ -257,20 +238,9 @@ class _SlotStep:
         / ``v`` ``[T, H, 2d]`` pair rows, against the layer's own pool: the
         ring of window layer ``ring_layer``, else the paged pool.  Returns
         ``(out [T, Hq, 2d], k_pool, v_pool)``."""
-        from .gpt import _attend_paged_shard
-        from ..ops.pallas_kernels.ragged_paged_attention import plan_at_layer
-
-        if ring_layer is None:
-            tbl, plan, window, scope = self.tbl, self.plan, None, "attn.shared"
-        else:
-            base = ring_layer * self.ring_size
-            tbl, plan = self.ring_tbl + base, plan_at_layer(self.win_plan, base)
-            window, scope = self.window, "attn.window"
-        out, pk, pv = _attend_paged_shard(
-            q[:, :, None, :], k[:, :, None, :], v[:, :, None, :], *pools,
-            tbl, self.pos, head_dim=head_dim, page_size=self.page_size,
-            ragged_plan=plan, window=window, attend_scope=scope)
-        return out[:, :, 0, :], pk, pv
+        return self.kv.attend(
+            q, k, v, pools, head_dim=head_dim, ring_layer=ring_layer,
+            scope="attn.shared" if ring_layer is None else "attn.window")
 
     def attend_shared(self, q, pools, *, head_dim):
         """A cross layer's attention: the full-attention layer's pools, read

@@ -146,12 +146,14 @@ class _DeviceLog:
     LIMB = 1 << 30
     ROUTE_ROWS = 16384
 
-    def _new_log(self, counter_names, routed_layers: int, top_k: int):
+    def _new_log(self, counter_names, routed_layers: int, top_k: int,
+                 route_rows: int = 0):
         self._log_names = tuple(counter_names)
         self.counters = Tensor(jnp.zeros((len(counter_names), 2), jnp.int32))
         self.routed_layers, self.top_k = routed_layers, top_k
         self.routes = Tensor(jnp.zeros(
-            (routed_layers * top_k + 2, self.ROUTE_ROWS), jnp.int32))
+            (routed_layers * top_k + 2, route_rows or self.ROUTE_ROWS),
+            jnp.int32))
         self.route_cursor = Tensor(jnp.zeros((1,), jnp.int32))
 
     def _release_log(self):
@@ -311,7 +313,9 @@ class SlotStateCache(_DeviceLog, _KVBuffers):
       as a K/V row write is, so a step that is dispatched again (the engine
       retries a failed step once) must find the state the last COMPLETED
       step left, and it does, because the rows swap only when a step's
-      results are harvested (``commit_step``).
+      results are harvested (``commit_step``).  None where ``rows`` is
+      empty: a decoder of window and full attention layers keeps the ring
+      and the pool alone.
 
     A run that starts at position 0 starts from zero state and reads no
     ring page older than itself (the window mask is by position), whatever
@@ -326,7 +330,8 @@ class SlotStateCache(_DeviceLog, _KVBuffers):
     ``state_counters`` names: ``(name, "runs" | "rows" | "chunk_rows")``,
     a step's runs, rows, or rows of runs longer than one) and the
     ``device_counters`` the compiled step adds to (``_DeviceLog``), which
-    ``ServingEngine.metrics()`` merges."""
+    ``ServingEngine.metrics()`` merges; ``route_rows`` sizes the route log
+    (``_DeviceLog.ROUTE_ROWS`` where 0)."""
 
     paged = True
     quantized = False
@@ -338,7 +343,7 @@ class SlotStateCache(_DeviceLog, _KVBuffers):
                  max_run: int, num_heads: int, row_dim: int, rows: dict,
                  attn_layers: int = 1, window: int = 0, window_layers: int = 0,
                  state_counters=(), device_counters=(), routed_layers: int = 0,
-                 top_k: int = 0, dtype: str = "bfloat16"):
+                 top_k: int = 0, route_rows: int = 0, dtype: str = "bfloat16"):
         if str(dtype) == "int8":
             raise ValueError(
                 "SlotStateCache: an int8 pool is not supported (the ring "
@@ -382,7 +387,7 @@ class SlotStateCache(_DeviceLog, _KVBuffers):
             + tuple(name for name, _ in self.state_counters))
         self._device_log = bool(device_counters or routed_layers)
         if self._device_log:
-            self._new_log(device_counters, routed_layers, top_k)
+            self._new_log(device_counters, routed_layers, top_k, route_rows)
         # which of its two state rows a slot's next step READS (0 or 1)
         self._flip = np.zeros((num_slots,), np.int64)
         self._tables = None         # the slots' ring tables, made on first use

@@ -29,7 +29,7 @@ SCOPES = (
     "gmu", "attn.window", "attn.shared", "attn.diff",
     "kda.proj", "kda.conv", "kda.gate", "kda.scan", "kda.norm",
     "kda.state_write", "kernel.kda", "attn.gqa", "moe.shared",
-    "kernel.kept_rows",
+    "kernel.kept_rows", "attn.full",
 )
 UNSCOPED = "unscoped"
 _VOCABULARY = frozenset(SCOPES)

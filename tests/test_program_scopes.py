@@ -38,7 +38,7 @@ VOCABULARY = (
     "gmu", "attn.window", "attn.shared", "attn.diff",
     "kda.proj", "kda.conv", "kda.gate", "kda.scan", "kda.norm",
     "kda.state_write", "kernel.kda", "attn.gqa", "moe.shared",
-    "kernel.kept_rows",
+    "kernel.kept_rows", "attn.full",
 )
 
 # where each scope must be found
@@ -67,6 +67,13 @@ IN_SOLAR = ("serve.unpack", "serve.sample", "embed", "layers", "attn.qkv",
             "kda.proj", "kda.conv", "kda.gate", "kda.scan", "kda.norm",
             "kda.state_write", "kernel.kda", "moe.route", "moe.experts",
             "moe.shared", "kernel.gmm", "lm_head")
+
+# the serving step of the window / full grouped-query decoder with
+# softmax-routed experts
+IN_MELLUM = ("serve.unpack", "serve.sample", "embed", "layers", "attn.qkv",
+             "attn.rope", "attn.window", "attn.full", "attn.pool_write",
+             "kernel.ragged", "attn.out", "moe.route", "moe.experts",
+             "kernel.gmm", "lm_head")
 
 
 def _leaf(s):
@@ -157,6 +164,21 @@ def solar_engine():
 
 
 @pytest.fixture(scope="module")
+def mellum_engine():
+    from paddle_tpu.models import Mellum2ForCausalLM, mellum2_tiny
+
+    pt.seed(0)
+    model = Mellum2ForCausalLM(mellum2_tiny(num_hidden_layers=8))    # two periods: a loop
+    model.eval()
+    eng = ServingEngine(model, num_slots=2, page_size=8, max_context=32,
+                        prefill_token_budget=8, cache_dtype="float32")
+    eng.submit(np.arange(11), 2)
+    eng.run_until_idle(max_steps=50)
+    yield eng
+    eng.close()
+
+
+@pytest.fixture(scope="module")
 def kernel_texts():
     """What the CPU cannot run it can still trace: the Mosaic calls' jaxprs
     with their name stacks, and the wrappers that fall back to XLA here."""
@@ -217,7 +239,8 @@ IN_KERNELS = {"kernel.flash_fwd": "flash", "kernel.flash_bwd_dkv": "flash",
 
 @pytest.mark.parametrize("scope", VOCABULARY)
 def test_scope_is_in_the_programs(scope, train_step, engine, hybrid_engine,
-                                  slot_state_engine, solar_engine, kernel_texts):
+                                  slot_state_engine, solar_engine, mellum_engine,
+                                  kernel_texts):
     """Every name of the vocabulary is on the operations of the program it
     belongs to: in ``lowered_texts()`` of the train step and of the serving
     step, and for the kernels the CPU never calls, in their traces."""
@@ -240,6 +263,10 @@ def test_scope_is_in_the_programs(scope, train_step, engine, hybrid_engine,
         found_somewhere = True
     if scope in IN_SOLAR:
         text = "".join(solar_engine.lowered_texts())
+        assert re.search(r'["/(]' + re.escape(scope) + r'[/)"]', text), scope
+        found_somewhere = True
+    if scope in IN_MELLUM:
+        text = "".join(mellum_engine.lowered_texts())
         assert re.search(r'["/(]' + re.escape(scope) + r'[/)"]', text), scope
         found_somewhere = True
     if scope in IN_KERNELS:
@@ -391,6 +418,33 @@ def test_op_scopes_of_the_delta_rule_serving_step(solar_engine):
     assert m["kda_rows"] == 12 and m["kda_runs"] == 3 and m["kda_chunk_rows"] == 11
     assert m["moe_pairs"] == 4 * 4 * 12     # layers x top-4 x real rows
     assert 0 < m["moe_local_pairs"] < m["moe_pairs"]
+
+
+def test_op_scopes_of_the_window_and_full_serving_step(mellum_engine):
+    """A window layer's rotation, ring write, launch and output projection
+    are under ``attn.window`` and the full layer's under ``attn.full``, each
+    launch ``.../kernel.ragged``; the experts ``moe.experts/kernel.gmm`` (a
+    ``ragged_dot`` off the chip); the counters the cache keeps, on the host
+    and on the device, are in ``metrics()``."""
+    assert mellum_engine.compiled_programs == 1
+    (mapped,) = mellum_engine.op_scopes()
+    paths = {s.scope for s in mapped.values()}
+    for kind in ("attn.window", "attn.full"):
+        assert any(p.endswith(f"{kind}/kernel.ragged") for p in paths), kind
+        assert any(p.endswith(f"{kind}/attn.rope") for p in paths), kind
+        assert any(p.endswith(f"{kind}/attn.out") for p in paths), kind
+    leaves = {_leaf(s) for s in mapped.values()}
+    assert {"attn.qkv", "attn.pool_write", "moe.route", "moe.experts",
+            "lm_head"} <= leaves
+    scatters = {_leaf(s) for name, s in mapped.items() if "scatter" in name}
+    assert "attn.pool_write" in scatters
+    loops = [s for name, s in mapped.items() if name.startswith("while")]
+    assert loops and all(s.scope.startswith("layers") for s in loops)
+    unscoped = [n for n, s in mapped.items() if s.scope == scopes.UNSCOPED]
+    assert len(unscoped) <= len(mapped) // 10, unscoped
+    m = mellum_engine.metrics()
+    assert m["moe_assignments"] == 8 * 2 * 12     # layers x top-2 x real rows
+    assert 0 < m["window_work_items"] and 0 < m["work_items"]
 
 
 def test_op_scopes_of_a_train_step_with_recomputation(train_step):
