@@ -23,21 +23,36 @@ into token granularity:
   narrow block (else one narrow block): while every block was 8 rows a
   512-token chunk was 64 blocks and each read every page of its context
   again, 656 work items at context 1,500 where 8 blocks of 64 rows read 84.
-  ``QW`` is :func:`ragged_wide_block` of the launch's own shapes: the
-  widest multiple of 8 whose item still costs about its DMA (``G * QW``
-  rows a head: one score for every ``_KV_BYTES_PER_SCORE`` bytes of a
-  position's K and V) and whose buffers fit the scoped VMEM: 64 with one
-  query head a pool head of 128 bf16 (the GPT cells), 16 with four (the
-  hybrid and the long-context cell).  On the chip (TPU v5e, PR 38) no
-  width from 32 rows up costs just its DMA: a step of 15 decode rows and
-  a 497-token chunk at context 1,024 is 834 items and 1,244 us a layer in
-  blocks of 8, 342 and 588 at 32, 258 and 514 at 64, 216 and 476 at 128 (a
-  wide item costs 0.8 us and 41 ns a row and page, all 16 heads: the body's
-  vector work, not the MXU); the document cell gives 17,346 / 17,740 /
-  18,122 tokens/s at 32 / 64 / 128 (the narrow plan 13,500).  Sixteen
-  tokens of four query heads (64 rows) read 1,754 items in 1,989 us where
-  blocks of 8 read 1,962 in 2,142 at the long-context cell's geometry, 32
-  tokens 1,650 in 1,974;
+  ``QW`` is :func:`ragged_wide_block` of the launch's own shapes: a width
+  in TOKENS, the widest multiple of 8 up to 64 whose buffers (``G * QW``
+  rows a head) fit the scoped VMEM: 64 at every served geometry, one, four
+  or eight query heads a pool head.  Tokens decide how often a chunk's
+  context is re-read, and a score's vector work is the same at any width,
+  so the width does not shrink as ``G`` grows.  On the chip (TPU v5e) no
+  width from 32 rows up costs just its DMA: at one query head a pool
+  head, a step of 15 decode rows and a 497-token chunk at context 1,024 is
+  834 items and 1,244 us a layer in blocks of 8, 342 and 588 at 32, 258
+  and 514 at 64, 216 and 476 at 128 (a wide item costs 0.8 us and 41 ns a
+  row and page, all 16 heads: the body's vector work, not the MXU); the
+  document cell gives 17,346 / 17,740 / 18,122 tokens/s at 32 / 64 / 128
+  (the narrow plan 13,500).  At eight query heads over four pool heads
+  of 128 (Mellum2's full layer: 29 decode rows at contexts of 4-28 k and
+  a 1,024-token chunk at context 6,656, one launch) blocks of 8 / 16 /
+  32 / 48 / 64 / 128 tokens read 10,556 / 6,940 / 5,132 / 4,572 / 4,228 /
+  3,776 items in 7,608 / 6,049 / 5,951 / 5,458 / 5,174 / 5,047 us (a wide
+  item of 64 tokens, 512 rows a head, ~3.0 us against 0.72 for a narrow
+  one); the chunk at context 16,384: 14,779 us at 8, 8,996 at 64, 8,681 at
+  128; a window layer over its ring (window 1,024): 1,413 items in 1,154
+  us at 8, 405 in 813 at 64; the end of one prompt and the start of the
+  next (300 and 724 tokens): 4,973 us at 8, 4,052 at 64, 4,186 at 128.
+  128 tokens gains at most 3.5% in a long chunk and loses in a short run
+  (twice the padded arithmetic of a tail's block), so 64 is the cap.
+  Where chunks are short the width hardly matters: at four query heads
+  over ten pool heads (the long-context cell's shared layer, 48 decode
+  rows and chunks of 120 and 57 tokens) 8 / 16 / 32 / 64 tokens take
+  1,799 / 1,696 / 1,674 / 1,654 us, its window layer 455 / 441 / 461 /
+  445; over eight (a step of 63 decode rows and a 35-token chunk) 573 /
+  593 / 592 / 613;
 - the grid iterates a host-built **work list** of (token-block, page)
   tuples — one entry per page a block actually has to read, built from
   the scheduler's host mirrors (``build_ragged_plan``).  The work-list
@@ -304,13 +319,11 @@ def ragged_head_block(num_heads: int, page_size: int, head_dim: int,
                if num_heads % hb == 0 and hb <= fit)
 
 
-# what a wide item may compute for the bytes it moves: one score (a query
-# row against one pool position of one head) for every so many bytes of that
-# position's K and V rows.  8 is 64 rows a head of 128 in bf16: measured in
-# the document cell beside 16 (32 rows, 2.2% fewer tokens/s) and 4 (128
-# rows, 2.2% more, with twice the padded arithmetic in a tail's block and
-# not yet run in the other three cells); the module docstring has the table
-_KV_BYTES_PER_SCORE = 8
+# the most tokens a wide block carries: how often a chunk's context is re-read
+# falls as 1 / QW; 128 gains at most a few percent over 64 in a long chunk
+# and loses in a short run (twice the padded arithmetic of a tail's block);
+# the module docstring has the tables
+_WIDE_TOKENS_MAX = 64
 
 
 def _wide_vmem_bytes(hb: int, rows: int, page_size: int, head_dim: int,
@@ -331,17 +344,16 @@ def ragged_wide_block(num_heads: int, group: int, page_size: int,
     """How many tokens a WIDE block carries (``QW``): the query rows of a
     work item that belongs to a run longer than one narrow block.  A pure
     function of the launch's own shapes, as :func:`ragged_head_block` is:
-    the widest multiple of 8 whose item still costs about its DMA,
-    ``group * QW`` rows a head at one score for every
-    ``_KV_BYTES_PER_SCORE`` bytes of a position's K and V, and whose
-    buffers (:func:`_wide_vmem_bytes`) fit the scoped VMEM.  16 heads of 128 in bf16, one query head a pool head: 64;
-    four query heads a pool head: 16.  ``token_block`` where nothing wider
-    fits: the plan then holds no wide block and the launch is the narrow
-    one alone."""
+    the widest multiple of 8, up to ``_WIDE_TOKENS_MAX`` tokens, whose
+    buffers (:func:`_wide_vmem_bytes`, ``group * QW`` rows a head) fit the
+    scoped VMEM.  A width in TOKENS, whatever ``group``: tokens decide how
+    often a chunk's context is re-read, and a score's vector work is the
+    same at any width.  ``token_block`` where nothing wider fits: the plan
+    then holds no wide block and the launch is the narrow one alone."""
     qb, group = int(token_block), int(group)
     itemsize = jnp.dtype(dtype).itemsize
     hb = ragged_head_block(num_heads, page_size, head_dim, dtype)
-    qw = 2 * int(head_dim) * itemsize // _KV_BYTES_PER_SCORE // group // 8 * 8
+    qw = _WIDE_TOKENS_MAX
     while qw > qb and (_wide_vmem_bytes(hb, group * qw, page_size,
                                         head_dim, itemsize)
                        > _SCOPED_VMEM_BYTES):

@@ -282,9 +282,9 @@ def test_no_operation_of_the_delta_rule_step_moves_the_kept_rows(topo):
     assert movers == []
     assert f"[{t},576,128]" not in text and f"[{t},3,{width}]" not in text
     # the delta-rule layers' three launches, each writing the kept rows where
-    # they lie; the parent's 17 calls were the ragged launch, the grouped
-    # products and the rule's
-    assert text.count("tpu_custom_call") == 20
+    # they lie, beside the GQA layer's two ragged launches (a narrow and a
+    # wide one), the grouped products and the rule's
+    assert text.count("tpu_custom_call") == 21
     launches = [c for c in pool_write_calls(text) if c["aliased"] == [kept[0]]]
     assert len(launches) == 3 and all(c["results"] == [0] for c in launches)
     mapped = scopes.scopes_of_hlo_text(text)

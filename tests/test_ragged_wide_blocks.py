@@ -2,13 +2,14 @@
 one narrow block is cut into blocks of ``QW`` tokens, so a prefill chunk reads
 each page of its context once a wide block, not once every 8 rows.
 
-- the rule (``ragged_wide_block``) is a function of the launch's shapes;
+- the rule (``ragged_wide_block``) is a width in tokens, a function of the
+  launch's shapes;
 - the plan: the item count the rule predicts, never more than the narrow
   plan's, for runs around every boundary, with and without a window over a
   ring;
 - the launch (Pallas interpreter; the one kernel, once a width): a mixed step
-  against the gather oracle for 1, 2 and 4 query heads a pool head, bit-equal
-  to the narrow plan's output;
+  against the gather oracle for 1, 2, 4 and 8 query heads a pool head (8
+  also over a ring under a window), bit-equal to the narrow plan's output;
 - the engine: greedy tokens of the wide plan are the narrow plan's, and the
   counters add up;
 - the described v5e: the launch at each serving cell's geometry holds the wide
@@ -42,38 +43,45 @@ QW = ra.ragged_wide_block(16, 1, PS, 128, "bfloat16")     # the GPT cells'
 
 @pytest.mark.parametrize("heads,group,dim,dtype,want", [
     (16, 1, 128, "bfloat16", 64),      # gpt_1p3b: one query head a pool head
-    (8, 4, 128, "bfloat16", 16),       # lfm2: 32 query heads over 8 K|V rows
-    (10, 4, 128, "bfloat16", 16),      # phi4 flash: 40 over 10 pair rows
-    (4, 1, 64, "bfloat16", 32),        # a row half as long moves half the bytes
-    (2, 8, 128, "bfloat16", 8),        # 8 query heads a pool head: no wide block
+    (8, 4, 128, "bfloat16", 64),       # lfm2: 32 query heads over 8 K|V rows
+    (10, 4, 128, "bfloat16", 64),      # phi4 flash: 40 over 10 pair rows
+    (4, 1, 64, "bfloat16", 64),        # a row half as long: the same tokens
+    (2, 8, 128, "bfloat16", 64),       # 8 query heads a pool head: 512 rows a head
+    (4, 8, 128, "bfloat16", 64),       # mellum2: 32 query heads over 4 K/V heads
+    (8, 8, 128, "bfloat16", 64),       # solar open2: 64 over 8
 ])
 def test_the_wide_block_follows_the_launchs_shapes(heads, group, dim, dtype, want):
     qw = ra.ragged_wide_block(heads, group, PS, dim, dtype)
     assert qw == want and qw % 8 == 0
-    # an item's scores stay within what its K and V bytes pay for, and its
-    # buffers within the scoped VMEM
+    # a width in tokens, whatever the group, and the widest under the cap
+    # whose buffers fit the scoped VMEM
+    assert QB < qw <= ra._WIDE_TOKENS_MAX
     itemsize = jnp.dtype(dtype).itemsize
-    assert group * qw <= max(2 * dim * itemsize // ra._KV_BYTES_PER_SCORE, group * QB)
     hb = ra.ragged_head_block(heads, PS, dim, dtype)
-    if qw > QB:
-        assert ra._wide_vmem_bytes(hb, group * qw, PS, dim, itemsize) \
-            <= ra._SCOPED_VMEM_BYTES
+    assert ra._wide_vmem_bytes(hb, group * qw, PS, dim, itemsize) \
+        <= ra._SCOPED_VMEM_BYTES
+    assert qw == ra._WIDE_TOKENS_MAX or ra._wide_vmem_bytes(
+        hb, group * (qw + 8), PS, dim, itemsize) > ra._SCOPED_VMEM_BYTES
 
 
 def test_the_wide_block_shrinks_to_what_the_scoped_vmem_holds():
-    """float32 rows of 256 would pay for 256 rows an item; the buffers of 16
-    heads do not fit, and the rule walks down to the widest that does."""
-    qw = ra.ragged_wide_block(16, 1, PS, 256, "float32")
-    assert QB < qw < 256 and qw % 8 == 0
-    assert ra._wide_vmem_bytes(16, qw, PS, 256, 4) <= ra._SCOPED_VMEM_BYTES
-    assert ra._wide_vmem_bytes(16, qw + 8, PS, 256, 4) > ra._SCOPED_VMEM_BYTES
+    """float32 rows of 256, two query heads a pool head: 64 tokens are 128
+    rows a head, whose buffers over 16 heads do not fit, and the rule walks
+    down to the widest width that does."""
+    qw = ra.ragged_wide_block(16, 2, PS, 256, "float32")
+    assert QB < qw < ra._WIDE_TOKENS_MAX and qw % 8 == 0
+    assert ra._wide_vmem_bytes(16, 2 * qw, PS, 256, 4) <= ra._SCOPED_VMEM_BYTES
+    assert ra._wide_vmem_bytes(16, 2 * (qw + 8), PS, 256, 4) > ra._SCOPED_VMEM_BYTES
 
 
 @pytest.mark.parametrize("t_max,qw,want", [
     (528, 8, 0),         # no wide block
     (528, 64, 10),       # the document cell: 8 full blocks and two tails
     (160, 64, 4),        # the chat cell
-    (320, 16, 22),       # the hybrid and long-context cells
+    (320, 16, 22),       # a width of 16 tokens
+    (320, 64, 7),        # the hybrid and long-context cells
+    (384, 64, 8),        # the Solar-Open2 cell
+    (1120, 64, 19),      # the Mellum2 cell
 ])
 def test_wide_capacity_is_a_bound_in_tokens(t_max, qw, want):
     assert ra.ragged_wide_capacity(t_max, QB, qw) == want
@@ -221,20 +229,29 @@ def test_a_run_whose_wide_blocks_do_not_fit_rides_narrow_blocks():
 # the launch, in the Pallas interpreter
 # ---------------------------------------------------------------------------
 
-def _mixed_step(group, qw, window=None, seed=0, heads=2, dim=128):
+def _mixed_step(group, qw, window=None, seed=0, heads=2, dim=128, ring=None):
     """Decode rows, a long chunk (full wide blocks and a wide tail), a short
     chunk (one wide block, mostly padding), a chunk whose tail rides a narrow
-    block, and a run of exactly one narrow block."""
+    block, and a run of exactly one narrow block.  ``ring=R``: each run's
+    table is its slot's ring of ``R`` pages (page-slot ``j`` at ring page
+    ``j mod R``), as a window layer's is."""
     rng = np.random.default_rng(seed)
     spec = [(300, 1), (5, 1), (200, 2 * qw + 19), (0, 9), (129, 1),
             (500, qw + 3), (7, 8)]
     mp = 8
-    perm = rng.permutation(np.arange(1, 1 + len(spec) * mp)).astype(np.int32)
-    runs = [(b, c, perm[i * mp:(i + 1) * mp]) for i, (b, c) in enumerate(spec)]
+    if ring:
+        per_run = ring
+        tables = [(1 + i * ring + np.arange(mp) % ring).astype(np.int32)
+                  for i in range(len(spec))]
+    else:
+        per_run = mp
+        perm = rng.permutation(np.arange(1, 1 + len(spec) * mp)).astype(np.int32)
+        tables = [perm[i * mp:(i + 1) * mp] for i in range(len(spec))]
+    runs = [(b, c, tables[i]) for i, (b, c) in enumerate(spec)]
     (narrow, ns), (wide, ws) = _plans(runs, qw=qw, window=window, max_pages=mp)
     t_max, t = len(narrow["tok_blk"]), ns["n_tokens"]
     q = jnp.asarray(rng.standard_normal((t_max, heads * group, dim)), jnp.bfloat16)
-    pool = (1 + len(spec) * mp, heads, PS, dim)
+    pool = (1 + len(spec) * per_run, heads, PS, dim)
     kp = jnp.asarray(rng.standard_normal(pool), jnp.bfloat16)
     vp = jnp.asarray(rng.standard_normal(pool), jnp.bfloat16)
     tables = np.zeros((t_max, mp), np.int32)
@@ -256,7 +273,7 @@ def _mixed_step(group, qw, window=None, seed=0, heads=2, dim=128):
     return launch(narrow), launch(wide), oracle, ns, ws
 
 
-@pytest.mark.parametrize("group", [1, 2, 4])
+@pytest.mark.parametrize("group", [1, 2, 4, 8])
 def test_a_mixed_step_matches_the_gather_oracle(group):
     qw = ra.ragged_wide_block(2, group, PS, 128, "bfloat16")
     narrow, wide, oracle, ns, ws = _mixed_step(group, qw)
@@ -271,11 +288,16 @@ def test_one_query_head_a_pool_head_gives_the_narrow_plans_bits():
     np.testing.assert_array_equal(wide, narrow)
 
 
-@pytest.mark.parametrize("group,window", [(1, 200), (2, 200), (4, 64)])
-def test_a_window_layers_wide_blocks_give_the_narrow_plans_bits(group, window):
+@pytest.mark.parametrize("group,qw,window,ring", [
+    (1, 16, 200, None), (2, 16, 200, None), (4, 16, 64, None),
+    # Mellum2's window layers: 8 query heads a pool head (512 rows a head in
+    # a wide block), each slot a ring of 4 pages that alias past the window
+    (8, 64, 128, 4),
+])
+def test_a_window_layers_wide_blocks_give_the_narrow_plans_bits(group, qw, window, ring):
     """A wide block visits pages that some of its rows' windows do not reach:
     fully masked for those rows, and wiped by the first page they do read."""
-    narrow, wide, oracle, ns, ws = _mixed_step(group, 16, window=window)
+    narrow, wide, oracle, ns, ws = _mixed_step(group, qw, window=window, ring=ring)
     assert ws["n_items"] < ns["n_items"]
     np.testing.assert_array_equal(wide, narrow)
     np.testing.assert_allclose(wide, oracle, atol=0.03)
@@ -357,7 +379,7 @@ def served():
 
 def test_greedy_tokens_are_the_narrow_plans(served):
     (narrow, _, nf), (wide, _, wf) = served[False], served[True]
-    assert nf == (QB, QB) and wf == (QB, 64)     # float32 rows of 64: 64 tokens
+    assert nf == (QB, QB) and wf == (QB, 64)     # the widest the rule gives
     assert wide == narrow and all(len(t) == 4 for t in wide)
 
 
@@ -444,9 +466,8 @@ def test_the_wide_launch_compiles_at_each_serving_cells_geometry(cell, one_chip,
     described v5e as ``benchmark/aot_compile.py`` lowers a step: the text
     holds ``_ragged_kernel`` twice, once over the wide q operand (outside any
     conditional), and Mosaic, which refuses a kernel over the scoped VMEM,
-    compiles both.  A geometry whose narrow block already carries as many
-    query rows as a wide one would (eight query heads a pool head, the
-    Solar-Open2 cell) has no wide block: its launch is the narrow one alone."""
+    compiles both.  Every served geometry has a wide block, eight query heads
+    a pool head (Solar-Open2, Mellum2: 512 rows a head) among them."""
     from benchmark.harness import manifest as bm
 
     ctx = bm.resolve_cell(cell)
@@ -459,8 +480,7 @@ def test_the_wide_launch_compiles_at_each_serving_cells_geometry(cell, one_chip,
     page, slots = eng["page_size"], eng["num_slots"]
     qb = ra.ragged_token_block(page, dim, eng["cache_dtype"])
     qw = ra.ragged_wide_block(heads, group, page, dim, eng["cache_dtype"], qb)
-    wide = qw > qb
-    assert wide or group * qb >= 64, "a geometry with few query rows a block is widened"
+    assert qw > qb, "every served geometry has a wide block"
     t_max = slots + eng["prefill_token_budget"]
     max_pages = eng["max_context"] // page
     nb = slots + eng["prefill_token_budget"] // qb
@@ -484,10 +504,8 @@ def test_the_wide_launch_compiles_at_each_serving_cells_geometry(cell, one_chip,
             struct((t_max, max_pages), jnp.int32), struct((t_max,), jnp.int32), *plan)
     text = lowered.as_text()
     # the one kernel, launched once a width
-    launches = 2 if wide else 1
-    assert "_ragged_kernel" in text and text.count("tpu_custom_call") == launches
-    if wide:
-        wide_q = f"tensor<{nbw}x{heads}x{group * qw}x{dim}xbf16>"
-        assert wide_q in text, wide_q
+    assert "_ragged_kernel" in text and text.count("tpu_custom_call") == 2
+    wide_q = f"tensor<{nbw}x{heads}x{group * qw}x{dim}xbf16>"
+    assert wide_q in text, wide_q
     assert "stablehlo.case" not in text and "stablehlo.if" not in text
-    assert lowered.compile().as_text().count("tpu_custom_call") >= launches
+    assert lowered.compile().as_text().count("tpu_custom_call") >= 2
